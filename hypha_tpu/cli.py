@@ -231,6 +231,22 @@ async def _run_worker(conf: WorkerConfig) -> None:
         )
     else:
         initialize()  # JAX_COORDINATOR_ADDRESS / _NUM_PROCESSES / _PROCESS_ID env
+    # The in-process executors compile here; config only, no backend touch.
+    from .hw import enable_compile_cache
+
+    log.info("compile cache: %s", enable_compile_cache())
+    if conf.resources.tpu > 0 and conf.executor.runtime == "in-process":
+        # This process will run the step, so it takes the chip now, before
+        # it sells anything: libtpu's start-up holds the GIL for seconds,
+        # and inside a job that stalls this event loop past the 10 s lease —
+        # the arbiter then cancels the job it was starting.
+        import jax
+
+        devices = jax.devices()
+        log.info(
+            "backend up: platform=%s kind=%r count=%d",
+            devices[0].platform, devices[0].device_kind, len(devices),
+        )
     node = _make_node(conf)
     worker = WorkerNode(
         None,

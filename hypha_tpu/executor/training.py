@@ -608,11 +608,10 @@ def _build_mesh(sharding: dict | None):
     if total <= 1:
         return None
     if total > len(jax.devices()):
-        log.warning(
-            "sharding %s needs %d devices, have %d; running unsharded",
-            sharding, total, len(jax.devices()),
+        raise ValueError(
+            f"sharding {sharding} needs {total} devices, "
+            f"this process sees {len(jax.devices())}"
         )
-        return None
     return create_mesh(sizes)
 
 
@@ -637,15 +636,21 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
         )
     # On TPU the pluggable-attention families run the pallas flash kernel by
     # default (sequence-parallel jobs swap in the ring kernel instead, via
-    # _build_mesh); off-TPU the XLA dense path is faster than interpret mode.
+    # _build_mesh), compiled — interpret=False, so a kernel Mosaic refuses
+    # fails the job instead of running interpreted. Off-TPU: XLA dense.
     attn_impl = None
     from ..hw import is_accelerator
 
     if is_accelerator() and not cfg.sharding:
+        import functools
+
         from ..ops.flash_attention import flash_attention
 
-        attn_impl = flash_attention
-        log.info("attention path: pallas flash kernel (backend=%s)", jax.default_backend())
+        attn_impl = functools.partial(flash_attention, interpret=False)
+        log.info(
+            "attention path: pallas flash kernel, compiled (backend=%s)",
+            jax.default_backend(),
+        )
     else:
         log.info("attention path: XLA dense (backend=%s)", jax.default_backend())
 
@@ -739,6 +744,7 @@ def run_training(
     import jax
     import jax.numpy as jnp
 
+    t_enter = time.monotonic()
     work_dir = Path(work_dir)
     cfg = spec.executor.train
     if cfg is None:
@@ -782,6 +788,13 @@ def run_training(
     )
 
     first_batch = next(stream)
+    # The process that runs the step says which device it has
+    # (chip_smoke.py reports this line, not its own view).
+    devices = jax.devices()
+    log.info(
+        "device: platform=%s kind=%r count=%d",
+        devices[0].platform, devices[0].device_kind, len(devices),
+    )
     model, params, causal_lm, has_aux = _init_model(cfg, session, work_dir, first_batch)
     mesh = _build_mesh(cfg.sharding)
 
@@ -1724,7 +1737,42 @@ def run_training(
             round_losses.append(loss)
             result.losses.append(loss)
 
+    # Per-round facts for the log. Step times are host-clock and end in the
+    # loss fetch (run_one), so they cover the device work; with the input
+    # pipeline's deferred read they cover step n-1's instead.
+    step_times: list[float] = []
+    round_mark = {"t0": time.monotonic(), "rounds": 0, "losses": 0, "tokens": 0}
+
+    def log_round() -> None:
+        if result.rounds == round_mark["rounds"] or not step_times:
+            return
+        losses = result.losses[round_mark["losses"]:]
+        stats = jax.local_devices()[0].memory_stats() or {}
+        now = time.monotonic()
+        log.info(
+            "round %d done: batch=%d steps=%d tokens=%d wall_s=%.3f "
+            "first_step_s=%.3f median_step_s=%.4f loss_first=%.4f "
+            "loss_last=%.4f loss_mean=%.4f nonfinite=%d peak_bytes=%s",
+            result.rounds - 1, cfg.batch_size, len(step_times),
+            round_mark["tokens"],
+            now - round_mark["t0"], step_times[0],
+            float(np.median(step_times)),
+            losses[0] if losses else math.nan,
+            losses[-1] if losses else math.nan,
+            float(np.mean(losses)) if losses else math.nan,
+            sum(not math.isfinite(x) for x in losses),
+            stats.get("peak_bytes_in_use"),
+        )
+        step_times.clear()
+        round_mark.update(
+            t0=now, rounds=result.rounds, losses=len(result.losses), tokens=0
+        )
+
     t0 = time.monotonic()
+    log.info(
+        "setup done: setup_s=%.3f (first slice, model init, placement)",
+        t0 - t_enter,
+    )
     try:
         for batch in batches():
             if should_stop is not None and should_stop():
@@ -1737,6 +1785,7 @@ def run_training(
                 if not finish_stream_sync():
                     break
             rtrace.batch(round_num)
+            step_t0 = time.monotonic()
             if mh is not None:
                 state, metrics, loss = _with_deadline(
                     lambda b=batch: run_one(b), mh_bound("step"), "train step"
@@ -1762,6 +1811,9 @@ def run_training(
                     flush_pending_loss()  # older deferred losses first
                     round_losses.append(loss)
                     result.losses.append(loss)
+            step_times.append(time.monotonic() - step_t0)
+            if isinstance(batch, dict) and "input_ids" in batch:
+                round_mark["tokens"] += int(np.size(batch["input_ids"]))
             result.batches += 1
             round_samples += cfg.batch_size
             if report_quality:
@@ -1797,10 +1849,12 @@ def run_training(
                         break
                 else:
                     countdown -= 1
+            log_round()
             if max_batches is not None and result.batches >= max_batches:
                 log.warning("max_batches=%d reached; stopping", max_batches)
                 break
         flush_pending_loss()
+        log_round()  # a round closed on a break path
     finally:
         rtrace.close_inner()
         # Stop the input pipeline's prefetch thread NOW (the generator's
@@ -1844,8 +1898,10 @@ def main(argv: list[str] | None = None) -> int:
     if not isinstance(spec, JobSpec):
         raise SystemExit(f"--job does not decode to a JobSpec: {type(spec)}")
 
+    from ..hw import enable_compile_cache
     from .bridge_client import Session
 
+    enable_compile_cache()
     with Session(args.socket) as session:
         run_training(session, args.work_dir, spec, max_batches=args.max_batches)
     return 0

@@ -272,14 +272,19 @@ def _dkv_kernel(
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _tpu_params(*parallel_then_arbitrary: str):
-    """dimension_semantics for the TPU backend; ignored under interpret."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
+def _tpu_kwargs(interpret: bool) -> dict:
+    """pallas_call kwargs carrying the TPU dimension_semantics (batch·head
+    and the outer block axis parallel, the reduction axis arbitrary); the
+    interpreter takes none."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
 
-        return pltpu.CompilerParams(dimension_semantics=parallel_then_arbitrary)
-    except Exception:  # pragma: no cover — old pallas layouts
-        return None
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        )
+    }
 
 
 def _kv_index(n_heads: int, n_kv: int):
@@ -304,10 +309,7 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
     num_q, num_k = seq_q // block_q, seq_k // block_k
     grid = (bh, num_q, num_k)
     kv = _kv_index(n_heads, n_kv)
-    kwargs = {}
-    params = _tpu_params("parallel", "parallel", "arbitrary")
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
+    kwargs = _tpu_kwargs(interpret)
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel,
@@ -359,10 +361,7 @@ def _bwd_impl(
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0))
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
-    kwargs = {}
-    params = _tpu_params("parallel", "parallel", "arbitrary")
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
+    kwargs = _tpu_kwargs(interpret)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -477,7 +476,8 @@ def flash_attention(
     requires Sq % block_q == 0, Sk % block_k == 0 and D <= 128; anything else
     transparently falls back to the XLA reference path (same numerics, denser
     memory traffic). ``interpret=None`` auto-selects interpret mode off-TPU
-    so tests exercise the kernels on CPU.
+    so tests exercise the kernels on CPU; code on the chip path passes
+    ``interpret=False``.
 
     Default blocks come from on-chip sweeps (TPU v5e, r3+r4): forward
     (512, 512) — (128, 128) halved throughput, per-cell overhead dominates

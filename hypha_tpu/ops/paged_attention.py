@@ -335,14 +335,10 @@ def _ragged_attention_tpu(
         operands += [ks, vs]
 
     kwargs = {}
-    try:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    except Exception:  # pragma: no cover — old pallas layouts
-        pass
-    if interpret:
-        kwargs.pop("compiler_params", None)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -384,13 +380,14 @@ def paged_attention(
     use_kernel: bool | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Ragged paged attention dispatcher: the Pallas kernel on TPU-class
-    backends, the masked-block XLA fallback elsewhere. ``use_kernel``
-    forces the choice (tests run the kernel in interpret mode)."""
+    """Ragged paged attention dispatcher: the compiled Pallas kernel on the
+    TPU, the masked-block XLA fallback elsewhere. ``use_kernel`` forces the
+    choice (tests run the kernel in interpret mode)."""
     if use_kernel is None:
         from ..hw import is_accelerator
 
-        use_kernel = is_accelerator()
+        # Chosen from the platform: compiled, never interpreted.
+        use_kernel, interpret = is_accelerator(), False
     if use_kernel:
         if interpret is None:
             from ..hw import interpret_default

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from einops import repeat
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..hw import shard_map_compat as shard_map
-
 __all__ = ["make_ring_attention", "ring_attention"]
 
 _NEG = -1e30
@@ -97,7 +95,7 @@ def make_ring_attention(
             causal=causal,
             scale=scale,
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec, spec, spec),

@@ -113,9 +113,7 @@ def merge_block_params(outer: Any, stacked: Any, prefix: str = "h_"):
 def _make_pipe(block_apply, mesh, n_micro: int, dp_axis: str):
     from jax.sharding import PartitionSpec as P
 
-    from ..hw import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         lambda stacked, x: pipeline_blocks(block_apply, stacked, x, n_micro),
         mesh=mesh,
         in_specs=(P("pp"), P(dp_axis)),

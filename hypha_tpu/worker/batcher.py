@@ -1,9 +1,9 @@
 """Cross-request batching for the serving path.
 
 Concurrent ``GenerateRequest``s used to run independent B=1 decodes that
-competed for the chip; decode throughput scales almost linearly with batch
-(SERVING_r03: B=8 delivered 24x the B=1 tok/s), so a serving worker must
-coalesce. The reference has no inference path at all (its Executor union is
+competed for the chip; decode at small batch is bound by the per-step
+weight read, which a batch shares, so a serving worker must coalesce. The
+reference has no inference path at all (its Executor union is
 Train|Aggregate, crates/messages/src/lib.rs:627-631) — this is the
 continuous-batching window every production server implements.
 

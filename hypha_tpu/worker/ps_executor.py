@@ -65,6 +65,7 @@ from safetensors.numpy import load_file, save_file
 from .. import aio
 from .. import compress
 from .. import native
+from ..codec import native_codec_active
 from ..ft.adaptive import LinkTable
 from ..ft.durable import (
     GENERATION_KEY,
@@ -2402,6 +2403,7 @@ class ParameterServerExecutor(JobExecutor):
         mean pseudo-gradient and of the applied outer update, plus the
         accepted-delta count.
         """
+        t0 = time.monotonic()
         if accum is None or accum.folds == 0:
             accum = _RoundAccum() if accum is None else accum
             for path, samples in received.values():
@@ -2436,6 +2438,14 @@ class ParameterServerExecutor(JobExecutor):
         save_file(update, str(out))
         save_file(momentum, str(momentum_tmp))
         os.replace(momentum_tmp, momentum_file)
+        # native_kernels=False means the numpy fallback ran: same numbers,
+        # the slow outer step — said aloud so no run mistakes one for the other.
+        log.info(
+            "ps outer step: round=%d deltas=%d tensors=%d native_kernels=%s "
+            "native_cbor=%s wall_s=%.3f",
+            round_num, len(received), len(update), native.native_available(),
+            native_codec_active(), time.monotonic() - t0,
+        )
         return out
 
     @staticmethod

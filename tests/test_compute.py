@@ -463,7 +463,7 @@ def test_flash_attention_matches_xla_reference():
         flash_attention(q, k, v, causal=True, block_k_bwd=200)
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_flash_attention_grad_matches_xla_reference():
@@ -524,7 +524,7 @@ def test_flash_attention_in_train_step():
     assert bool(jnp.isfinite(metrics["grad_norm"])) and float(metrics["grad_norm"]) > 0
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_moe_expert_parallel_matches_single_device():
@@ -572,7 +572,7 @@ def test_moe_expert_parallel_matches_single_device():
     )
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_chunked_causal_ce_matches_dense_loss_and_grads():

@@ -39,12 +39,11 @@ _CHILD = textwrap.dedent("""
     assert initialize(MultihostConfig({addr!r}, 2, rank))
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from hypha_tpu.hw import shard_map_compat
     devs = jax.devices()
     assert len(devs) == 4, devs  # 2 procs x 2 virtual devices = global view
     mesh = Mesh(devs, ("dp",))
     out = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             lambda x: jax.lax.psum(x, "dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P(),
         )

@@ -61,9 +61,7 @@ def test_pipeline_forward_matches_plain_model():
     mesh = create_mesh({"dp": 2, "pp": 4})
     from jax.sharding import PartitionSpec as P
 
-    from hypha_tpu.hw import shard_map_compat
-
-    pipe = shard_map_compat(
+    pipe = jax.shard_map(
         lambda s, x: pipeline_blocks(block_apply, s, x, n_micro=2),
         mesh=mesh, in_specs=(P("pp"), P("dp")), out_specs=P("dp"),
         check_vma=False,
@@ -77,7 +75,7 @@ def test_pipeline_forward_matches_plain_model():
     np.testing.assert_allclose(h_pipe, np.asarray(h_ref), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_pp_train_step_matches_plain_loss_and_grads():
@@ -128,7 +126,7 @@ def test_pipeline_rejects_indivisible_shapes():
         make_gpt2_pp_train_step(cfg, mesh, n_micro=2)
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_llama_pp_train_step_matches_plain_model():
@@ -163,7 +161,7 @@ def test_llama_pp_train_step_matches_plain_model():
     assert float(metrics["loss"]) < loss_ref
 
 
-@pytest.mark.slow  # 15-27 s each: recovered by the shard_map compat
+@pytest.mark.slow  # 15-27 s each
 # shim but too heavy for the tier-1 wall-clock budget; `make test` minus
 # the marker filter still runs them
 def test_pp_honors_remat():
