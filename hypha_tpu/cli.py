@@ -117,9 +117,13 @@ def _make_node(conf, *, registry_server: bool = False, peer_id: str | None = Non
 
 def _telemetry_for(conf, node=None):
     """Provider bundle from the config's telemetry section; OTEL_* env wins
-    (reference wiring: hypha-scheduler.rs:55-94, docs/worker.md:188-218)."""
-    from .telemetry import init_telemetry, instrument_node
+    (reference wiring: hypha-scheduler.rs:55-94, docs/worker.md:188-218).
+    ``telemetry.trace_dir`` switches the round-trace spans on, under the
+    node's name."""
+    from .telemetry import init_telemetry, instrument_node, trace
 
+    if conf.telemetry.trace_dir:
+        trace.enable(conf.telemetry.trace_dir, node=conf.name)
     telemetry = init_telemetry(
         service_name=conf.telemetry.service_name or f"hypha-{conf.name}",
         endpoint=conf.telemetry.endpoint,

@@ -289,10 +289,15 @@ class SlicePrefetcher:
 def _boundary_wait(acquire: Callable[[], str], span_ctx) -> str:
     """Time (and trace) the training thread's slice acquisition — the
     slice-boundary stall the prefetcher exists to hide. ``span_ctx`` is a
-    zero-arg callable returning ``(traceparent, node)`` so the span joins
-    the current round's trace (no-op when tracing is off)."""
-    parent, node = span_ctx() if span_ctx is not None else (None, None)
-    span = trace.begin("input_wait", parent=parent, node=node)
+    zero-arg callable returning ``(traceparent, node, round)`` so the span
+    joins the current round's trace (no-op when tracing is off)."""
+    parent, node, round_num = (
+        span_ctx() if span_ctx is not None else (None, None, None)
+    )
+    span = trace.begin(
+        "input_wait", parent=parent, node=node,
+        attrs=None if round_num is None else {"round": round_num},
+    )
     t0 = time.monotonic()
     try:
         return acquire()
@@ -309,7 +314,7 @@ def stream_batches(
     *,
     pipeline: bool = False,
     prefetch: int | None = None,
-    span_ctx: "Callable[[], tuple[Any, Any]] | None" = None,
+    span_ctx: "Callable[[], tuple[Any, Any, Any]] | None" = None,
     unlink_consumed: bool = False,
 ) -> Iterator[dict[str, np.ndarray]]:
     """Infinite batch stream: ``fetch_slice()`` blocks until the scheduler

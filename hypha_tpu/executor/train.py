@@ -197,8 +197,9 @@ def chunked_causal_ce(
         nll = (lse - picked.astype(jnp.float32)) * valid
         return nll.sum(), valid.sum()
 
-    sums, counts = jax.lax.map(lambda args: one(*args), (hs, ls))
-    return sums.sum() / jnp.maximum(counts.sum(), 1)
+    with jax.named_scope("loss"):
+        sums, counts = jax.lax.map(lambda args: one(*args), (hs, ls))
+        return sums.sum() / jnp.maximum(counts.sum(), 1)
 
 
 def make_train_step(
@@ -246,7 +247,8 @@ def make_train_step(
         (total, (loss, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, state.step
         )
-        new_state = state.apply_gradients(grads)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads)
         metrics = {
             "loss": loss,
             "total_loss": total,
@@ -293,7 +295,8 @@ def make_loss_fn(
         if has_aux:
             out, aux = out
         if loss_override is not None:
-            loss = loss_override(out, batch)
+            with jax.named_scope("loss"):
+                loss = loss_override(out, batch)
             return loss + aux, (loss, aux)
         if causal_lm:
             # Teacher forcing over the target stream. Three layouts:
@@ -318,7 +321,8 @@ def make_loss_fn(
         else:
             logits = out
             labels = batch["labels"]
-        loss = compute_loss(loss_kind, logits, labels)
+        with jax.named_scope("loss"):
+            loss = compute_loss(loss_kind, logits, labels)
         return loss + aux, (loss, aux)
 
     return loss_fn

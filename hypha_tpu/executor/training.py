@@ -722,6 +722,13 @@ def adopt_schedule(resp: ProgressResponse, countdown: "int | None") -> "int | No
     return countdown
 
 
+def _tree_nbytes(tree) -> int:
+    """Bytes of a host tree's leaves (a span attribute: rate = bytes / s)."""
+    import jax
+
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
 def run_training(
     session,
     work_dir: Path | str,
@@ -738,8 +745,8 @@ def run_training(
     ``max_batches`` is a safety valve for tests. ``should_stop`` is polled
     between batches — the in-process executor's cooperative cancellation.
     ``trace_node`` labels this worker's round-trace spans (telemetry.trace;
-    the in-process executor passes its peer id, subprocess executors label
-    via $HYPHA_TRACE_NODE) — ignored while tracing is off.
+    the in-process executor passes its peer id, a subprocess executor the
+    ``--trace-node`` its worker handed it) — ignored while tracing is off.
     """
     import jax
     import jax.numpy as jnp
@@ -766,7 +773,7 @@ def run_training(
     def input_span_ctx():
         # The most recent round context handed down by the scheduler —
         # good enough to attribute a mid-round input stall to its round.
-        return rtrace.tp, rtrace.node
+        return rtrace.tp, rtrace.node, rtrace.tp_round if rtrace.tp else None
 
     model_spec = dict(cfg.model)
     input_names = model_spec.get("input_names")
@@ -1216,6 +1223,10 @@ def run_training(
                 round_num, meta.get("epoch", "?"), len(flat),
             )
 
+    # Host-side moments of the step being timed: the generator wait that
+    # handed it its batch, and when its step() returned (set by run_one).
+    step_clock = {"input_wait_s": 0.0, "dispatched": 0.0}
+
     def batches() -> Iterator[Any]:
         yield first_batch
         while True:
@@ -1224,106 +1235,43 @@ def run_training(
             # Total input wait: host assembly + any slice acquisition that
             # ran inline — the fraction databench asserts the pipeline
             # shrinks (recording only; values and order are untouched).
-            DATA_METRICS.note_input_wait(time.monotonic() - t0)
+            # The step that takes this batch carries it as ``input_wait_s``.
+            step_clock["input_wait_s"] = time.monotonic() - t0
+            DATA_METRICS.note_input_wait(step_clock["input_wait_s"])
             if batch is None:
                 return
             yield batch
 
-    def do_update() -> bool:
-        """Ship Δθ, wait for the PS broadcast, merge. True = next round."""
-        nonlocal state, anchor, host_anchor, round_num, round_samples
+    # One timer a phase of the blocking outer sync (telemetry.trace.phase):
+    # the seconds go into ``sync`` for the ``sync done`` line, tracing on or
+    # off, and the same interval into a span when it is on. This process
+    # holds the chip, so a phase also enters the profiler's trace under its
+    # span's name (a flag check while no profiler session is open).
+    sync: dict[str, float] = {}
+
+    def sync_phase(
+        name: str, *, parent=None, key: str | None = None, min_s: float = 0.0,
+        **attrs,
+    ):
+        return trace.phase(
+            name, parent=parent, attrs=attrs, node=rtrace.node,
+            into=sync if key else None, key=key, min_s=min_s,
+            annotation=jax.profiler.TraceAnnotation(name),
+        )
+
+    def log_sync(done_round: int, bytes_up: int, bytes_down: int) -> None:
+        log.info(
+            "sync done: round=%d encode_s=%.3f upload_s=%.3f wait_s=%.3f "
+            "merge_s=%.3f cleanup_s=%.3f bytes_up=%d bytes_down=%d",
+            done_round, sync.get("encode_s", 0.0), sync.get("upload_s", 0.0),
+            sync.get("wait_s", 0.0), sync.get("merge_s", 0.0),
+            sync.get("cleanup_s", 0.0), bytes_up, bytes_down,
+        )
+
+    def await_round_update(delta_path: Path) -> tuple[dict, dict]:
+        """Results-stream events until this round's update broadcast:
+        the event and its meta."""
         nonlocal ps_generation
-        rtrace.close_inner()
-        round_tp = rtrace.ctx(round_num)
-        send_status_gated(
-            Progress(
-                kind=ProgressKind.UPDATE, job_id=spec.job_id,
-                traceparent=round_tp,
-            )
-        )
-        enc_span = trace.begin(
-            "encode", parent=round_tp,
-            attrs={"round": round_num, "codec": wire_codec}, node=rtrace.node,
-        )
-        host_params = None
-        if mh is not None:
-            # Collective Δθ: the allgather every process joins (OP_GATHER),
-            # then host-side subtraction against the host anchor — param
-            # shards on other processes' devices cannot be device_get here.
-            host_params = _with_deadline(
-                lambda: mh.gather(state.params), mh_bound("gather"),
-                "param gather",
-            )
-            compiled_once["gather"] = True
-            host_delta = jax.tree.map(
-                lambda p, a: p - a, host_params, host_anchor
-            )
-        else:
-            delta = extract_delta(state.params, anchor)
-            host_delta = jax.device_get(delta)
-        delta_path = work_dir / f"delta-{round_num}.safetensors"
-        # One send-side entry point for every codec (hypha_tpu.compress):
-        # int8/int4 ship Q(Δθ + e) as an HQD1 frame and keep
-        # e' = (Δθ + e) − Q(Δθ + e) for the next round (quantization error
-        # is re-shipped, never dropped); bf16 halves the upload; the PS
-        # widens/accumulates in f32 in every case.
-        wire_flat = flatten_tree(host_delta)
-        if (
-            delta_ef is not None
-            and wire_codec not in compress.QUANT_CODECS
-            and delta_ef.tensors
-        ):
-            # The link recovered (per-link hint switched quant -> base
-            # codec) with a residual still pending: fold it into this
-            # upload — EF's promise is that quantization error is
-            # re-shipped, never dropped, and an uncompressed wire can
-            # carry it exactly.
-            wire_flat = delta_ef.compensate(wire_flat)
-            delta_ef.reset()
-        compress.write_delta(
-            delta_path, wire_flat, wire_codec, ef=delta_ef
-        )
-        trace.finish(enc_span)
-        up_span = trace.begin(
-            "upload", parent=round_tp,
-            attrs={
-                "round": round_num, "codec": wire_codec,
-                "bytes": delta_path.stat().st_size,
-            },
-            node=rtrace.node,
-        )
-        session.send_resource(
-            cfg.updates,
-            delta_path.name,
-            # The Send reference's resource tag routes the stream to the
-            # right consumer on the PS node (job-unique, set by the
-            # scheduler's orchestrator).
-            resource=cfg.updates.ref.resource or "updates",
-            # round tags the delta so an elastic parameter server can
-            # reject a stale one (arriving after its round aggregated at
-            # quorum) instead of folding it into the wrong mean. Traced
-            # jobs additionally stamp the round context so the parameter
-            # server's spans join the round's trace.
-            meta=rtrace.stamp(
-                {"num_samples": float(round_samples), "round": round_num},
-                round_num,
-            ),
-        )
-        trace.finish(up_span)
-        mean_loss = float(np.mean(round_losses)) if round_losses else math.nan
-        round_metrics = {"loss": mean_loss, "samples": float(round_samples)}
-        if report_quality:
-            round_metrics.update(quality_metrics(mean_loss))
-            round_metrics["delta_norm"] = delta_norm_of(wire_flat)
-        send_status_gated(
-            Progress(
-                kind=ProgressKind.METRICS,
-                job_id=spec.job_id,
-                round=round_num,
-                metrics=round_metrics,
-                traceparent=round_tp,
-            )
-        )
         with session.receive(cfg.results) as events:
             while True:
                 # Not bare next(): a severed bridge ends the SSE stream,
@@ -1374,42 +1322,167 @@ def run_training(
                     # merged it — absorbing again would double-apply.
                     (work_dir / event["path"]).unlink(missing_ok=True)
                     continue
-                break
+                return event, meta
+
+    def do_update() -> bool:
+        """Ship Δθ, wait for the PS broadcast, merge. True = next round."""
+        nonlocal state, anchor, host_anchor, round_num, round_samples
+        rtrace.close_inner()
+        round_tp = rtrace.ctx(round_num)
+        send_status_gated(
+            Progress(
+                kind=ProgressKind.UPDATE, job_id=spec.job_id,
+                traceparent=round_tp,
+            )
+        )
+        sync.clear()
+        host_params = None
+        with sync_phase(
+            "encode", parent=round_tp, key="encode_s",
+            round=round_num, codec=wire_codec,
+        ) as enc:
+            # Ends when the bytes are on the host: device_get blocks on the
+            # subtraction and the device-to-host copy.
+            with sync_phase("encode.extract", parent=enc.span) as ph:
+                if mh is not None:
+                    # Collective Δθ: the allgather every process joins
+                    # (OP_GATHER), then host-side subtraction against the
+                    # host anchor — param shards on other processes' devices
+                    # cannot be device_get here.
+                    host_params = _with_deadline(
+                        lambda: mh.gather(state.params), mh_bound("gather"),
+                        "param gather",
+                    )
+                    compiled_once["gather"] = True
+                    host_delta = jax.tree.map(
+                        lambda p, a: p - a, host_params, host_anchor
+                    )
+                else:
+                    delta = extract_delta(state.params, anchor)
+                    host_delta = jax.device_get(delta)
+                ph.set("bytes", _tree_nbytes(host_delta))
+            delta_path = work_dir / f"delta-{round_num}.safetensors"
+            with sync_phase("encode.write", parent=enc.span) as ph:
+                # One send-side entry point for every codec
+                # (hypha_tpu.compress): int8/int4 ship Q(Δθ + e) as an HQD1
+                # frame and keep e' = (Δθ + e) − Q(Δθ + e) for the next
+                # round (quantization error is re-shipped, never dropped);
+                # bf16 halves the upload; the PS widens/accumulates in f32
+                # in every case.
+                wire_flat = flatten_tree(host_delta)
+                if (
+                    delta_ef is not None
+                    and wire_codec not in compress.QUANT_CODECS
+                    and delta_ef.tensors
+                ):
+                    # The link recovered (per-link hint switched quant ->
+                    # base codec) with a residual still pending: fold it
+                    # into this upload — EF's promise is that quantization
+                    # error is re-shipped, never dropped, and an
+                    # uncompressed wire can carry it exactly.
+                    wire_flat = delta_ef.compensate(wire_flat)
+                    delta_ef.reset()
+                compress.write_delta(
+                    delta_path, wire_flat, wire_codec, ef=delta_ef
+                )
+                bytes_up = delta_path.stat().st_size
+                ph.set("bytes", bytes_up)
+                ph.set("leaves", len(wire_flat))
+        with sync_phase(
+            "upload", parent=round_tp, key="upload_s",
+            round=round_num, codec=wire_codec, bytes=bytes_up,
+        ):
+            session.send_resource(
+                cfg.updates,
+                delta_path.name,
+                # The Send reference's resource tag routes the stream to the
+                # right consumer on the PS node (job-unique, set by the
+                # scheduler's orchestrator).
+                resource=cfg.updates.ref.resource or "updates",
+                # round tags the delta so an elastic parameter server can
+                # reject a stale one (arriving after its round aggregated at
+                # quorum) instead of folding it into the wrong mean. Traced
+                # jobs additionally stamp the round context so the parameter
+                # server's spans join the round's trace.
+                meta=rtrace.stamp(
+                    {"num_samples": float(round_samples), "round": round_num},
+                    round_num,
+                ),
+            )
+        # What the worker waits for transport and parameter server together:
+        # from the upload's end to the broadcast event that ends the loop.
+        with sync_phase(
+            "await_update", parent=round_tp, key="wait_s", round=round_num
+        ):
+            mean_loss = float(np.mean(round_losses)) if round_losses else math.nan
+            round_metrics = {"loss": mean_loss, "samples": float(round_samples)}
+            if report_quality:
+                round_metrics.update(quality_metrics(mean_loss))
+                round_metrics["delta_norm"] = delta_norm_of(wire_flat)
+            send_status_gated(
+                Progress(
+                    kind=ProgressKind.METRICS,
+                    job_id=spec.job_id,
+                    round=round_num,
+                    metrics=round_metrics,
+                    traceparent=round_tp,
+                )
+            )
+            event, meta = await_round_update(delta_path)
         apply_codec_hint(meta)
-        merge_span = trace.begin(
+        update_file = work_dir / event["path"]
+        with sync_phase(
             "merge",
             # Parent under the broadcast's context when the PS stamped
             # one (the same round trace), else the scheduler's round.
-            parent=meta.get(TRACEPARENT_KEY) or round_tp,
-            attrs={"round": round_num}, node=rtrace.node,
-        )
-        update_file = work_dir / event["path"]
-        # read_delta sniffs the format: a quantized (HQD1) broadcast
-        # dequantizes to f32, a SafeTensors one loads as before.
-        flat = compress.read_delta(update_file)
-        if mh is not None:
-            # followers mirror the merge dispatch; bounded like the step
-            # broadcasts — a lost follower must fail the job, not hang it
-            _with_deadline(
-                lambda: mh.merge(flat), mh_bound("merge"), "merge broadcast"
-            )
-            compiled_once["merge"] = True
-        update = unflatten_like(flat, state.params)
-        state = state.replace(params=merge_update(state.params, update))
-        if mh is not None:
-            # New anchor = merged params, assembled on the host from the
-            # round's gathered params + the same update the device merge
-            # applied — no second collective needed.
-            host_anchor = jax.tree.map(
-                lambda p, u: p + np.asarray(u, p.dtype), host_params, update
-            )
-        else:
-            anchor = snapshot(state.params)
-        trace.finish(merge_span)
-        delta_path.unlink(missing_ok=True)
-        # The broadcast update is merged — drop it, or a long job accumulates
-        # one full-parameter-sized file per round under work_dir/incoming.
-        update_file.unlink(missing_ok=True)
+            parent=meta.get(TRACEPARENT_KEY) or round_tp, key="merge_s",
+            round=round_num,
+        ) as mrg:
+            with sync_phase("merge.read", parent=mrg.span) as ph:
+                # read_delta sniffs the format: a quantized (HQD1) broadcast
+                # dequantizes to f32, a SafeTensors one loads as before.
+                flat = compress.read_delta(update_file)
+                bytes_down = update_file.stat().st_size
+                ph.set("bytes", bytes_down)
+                ph.set("leaves", len(flat))
+            # Ends when the merge and the new anchor are DISPATCHED: nothing
+            # here waits for the device, and a span must not add a
+            # synchronisation. What the device still owes is paid in the
+            # next round's first step (its fetch_s).
+            with sync_phase(
+                "merge.apply", parent=mrg.span,
+                ends_at="dispatch", leaves=len(flat),
+            ):
+                if mh is not None:
+                    # followers mirror the merge dispatch; bounded like the
+                    # step broadcasts — a lost follower must fail the job,
+                    # not hang it
+                    _with_deadline(
+                        lambda: mh.merge(flat), mh_bound("merge"),
+                        "merge broadcast",
+                    )
+                    compiled_once["merge"] = True
+                update = unflatten_like(flat, state.params)
+                state = state.replace(params=merge_update(state.params, update))
+                if mh is not None:
+                    # New anchor = merged params, assembled on the host from
+                    # the round's gathered params + the same update the
+                    # device merge applied — no second collective needed.
+                    host_anchor = jax.tree.map(
+                        lambda p, u: p + np.asarray(u, p.dtype), host_params, update
+                    )
+                else:
+                    anchor = snapshot(state.params)
+        with sync_phase(
+            "cleanup", parent=round_tp, key="cleanup_s",
+            min_s=trace.SLOW_CLEANUP_S, round=round_num,
+        ):
+            delta_path.unlink(missing_ok=True)
+            # The broadcast update is merged — drop it, or a long job
+            # accumulates one full-parameter-sized file per round under
+            # work_dir/incoming.
+            update_file.unlink(missing_ok=True)
+        log_sync(round_num, bytes_up, bytes_down)
         resp = send_status_gated(
             Progress(
                 kind=ProgressKind.UPDATE_RECEIVED, job_id=spec.job_id,
@@ -1482,13 +1555,16 @@ def run_training(
                 traceparent=round_tp,
             )
         )
-        enc_span = trace.begin(
-            "encode", parent=round_tp,
-            attrs={"round": round_num, "codec": wire_codec}, node=rtrace.node,
-        )
-        delta = extract_delta(state.params, anchor)
-        host_delta = jax.device_get(delta)
-        wire_flat = flatten_tree(host_delta)
+        sync.clear()
+        with sync_phase(
+            "encode", parent=round_tp, key="encode_s",
+            round=round_num, codec=wire_codec,
+        ) as enc:
+            with sync_phase("encode.extract", parent=enc.span) as ph:
+                delta = extract_delta(state.params, anchor)
+                host_delta = jax.device_get(delta)
+                ph.set("bytes", _tree_nbytes(host_delta))
+            wire_flat = flatten_tree(host_delta)
         P = int(shard_map.fragments) or len(shard_map.shards)
         if shard_ctx["parts"] is None:
             # Deterministic by (name, size) only — shards, reducers and
@@ -1504,113 +1580,131 @@ def run_training(
             ]
         parts = shard_ctx["parts"]
         samples = float(round_samples)
-        trace.finish(enc_span)
-        up_span = trace.begin(
-            "upload", parent=round_tp,
-            attrs={"round": round_num, "codec": wire_codec, "parts": len(parts)},
-            node=rtrace.node,
-        )
         paths: dict[int, Path] = {}
-        for p, names in enumerate(parts):
-            tag = FragmentTag(round=round_num, fragment_id=p, fragments=P)
-            path = work_dir / f"delta-{round_num}-p{p}.safetensors"
-            compress.write_delta(
-                path, {n: wire_flat[n] for n in names}, wire_codec,
-                ef=shard_ctx["efs"][p], tag=tag.header(),
+        # Here the parts are written as they are pushed, so the wire write
+        # is inside ``upload`` and ``encode`` has no ``encode.write``.
+        with sync_phase(
+            "upload", parent=round_tp, key="upload_s",
+            round=round_num, codec=wire_codec, parts=len(parts),
+        ):
+            for p, names in enumerate(parts):
+                tag = FragmentTag(round=round_num, fragment_id=p, fragments=P)
+                path = work_dir / f"delta-{round_num}-p{p}.safetensors"
+                compress.write_delta(
+                    path, {n: wire_flat[n] for n in names}, wire_codec,
+                    ef=shard_ctx["efs"][p], tag=tag.header(),
+                )
+                paths[p] = path
+                _push_part(p, path, samples)
+        bytes_up = sum(path.stat().st_size for path in paths.values())
+        with sync_phase(
+            "await_update", parent=round_tp, key="wait_s", round=round_num
+        ):
+            mean_loss = float(np.mean(round_losses)) if round_losses else math.nan
+            round_metrics = {"loss": mean_loss, "samples": samples}
+            if report_quality:
+                round_metrics.update(quality_metrics(mean_loss))
+                round_metrics["delta_norm"] = delta_norm_of(wire_flat)
+            send_status_gated(
+                Progress(
+                    kind=ProgressKind.METRICS,
+                    job_id=spec.job_id,
+                    round=round_num,
+                    metrics=round_metrics,
+                    traceparent=round_tp,
+                )
             )
-            paths[p] = path
-            _push_part(p, path, samples)
-        trace.finish(up_span)
-        mean_loss = float(np.mean(round_losses)) if round_losses else math.nan
-        round_metrics = {"loss": mean_loss, "samples": samples}
-        if report_quality:
-            round_metrics.update(quality_metrics(mean_loss))
-            round_metrics["delta_norm"] = delta_norm_of(wire_flat)
-        send_status_gated(
-            Progress(
-                kind=ProgressKind.METRICS,
-                job_id=spec.job_id,
-                round=round_num,
-                metrics=round_metrics,
-                traceparent=round_tp,
-            )
-        )
-        gens = shard_ctx["gens"]
-        got: dict[int, Path] = {}
-        with session.receive(cfg.results) as events:
-            while len(got) < P:
-                event = next(events, None)
-                if event is None:
-                    raise RuntimeError(
-                        "results stream ended before every part's update "
-                        "broadcast"
-                    )
-                meta = event.get("meta") or {}
-                try:
-                    sid = int(meta.get(SHARD_KEY, 0))
-                except (TypeError, ValueError):
-                    sid = 0
-                gens[sid], resend = restart_signal(meta, gens.get(sid))
-                if resend:
-                    # That shard restarted: re-send its still-un-acked
-                    # parts — the shard's journal dedup absorbs any copy
-                    # whose original did land.
-                    for p, path in paths.items():
-                        if (
-                            p in got
-                            or not path.is_file()
-                            or shard_of(p, len(shard_map.shards)) != sid
-                        ):
-                            continue
-                        log.warning(
-                            "ps shard %d restart detected; re-sending "
-                            "round %d part %d", sid, round_num, p,
+            gens = shard_ctx["gens"]
+            got: dict[int, Path] = {}
+            with session.receive(cfg.results) as events:
+                while len(got) < P:
+                    event = next(events, None)
+                    if event is None:
+                        raise RuntimeError(
+                            "results stream ended before every part's update "
+                            "broadcast"
                         )
-                        _push_part(p, path, samples)
-                if meta.get(RESYNC_KEY) or meta.get(CATCHUP_KEY):
-                    (work_dir / event["path"]).unlink(missing_ok=True)
-                    continue
-                try:
-                    eround = int(meta.get("round", round_num))
-                except (TypeError, ValueError):
-                    eround = round_num
-                if eround < round_num:
-                    # A recovered shard's re-broadcast of a merged round.
-                    (work_dir / event["path"]).unlink(missing_ok=True)
-                    continue
-                etag = FragmentTag.from_header(meta)
-                p = int(etag.fragment_id) if etag is not None else sid
-                if p in got or p not in paths:
-                    (work_dir / event["path"]).unlink(missing_ok=True)
-                    continue
-                got[p] = work_dir / event["path"]
+                    meta = event.get("meta") or {}
+                    try:
+                        sid = int(meta.get(SHARD_KEY, 0))
+                    except (TypeError, ValueError):
+                        sid = 0
+                    gens[sid], resend = restart_signal(meta, gens.get(sid))
+                    if resend:
+                        # That shard restarted: re-send its still-un-acked
+                        # parts — the shard's journal dedup absorbs any copy
+                        # whose original did land.
+                        for p, path in paths.items():
+                            if (
+                                p in got
+                                or not path.is_file()
+                                or shard_of(p, len(shard_map.shards)) != sid
+                            ):
+                                continue
+                            log.warning(
+                                "ps shard %d restart detected; re-sending "
+                                "round %d part %d", sid, round_num, p,
+                            )
+                            _push_part(p, path, samples)
+                    if meta.get(RESYNC_KEY) or meta.get(CATCHUP_KEY):
+                        (work_dir / event["path"]).unlink(missing_ok=True)
+                        continue
+                    try:
+                        eround = int(meta.get("round", round_num))
+                    except (TypeError, ValueError):
+                        eround = round_num
+                    if eround < round_num:
+                        # A recovered shard's re-broadcast of a merged round.
+                        (work_dir / event["path"]).unlink(missing_ok=True)
+                        continue
+                    etag = FragmentTag.from_header(meta)
+                    p = int(etag.fragment_id) if etag is not None else sid
+                    if p in got or p not in paths:
+                        (work_dir / event["path"]).unlink(missing_ok=True)
+                        continue
+                    got[p] = work_dir / event["path"]
         # Merge every part — disjoint tensors, so their flat maps union
         # into ONE combined merge/replace pass (P separate passes would
         # re-flatten and rebuild the whole parameter tree per part) —
         # then re-anchor ONCE (blocking semantics: no drift correction).
-        merge_span = trace.begin(
-            "merge", parent=round_tp, attrs={"round": round_num},
-            node=rtrace.node,
-        )
-        combined: dict = {}
-        for p in sorted(got):
-            flat = compress.read_delta(got[p])
-            if set(flat) != set(parts[p]):
-                raise ValueError(
-                    f"part {p} placement mismatch: update carries "
-                    f"{len(flat)} tensors, worker expects {len(parts[p])}"
+        with sync_phase(
+            "merge", parent=round_tp, key="merge_s", round=round_num
+        ) as mrg:
+            combined: dict = {}
+            with sync_phase("merge.read", parent=mrg.span) as ph:
+                bytes_down = 0
+                for p in sorted(got):
+                    flat = compress.read_delta(got[p])
+                    if set(flat) != set(parts[p]):
+                        raise ValueError(
+                            f"part {p} placement mismatch: update carries "
+                            f"{len(flat)} tensors, worker expects {len(parts[p])}"
+                        )
+                    combined.update(flat)
+                    bytes_down += got[p].stat().st_size
+                    got[p].unlink(missing_ok=True)
+                ph.set("bytes", bytes_down)
+                ph.set("leaves", len(combined))
+            # Ends at dispatch, as in do_update.
+            with sync_phase(
+                "merge.apply", parent=mrg.span,
+                ends_at="dispatch", leaves=len(combined),
+            ):
+                params_flat = flat_leaf_map(state.params)
+                new_live = merge_update(
+                    {n: params_flat[n] for n in combined}, combined
                 )
-            combined.update(flat)
-            got[p].unlink(missing_ok=True)
-        params_flat = flat_leaf_map(state.params)
-        new_live = merge_update(
-            {n: params_flat[n] for n in combined}, combined
-        )
-        state = state.replace(params=replace_leaves(state.params, new_live))
-        anchor = snapshot(state.params)
-        trace.finish(merge_span)
-        for path in paths.values():
-            path.unlink(missing_ok=True)
+                state = state.replace(
+                    params=replace_leaves(state.params, new_live)
+                )
+                anchor = snapshot(state.params)
+        with sync_phase(
+            "cleanup", parent=round_tp, key="cleanup_s",
+            min_s=trace.SLOW_CLEANUP_S, round=round_num,
+        ):
+            for path in paths.values():
+                path.unlink(missing_ok=True)
+        log_sync(round_num, bytes_up, bytes_down)
         resp = send_status_gated(
             Progress(
                 kind=ProgressKind.UPDATE_RECEIVED, job_id=spec.job_id,
@@ -1713,6 +1807,7 @@ def run_training(
         if mh is not None:
             mh.step(batch)  # followers dispatch the same step
         new_state, metrics = step(state, place(batch))
+        step_clock["dispatched"] = time.monotonic()
         return new_state, metrics, float(metrics["loss"])
 
     def run_one_deferred(batch):
@@ -1722,6 +1817,7 @@ def run_training(
         The metrics land in ``pending_metrics``; ``flush_pending_loss``
         reads them one step later (same values, same order)."""
         new_state, metrics = step(state, place(batch))
+        step_clock["dispatched"] = time.monotonic()
         return new_state, metrics
 
     # One-step-deferred loss reads (input_pipeline only; empty otherwise).
@@ -1741,7 +1837,10 @@ def run_training(
     # loss fetch (run_one), so they cover the device work; with the input
     # pipeline's deferred read they cover step n-1's instead.
     step_times: list[float] = []
-    round_mark = {"t0": time.monotonic(), "rounds": 0, "losses": 0, "tokens": 0}
+    round_mark = {
+        "t0": time.monotonic(), "rounds": 0, "losses": 0, "tokens": 0,
+        "status_s": 0.0, "input_wait_s": 0.0,
+    }
 
     def log_round() -> None:
         if result.rounds == round_mark["rounds"] or not step_times:
@@ -1752,7 +1851,8 @@ def run_training(
         log.info(
             "round %d done: batch=%d steps=%d tokens=%d wall_s=%.3f "
             "first_step_s=%.3f median_step_s=%.4f loss_first=%.4f "
-            "loss_last=%.4f loss_mean=%.4f nonfinite=%d peak_bytes=%s",
+            "loss_last=%.4f loss_mean=%.4f nonfinite=%d peak_bytes=%s "
+            "steps_sum_s=%.4f max_step_s=%.4f status_s=%.4f input_wait_s=%.4f",
             result.rounds - 1, cfg.batch_size, len(step_times),
             round_mark["tokens"],
             now - round_mark["t0"], step_times[0],
@@ -1762,10 +1862,13 @@ def run_training(
             float(np.mean(losses)) if losses else math.nan,
             sum(not math.isfinite(x) for x in losses),
             stats.get("peak_bytes_in_use"),
+            sum(step_times), max(step_times),
+            round_mark["status_s"], round_mark["input_wait_s"],
         )
         step_times.clear()
         round_mark.update(
-            t0=now, rounds=result.rounds, losses=len(result.losses), tokens=0
+            t0=now, rounds=result.rounds, losses=len(result.losses), tokens=0,
+            status_s=0.0, input_wait_s=0.0,
         )
 
     t0 = time.monotonic()
@@ -1785,40 +1888,65 @@ def run_training(
                 if not finish_stream_sync():
                     break
             rtrace.batch(round_num)
-            step_t0 = time.monotonic()
-            if mh is not None:
-                state, metrics, loss = _with_deadline(
-                    lambda b=batch: run_one(b), mh_bound("step"), "train step"
-                )
-                compiled_once["step"] = True
-                round_losses.append(loss)
-                result.losses.append(loss)
-            else:
-                overlapping = stream_state is not None and stream_state.in_flight
-                if pipeline_on and not overlapping:
-                    # Deferred sync: dispatch step n, then read step n-1's
-                    # loss (already done on device) — never this step's.
-                    # Skipped while a stream flight is up: note_compute's
-                    # overlap accounting needs the synchronous read.
-                    state, metrics = run_one_deferred(batch)
-                    flush_pending_loss()
-                    pending_metrics.append(metrics)
-                else:
-                    bt0 = time.monotonic() if overlapping else 0.0
-                    state, metrics, loss = run_one(batch)
-                    if overlapping:
-                        stream_state.note_compute(time.monotonic() - bt0)
-                    flush_pending_loss()  # older deferred losses first
+            tokens = (
+                int(np.size(batch["input_ids"]))
+                if isinstance(batch, dict) and "input_ids" in batch else 0
+            )
+            input_wait_s, step_clock["input_wait_s"] = step_clock["input_wait_s"], 0.0
+            # The per-step record: one ``step`` span with the boundaries of
+            # ``step_times`` (to after the loss fetch), written once the
+            # status round trip that follows the step is known.
+            with trace.phase(
+                "step", parent=rtrace.inner, node=rtrace.node,
+                attrs={
+                    "round": round_num, "step": len(step_times),
+                    "tokens": tokens, "input_wait_s": input_wait_s,
+                },
+                annotation=jax.profiler.StepTraceAnnotation(
+                    "inner_step", step_num=result.batches
+                ),
+                defer=True,
+            ) as step_rec:
+                step_t0 = time.monotonic()
+                if mh is not None:
+                    state, metrics, loss = _with_deadline(
+                        lambda b=batch: run_one(b), mh_bound("step"), "train step"
+                    )
+                    compiled_once["step"] = True
                     round_losses.append(loss)
                     result.losses.append(loss)
-            step_times.append(time.monotonic() - step_t0)
-            if isinstance(batch, dict) and "input_ids" in batch:
-                round_mark["tokens"] += int(np.size(batch["input_ids"]))
+                else:
+                    overlapping = (
+                        stream_state is not None and stream_state.in_flight
+                    )
+                    if pipeline_on and not overlapping:
+                        # Deferred sync: dispatch step n, then read step
+                        # n-1's loss (already done on device) — never this
+                        # step's. Skipped while a stream flight is up:
+                        # note_compute's overlap accounting needs the
+                        # synchronous read.
+                        state, metrics = run_one_deferred(batch)
+                        flush_pending_loss()
+                        pending_metrics.append(metrics)
+                    else:
+                        bt0 = time.monotonic() if overlapping else 0.0
+                        state, metrics, loss = run_one(batch)
+                        if overlapping:
+                            stream_state.note_compute(time.monotonic() - bt0)
+                        flush_pending_loss()  # older deferred losses first
+                        round_losses.append(loss)
+                        result.losses.append(loss)
+                step_rec.set("dispatch_s", step_clock["dispatched"] - step_t0)
+                step_rec.set("fetch_s", time.monotonic() - step_clock["dispatched"])
+            step_times.append(step_rec.seconds)
+            round_mark["tokens"] += tokens
+            round_mark["input_wait_s"] += input_wait_s
             result.batches += 1
             round_samples += cfg.batch_size
             if report_quality:
                 note_quality_batch(batch)
 
+            status_t0 = time.monotonic()
             resp = send_status_gated(
                 Progress(
                     kind=ProgressKind.STATUS,
@@ -1826,6 +1954,10 @@ def run_training(
                     batch_size=cfg.batch_size,
                 )
             )
+            status_s = time.monotonic() - status_t0
+            round_mark["status_s"] += status_s
+            step_rec.set("status_s", status_s)
+            step_rec.write()
             if resp.kind == ProgressResponseKind.DONE:
                 break
             if resp.kind == ProgressResponseKind.SCHEDULE_UPDATE:
@@ -1884,6 +2016,11 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--work-dir", required=True)
     parser.add_argument("--job", required=True, help="job spec JSON (inline or @file)")
     parser.add_argument("--max-batches", type=int, default=None)
+    parser.add_argument(
+        "--trace-dir", default="",
+        help="round-trace span directory, handed down by the worker; empty = off",
+    )
+    parser.add_argument("--trace-node", default="", help="node label of the spans")
     return parser
 
 
@@ -1902,8 +2039,13 @@ def main(argv: list[str] | None = None) -> int:
     from .bridge_client import Session
 
     enable_compile_cache()
+    if args.trace_dir:
+        trace.enable(args.trace_dir, node=args.trace_node or f"pid{os.getpid()}")
     with Session(args.socket) as session:
-        run_training(session, args.work_dir, spec, max_batches=args.max_batches)
+        run_training(
+            session, args.work_dir, spec, max_batches=args.max_batches,
+            trace_node=args.trace_node or None,
+        )
     return 0
 
 

@@ -36,6 +36,9 @@ class Lease(Generic[T]):
     leasable: T
     timeout: float  # absolute wall-clock seconds (time.time())
     id: str = field(default_factory=lambda: str(uuid.uuid4()))
+    # When the lease was last renewed (same clock); None until the first
+    # renewal, which is how an offer lease differs from an accepted one.
+    renewed_at: float | None = None
 
     def is_expired(self, now: float | None = None) -> bool:
         return (time.time() if now is None else now) >= self.timeout
@@ -85,7 +88,8 @@ class Ledger(Generic[T]):
                 lease = self._leases[lease_id]
             except KeyError:
                 raise LeaseNotFound(lease_id) from None
-            lease.timeout = self._clock() + duration
+            lease.renewed_at = self._clock()
+            lease.timeout = lease.renewed_at + duration
             return lease
 
     def list(self) -> list[Lease[T]]:

@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import Callable
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 
@@ -313,25 +314,31 @@ class _Attention(nn.Module):
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         window = cfg.sliding_window
-        if window is not None and S > window:
-            # Mistral local attention: position i sees (i-window, i]. The
-            # window threads through attn_impl when the kernel supports it;
-            # otherwise the fused-iota dense path runs (the flash/ring
-            # kernels don't take a window yet — warn, don't silently alter
-            # the objective OR silently drop the installed kernel).
-            impl = self.attn_impl or dot_product_attention
-            if _accepts_kw(impl, "window"):
-                attn = impl(q, k, v, causal=True, window=window)
-            else:
-                if self.attn_impl is not None:
-                    warnings.warn(
-                        "sliding_window set but the installed attn_impl "
-                        "takes no 'window' kwarg; using the dense windowed "
-                        "path instead", stacklevel=2,
+        with jax.named_scope("attention"):
+            if window is not None and S > window:
+                # Mistral local attention: position i sees (i-window, i].
+                # The window threads through attn_impl when the kernel
+                # supports it; otherwise the fused-iota dense path runs (the
+                # flash/ring kernels don't take a window yet — warn, don't
+                # silently alter the objective OR silently drop the
+                # installed kernel).
+                impl = self.attn_impl or dot_product_attention
+                if _accepts_kw(impl, "window"):
+                    attn = impl(q, k, v, causal=True, window=window)
+                else:
+                    if self.attn_impl is not None:
+                        warnings.warn(
+                            "sliding_window set but the installed attn_impl "
+                            "takes no 'window' kwarg; using the dense windowed "
+                            "path instead", stacklevel=2,
+                        )
+                    attn = dot_product_attention(
+                        q, k, v, causal=True, window=window
                     )
-                attn = dot_product_attention(q, k, v, causal=True, window=window)
-        else:
-            attn = (self.attn_impl or dot_product_attention)(q, k, v, causal=True)
+            else:
+                attn = (self.attn_impl or dot_product_attention)(
+                    q, k, v, causal=True
+                )
         attn = attn.reshape(B, S, cfg.num_heads * hd)
         return self._proj(attn, E, False, dtype, "o_proj")
 
@@ -416,9 +423,13 @@ class Llama(nn.Module):
             (cfg.vocab_size, cfg.hidden_size),
             jnp.float32,
         )
-        x = embed[input_ids].astype(dtype)
-        if cfg.embed_scale:  # Gemma: inputs scaled by sqrt(hidden), in dtype
-            x = x * jnp.asarray(cfg.hidden_size**0.5, dtype)
+        # named_scope: the name lands in the metadata of every device event
+        # of this part of the step (docs/observability.md); flax names the
+        # modules (layers_N, self_attn, mlp, norm) the same way.
+        with jax.named_scope("embed"):
+            x = embed[input_ids].astype(dtype)
+            if cfg.embed_scale:  # Gemma: inputs scaled by sqrt(hidden), in dtype
+                x = x * jnp.asarray(cfg.hidden_size**0.5, dtype)
         table_len = max(cfg.max_seq_len, self.decode_len)
         cos, sin = rope_frequencies(cfg.head_dim, table_len, cfg.rope_theta)
         block_cls = nn.remat(_Block) if cfg.remat and not self.decode else _Block
@@ -441,4 +452,5 @@ class Llama(nn.Module):
                 (cfg.vocab_size, cfg.hidden_size),
                 jnp.float32,
             )
-        return jnp.einsum("bse,ve->bsv", x.astype(jnp.float32), lm_head)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bse,ve->bsv", x.astype(jnp.float32), lm_head)

@@ -84,6 +84,13 @@ class TelemetryConfig:
     attributes: dict = field(
         default_factory=dict, metadata={"doc": "extra resource attributes (k = v)"}
     )
+    trace_dir: str = field(
+        default="",
+        metadata={
+            "doc": "directory for round-trace spans (spans-<name>.jsonl, "
+            "docs/observability.md); empty = off"
+        },
+    )
 
     def validate(self) -> None:
         if self.protocol != "http":

@@ -447,10 +447,11 @@ def _flash_bwd(
     n_heads, n_kv, res, do,
 ):
     q, k, v, o, lse = res
-    return _bwd_impl(
-        q, k, v, o, lse, do, causal, scale, bwd_block_q, bwd_block_k,
-        interpret, n_heads, n_kv,
-    )
+    with jax.named_scope("flash_attention_bwd"):
+        return _bwd_impl(
+            q, k, v, o, lse, do, causal, scale, bwd_block_q, bwd_block_k,
+            interpret, n_heads, n_kv,
+        )
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -542,9 +543,12 @@ def flash_attention(
         b, s, h, d = x.shape
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
-    out = _flash(
-        to_bhsd(q), to_bhsd(k), to_bhsd(v),
-        causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
-        interpret, H, Hkv,
-    )
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    # The scope names the forward kernel's device events; the backward
+    # kernels are traced from _flash_bwd, under a scope of their own.
+    with jax.named_scope("flash_attention"):
+        out = _flash(
+            to_bhsd(q), to_bhsd(k), to_bhsd(v),
+            causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
+            interpret, H, Hkv,
+        )
+        return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

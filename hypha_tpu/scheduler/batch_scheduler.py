@@ -223,6 +223,14 @@ class BatchScheduler:
             else None
         )
 
+    def round_ctx(self) -> "tuple[str | None, int | None]":
+        """The open round's root-span context and its number, without
+        opening one: what a span of the scheduler's own is filed under."""
+        span = self._round_span
+        if span is None:
+            return None, None
+        return span.traceparent, self._round_span_num
+
     def _close_round_span(self) -> None:
         tracing = trace.active()
         if tracing is not None and self._round_span is not None:

@@ -881,6 +881,10 @@ class Orchestrator:
             generation=generation,
         )
         ctx.batch_scheduler = batch_scheduler
+        # A traced lease renewal is a span under the round that is open.
+        for handle in (*ctx.handles.values(), *ctx.ps_handles):
+            if handle is not None:
+                handle.round_ctx = batch_scheduler.round_ctx
 
         async def on_progress(peer: str, progress: Progress):
             # Deliberately ahead of the generation fence: any traffic from
@@ -1773,6 +1777,8 @@ class Orchestrator:
             handle: WorkerHandle | None = None
             try:
                 handle = await WorkerHandle.create(self.node, same[0])
+                if ctx.batch_scheduler is not None:
+                    handle.round_ctx = ctx.batch_scheduler.round_ctx
                 task = await Task.dispatch(
                     self.node, ctx.router, ctx.ps_specs[shard], [handle]
                 )
@@ -2026,6 +2032,8 @@ class Orchestrator:
                 )
                 if ctx.detector is not None:
                     handle.on_renew = ctx.detector.heartbeat
+                if ctx.batch_scheduler is not None:
+                    handle.round_ctx = ctx.batch_scheduler.round_ctx
                 # Tracker + membership BEFORE dispatch: the worker's first
                 # Status must find it tracked, and the PS must have queued
                 # its catch-up before the executor starts waiting for it.

@@ -11,10 +11,12 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 
 from .. import aio
 from ..messages import PROTOCOL_API, RenewLease, RenewLeaseResponse, WorkerOffer
 from ..network.node import Node, RequestError
+from ..telemetry import trace
 
 __all__ = ["WorkerHandle", "WorkerFailure"]
 
@@ -44,6 +46,9 @@ class WorkerHandle:
         # renewal — the orchestrator's φ-accrual detector feeds on it
         # alongside the per-batch Status heartbeats (hypha_tpu.ft.detector).
         self.on_renew: "callable | None" = None
+        # ``() -> (traceparent, round)`` of the round that is open (the
+        # BatchScheduler's root span): a traced renewal is filed under it.
+        self.round_ctx: "callable | None" = None
         self._renewal: asyncio.Task | None = None
         self._released = False
 
@@ -99,27 +104,66 @@ class WorkerHandle:
         One immediate retry before declaring failure: renewing at 2/3 of
         the TTL leaves a third of it unspent, so a single RPC timeout on a
         loaded host must not depose a healthy worker — a dead node fails
-        both attempts fast and detection latency stays unchanged."""
+        both attempts fast and detection latency stays unchanged.
+
+        Every renewal says how late this loop woke against the moment it
+        asked for (``late_s``: the scheduler's own event-loop lag, at the
+        one moment it costs a job) and the round trip (``rtt_s``); the
+        worker's ``lease renewed`` line has the margin that was left."""
         while not self._released:
+            due = time.monotonic() + timeout * 2 / 3
             await asyncio.sleep(timeout * 2 / 3)
             if self._released:
                 return
+            tp, round_num = self.round_ctx() if self.round_ctx else (None, None)
+            # The span is the instant this loop woke, written once the round
+            # trip is known: an interval as long as the round trip would be
+            # the shortest span open while a worker's loop stands still, and
+            # a reader that hands idle time to the shortest open span would
+            # take it from the phase that worker was in.
+            with trace.phase(
+                "lease_renew", parent=tp, node="scheduler", defer=True,
+                attrs={"peer": self.peer_id}
+                | ({} if round_num is None else {"round": round_num}),
+            ) as renewal:
+                woke = time.monotonic()
+            late_s = woke - due
+            failure: RequestError | None = None
             try:
                 try:
                     timeout = await self._renew()
                 except RequestError as e:
                     log.warning(
-                        "renewal of %s failed (%s); one retry", self.peer_id, e
+                        "renewal of %s failed (%s); one retry late_s=%.3f "
+                        "rtt_s=%.3f",
+                        self.peer_id, e, late_s, time.monotonic() - woke,
                     )
                     timeout = await self._renew()
-                if self.on_renew is not None:
-                    self.on_renew(self.peer_id)
             except RequestError as e:
+                failure = e
+            rtt_s = time.monotonic() - woke
+            renewal.set("late_s", late_s)
+            renewal.set("rtt_s", rtt_s)
+            renewal.set("renewed", failure is None)
+            renewal.write()
+            if failure is not None:
+                log.warning(
+                    "renewal of %s failed (%s); worker lost late_s=%.3f "
+                    "rtt_s=%.3f", self.peer_id, failure, late_s, rtt_s,
+                )
                 # Resolved with (not raised as) the failure so an un-awaited
                 # handle doesn't log "exception never retrieved".
                 if not self.failed.done():
-                    self.failed.set_result(WorkerFailure(self.peer_id, str(e)))
+                    self.failed.set_result(
+                        WorkerFailure(self.peer_id, str(failure))
+                    )
                 return
+            log.info(
+                "lease renewal: peer=%s late_s=%.3f rtt_s=%.3f",
+                self.peer_id, late_s, rtt_s,
+            )
+            if self.on_renew is not None:
+                self.on_renew(self.peer_id)
 
     async def release(self) -> None:
         """Stop renewing; the worker-side lease expires on its own and the

@@ -36,6 +36,7 @@ from pathlib import Path
 import numpy as np
 
 from .. import compress
+from ..telemetry import trace
 
 __all__ = ["RoundAccum"]
 
@@ -62,9 +63,17 @@ class RoundAccum:
         samples: float,
         sign: float = 1.0,
         prefolded: bool = False,
+        span: "trace.TraceSpan | None" = None,
     ) -> None:
-        tree = compress.read_delta(path)
-        self.fold_tree(tree, samples, sign, prefolded)
+        """Read one delta file and fold it. ``span`` is the caller's
+        round-trace ``fold`` span (None when off): the file read and the
+        arithmetic become its two children."""
+        with trace.phase("fold.read", parent=span) as ph:
+            tree = compress.read_delta(path)
+            ph.set("bytes", Path(path).stat().st_size)
+            ph.set("leaves", len(tree))
+        with trace.phase("fold.accumulate", parent=span, attrs={"leaves": len(tree)}):
+            self.fold_tree(tree, samples, sign, prefolded)
 
     def fold_tree(
         self,

@@ -25,18 +25,22 @@ flight thread) and must serialize to per-node files for offline merge.
 Records are file-backed so a crashed node's spans survive for forensics —
 the complement of the flight recorder's in-memory ring.
 
-Process-global switch: :func:`enable` (benches, tests) or the
-``HYPHA_TRACE_DIR`` / ``HYPHA_TRACE_NODE`` environment (executor
-subprocesses inherit tracing through their environment). Disabled, every
+Process-global switch: :func:`enable`, which every CLI role calls from its
+``telemetry.trace_dir`` config key (``--set telemetry.trace_dir=…``, the
+TOML key, or ``HYPHA_TELEMETRY__TRACE_DIR``); a train executor run as a
+child process is handed the directory on its command line. Disabled, every
 helper is a cheap no-op returning ``None`` — instrumentation sites never
 branch on config themselves.
+
+:class:`phase` is the one timer of a phase inside a larger span: the same
+pair of clock readings feeds the role's log line (tracing on or off) and,
+when tracing is on, the child span.
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -61,24 +65,9 @@ __all__ = [
     "inject",
     "traceparent_of",
     "reparent",
+    "phase",
+    "SLOW_CLEANUP_S",
 ]
-
-# Span names the round trace vocabulary uses (docs/observability.md):
-# scheduler root; worker compute/ship/merge; PS aggregate/step/fan-out;
-# serving route/prefill/decode. Kept here so the timeline tool and the
-# docs share one list.
-ROUND_SPANS = (
-    "round",
-    "inner_steps",
-    "encode",
-    "upload",
-    "fold",
-    "quorum_wait",
-    "outer_step",
-    "broadcast",
-    "merge",
-)
-SERVE_SPANS = ("route", "prefill", "decode")
 
 # One id generator for the whole telemetry package: os.urandom, NOT the
 # global random module — deterministic chaos runs seed the global RNG,
@@ -201,8 +190,8 @@ class NodeTracing:
         )
 
     def finish(self, span: TraceSpan, ok: bool = True) -> TraceSpan:
-        span.end_ns = time.time_ns()
-        span.end_mono_ns = time.monotonic_ns()
+        if span.end_mono_ns is None:  # else a deferred phase fixed it
+            _mark_end(span)
         span.status_ok = span.status_ok and ok
         self._write(span)
         return span
@@ -254,60 +243,37 @@ class NodeTracing:
 # Process-global switch
 # ---------------------------------------------------------------------------
 
+# A sync's file clean-up gets a span only when it takes this long: unlinking
+# parameter-sized files can, on some file layers (worker and PS share it).
+SLOW_CLEANUP_S = 0.010
+
 _ACTIVE: NodeTracing | None = None
-_ENV_CHECKED = False
 _STATE_LOCK = threading.Lock()
 
 
 def enable(trace_dir: str | Path, node: str = "node") -> NodeTracing:
     """Turn tracing on for this process, writing under ``trace_dir``."""
-    global _ACTIVE, _ENV_CHECKED
+    global _ACTIVE
     with _STATE_LOCK:
         if _ACTIVE is not None:
             _ACTIVE.close()
         _ACTIVE = NodeTracing(trace_dir, node)
-        _ENV_CHECKED = True
         return _ACTIVE
 
 
 def disable() -> None:
-    global _ACTIVE, _ENV_CHECKED
+    global _ACTIVE
     with _STATE_LOCK:
         if _ACTIVE is not None:
             _ACTIVE.close()
         _ACTIVE = None
-        _ENV_CHECKED = True  # an explicit disable wins over the env
-
-
-def _reset_for_tests() -> None:
-    """Forget the cached env decision so monkeypatched env is re-read."""
-    global _ACTIVE, _ENV_CHECKED
-    with _STATE_LOCK:
-        if _ACTIVE is not None:
-            _ACTIVE.close()
-        _ACTIVE = None
-        _ENV_CHECKED = False
 
 
 def active() -> NodeTracing | None:
     """The process recorder, or None when tracing is off (the default).
 
-    The environment is consulted once: ``HYPHA_TRACE_DIR`` turns tracing
-    on (``HYPHA_TRACE_NODE`` names this process's spans), which is how the
-    process train executor inherits the bench's ``--trace`` flag.
+    One read of a module global: this runs at every instrumentation site.
     """
-    global _ACTIVE, _ENV_CHECKED
-    if _ENV_CHECKED:
-        return _ACTIVE
-    with _STATE_LOCK:
-        if not _ENV_CHECKED:
-            trace_dir = os.environ.get("HYPHA_TRACE_DIR")
-            if trace_dir:
-                _ACTIVE = NodeTracing(
-                    trace_dir,
-                    os.environ.get("HYPHA_TRACE_NODE", f"pid{os.getpid()}"),
-                )
-            _ENV_CHECKED = True
     return _ACTIVE
 
 
@@ -384,3 +350,98 @@ def reparent(span: "TraceSpan | None", context: "TraceSpan | str | None") -> Non
     )
     if parsed is not None:
         span.trace_id, span.parent_id = parsed
+
+
+def _mark_end(span: "TraceSpan | None") -> None:
+    """Fix a span's end at now, without writing it. For a record whose
+    attributes are complete only after its interval (the worker's ``step``:
+    the status round trip follows the loss fetch); :func:`finish` then
+    writes the line with this end."""
+    if span is not None:
+        span.end_ns = time.time_ns()
+        span.end_mono_ns = time.monotonic_ns()
+
+
+class phase:
+    """Time one phase of a larger span, once, for both of its readers.
+
+    ``with phase("outer_step.mean", parent=outer, into=times, key="mean_s")``
+    adds the block's seconds to ``times["mean_s"]`` whether tracing is on or
+    off — the roles' log lines are written from that dict — and, when
+    tracing is on, records the same interval as a span: the seconds ARE the
+    span's monotonic end minus start, not a second clock beside it.
+
+    A ``parent`` that is a local span also lends the child its node and its
+    ``round``. ``min_s`` drops the span (never the seconds) of a phase that
+    took less. ``annotation`` is a context manager entered around the block:
+    the process that holds the chip passes ``jax.profiler.TraceAnnotation``,
+    which puts the phase into an open profiler session's trace on the
+    device's time base and is a flag check when none is open. ``defer``
+    leaves the ended span unwritten until :meth:`write`, for attributes
+    that are known only later.
+    """
+
+    __slots__ = (
+        "name", "parent", "attrs", "node", "into", "key", "min_s",
+        "annotation", "defer", "span", "seconds", "_t0",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        *,
+        parent: "TraceSpan | str | None" = None,
+        attrs: dict | None = None,
+        node: str | None = None,
+        into: dict | None = None,
+        key: str | None = None,
+        min_s: float = 0.0,
+        annotation: Any = None,
+        defer: bool = False,
+    ) -> None:
+        if isinstance(parent, TraceSpan):
+            node = node or parent.node
+            if "round" in parent.attributes:
+                attrs = {"round": parent.attributes["round"], **(attrs or {})}
+        self.name, self.parent, self.attrs, self.node = name, parent, attrs, node
+        self.into, self.key, self.min_s = into, key or name, min_s
+        self.annotation, self.defer = annotation, defer
+        self.span: TraceSpan | None = None
+        self.seconds = 0.0
+
+    def __enter__(self) -> "phase":
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        self.span = begin(
+            self.name, parent=self.parent, attrs=self.attrs, node=self.node
+        )
+        self._t0 = (
+            self.span.start_mono_ns if self.span is not None
+            else time.monotonic_ns()
+        )
+        return self
+
+    def set(self, key: str, value: Any) -> None:
+        """A span attribute known only once the work is done (bytes read)."""
+        if self.span is not None:
+            self.span.attributes[key] = value
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        span = self.span
+        _mark_end(span)
+        end = span.end_mono_ns if span is not None else time.monotonic_ns()
+        self.seconds = (end - self._t0) / 1e9
+        if self.into is not None:
+            self.into[self.key] = self.into.get(self.key, 0.0) + self.seconds
+        if span is not None:
+            span.status_ok = exc_type is None
+            if not self.defer:
+                self.write()
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
+        return False
+
+    def write(self) -> None:
+        if self.span is not None and self.seconds >= self.min_s:
+            finish(self.span)
+        self.span = None

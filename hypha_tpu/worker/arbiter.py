@@ -10,7 +10,8 @@ rfc/2025-08-04 "Lease Renewal"); a prune loop cancels jobs of expired
 leases every 250 ms; ``DispatchJob`` is only honored under an active lease
 owned by the dispatching peer.
 
-Timing constants are the reference's (arbiter.rs:25-29).
+Timing constants are the reference's (arbiter.rs:25-29), but for the
+lease's lifetime (see ``LEASE_TIMEOUT_S``).
 """
 
 from __future__ import annotations
@@ -59,7 +60,16 @@ log = logging.getLogger("hypha.worker.arbiter")
 OFFER_WINDOW_LIMIT = 100
 OFFER_WINDOW_S = 0.200
 OFFER_TIMEOUT_S = 0.500
-LEASE_TIMEOUT_S = 10.0
+# The reference's value is 10 s. The scheduler renews at 2/3 of what is
+# granted here, so 10 s left a margin of 3.3 s: less than the scheduler's one
+# RPC timeout (5 s) plus its retry, and less than the stalls a shared host
+# was seen to give every role at once (renewals 3.5-5.5 s late while a first
+# step compiled or the parameter server saved 1.9 GB; PERF.md section 6).
+# A late renewal lost the whole job. 30 s renews every 20 s with 10 s to
+# spare. The price: a worker whose scheduler died frees its resources after
+# 30 s, not 10 (docs/fault_tolerance.md). A dead worker is still found by
+# the failed renewal RPC, as fast as before.
+LEASE_TIMEOUT_S = 30.0
 PRUNE_INTERVAL_S = 0.250
 
 
@@ -188,8 +198,20 @@ class Arbiter:
     # ------------------------------------------------------------- leases
 
     async def _on_renew(self, peer: str, msg: RenewLease) -> RenewLeaseResponse:
-        """First renewal = acceptance; owner-checked (arbiter.rs:143-201)."""
+        """First renewal = acceptance; owner-checked (arbiter.rs:143-201).
+
+        ``margin_s`` is what the lease still had when the renewal came: how
+        close this worker was to cancelling its job. The first renewal's is
+        the offer lease's and says ``lease accepted``, so that the smallest
+        margin of a run is taken over real renewals only."""
+        before = self.lease_manager.get(msg.lease_id)
+        margin, accepted = before.timeout - time.time(), before.renewed_at is None
         lease = self.lease_manager.renew(msg.lease_id, peer, LEASE_TIMEOUT_S)
+        log.info(
+            "lease %s: lease=%s peer=%s margin_s=%.3f ttl_s=%.1f",
+            "accepted" if accepted else "renewed", lease.id, peer, margin,
+            LEASE_TIMEOUT_S,
+        )
         return RenewLeaseResponse(lease_id=lease.id, timeout=LEASE_TIMEOUT_S)
 
     async def _prune_loop(self) -> None:
@@ -216,7 +238,12 @@ class Arbiter:
                     # gone, and an unhandled KeyError here would kill the
                     # prune loop for the worker's lifetime.
                     continue
-                log.info("lease %s expired", lease.id)
+                # An offer that was not taken was never renewed.
+                log.info(
+                    "lease %s expired last_renew_age_s=%s", lease.id,
+                    "never" if lease.renewed_at is None
+                    else f"{time.time() - lease.renewed_at:.3f}",
+                )
                 await self.job_manager.cancel_for_lease(lease.id)
 
     async def _on_hello(self, peer: str, msg: SchedulerHello) -> AdoptAck:

@@ -3,7 +3,10 @@
 Reference: crates/worker/src/executor/process.rs:78-198 — per-job work dir
 ``hypha-{uuid}`` containing the bridge socket; the configured command is
 spawned with ``{SOCKET_PATH}`` / ``{WORK_DIR}`` / ``{JOB_JSON}`` placeholder
-substitution in args (also exported as environment variables); stdout is
+substitution in args (also exported as environment variables), plus
+``{TRACE_DIR}`` / ``{TRACE_NODE}``: this worker's round-trace directory
+(empty while tracing is off) and its peer id, which is how the child joins
+the trace (telemetry.trace); stdout is
 piped through the worker's log; cancellation sends SIGTERM and escalates to
 SIGKILL after a 5 s grace period; the work dir is cleaned up afterwards.
 """
@@ -23,6 +26,7 @@ from .. import aio
 from .. import messages
 from ..messages import JobSpec
 from ..network.node import Node
+from ..telemetry import trace
 from .bridge import Bridge
 from .connectors import Connector
 from .job_manager import Execution, JobExecutor
@@ -80,10 +84,13 @@ class ProcessExecutor(JobExecutor):
         )
         socket_path = await bridge.start()
         job_json = json.dumps(messages.to_json_dict(spec))
+        tracing = trace.active()
         subst = {
             "SOCKET_PATH": str(socket_path),
             "WORK_DIR": str(work_dir),
             "JOB_JSON": job_json,
+            "TRACE_DIR": str(tracing.trace_dir.resolve()) if tracing else "",
+            "TRACE_NODE": self.node.peer_id,
         }
         argv = [self.cmd] + [_substitute(a, subst) for a in self.args]
         proc = await asyncio.create_subprocess_exec(

@@ -182,6 +182,13 @@ class _PsTrace:
     def ctx(self, round_num: int) -> str | None:
         return self._by_round.get(round_num)
 
+    def phase(self, name: str, round_num: int, min_s: float = 0.0) -> trace.phase:
+        """A span of the round's close sequence, filed under the round."""
+        return trace.phase(
+            name, parent=self.ctx(round_num), attrs={"round": round_num},
+            node=self.node, min_s=min_s,
+        )
+
     def adopt(self, response, round_num: int) -> None:
         tp = getattr(response, "traceparent", None)
         # Skip a context already filed under an earlier round: on a
@@ -595,7 +602,7 @@ class ParameterServerExecutor(JobExecutor):
                 update_path = await asyncio.to_thread(
                     self._outer_step,
                     received, momentum_file, lr, mu, work_dir, round_num,
-                    accum, quality,
+                    accum, quality, outer_span,
                 )
                 trace.finish(outer_span)
                 if link is not None:
@@ -621,19 +628,22 @@ class ParameterServerExecutor(JobExecutor):
                             )
                         )
                     )
-                    response = await self._notify_updated_resilient(
-                        scheduler_peer, job_id, round_num, arrivals=arrivals,
-                        traceparent=ptrace.ctx(round_num),
-                        execution=execution, park_s=park_s,
-                        on_first_failure=bcast_adaptive,
-                        quality=quality,
-                    )
+                    with ptrace.phase("notify", round_num):
+                        response = await self._notify_updated_resilient(
+                            scheduler_peer, job_id, round_num,
+                            arrivals=arrivals,
+                            traceparent=ptrace.ctx(round_num),
+                            execution=execution, park_s=park_s,
+                            on_first_failure=bcast_adaptive,
+                            quality=quality,
+                        )
                     ptrace.adopt(response, round_num + 1)
                     await bcast_adaptive()
-                    for path, _ in received.values():
-                        path.unlink(missing_ok=True)
+                    with ptrace.phase("cleanup", round_num, min_s=trace.SLOW_CLEANUP_S):
+                        for path, _ in received.values():
+                            path.unlink(missing_ok=True)
+                        update_path.unlink(missing_ok=True)
                     round_num += 1
-                    update_path.unlink(missing_ok=True)
                     if elastic is not None:
                         await self._serve_joins(elastic, cfg, round_num, work_dir)
                     if response.kind == ProgressResponseKind.DONE:
@@ -705,13 +715,14 @@ class ParameterServerExecutor(JobExecutor):
                         span_round=_r,
                     )
                 )
-                response = await self._notify_updated_resilient(
-                    scheduler_peer, job_id, round_num, arrivals=arrivals,
-                    traceparent=ptrace.ctx(round_num),
-                    execution=execution, park_s=park_s,
-                    on_first_failure=bcast_static,
-                    quality=quality,
-                )
+                with ptrace.phase("notify", round_num):
+                    response = await self._notify_updated_resilient(
+                        scheduler_peer, job_id, round_num, arrivals=arrivals,
+                        traceparent=ptrace.ctx(round_num),
+                        execution=execution, park_s=park_s,
+                        on_first_failure=bcast_static,
+                        quality=quality,
+                    )
                 ptrace.adopt(response, round_num + 1)
                 if dur is not None:
                     await asyncio.to_thread(
@@ -719,17 +730,18 @@ class ParameterServerExecutor(JobExecutor):
                         response.kind == ProgressResponseKind.DONE,
                     )
                 await bcast_static()
-                if dur is None:
-                    # Durable runs keep the delta files — the journal
-                    # references them until a checkpoint covers the round.
-                    for path, _ in received.values():
-                        path.unlink(missing_ok=True)
+                with ptrace.phase("cleanup", round_num, min_s=trace.SLOW_CLEANUP_S):
+                    if dur is None:
+                        # Durable runs keep the delta files — the journal
+                        # references them until a checkpoint covers the round.
+                        for path, _ in received.values():
+                            path.unlink(missing_ok=True)
+                    # Broadcast done (and catch-up folded): a long job must
+                    # not accumulate two parameter-sized files per round.
+                    update_path.unlink(missing_ok=True)
+                    if wire_path != update_path:
+                        wire_path.unlink(missing_ok=True)
                 round_num += 1
-                # Broadcast done (and catch-up folded): a long job must not
-                # accumulate two parameter-sized files per round.
-                update_path.unlink(missing_ok=True)
-                if wire_path != update_path:
-                    wire_path.unlink(missing_ok=True)
                 if elastic is not None:
                     await self._serve_joins(elastic, cfg, round_num, work_dir)
                 if response.kind == ProgressResponseKind.DONE:
@@ -1165,7 +1177,7 @@ class ParameterServerExecutor(JobExecutor):
             else None
         )
         await asyncio.to_thread(
-            accum.fold, entry[0], entry[1], sign, prefolded
+            accum.fold, entry[0], entry[1], sign, prefolded, fold_span
         )
         trace.finish(fold_span)
 
@@ -1742,7 +1754,7 @@ class ParameterServerExecutor(JobExecutor):
                 update_path = await asyncio.to_thread(
                     self._outer_step,
                     received, momentum_file, lr, mu, work_dir, round_num,
-                    accum, quality,
+                    accum, quality, outer_span,
                 )
                 trace.finish(outer_span)
                 if frag not in bcast_efs:
@@ -2390,6 +2402,7 @@ class ParameterServerExecutor(JobExecutor):
         round_num: int,
         accum: "_RoundAccum | None" = None,
         stats: dict | None = None,
+        parent: "trace.TraceSpan | None" = None,
     ) -> Path:
         """Nesterov over the round's sample-weighted mean pseudo-gradient.
 
@@ -2401,50 +2414,79 @@ class ParameterServerExecutor(JobExecutor):
         ``stats`` (metrics plane, None = skip the extra flops) is filled
         with the round's training-quality numbers: the L2 norms of the
         mean pseudo-gradient and of the applied outer update, plus the
-        accepted-delta count.
+        accepted-delta count. ``parent`` is the caller's ``outer_step``
+        span: the five phases below are its children when tracing is on,
+        and the log line's ``*_s`` fields either way.
         """
         t0 = time.monotonic()
+        times: dict[str, float] = {}
+
+        def phase(name: str, key: str) -> trace.phase:
+            return trace.phase(
+                f"outer_step.{name}", parent=parent, into=times, key=key
+            )
+
         if accum is None or accum.folds == 0:
             accum = _RoundAccum() if accum is None else accum
             for path, samples in received.values():
                 accum.fold(path, samples)
-        mean = accum.mean()
+        with phase("mean", "mean_s") as ph:
+            mean = accum.mean()
+            nbytes = sum(int(g.nbytes) for g in mean.values())
+            ph.set("bytes", nbytes)
+            ph.set("leaves", len(mean))
         out = work_dir / f"update-{round_num}.safetensors"
         momentum_tmp = work_dir / "momentum.next.safetensors"
         momentum: dict[str, np.ndarray] = {}
-        if momentum_file.is_file():
-            momentum = dict(load_file(str(momentum_file)))
+        with phase("load_momentum", "load_s") as ph:
+            if momentum_file.is_file():
+                momentum = dict(load_file(str(momentum_file)))
+            ph.set("bytes", sum(int(m.nbytes) for m in momentum.values()))
+            ph.set("leaves", len(momentum))
         update: dict[str, np.ndarray] = {}
-        for key, g in mean.items():
-            m = momentum.get(key)
-            if m is None:
-                m = np.zeros(g.size, np.float32)
-            elif m.size != g.size:
-                # The flat kernel trusts n = momentum.size; a short tensor
-                # from a buggy/malicious worker must fail here, not read
-                # out of bounds.
-                raise ValueError(
-                    f"delta {key!r}: size {g.size} != momentum {m.size}"
-                )
-            new_m, upd = native.nesterov_update(m, g.ravel(), lr, mu)
-            momentum[key] = new_m.reshape(g.shape)
-            update[key] = upd.reshape(g.shape)
+        with phase("nesterov", "nesterov_s") as ph:
+            ph.set("native", native.native_available())
+            ph.set("bytes", nbytes)
+            ph.set("leaves", len(mean))
+            for key, g in mean.items():
+                m = momentum.get(key)
+                if m is None:
+                    m = np.zeros(g.size, np.float32)
+                elif m.size != g.size:
+                    # The flat kernel trusts n = momentum.size; a short
+                    # tensor from a buggy/malicious worker must fail here,
+                    # not read out of bounds.
+                    raise ValueError(
+                        f"delta {key!r}: size {g.size} != momentum {m.size}"
+                    )
+                new_m, upd = native.nesterov_update(m, g.ravel(), lr, mu)
+                momentum[key] = new_m.reshape(g.shape)
+                update[key] = upd.reshape(g.shape)
         if stats is not None:
             g_sq = sum(float(np.vdot(g, g)) for g in mean.values())
             u_sq = sum(float(np.vdot(u, u)) for u in update.values())
             stats["delta_norm"] = float(np.sqrt(g_sq))
             stats["update_norm"] = float(np.sqrt(u_sq))
             stats["accepted"] = float(len(received))
-        save_file(update, str(out))
-        save_file(momentum, str(momentum_tmp))
-        os.replace(momentum_tmp, momentum_file)
+        with phase("save_update", "save_update_s") as ph:
+            save_file(update, str(out))
+            ph.set("bytes", out.stat().st_size)
+            ph.set("leaves", len(update))
+        with phase("save_momentum", "save_momentum_s") as ph:
+            save_file(momentum, str(momentum_tmp))
+            ph.set("bytes", momentum_tmp.stat().st_size)
+            ph.set("leaves", len(momentum))
+            os.replace(momentum_tmp, momentum_file)
         # native_kernels=False means the numpy fallback ran: same numbers,
         # the slow outer step — said aloud so no run mistakes one for the other.
         log.info(
             "ps outer step: round=%d deltas=%d tensors=%d native_kernels=%s "
-            "native_cbor=%s wall_s=%.3f",
+            "native_cbor=%s wall_s=%.3f mean_s=%.3f load_s=%.3f "
+            "nesterov_s=%.3f save_update_s=%.3f save_momentum_s=%.3f bytes=%d",
             round_num, len(received), len(update), native.native_available(),
             native_codec_active(), time.monotonic() - t0,
+            times["mean_s"], times["load_s"], times["nesterov_s"],
+            times["save_update_s"], times["save_momentum_s"], nbytes,
         )
         return out
 

@@ -79,6 +79,8 @@ class WorkerNode:
                         "--socket", "{SOCKET_PATH}",
                         "--work-dir", "{WORK_DIR}",
                         "--job", "{JOB_JSON}",
+                        "--trace-dir", "{TRACE_DIR}",
+                        "--trace-node", "{TRACE_NODE}",
                     ],
                     work_root=work_root,
                 )

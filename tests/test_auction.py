@@ -269,7 +269,10 @@ def test_expired_lease_prunes_and_cancels_jobs():
     run(main())
 
 
-def test_renewal_failure_surfaces_as_worker_failure():
+def test_renewal_failure_surfaces_as_worker_failure(monkeypatch):
+    # The failure shows at the next renewal, 2/3 of the lease after the last.
+    monkeypatch.setattr("hypha_tpu.worker.arbiter.LEASE_TIMEOUT_S", 3.0)
+
     async def main():
         hub = MemoryTransport()
         sched = Node(hub.shared(), peer_id="sched")

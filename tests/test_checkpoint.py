@@ -115,6 +115,7 @@ def test_job_resumes_from_checkpoint(tmp_path):
 
     async def main():
         from hypha_tpu.scheduler.orchestrator import Orchestrator
+        from hypha_tpu.worker.arbiter import LEASE_TIMEOUT_S
 
         hub, gw, data, workers, sched = await start_cluster(tmp_path)
         orch = Orchestrator(sched)
@@ -143,7 +144,9 @@ def test_job_resumes_from_checkpoint(tmp_path):
         try:
             await orch.run(job, auction_timeout=1.5)
             manifests_1 = await read_manifests(both)
-            await asyncio.sleep(11)  # let the 10 s train leases lapse
+            # Released leases are left to lapse before the resources are
+            # free for the next auction.
+            await asyncio.sleep(LEASE_TIMEOUT_S + 1)
             await orch.run(job, auction_timeout=1.5)
             manifests_2 = await read_manifests(
                 lambda found: both(found)

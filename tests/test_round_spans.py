@@ -1,0 +1,331 @@
+"""The split of a blocking DiLoCo round from inside the program.
+
+One worker and one parameter server run a two-round job in-process on the
+memory fabric, once with the round trace on and once with it off. On: every
+phase span of docs/observability.md's table is there, under its parent, in
+its parent's interval, tagged with its round, and each round has one ``step``
+span an inner step whose median is the line's ``median_step_s``. Off: the
+same numbers are on the lines the roles log, as ``key=value``.
+"""
+
+from __future__ import annotations
+
+import json
+import logging
+import re
+import statistics
+from collections import defaultdict
+
+import pytest
+
+from hypha_tpu.data_node import DataNode
+from hypha_tpu.gateway import Gateway
+from hypha_tpu.network import MemoryTransport, Node
+from hypha_tpu.resources import Resources
+from hypha_tpu.scheduler.orchestrator import Orchestrator
+from hypha_tpu.telemetry import trace
+from hypha_tpu.worker import arbiter
+from hypha_tpu.worker.arbiter import OfferConfig
+from hypha_tpu.worker.runtime import WorkerNode
+from pathlib import Path
+
+from perfbench import logs
+from test_e2e import diloco_job, make_dataset, run
+
+REPO = Path(__file__).resolve().parent.parent
+ROUNDS = 2
+# parent span name -> its phase spans (docs/observability.md)
+CHILDREN = {
+    "outer_step": [
+        "outer_step.mean", "outer_step.load_momentum", "outer_step.nesterov",
+        "outer_step.save_update", "outer_step.save_momentum",
+    ],
+    "fold": ["fold.read", "fold.accumulate"],
+    "encode": ["encode.extract", "encode.write"],
+    "merge": ["merge.read", "merge.apply"],
+    "inner_steps": ["step"],
+}
+ROUND_LEVEL = ["notify", "await_update"]  # children of the round itself
+MOVES_DATA = [
+    "outer_step.mean", "outer_step.load_momentum", "outer_step.nesterov",
+    "outer_step.save_update", "outer_step.save_momentum", "fold.read",
+    "encode.extract", "encode.write", "merge.read",
+]
+ROUND_FIELDS = {"steps_sum_s", "max_step_s", "status_s", "input_wait_s",
+                "median_step_s", "first_step_s", "wall_s", "steps", "tokens"}
+SYNC_FIELDS = {"round", "encode_s", "upload_s", "wait_s", "merge_s", "cleanup_s",
+               "bytes_up", "bytes_down"}
+OUTER_FIELDS = {"round", "wall_s", "mean_s", "load_s", "nesterov_s",
+                "save_update_s", "save_momentum_s", "bytes", "native_kernels"}
+LOGGERS = ("hypha.executor.training", "hypha.worker.ps", "hypha.worker.arbiter",
+           "hypha.scheduler.worker")
+# The first job of the process lasts some ten seconds, most of them set-up
+# and compiling: a lease this short is renewed inside it, and a loop that
+# stands still for a second keeps it. The second job (untraced) can be over
+# before its first renewal; test_lease_margin.py has the lines with tracing off.
+LEASE_S = 3.0
+
+
+class _Lines(logging.Handler):
+    def __init__(self) -> None:
+        super().__init__(logging.INFO)
+        self.lines: list[str] = []
+
+    def emit(self, record: logging.LogRecord) -> None:
+        self.lines.append(record.getMessage())
+
+
+async def _job(tmp_path):
+    hub = MemoryTransport()
+    gw = Gateway(hub.shared(), peer_id="gw")
+    await gw.start()
+    boot = [gw.node.listen_addrs[0]]
+    data = DataNode(
+        hub.shared(), {"toy": make_dataset(tmp_path)}, peer_id="data", bootstrap=boot
+    )
+    await data.start()
+    workers = []
+    for name, res in (("w0", Resources(tpu=1.0, cpu=8, memory=1000)),
+                      ("ps", Resources(cpu=2, memory=200))):
+        w = WorkerNode(
+            hub.shared(), resources=res, peer_id=name, bootstrap=boot,
+            offer=OfferConfig(price=1.0, strategy="whole"),
+            work_root=tmp_path / name,
+        )
+        await w.start()
+        workers.append(w)
+    sched = Node(hub.shared(), peer_id="sched", bootstrap=boot)
+    await sched.start()
+    await sched.wait_for_bootstrap()
+    job = diloco_job(rounds=ROUNDS)
+    job.resources.num_workers = 1
+    try:
+        return await Orchestrator(sched).run(job, auction_timeout=1.5)
+    finally:
+        for w in workers:
+            await w.stop()
+        await data.stop()
+        await sched.stop()
+        await gw.stop()
+
+
+def _run_job(tmp_path) -> list[str]:
+    """The job's log lines (worker, parameter server, arbiters, scheduler)."""
+    handler = _Lines()
+    loggers = [logging.getLogger(n) for n in LOGGERS]
+    levels = [lg.level for lg in loggers]
+    for lg in loggers:
+        lg.addHandler(handler)
+        lg.setLevel(logging.INFO)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(arbiter, "LEASE_TIMEOUT_S", LEASE_S)
+            result = run(_job(tmp_path))
+    finally:
+        for lg, level in zip(loggers, levels):
+            lg.removeHandler(handler)
+            lg.setLevel(level)
+    assert result.rounds == ROUNDS
+    return handler.lines
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("traced")
+    trace.enable(tmp / "spans", node="sched")
+    try:
+        lines = _run_job(tmp)
+    finally:
+        trace.disable()
+    spans = []
+    for path in sorted((tmp / "spans").glob("spans-*.jsonl")):
+        spans += [json.loads(x) for x in path.read_text().splitlines()]
+    return spans, lines
+
+
+@pytest.fixture(scope="module")
+def untraced(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("untraced")
+    assert trace.active() is None
+    lines = _run_job(tmp)
+    assert not list(tmp.rglob("spans-*.jsonl"))
+    return lines
+
+
+def _named(spans, name, rnd=None):
+    return [s for s in spans if s["name"] == name
+            and (rnd is None or s["attrs"].get("round") == rnd)]
+
+
+def _fields(lines, pattern):
+    return [logs.parse_fields(m.group(0)) for x in lines
+            for m in [re.search(pattern, x)] if m]
+
+
+@pytest.mark.parametrize("name", sorted({c for cs in CHILDREN.values() for c in cs}
+                                        | set(ROUND_LEVEL)))
+def test_every_round_has_the_phase_span_with_a_parent_that_exists(traced, name):
+    spans, _ = traced
+    ids = {s["span_id"] for s in spans}
+    for rnd in range(ROUNDS):
+        found = _named(spans, name, rnd)
+        assert found, f"no {name} span in round {rnd}"
+        for s in found:
+            assert s["ok"] and s["mono_end_ns"] >= s["mono_start_ns"]
+            # Round 0's root context reaches the PS only with the first
+            # delta, so its round-level spans may be roots there.
+            assert s["parent_id"] in ids or (rnd == 0 and s["parent_id"] is None), s
+
+
+@pytest.mark.parametrize("parent,children", sorted(CHILDREN.items()))
+def test_children_lie_inside_their_parent_and_share_its_node(traced, parent, children):
+    spans, _ = traced
+    by_id = {s["span_id"]: s for s in spans}
+    seen = set()
+    for child in (s for s in spans if s["name"] in children):
+        p = by_id[child["parent_id"]]
+        assert p["name"] == parent and p["node"] == child["node"]
+        assert p["trace_id"] == child["trace_id"]
+        assert p["attrs"]["round"] == child["attrs"]["round"]
+        assert p["mono_start_ns"] <= child["mono_start_ns"]
+        assert child["mono_end_ns"] <= p["mono_end_ns"]
+        seen.add(child["name"])
+    assert seen == set(children)
+
+
+@pytest.mark.parametrize("name", MOVES_DATA)
+def test_a_span_that_moves_data_says_how_much(traced, name):
+    spans, _ = traced
+    # Round 0 has no momentum file to load yet.
+    rounds = [1] if name == "outer_step.load_momentum" else range(ROUNDS)
+    for rnd in rounds:
+        for s in _named(spans, name, rnd):
+            assert s["attrs"]["bytes"] > 0 and s["attrs"].get("leaves", 1) > 0, s
+
+
+@pytest.mark.parametrize("name", sorted({c for cs in CHILDREN.values() for c in cs}
+                                        | set(ROUND_LEVEL) | {"cleanup", "input_wait"}))
+def test_the_docs_table_names_the_span(name):
+    table = (REPO / "docs" / "observability.md").read_text()
+    assert re.search(rf"^\| `{re.escape(name)}` \|", table, re.M), name
+
+
+def test_every_emitted_span_name_is_in_the_docs(traced):
+    spans, _ = traced
+    table = (REPO / "docs" / "observability.md").read_text()
+    for name in {s["name"] for s in spans}:
+        assert f"`{name}`" in table, name
+
+
+def test_existing_spans_keep_names_and_attributes(traced):
+    spans, _ = traced
+    for name, node, keys in (
+        ("inner_steps", "w0", {"round"}), ("encode", "w0", {"round", "codec"}),
+        ("upload", "w0", {"round", "codec", "bytes"}), ("merge", "w0", {"round"}),
+        ("upload", "ps", {"round", "peer", "bytes"}), ("fold", "ps", {"round", "peer"}),
+        ("quorum_wait", "ps", {"round"}), ("outer_step", "ps", {"round"}),
+        ("broadcast", "ps", {"round"}),
+    ):
+        found = [s for s in _named(spans, name) if s["node"] == node]
+        assert len(found) == ROUNDS, (name, node)
+        for s in found:
+            assert keys <= set(s["attrs"]), s
+
+
+def test_merge_apply_says_that_it_ends_at_dispatch(traced):
+    spans, _ = traced
+    assert all(s["attrs"]["ends_at"] == "dispatch" for s in _named(spans, "merge.apply"))
+    assert all(isinstance(s["attrs"]["native"], bool)
+               for s in _named(spans, "outer_step.nesterov"))
+
+
+def test_one_step_span_a_step_and_their_median_is_the_lines(traced):
+    spans, lines = traced
+    done = {r["round"]: r for r in logs.rounds("\n".join(lines))}
+    assert sorted(done) == list(range(ROUNDS))
+    for rnd, line in done.items():
+        steps = sorted(_named(spans, "step", rnd), key=lambda s: s["attrs"]["step"])
+        assert [s["attrs"]["step"] for s in steps] == list(range(line["steps"]))
+        assert sum(s["attrs"]["tokens"] for s in steps) == line["tokens"]
+        secs = [(s["mono_end_ns"] - s["mono_start_ns"]) / 1e9 for s in steps]
+        assert abs(statistics.median(secs) - line["median_step_s"]) < 1e-4
+        assert abs(secs[0] - line["first_step_s"]) < 1e-3
+        assert abs(sum(secs) - line["steps_sum_s"]) < 1e-3
+        assert abs(max(secs) - line["max_step_s"]) < 1e-4
+        for key in ("status_s", "input_wait_s"):
+            assert abs(sum(s["attrs"][key] for s in steps) - line[key]) < 1e-3
+        for s, sec in zip(steps, secs):
+            a = s["attrs"]
+            assert 0 <= a["dispatch_s"] and 0 <= a["fetch_s"]
+            assert a["dispatch_s"] + a["fetch_s"] <= sec + 1e-3
+
+
+def test_children_account_for_their_parent(traced):
+    """Self time is small beside the parent: no phase was left out. (At
+    this size the bound is loose; the chip's is in PERF.md.)"""
+    spans, _ = traced
+    kids = defaultdict(float)
+    for s in spans:
+        kids[s["parent_id"]] += (s["mono_end_ns"] - s["mono_start_ns"]) / 1e9
+    for name in ("outer_step", "fold", "encode", "merge"):
+        for s in _named(spans, name):
+            dur = (s["mono_end_ns"] - s["mono_start_ns"]) / 1e9
+            assert kids[s["span_id"]] <= dur + 1e-6
+            assert dur - kids[s["span_id"]] < 0.05, (name, dur, kids[s["span_id"]])
+
+
+@pytest.mark.parametrize("which", ["traced", "untraced"])
+def test_the_lines_carry_the_split_with_tracing_on_and_off(request, which):
+    lines = request.getfixturevalue(which)
+    lines = lines[1] if which == "traced" else lines
+    rounds = logs.rounds("\n".join(lines))
+    syncs = _fields(lines, r"sync done: .*")
+    outers = logs.outer_steps("\n".join(lines))
+    assert len(rounds) == len(syncs) == len(outers) == ROUNDS
+    for r in rounds:
+        assert ROUND_FIELDS <= set(r)
+        assert all(isinstance(r[k], (int, float)) for k in ROUND_FIELDS)
+        assert r["steps"] * r["median_step_s"] > 0
+        assert r["max_step_s"] >= r["median_step_s"]
+        assert r["steps_sum_s"] + r["status_s"] + r["input_wait_s"] <= r["wall_s"] + 1e-2
+    for n, s in enumerate(syncs):
+        assert SYNC_FIELDS <= set(s) and s["round"] == n
+        assert s["bytes_up"] > 0 and s["bytes_down"] > 0
+        assert all(s[k] >= 0 for k in ("encode_s", "upload_s", "wait_s", "merge_s"))
+    for n, o in enumerate(outers):
+        assert OUTER_FIELDS <= set(o) and o["round"] == n
+        parts = sum(o[k] for k in ("mean_s", "load_s", "nesterov_s",
+                                   "save_update_s", "save_momentum_s"))
+        assert parts <= o["wall_s"] + 5e-3 and o["bytes"] > 0
+
+
+def test_a_lease_renewal_is_a_span_under_the_round_that_is_open(traced):
+    spans, _ = traced
+    by_id = {s["span_id"]: s for s in spans}
+    renewals = _named(spans, "lease_renew")
+    assert {s["attrs"]["peer"] for s in renewals} == {"w0", "ps"}
+    for s in renewals:
+        assert s["node"] == "scheduler" and s["ok"]
+        assert s["attrs"]["late_s"] >= 0 and s["attrs"]["rtt_s"] >= 0
+        root = by_id[s["parent_id"]]
+        assert root["name"] == "round" and root["node"] == "scheduler"
+        assert root["attrs"]["round"] == s["attrs"]["round"]
+        assert root["mono_start_ns"] <= s["mono_start_ns"] <= root["mono_end_ns"]
+
+
+def test_the_lease_lines_parse(traced, untraced):
+    for line in _fields(untraced, r"lease accepted: .*"):
+        assert line["peer"] == "sched" and line["ttl_s"] == LEASE_S
+    lines = traced[1]
+    accepted = _fields(lines, r"lease accepted: .*")
+    renewed = _fields(lines, r"lease renewed: .*")
+    renewal = _fields(lines, r"lease renewal: .*")
+    # A renewal in flight when the job ends is on the worker's line alone.
+    assert len(accepted) == 2 and renewed and 0 <= len(renewed) - len(renewal) <= 2
+    for line in accepted + renewed:
+        assert line["peer"] == "sched" and line["ttl_s"] == LEASE_S
+        assert isinstance(line["margin_s"], float) and isinstance(line["lease"], str)
+    # Renewed at 2/3, a lease has a third left unless a loop stood still.
+    assert all(0 < line["margin_s"] <= LEASE_S / 3 + 0.01 for line in renewed)
+    assert {line["peer"] for line in renewal} == {"w0", "ps"}
+    assert all(0 <= line["late_s"] < LEASE_S / 3 and line["rtt_s"] >= 0 for line in renewal)

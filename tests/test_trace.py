@@ -5,6 +5,7 @@ per-round root-span propagation."""
 
 from __future__ import annotations
 
+import argparse
 import json
 import random
 
@@ -28,18 +29,17 @@ from hypha_tpu.telemetry.flight import FlightRecorder
 @pytest.fixture
 def tracing_off():
     """Guarantee tracing is globally OFF and reset state afterwards."""
-    trace._reset_for_tests()
     trace.disable()
     yield
-    trace._reset_for_tests()
+    trace.disable()
 
 
 @pytest.fixture
 def tracing_on(tmp_path):
-    trace._reset_for_tests()
+    trace.disable()
     t = trace.enable(tmp_path, node="testnode")
     yield t
-    trace._reset_for_tests()
+    trace.disable()
 
 
 # -------------------------------------------------- wire-bit equality
@@ -174,15 +174,137 @@ def test_module_helpers_noop_when_off(tracing_off):
     assert trace.traceparent_of(None) is None
 
 
-def test_env_enables_tracing(tmp_path, monkeypatch):
-    trace._reset_for_tests()
-    monkeypatch.setenv("HYPHA_TRACE_DIR", str(tmp_path))
-    monkeypatch.setenv("HYPHA_TRACE_NODE", "envnode")
+ROLES = ("gateway", "scheduler", "worker", "data")
+
+
+def _role_config(role: str, *sets: str):
+    """What ``hypha-tpu <role> run --set ...`` builds, environment included."""
+    from hypha_tpu import cli
+
+    if role == "data":  # a data node refuses to build without a dataset
+        sets += ("datasets.toy=/nonexistent",)
+    return cli._load_config(
+        role, argparse.Namespace(config=None, set=list(sets), name="n1")
+    )
+
+
+@pytest.mark.parametrize("role", ROLES)
+def test_config_key_enables_tracing(role, tmp_path, monkeypatch, tracing_off):
+    """The one switch: ``telemetry.trace_dir``, here from the environment,
+    through the call every role's runner makes."""
+    from hypha_tpu import cli
+
+    monkeypatch.setenv("HYPHA_TELEMETRY__TRACE_DIR", str(tmp_path))
+    conf = _role_config(role)
+    assert conf.telemetry.trace_dir == str(tmp_path)
+    telemetry = cli._telemetry_for(conf)
     try:
         t = trace.active()
-        assert t is not None and t.node == "envnode"
+        assert t is not None and t.node == "n1" and t.trace_dir == tmp_path
     finally:
-        trace._reset_for_tests()
+        telemetry.shutdown()
+
+
+@pytest.mark.parametrize("role", ROLES)
+def test_set_flag_carries_the_trace_dir_and_default_is_off(role, tmp_path, tracing_off):
+    from hypha_tpu import cli
+
+    conf = _role_config(role, f"telemetry.trace_dir={tmp_path}")
+    assert conf.telemetry.trace_dir == str(tmp_path)
+    off = _role_config(role)
+    assert off.telemetry.trace_dir == ""
+    cli._telemetry_for(off).shutdown()
+    assert trace.active() is None and not list(tmp_path.iterdir())
+
+
+@pytest.mark.parametrize("role", ROLES)
+def test_the_old_environment_switch_is_refused_by_name(role, tmp_path, monkeypatch):
+    """``HYPHA_TRACE_DIR`` was never a config key: every role reads
+    ``HYPHA_*`` as configuration and says which key it does not know."""
+    from hypha_tpu.config import ConfigError
+
+    monkeypatch.setenv("HYPHA_TRACE_DIR", str(tmp_path))
+    with pytest.raises(ConfigError, match="unknown config key 'trace_dir'"):
+        _role_config(role)
+
+
+def test_nothing_in_the_environment_enables_tracing(tmp_path, monkeypatch, tracing_off):
+    monkeypatch.setenv("HYPHA_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("HYPHA_TELEMETRY__TRACE_DIR", str(tmp_path))
+    assert trace.active() is None and trace.begin("x") is None
+
+
+def test_child_executor_is_handed_the_trace_dir_on_its_command_line(tmp_path, tracing_off):
+    from hypha_tpu.executor.training import build_parser
+
+    args = build_parser().parse_args(
+        ["--socket", "s", "--work-dir", "w", "--job", "{}",
+         "--trace-dir", str(tmp_path), "--trace-node", "w7"]
+    )
+    assert (args.trace_dir, args.trace_node) == (str(tmp_path), "w7")
+    bare = build_parser().parse_args(["--socket", "s", "--work-dir", "w", "--job", "{}"])
+    assert bare.trace_dir == ""
+
+
+# ------------------------------------------------------------- phases
+
+
+def test_phase_times_into_the_dict_with_tracing_off(tracing_off):
+    times: dict = {}
+    with trace.phase("outer_step.mean", into=times, key="mean_s") as ph:
+        ph.set("bytes", 1)
+    with trace.phase("outer_step.mean", into=times, key="mean_s"):
+        pass
+    assert ph.span is None and times["mean_s"] >= ph.seconds > 0
+
+
+def test_phase_span_and_dict_are_one_timing(tmp_path, tracing_on):
+    times: dict = {}
+    root = tracing_on.begin("outer_step", attrs={"round": 4}, node="ps")
+    with trace.phase("outer_step.mean", parent=root, into=times, key="mean_s") as ph:
+        ph.set("bytes", 12)
+    trace.finish(root)
+    child, parent = [
+        json.loads(x) for x in (tmp_path / "spans-ps.jsonl").read_text().splitlines()
+    ]
+    assert child["name"] == "outer_step.mean" and child["parent_id"] == parent["span_id"]
+    assert child["node"] == "ps" and child["attrs"] == {"round": 4, "bytes": 12}
+    assert times["mean_s"] == (child["mono_end_ns"] - child["mono_start_ns"]) / 1e9
+
+
+def test_phase_min_s_drops_the_span_not_the_seconds(tmp_path, tracing_on):
+    times: dict = {}
+    with trace.phase("cleanup", into=times, min_s=60.0):
+        pass
+    assert times["cleanup"] > 0
+    assert not (tmp_path / "spans-testnode.jsonl").exists()
+
+
+def test_deferred_phase_is_written_later_with_the_end_it_had(tmp_path, tracing_on):
+    with trace.phase("step", defer=True) as ph:
+        pass
+    assert not (tmp_path / "spans-testnode.jsonl").exists()
+    ph.set("status_s", 0.25)
+    ph.write()
+    (rec,) = [json.loads(x) for x in (tmp_path / "spans-testnode.jsonl").read_text().splitlines()]
+    assert rec["attrs"] == {"status_s": 0.25}
+    assert (rec["mono_end_ns"] - rec["mono_start_ns"]) / 1e9 == ph.seconds
+
+
+def test_phase_enters_and_leaves_its_annotation(tracing_off):
+    seen = []
+
+    class Annotation:
+        def __enter__(self):
+            seen.append("in")
+
+        def __exit__(self, *exc):
+            seen.append("out")
+
+    with pytest.raises(KeyError):
+        with trace.phase("encode", annotation=Annotation()):
+            raise KeyError("x")
+    assert seen == ["in", "out"]
 
 
 def test_reparent_binds_only_parentless_spans(tracing_on):
