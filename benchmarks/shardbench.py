@@ -94,7 +94,10 @@ def measure_shard_pipeline(
     fold W part-deltas (real files, real RoundAccum), run the real outer
     step, encode the broadcast. Shards are symmetric (LPT-balanced
     parts), so shard 0's costs stand in for the round."""
-    from hypha_tpu.worker.ps_executor import ParameterServerExecutor
+    from hypha_tpu.worker.ps_executor import (
+        ParameterServerExecutor,
+        _OuterMomentum,
+    )
 
     sizes = {n: int(np.prod(s)) for n, s in shapes.items()}
     parts = partition_names(sizes, num_shards)
@@ -120,7 +123,7 @@ def measure_shard_pipeline(
         accum.fold(f, samples)
     fold_s = time.perf_counter() - t0
 
-    momentum = shard_dir / "momentum.safetensors"
+    momentum = _OuterMomentum(shard_dir / "momentum.safetensors", save=False)
     received = {f"w{i}": e for i, e in enumerate(files)}
     t0 = time.perf_counter()
     update_path = ParameterServerExecutor._outer_step(

@@ -6,7 +6,7 @@ Nesterov over mmapped SafeTensors). The C++ equivalents live in
 ``native/``:
 
   * ``hypha_ps.cpp``          — flat f32 kernels (weighted sum, Nesterov,
-    fused mean+Nesterov);
+    and the PS's outer step: mean + Nesterov in place, threaded);
   * ``hypha_safetensors.cpp`` — mmap'd SafeTensors reader (own JSON header
     parser), writer, and ``ps_outer_step``: the WHOLE outer step over the
     delta files, zero-copy;
@@ -70,8 +70,8 @@ def _load() -> ctypes.CDLL | None:
             _SO.parent.mkdir(parents=True, exist_ok=True)
             subprocess.run(
                 [
-                    "g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                    "-fPIC", *map(str, _SRCS), "-o", str(_SO),
+                    "g++", "-O3", "-march=native", "-std=c++17", "-pthread",
+                    "-shared", "-fPIC", *map(str, _SRCS), "-o", str(_SO),
                 ],
                 check=True,
                 capture_output=True,
@@ -84,10 +84,11 @@ def _load() -> ctypes.CDLL | None:
         lib.nesterov_update_f32.argtypes = [
             _F32P, _F32P, _F32P, ctypes.c_int64, ctypes.c_float, ctypes.c_float,
         ]
-        lib.fused_mean_nesterov_f32.argtypes = [
-            ctypes.POINTER(_F32P), _F32P, ctypes.c_int64,
-            _F32P, _F32P, ctypes.c_int64, ctypes.c_float, ctypes.c_float,
+        lib.fused_mean_nesterov_inplace_f32.argtypes = [
+            _F32P, ctypes.c_float, _F32P, ctypes.c_int64,
+            ctypes.c_float, ctypes.c_float, ctypes.c_int64,
         ]
+        lib.fused_mean_nesterov_inplace_f32.restype = ctypes.c_int64
         lib.st_open.argtypes = [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int]
         lib.st_open.restype = ctypes.c_void_p
         lib.st_close.argtypes = [ctypes.c_void_p]
@@ -169,30 +170,61 @@ def nesterov_update(
     return m, upd
 
 
+# The numpy fallback's block: small enough that its one scratch buffer is
+# not a parameter-sized temporary.
+_FALLBACK_BLOCK = 1 << 20
+
+
+def _in_place_f32(a: np.ndarray, what: str) -> np.ndarray:
+    if a.dtype != np.float32 or not a.flags.c_contiguous or not a.flags.writeable:
+        raise ValueError(f"{what} must be a writable C-contiguous float32 array")
+    return a.reshape(-1)
+
+
 def fused_mean_nesterov(
-    srcs: list[np.ndarray],
-    weights: np.ndarray,
+    acc: np.ndarray,
+    denom: float,
     momentum: np.ndarray,
     lr: float,
     mu: float,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Weighted mean of ``srcs`` then Nesterov, one pass.
-    Returns (momentum, update)."""
-    srcs = [_as_f32(s).ravel() for s in srcs]
-    w = _as_f32(np.asarray(weights)).ravel()
-    m = _as_f32(momentum).ravel().copy()
+    threads: int = 1,
+) -> int:
+    """The PS's outer step over one leaf, one pass, IN PLACE:
+    ``g = acc / denom; m <- mu*m + g; acc <- lr*(mu*m + g)``.
+
+    ``acc`` is the round's partial sum Σ samples·Δθ and comes back as the
+    update; ``momentum`` is updated where it lies. Nothing leaf-sized is
+    allocated. Bit-equal to ``RoundAccum.mean()`` followed by
+    :func:`nesterov_update` (same expressions, same order), whatever
+    ``threads`` is: the kernel splits a leaf's elements, and a leaf under
+    about a million elements runs on the caller alone. Returns the number
+    of threads that ran (1 on the numpy fallback).
+    """
+    a = _in_place_f32(acc, "acc")
+    m = _in_place_f32(momentum, "momentum")
+    if a.size != m.size:
+        # The flat kernel trusts n: a short tensor must fail here, not
+        # read out of bounds.
+        raise ValueError(f"acc size {a.size} != momentum size {m.size}")
+    denom = np.float32(denom)
     lib = _load()
-    if lib is None:
-        g = sum(wk * s for wk, s in zip(w, srcs)).astype(np.float32)
-        m = mu * m + g
-        return m, (lr * (mu * m + g)).astype(np.float32)
-    upd = np.empty_like(m)
-    arr_type = _F32P * len(srcs)
-    lib.fused_mean_nesterov_f32(
-        arr_type(*(_ptr(s) for s in srcs)), _ptr(w), len(srcs),
-        _ptr(m), _ptr(upd), m.size, lr, mu,
-    )
-    return m, upd
+    if lib is not None:
+        return int(
+            lib.fused_mean_nesterov_inplace_f32(
+                _ptr(a), denom, _ptr(m), a.size, lr, mu, threads
+            )
+        )
+    scratch = np.empty(min(a.size, _FALLBACK_BLOCK), np.float32)
+    for lo in range(0, a.size, _FALLBACK_BLOCK):
+        g, mm = a[lo:lo + _FALLBACK_BLOCK], m[lo:lo + _FALLBACK_BLOCK]
+        tmp = scratch[:g.size]
+        np.divide(g, denom, out=g)
+        np.multiply(mm, mu, out=mm)
+        np.add(mm, g, out=mm)
+        np.multiply(mm, mu, out=tmp)
+        np.add(tmp, g, out=g)
+        np.multiply(g, lr, out=g)
+    return 1
 
 
 # ---------------------------------------------------------------------------

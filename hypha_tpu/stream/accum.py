@@ -47,8 +47,10 @@ class RoundAccum:
     Holds ONE param-sized f32 tree (Σ samples·Δθ) instead of every
     worker's decoded delta: ``fold`` runs as each push lands (off the
     event loop via ``asyncio.to_thread``), ``fold(…, sign=-1)`` un-folds a
-    replaced duplicate, and :meth:`mean` finishes the weighted mean when
-    quorum closes — leaving only the Nesterov step on the critical path.
+    replaced duplicate, and when quorum closes the PS :meth:`take`s the
+    sum for its one in-place pass (division and Nesterov fused);
+    :meth:`mean` and :meth:`partial` read it without taking it, for the
+    reducers and the tests.
     """
 
     def __init__(self) -> None:
@@ -108,12 +110,27 @@ class RoundAccum:
         self.total_samples += sign * samples
         self.folds += 1 if sign > 0 else -1
 
+    def _denom(self) -> np.float32:
+        return np.float32(max(self.total_samples, 1e-20))
+
     def mean(self) -> dict[str, np.ndarray]:
         """The sample-weighted mean ḡ = Σ samples·Δθ / Σ samples (f32)."""
         if not self._acc:
             raise ValueError("no deltas folded")
-        denom = np.float32(max(self.total_samples, 1e-20))
+        denom = self._denom()
         return {k: v / denom for k, v in self._acc.items()}
+
+    def take(self) -> tuple[dict[str, np.ndarray], np.float32]:
+        """Hand over the partial sum Σ samples·Δθ and the divisor
+        :meth:`mean` would use, and leave the accumulator empty: the PS's
+        outer step writes the update over the sum where it lies, so nobody
+        may read this sum again."""
+        if not self._acc:
+            raise ValueError("no deltas folded")
+        taken = self._acc, self._denom()
+        self._acc, self._shapes = {}, {}
+        self.total_samples, self.folds = 0.0, 0
+        return taken
 
     def partial(self) -> dict[str, np.ndarray]:
         """The raw weighted partial sum Σ samples·Δθ (f32) — what a group

@@ -57,6 +57,7 @@ import os
 import shutil
 import time
 import uuid
+from dataclasses import dataclass
 from pathlib import Path
 
 import numpy as np
@@ -264,6 +265,25 @@ class _ElasticState:
             self.pending_joins.setdefault(peer, 3)
 
 
+@dataclass
+class _OuterMomentum:
+    """The job's Nesterov momentum: resident in the PS for the job's life.
+
+    ``file`` is read once, by the first outer step, where something put a
+    file there before it (the warm start from ``checkpoint_dir``,
+    ``DurablePS.restore_momentum``); otherwise a leaf starts as zeros in
+    the first round that carries it. ``save`` is whether the job has a
+    ``checkpoint_dir``: the durable commit and ``_checkpoint_momentum``
+    are the file's only readers, so only then does an outer step write
+    it. ``threads`` is what the fused pass may use.
+    """
+
+    file: Path
+    save: bool
+    threads: int = 1
+    tree: dict[str, np.ndarray] | None = None
+
+
 def _fire_once(fn):
     """Wrap an async thunk so only the FIRST call runs it.
 
@@ -286,9 +306,17 @@ def _fire_once(fn):
 
 
 class ParameterServerExecutor(JobExecutor):
-    def __init__(self, node: Node, work_root: Path | str = "/tmp") -> None:
+    def __init__(
+        self, node: Node, work_root: Path | str = "/tmp", cpus: float = 0
+    ) -> None:
         self.node = node
         self.work_root = Path(work_root)
+        # The outer step's threads: the cores this process may run on, and
+        # no more than the node was given (``resources.cpu``, 0 = not said).
+        threads = len(os.sched_getaffinity(0))
+        if cpus >= 1:
+            threads = min(threads, int(cpus))
+        self.threads = max(threads, 1)
 
     def _trace_node(self) -> str:
         """Span/event node label; tests construct executors without a
@@ -347,11 +375,16 @@ class ParameterServerExecutor(JobExecutor):
         if sharded and sync_mode == "stream":
             def owned(r, _p=parts, _n=num_shards, _s=shard):
                 return shard_owns_round("stream", r, _p, _n, _s)
-        # Momentum lives as a SafeTensors FILE (like the reference,
-        # parameter_server.rs:392-397) so the native C++ outer step can mmap
-        # it; the checkpoint dir keeps a copy across PS restarts (net-new).
+        # Momentum is resident state of this job (_OuterMomentum). It is a
+        # SafeTensors FILE as well (like the reference,
+        # parameter_server.rs:392-397) exactly when the job has a
+        # checkpoint_dir: the durable commit and the checkpoint copy, which
+        # keeps it across PS restarts, are all that ever read the file.
         momentum_file = work_dir / "momentum.safetensors"
         ckpt_dir = Path(cfg.checkpoint_dir) if cfg.checkpoint_dir else None
+        momentum = _OuterMomentum(
+            momentum_file, save=ckpt_dir is not None, threads=self.threads
+        )
         # Durable PS state (ft.durable): a checkpointing job gets a round
         # journal + outer-state checkpoints under the checkpoint dir, so a
         # PS crash resumes the interrupted round instead of killing the job.
@@ -515,7 +548,7 @@ class ParameterServerExecutor(JobExecutor):
                     recovery_done,
                 ) = await self._recover(
                     dur, job_id, cfg, scheduler_peer, work_dir,
-                    momentum_file, elastic, lr, mu, bcast_codec,
+                    momentum, elastic, lr, mu, bcast_codec,
                     stream=(sync_mode != "blocking") or sharded,
                     fragments=stream_fragments,
                     shard=shard, num_shards=num_shards,
@@ -538,7 +571,7 @@ class ParameterServerExecutor(JobExecutor):
                 await self._stream_rounds(
                     execution, job_id, cfg, scheduler_peer, work_dir,
                     consumer, elastic, allowed, num_workers,
-                    momentum_file, ckpt_dir, lr, mu, bcast_codec,
+                    momentum, ckpt_dir, lr, mu, bcast_codec,
                     stream_fragments,
                     dur=dur, round_start=round_num,
                     init_accums=recovered_accums, init_pending=preload,
@@ -601,7 +634,7 @@ class ParameterServerExecutor(JobExecutor):
                 quality = {} if report_s else None
                 update_path = await asyncio.to_thread(
                     self._outer_step,
-                    received, momentum_file, lr, mu, work_dir, round_num,
+                    received, momentum, lr, mu, work_dir, round_num,
                     accum, quality, outer_span,
                 )
                 trace.finish(outer_span)
@@ -771,7 +804,7 @@ class ParameterServerExecutor(JobExecutor):
         cfg,
         scheduler_peer: str,
         work_dir: Path,
-        momentum_file: Path,
+        momentum: _OuterMomentum,
         elastic: "_ElasticState | None",
         lr: float,
         mu: float,
@@ -807,7 +840,8 @@ class ParameterServerExecutor(JobExecutor):
         """
         resume = dur.resume
         assert resume is not None
-        await asyncio.to_thread(dur.restore_momentum, momentum_file)
+        # The first replayed (or live) outer step reads the restored file.
+        await asyncio.to_thread(dur.restore_momentum, momentum.file)
         quant = bcast_codec in compress.QUANT_CODECS
         bcast_efs: dict[int, "compress.ErrorFeedback | None"] = {}
         if quant:
@@ -837,7 +871,7 @@ class ParameterServerExecutor(JobExecutor):
                 )
             update_path = await asyncio.to_thread(
                 self._outer_step,
-                {}, momentum_file, lr, mu, work_dir, rnd, accum,
+                {}, momentum, lr, mu, work_dir, rnd, accum,
             )
             if quant and frag not in bcast_efs:
                 bcast_efs[frag] = compress.ErrorFeedback()
@@ -1625,7 +1659,7 @@ class ParameterServerExecutor(JobExecutor):
         elastic: "_ElasticState | None",
         allowed: set[str],
         num_workers: int,
-        momentum_file: Path,
+        momentum: _OuterMomentum,
         ckpt_dir: Path | None,
         lr: float,
         mu: float,
@@ -1753,7 +1787,7 @@ class ParameterServerExecutor(JobExecutor):
                 )
                 update_path = await asyncio.to_thread(
                     self._outer_step,
-                    received, momentum_file, lr, mu, work_dir, round_num,
+                    received, momentum, lr, mu, work_dir, round_num,
                     accum, quality, outer_span,
                 )
                 trace.finish(outer_span)
@@ -1789,7 +1823,7 @@ class ParameterServerExecutor(JobExecutor):
                             elastic.membership.epoch
                             if elastic is not None else 0
                         ),
-                        momentum_file=momentum_file,
+                        momentum_file=momentum.file,
                         catchup=(
                             elastic.catchup if elastic is not None else None
                         ),
@@ -1800,7 +1834,7 @@ class ParameterServerExecutor(JobExecutor):
                         ),
                     )
                 if ckpt_dir is not None:
-                    self._checkpoint_momentum(momentum_file, ckpt_dir)
+                    self._checkpoint_momentum(momentum.file, ckpt_dir)
                 # Freeze the fan-out's peer set at CLOSE time: the
                 # backgrounded push must not pick up a rejoiner who joins
                 # while it is pending — that peer's catch-up (served
@@ -2395,7 +2429,7 @@ class ParameterServerExecutor(JobExecutor):
     def _outer_step(
         self,
         received: dict[str, tuple[Path, float]],
-        momentum_file: Path,
+        momentum: _OuterMomentum,
         lr: float,
         mu: float,
         work_dir: Path,
@@ -2407,86 +2441,119 @@ class ParameterServerExecutor(JobExecutor):
         """Nesterov over the round's sample-weighted mean pseudo-gradient.
 
         The streaming path hands in an accumulator that already folded
-        every delta as it arrived — only ḡ/Σw and the Nesterov recurrence
-        run here (C++ flat kernel via native.nesterov_update, numpy
-        fallback). Callers without an accumulator (tests, the degenerate
-        path) fold the received files now, with the same validation.
+        every delta as it arrived. Its sum is taken (the accumulator is
+        left empty) and one fused pass a leaf divides it by Σw and runs the
+        Nesterov recurrence IN PLACE: the momentum is updated where it
+        lies in ``momentum`` (resident for the job, only this round's keys
+        touched, so a fragment round costs its fragment) and the update is
+        written over the sum — nothing parameter-sized is allocated here.
+        C++ kernel over the leaf's elements in threads
+        (native.fused_mean_nesterov), numpy fallback. Callers without an
+        accumulator (tests, the degenerate path) fold the received files
+        now, with the same validation.
+
+        Momentum is durable exactly when the job has a ``checkpoint_dir``
+        (``momentum.save``): then its file is written here, before the
+        caller's durable commit, notify and broadcast; otherwise nobody
+        would read it and it is not written.
+
         ``stats`` (metrics plane, None = skip the extra flops) is filled
         with the round's training-quality numbers: the L2 norms of the
         mean pseudo-gradient and of the applied outer update, plus the
         accepted-delta count. ``parent`` is the caller's ``outer_step``
-        span: the five phases below are its children when tracing is on,
-        and the log line's ``*_s`` fields either way.
+        span: the phases below are its children when tracing is on, and
+        the log line's ``*_s`` fields either way (a phase that did not
+        run reads 0.000 and has no span).
         """
         t0 = time.monotonic()
-        times: dict[str, float] = {}
+        times = dict.fromkeys(
+            ("mean_s", "load_s", "nesterov_s", "save_update_s", "save_momentum_s"),
+            0.0,
+        )
 
-        def phase(name: str, key: str) -> trace.phase:
+        def phase(name: str, key: str, attrs: dict | None = None) -> trace.phase:
             return trace.phase(
-                f"outer_step.{name}", parent=parent, into=times, key=key
+                f"outer_step.{name}", parent=parent, attrs=attrs, into=times,
+                key=key,
             )
 
         if accum is None or accum.folds == 0:
             accum = _RoundAccum() if accum is None else accum
             for path, samples in received.values():
                 accum.fold(path, samples)
-        with phase("mean", "mean_s") as ph:
-            mean = accum.mean()
-            nbytes = sum(int(g.nbytes) for g in mean.values())
-            ph.set("bytes", nbytes)
-            ph.set("leaves", len(mean))
+        update, denom = accum.take()
+        nbytes = sum(int(a.nbytes) for a in update.values())
         out = work_dir / f"update-{round_num}.safetensors"
-        momentum_tmp = work_dir / "momentum.next.safetensors"
-        momentum: dict[str, np.ndarray] = {}
-        with phase("load_momentum", "load_s") as ph:
-            if momentum_file.is_file():
-                momentum = dict(load_file(str(momentum_file)))
-            ph.set("bytes", sum(int(m.nbytes) for m in momentum.values()))
-            ph.set("leaves", len(momentum))
-        update: dict[str, np.ndarray] = {}
-        with phase("nesterov", "nesterov_s") as ph:
-            ph.set("native", native.native_available())
-            ph.set("bytes", nbytes)
-            ph.set("leaves", len(mean))
-            for key, g in mean.items():
-                m = momentum.get(key)
+        resident = momentum.tree is not None and all(
+            key in momentum.tree for key in update
+        )
+        if momentum.tree is None:
+            momentum.tree = {}
+            if momentum.file.is_file():
+                with phase("load_momentum", "load_s") as ph:
+                    momentum.tree = {
+                        k: np.require(v, np.float32, ["C", "W"])
+                        for k, v in load_file(str(momentum.file)).items()
+                    }
+                    ph.set("bytes", sum(int(m.nbytes) for m in momentum.tree.values()))
+                    ph.set("leaves", len(momentum.tree))
+        for key, acc in update.items():
+            m = momentum.tree.get(key)
+            if m is not None and m.size != acc.size:
+                # The flat kernel trusts n = momentum.size; a short tensor
+                # from a buggy/malicious worker must fail here, before any
+                # leaf's momentum is written, not read out of bounds.
+                raise ValueError(
+                    f"delta {key!r}: size {acc.size} != momentum {m.size}"
+                )
+        g_sq = u_sq = 0.0
+        threads = 1
+        with phase(
+            "nesterov", "nesterov_s",
+            {"native": native.native_available(), "fused_mean": True,
+             "in_place": True, "bytes": nbytes, "leaves": len(update)},
+        ) as ph:
+            for key, acc in update.items():
+                acc = update[key] = np.require(acc, np.float32, ["C", "W"])
+                m = momentum.tree.get(key)
                 if m is None:
-                    m = np.zeros(g.size, np.float32)
-                elif m.size != g.size:
-                    # The flat kernel trusts n = momentum.size; a short
-                    # tensor from a buggy/malicious worker must fail here,
-                    # not read out of bounds.
-                    raise ValueError(
-                        f"delta {key!r}: size {g.size} != momentum {m.size}"
-                    )
-                new_m, upd = native.nesterov_update(m, g.ravel(), lr, mu)
-                momentum[key] = new_m.reshape(g.shape)
-                update[key] = upd.reshape(g.shape)
+                    m = momentum.tree[key] = np.zeros(acc.shape, np.float32)
+                if stats is not None:
+                    g_sq += float(np.vdot(acc, acc))
+                threads = max(threads, native.fused_mean_nesterov(
+                    acc, denom, m, lr, mu, momentum.threads
+                ))
+                if stats is not None:
+                    u_sq += float(np.vdot(acc, acc))
+            ph.set("threads", threads)
         if stats is not None:
-            g_sq = sum(float(np.vdot(g, g)) for g in mean.values())
-            u_sq = sum(float(np.vdot(u, u)) for u in update.values())
-            stats["delta_norm"] = float(np.sqrt(g_sq))
+            # |Σ/d| = |Σ|/d: the mean's norm without the mean's tree.
+            stats["delta_norm"] = float(np.sqrt(g_sq) / denom)
             stats["update_norm"] = float(np.sqrt(u_sq))
             stats["accepted"] = float(len(received))
         with phase("save_update", "save_update_s") as ph:
             save_file(update, str(out))
             ph.set("bytes", out.stat().st_size)
             ph.set("leaves", len(update))
-        with phase("save_momentum", "save_momentum_s") as ph:
-            save_file(momentum, str(momentum_tmp))
-            ph.set("bytes", momentum_tmp.stat().st_size)
-            ph.set("leaves", len(momentum))
-            os.replace(momentum_tmp, momentum_file)
+        if momentum.save:
+            momentum_tmp = work_dir / "momentum.next.safetensors"
+            with phase("save_momentum", "save_momentum_s") as ph:
+                save_file(momentum.tree, str(momentum_tmp))
+                ph.set("bytes", momentum_tmp.stat().st_size)
+                ph.set("leaves", len(momentum.tree))
+                os.replace(momentum_tmp, momentum.file)
         # native_kernels=False means the numpy fallback ran: same numbers,
         # the slow outer step — said aloud so no run mistakes one for the other.
         log.info(
             "ps outer step: round=%d deltas=%d tensors=%d native_kernels=%s "
             "native_cbor=%s wall_s=%.3f mean_s=%.3f load_s=%.3f "
-            "nesterov_s=%.3f save_update_s=%.3f save_momentum_s=%.3f bytes=%d",
+            "nesterov_s=%.3f save_update_s=%.3f save_momentum_s=%.3f bytes=%d "
+            "threads=%d momentum_resident=%d momentum_saved=%d",
             round_num, len(received), len(update), native.native_available(),
             native_codec_active(), time.monotonic() - t0,
             times["mean_s"], times["load_s"], times["nesterov_s"],
             times["save_update_s"], times["save_momentum_s"], nbytes,
+            threads, resident, momentum.save,
         )
         return out
 

@@ -89,7 +89,7 @@ class WorkerNode:
                     node=self.node, work_root=work_root, max_batches=max_batches
                 )
             executors[("aggregate", AGGREGATE_EXECUTOR_NAME)] = (
-                ParameterServerExecutor(self.node, work_root)
+                ParameterServerExecutor(self.node, work_root, cpus=resources.cpu)
             )
             # Serving (net-new; BASELINE config 4): every worker can host
             # infer jobs — the model loads lazily on dispatch.

@@ -12,10 +12,14 @@
 // a single weighted sum over all N workers, not order-dependent pairwise
 // averaging.
 //
-// Build: g++ -O3 -march=native -shared -fPIC hypha_ps.cpp -o libhypha_ps.so
+// Build: g++ -O3 -march=native -pthread -shared -fPIC hypha_ps.cpp -o libhypha_ps.so
 
+#include <algorithm>
 #include <cstddef>
 #include <cstdint>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 extern "C" {
 
@@ -60,6 +64,49 @@ void fused_mean_nesterov_f32(const float *const *srcs, const float *weights,
     momentum[i] = m;
     update_out[i] = lr * (mu * m + g);
   }
+}
+
+// The parameter server's outer step over one leaf, in place and in one pass:
+//   g = acc / denom;  m <- mu * m + g;  acc <- lr * (mu * m + g)
+// `acc` is the round's partial sum (sum of samples * delta) and comes back
+// as the update; `momentum` is the resident outer state. The division and
+// the two Nesterov lines are the expressions of RoundAccum.mean() and
+// nesterov_update_f32, in their order, so the result is bit-equal to the
+// two of them in series. Elementwise, so how a leaf is split over threads
+// cannot change a bit either.
+static void mean_nesterov_range(float *__restrict__ acc,
+                                float *__restrict__ momentum, int64_t lo,
+                                int64_t hi, float denom, float lr, float mu) {
+  for (int64_t i = lo; i < hi; ++i) {
+    float g = acc[i] / denom;
+    float m = mu * momentum[i] + g;
+    momentum[i] = m;
+    acc[i] = lr * (mu * m + g);
+  }
+}
+
+// A thread gets at least 2^19 elements (a leaf under about a million runs on
+// the caller alone), and ranges start on a cache line. Returns the number
+// of threads that ran, the caller's included.
+int64_t fused_mean_nesterov_inplace_f32(float *acc, float denom,
+                                        float *momentum, int64_t n, float lr,
+                                        float mu, int64_t threads) {
+  const int64_t kMinPerThread = int64_t{1} << 19;
+  int64_t t = std::max<int64_t>(1, std::min(threads, n / kMinPerThread));
+  int64_t per = ((n + t - 1) / t + 15) / 16 * 16;
+  std::vector<std::thread> pool;
+  int64_t lo = 0;
+  for (int64_t k = 1; k < t && lo + per < n; ++k, lo += per) {
+    try {
+      pool.emplace_back(mean_nesterov_range, acc, momentum, lo, lo + per,
+                        denom, lr, mu);
+    } catch (const std::system_error &) {
+      break;  // no thread to be had: the caller does the rest itself
+    }
+  }
+  mean_nesterov_range(acc, momentum, lo, n, denom, lr, mu);
+  for (auto &th : pool) th.join();
+  return static_cast<int64_t>(pool.size()) + 1;
 }
 
 // BF16 variant for the wire-format deltas: a 7B round ships ~13.5 GB per

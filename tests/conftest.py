@@ -12,6 +12,8 @@ unless HYPHA_ALLOW_TPU=1 targets the on-chip kernel tests.
 import os
 import sys
 
+import pytest
+
 # Escape hatch for the ON-HARDWARE kernel tests (tests/test_tpu_hw.py):
 # `HYPHA_ALLOW_TPU=1 pytest tests/test_tpu_hw.py` leaves the default backend
 # alone so the pallas kernels are validated on the chip. The hatch only opens
@@ -43,3 +45,15 @@ def pytest_configure(config):
         "fault: chaos/fault-injection tests (hypha_tpu.ft) — filter with "
         "-m fault / -m 'not fault'",
     )
+
+
+@pytest.fixture(params=["native", "numpy"])
+def kernel_backend(request, monkeypatch):
+    """Both implementations of the flat f32 kernels: the C++ library, and
+    the numpy fallback a host without a toolchain runs."""
+    from hypha_tpu import native
+
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    assert native.native_available() == (request.param == "native")
+    return request.param

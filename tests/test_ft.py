@@ -38,7 +38,11 @@ from hypha_tpu.messages import (
     encode,
 )
 from hypha_tpu.telemetry.ft_metrics import FT_METRICS
-from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _ElasticState
+from hypha_tpu.worker.ps_executor import (
+    ParameterServerExecutor,
+    _ElasticState,
+    _OuterMomentum,
+)
 
 
 # --------------------------------------------------------------------------
@@ -242,7 +246,8 @@ def test_quorum_aggregation_closes_at_deadline_with_3_of_4(tmp_path):
     # weights 10,20,10 → ḡ = (1·10 + 2·20 + 3·10)/40 = 2.0; zero momentum
     # Nesterov: m=ḡ, update = lr·(μ·ḡ + ḡ) = 0.7·1.9·2.0 = 2.66.
     out = ps._outer_step(
-        received, tmp_path / "momentum.safetensors", 0.7, 0.9, tmp_path, 0
+        received, _OuterMomentum(tmp_path / "momentum.safetensors", save=False),
+        0.7, 0.9, tmp_path, 0,
     )
     update = load_file(str(out))["w"]
     np.testing.assert_allclose(update, np.full((3,), 0.7 * 1.9 * 2.0), rtol=1e-6)
@@ -331,7 +336,8 @@ def test_elastic_duplicate_resend_replaces_cleanly(tmp_path):
     # Fold accounting: (5·10 + 3·10)/20 = 4.0, no trace of the first send.
     np.testing.assert_allclose(accum.mean()["w"], np.full(3, 4.0), rtol=1e-6)
     out = ps._outer_step(
-        received, tmp_path / "m.st", 0.7, 0.9, tmp_path, 0, accum
+        received, _OuterMomentum(tmp_path / "m.st", save=False),
+        0.7, 0.9, tmp_path, 0, accum,
     )
     np.testing.assert_allclose(
         load_file(str(out))["w"], np.full(3, 0.7 * 1.9 * 4.0), rtol=1e-6

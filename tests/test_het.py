@@ -49,7 +49,11 @@ from hypha_tpu.messages import (
 from hypha_tpu.scheduler.batch_scheduler import BatchScheduler
 from hypha_tpu.scheduler.trackers import ProgressTracker
 from hypha_tpu.telemetry.ft_metrics import HET_METRICS, register_on
-from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _ElasticState
+from hypha_tpu.worker.ps_executor import (
+    ParameterServerExecutor,
+    _ElasticState,
+    _OuterMomentum,
+)
 
 
 def run(coro, timeout=20):
@@ -372,7 +376,8 @@ def test_collector_defaults_bit_exact_with_explicit_none(tmp_path):
             )
         )
         out = ps._outer_step(
-            received, sub / "momentum.safetensors", 0.7, 0.9, sub, 0
+            received, _OuterMomentum(sub / "momentum.safetensors", save=False),
+            0.7, 0.9, sub, 0,
         )
         outs.append(Path(out).read_bytes())
     assert outs[0] == outs[1]

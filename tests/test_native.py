@@ -25,6 +25,11 @@ def _write_st(path, tensors):
     return path
 
 
+def _mean_then_nesterov(srcs, w, momentum, lr, mu):
+    """The plain two-kernel reference: weighted mean, then Nesterov."""
+    return native.nesterov_update(momentum, native.weighted_sum(srcs, w), lr, mu)
+
+
 def test_safetensors_view_parity(tmp_path):
     tensors = {
         "a/w": np.arange(12, dtype=np.float32).reshape(3, 4),
@@ -79,7 +84,7 @@ def test_native_outer_step_matches_python_kernels(tmp_path):
     momentum = load_file(str(m_out))
     for name in shapes:
         srcs = [d[name] for d in deltas]
-        m_ref, u_ref = native.fused_mean_nesterov(
+        m_ref, u_ref = _mean_then_nesterov(
             srcs, w, np.zeros(srcs[0].size, np.float32), lr, mu
         )
         np.testing.assert_allclose(update[name].ravel(), u_ref, rtol=1e-5)
@@ -91,10 +96,10 @@ def test_native_outer_step_matches_python_kernels(tmp_path):
     momentum2 = load_file(str(m_out))
     for name in shapes:
         srcs = [d[name] for d in deltas]
-        m1, _ = native.fused_mean_nesterov(
+        m1, _ = _mean_then_nesterov(
             srcs, w, np.zeros(srcs[0].size, np.float32), lr, mu
         )
-        m2_ref, _ = native.fused_mean_nesterov(srcs, w, m1, lr, mu)
+        m2_ref, _ = _mean_then_nesterov(srcs, w, m1, lr, mu)
         np.testing.assert_allclose(momentum2[name].ravel(), m2_ref, rtol=1e-5)
 
 
@@ -112,6 +117,63 @@ def test_native_outer_step_rejects_mismatch(tmp_path):
             [c], np.asarray([1.0], np.float32),
             None, tmp_path / "m", tmp_path / "u", 0.7, 0.9,
         )
+
+
+@pytest.mark.parametrize("threads", [1, 2, 7])
+@pytest.mark.parametrize("size", [1, 4096, 999_983, 2**22 + 3])
+def test_fused_in_place_pass_is_bit_equal_to_mean_then_nesterov(kernel_backend, size, threads):
+    """The PS's one pass (division and Nesterov fused, written over its
+    inputs) against RoundAccum.mean() + nesterov_update, two rounds, on the
+    same backend: every bit, whatever the thread count."""
+    from hypha_tpu.stream.accum import RoundAccum
+
+    rng = np.random.default_rng(size * 8 + threads)
+    lr, mu = 0.7, 0.9
+    m_ref = np.zeros(size, np.float32)
+    m = np.zeros(size, np.float32)
+    for _ in range(2):
+        accum = RoundAccum()
+        for samples in (24.0, 8.0, 40.0):
+            accum.fold_tree({"w": rng.standard_normal(size).astype(np.float32)}, samples)
+        m_ref, u_ref = native.nesterov_update(m_ref, accum.mean()["w"], lr, mu)
+        tree, denom = accum.take()
+        acc, m_before = tree["w"], m
+        used = native.fused_mean_nesterov(acc, denom, m, lr, mu, threads)
+        assert m is m_before and acc is tree["w"]  # in place: no new arrays
+        assert acc.tobytes() == u_ref.tobytes()
+        assert m.tobytes() == m_ref.tobytes()
+        if kernel_backend == "native":
+            assert used == max(1, min(threads, size >> 19))
+        else:
+            assert used == 1
+
+
+@pytest.mark.parametrize("fault", ["short_momentum", "float64", "read_only", "strided"])
+def test_fused_in_place_pass_refuses_what_it_cannot_write_over(kernel_backend, fault):
+    acc = np.ones(64, np.float32)
+    m = np.zeros(64, np.float32)
+    if fault == "short_momentum":
+        m = np.zeros(63, np.float32)
+    elif fault == "float64":
+        acc = acc.astype(np.float64)
+    elif fault == "read_only":
+        m.flags.writeable = False
+    else:
+        acc = np.ones(128, np.float32)[::2]
+    before = (acc.copy(), m.copy())
+    with pytest.raises(ValueError):
+        native.fused_mean_nesterov(acc, 4.0, m, 0.7, 0.9, 2)
+    np.testing.assert_array_equal(acc, before[0])
+    np.testing.assert_array_equal(m, before[1])
+
+
+def test_fused_in_place_pass_keeps_the_leaf_shape(kernel_backend):
+    acc = np.full((3, 5), 8.0, np.float32)
+    m = np.zeros((3, 5), np.float32)
+    native.fused_mean_nesterov(acc, 4.0, m, 0.5, 0.5, 1)
+    assert acc.shape == m.shape == (3, 5)
+    np.testing.assert_array_equal(m, np.full((3, 5), 2.0, np.float32))
+    np.testing.assert_array_equal(acc, np.full((3, 5), 1.5, np.float32))
 
 
 def test_send_file_fd_socketpair(tmp_path):

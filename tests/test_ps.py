@@ -56,16 +56,18 @@ def test_nesterov_golden_vs_torch():
 
 
 def test_fused_equals_separate():
+    """Sum / Σw then Nesterov in one in-place pass == weighted sum with
+    normalized weights, then the separate Nesterov kernel."""
     rng = np.random.default_rng(3)
     srcs = [rng.standard_normal(256).astype(np.float32) for _ in range(4)]
-    w = np.asarray([4, 2, 1, 1], np.float32)
-    w = w / w.sum()
+    samples = np.asarray([4, 2, 1, 1], np.float32)
     m0 = rng.standard_normal(256).astype(np.float32)
-    mean = native.weighted_sum(srcs, w)
+    mean = native.weighted_sum(srcs, samples / samples.sum())
     m_a, upd_a = native.nesterov_update(m0, mean, 0.7, 0.9)
-    m_b, upd_b = native.fused_mean_nesterov(srcs, w, m0, 0.7, 0.9)
-    np.testing.assert_allclose(m_a, m_b, rtol=1e-6)
-    np.testing.assert_allclose(upd_a, upd_b, rtol=1e-6)
+    upd_b, m_b = native.weighted_sum(srcs, samples), m0.copy()
+    native.fused_mean_nesterov(upd_b, samples.sum(), m_b, 0.7, 0.9)
+    np.testing.assert_allclose(m_a, m_b, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(upd_a, upd_b, rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -321,7 +323,336 @@ def test_ps_outer_step_bf16_deltas(tmp_path):
     for n in shapes:
         srcs = [np.asarray(t[n], np.float32).ravel() for t in srcs16]
         m0 = np.zeros(srcs[0].size, np.float32)
-        new_m, upd = native.fused_mean_nesterov(srcs, w, m0, lr, mu)
+        new_m, upd = native.nesterov_update(
+            m0, native.weighted_sum(srcs, w), lr, mu
+        )
         np.testing.assert_allclose(
             upd.reshape(shapes[n]), u16[n], rtol=1e-6, atol=1e-6
         )
+
+
+# ---------------------------------------------------------------------------
+# The outer step over resident momentum: one in-place pass a leaf
+# ---------------------------------------------------------------------------
+
+LEAVES = {"wte": (64, 32), "h_0/attn": (2**21 + 5,), "bias": (7,), "scale": (1,)}
+
+
+def _delta(rng, keys=LEAVES):
+    return {k: rng.standard_normal(LEAVES[k]).astype(np.float32) for k in keys}
+
+
+def _folded(rng, keys=LEAVES):
+    from hypha_tpu.stream.accum import RoundAccum
+
+    accum = RoundAccum()
+    for samples in (24.0, 8.0):
+        accum.fold_tree(_delta(rng, keys), samples)
+    return accum
+
+
+def _file_based_outer_step(accum, momentum_file, lr, mu, work_dir, round_num):
+    """The outer step as it was before the momentum became resident, kept
+    here as the plain reference: a mean tree, the momentum read from the
+    file the last round wrote, nesterov_update into two fresh trees, two
+    files written."""
+    from safetensors.numpy import load_file, save_file
+
+    mean = accum.mean()
+    momentum = dict(load_file(str(momentum_file))) if momentum_file.is_file() else {}
+    update = {}
+    for key, g in mean.items():
+        m = momentum.get(key)
+        if m is None:
+            m = np.zeros(g.size, np.float32)
+        new_m, upd = native.nesterov_update(m, g.ravel(), lr, mu)
+        momentum[key] = new_m.reshape(g.shape)
+        update[key] = upd.reshape(g.shape)
+    out = work_dir / f"update-{round_num}.safetensors"
+    save_file(update, str(out))
+    save_file(momentum, str(momentum_file))
+    return out
+
+
+def _outer_line(caplog) -> dict:
+    from perfbench import logs
+
+    return logs.outer_steps("\n".join(r.getMessage() for r in caplog.records))[-1]
+
+
+@pytest.mark.parametrize("save", [False, True], ids=["no_checkpoint_dir", "checkpoint_dir"])
+def test_three_rounds_over_resident_momentum_write_the_file_based_paths_bytes(
+    tmp_path, kernel_backend, save, caplog
+):
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    caplog.set_level("INFO", logger="hypha.worker.ps")
+    ref_dir, new_dir = tmp_path / "ref", tmp_path / "new"
+    ref_dir.mkdir(); new_dir.mkdir()
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    momentum = _OuterMomentum(new_dir / "momentum.safetensors", save=save, threads=3)
+    for rnd in range(3):
+        want = _file_based_outer_step(
+            _folded(np.random.default_rng(rnd)), ref_dir / "momentum.safetensors",
+            0.7, 0.9, ref_dir, rnd,
+        )
+        accum = _folded(np.random.default_rng(rnd))
+        got = ps._outer_step({}, momentum, 0.7, 0.9, new_dir, rnd, accum)
+        assert got.read_bytes() == want.read_bytes(), rnd
+        assert accum.folds == 0  # taken: nobody reads a sum written over
+        with pytest.raises(ValueError, match="no deltas folded"):
+            accum.mean()
+        line = _outer_line(caplog)
+        assert line["native_kernels"] is (kernel_backend == "native")
+        assert line["threads"] == (3 if kernel_backend == "native" else 1)
+        assert line["momentum_resident"] == (1 if rnd else 0)
+        assert line["momentum_saved"] == int(save)
+        assert line["mean_s"] == 0 and line["load_s"] == 0
+        assert (line["save_momentum_s"] > 0) == save
+        # The momentum file has a reader only under a checkpoint_dir.
+        assert momentum.file.is_file() == save
+        if save:
+            assert momentum.file.read_bytes() == (ref_dir / "momentum.safetensors").read_bytes()
+        assert not (new_dir / "momentum.next.safetensors").exists()
+
+
+def test_a_momentum_file_put_there_before_the_first_round_is_read_once(tmp_path, caplog):
+    """Warm start and recovery: a file that was there before the first outer
+    step seeds the resident tree; no later round reads it."""
+    from safetensors.numpy import load_file, save_file
+
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    caplog.set_level("INFO", logger="hypha.worker.ps")
+    ref_dir, new_dir = tmp_path / "ref", tmp_path / "new"
+    ref_dir.mkdir(); new_dir.mkdir()
+    saved = _delta(np.random.default_rng(9))
+    for d in (ref_dir, new_dir):
+        save_file(saved, str(d / "momentum.safetensors"))
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    momentum = _OuterMomentum(new_dir / "momentum.safetensors", save=True)
+    for rnd in range(2):
+        want = _file_based_outer_step(
+            _folded(np.random.default_rng(rnd)), ref_dir / "momentum.safetensors",
+            0.7, 0.9, ref_dir, rnd,
+        )
+        got = ps._outer_step(
+            {}, momentum, 0.7, 0.9, new_dir, rnd, _folded(np.random.default_rng(rnd))
+        )
+        assert got.read_bytes() == want.read_bytes()
+        line = _outer_line(caplog)
+        assert (line["load_s"] > 0) == (rnd == 0)
+        assert line["momentum_resident"] == rnd
+    on_disk = load_file(str(momentum.file))
+    for key, m in momentum.tree.items():
+        np.testing.assert_array_equal(on_disk[key], m)
+
+
+def test_a_size_mismatch_raises_before_any_kernel_runs(tmp_path, monkeypatch):
+    from hypha_tpu.stream.accum import RoundAccum
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    momentum = _OuterMomentum(tmp_path / "momentum.safetensors", save=False)
+    good = RoundAccum()
+    good.fold_tree({"a": np.ones(8, np.float32), "b": np.ones(6, np.float32)}, 2.0)
+    ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, 0, good)
+    before = {k: v.copy() for k, v in momentum.tree.items()}
+    calls = []
+    monkeypatch.setattr(
+        native, "fused_mean_nesterov", lambda *a, **k: calls.append(a) or 1
+    )
+    short = RoundAccum()
+    # "a" is sound and comes first; "b" is short: neither may be touched.
+    short.fold_tree({"a": np.ones(8, np.float32), "b": np.ones(5, np.float32)}, 2.0)
+    with pytest.raises(ValueError, match="size 5 != momentum 6"):
+        ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, 1, short)
+    assert not calls
+    for key, m in before.items():
+        np.testing.assert_array_equal(momentum.tree[key], m)
+
+
+def test_a_fragment_round_touches_only_its_keys_momentum(tmp_path, caplog):
+    """Stream and overlap close one fragment a round: the resident tree
+    takes that fragment's keys and leaves the others' arrays as they are."""
+    from safetensors.numpy import load_file
+
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    caplog.set_level("INFO", logger="hypha.worker.ps")
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    momentum = _OuterMomentum(tmp_path / "momentum.safetensors", save=False)
+    frags = [("wte", "bias"), ("h_0/attn", "scale")]
+    rng = np.random.default_rng(4)
+    for rnd, keys in enumerate(frags):  # each fragment's first round
+        out = ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, _folded(rng, keys))
+        assert set(load_file(str(out))) == set(keys)
+        assert _outer_line(caplog)["momentum_resident"] == 0
+    assert set(momentum.tree) == set(LEAVES)
+    held = dict(momentum.tree)
+    others = {k: momentum.tree[k].copy() for k in frags[1]}
+    mine = {k: momentum.tree[k].copy() for k in frags[0]}
+    ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, 2, _folded(rng, frags[0]))
+    assert _outer_line(caplog)["momentum_resident"] == 1
+    for k in LEAVES:
+        assert momentum.tree[k] is held[k]  # updated where it lies
+    for k in frags[1]:
+        np.testing.assert_array_equal(momentum.tree[k], others[k])
+    for k in frags[0]:
+        assert not np.array_equal(momentum.tree[k], mine[k])
+
+
+def test_the_metrics_planes_norms_need_no_mean_tree(tmp_path):
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    momentum = _OuterMomentum(tmp_path / "momentum.safetensors", save=False)
+    mean = _folded(np.random.default_rng(2)).mean()
+    stats: dict = {}
+    out = ps._outer_step(
+        {"w0": None, "w1": None}, momentum, 0.7, 0.9, tmp_path, 0,
+        _folded(np.random.default_rng(2)), stats,
+    )
+    from safetensors.numpy import load_file
+
+    update = load_file(str(out))
+    g = np.sqrt(sum(float(np.vdot(v, v)) for v in mean.values()))
+    u = np.sqrt(sum(float(np.vdot(v, v)) for v in update.values()))
+    assert stats["delta_norm"] == pytest.approx(g, rel=1e-5)
+    assert stats["update_norm"] == pytest.approx(u, rel=1e-6)
+    assert stats["accepted"] == 2.0
+
+
+@pytest.mark.parametrize("cpus,want", [(0, None), (0.5, None), (2, 2), (10_000, None)])
+def test_the_outer_steps_threads_stay_within_what_the_node_was_given(tmp_path, cpus, want):
+    import os
+
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor
+
+    allowed = len(os.sched_getaffinity(0))
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path, cpus=cpus)
+    assert ps.threads == (min(want, allowed) if want else allowed)
+
+
+@pytest.mark.parametrize("checkpoint", [False, True], ids=["no_checkpoint_dir", "checkpoint_dir"])
+def test_momentum_is_on_disk_before_commit_and_broadcast_exactly_under_a_checkpoint_dir(
+    tmp_path, monkeypatch, checkpoint
+):
+    """A whole aggregate job, two rounds. With a checkpoint_dir the momentum
+    file is there, equal to the resident tree, when the durable commit runs
+    and when the broadcast starts; without one it is never written and the
+    work dir holds one parameter-sized file fewer."""
+    from safetensors.numpy import load_file, save_file
+
+    from hypha_tpu.ft.durable import DurablePS
+    from hypha_tpu.messages import (
+        PROTOCOL_PROGRESS,
+        AggregateExecutorConfig,
+        Executor,
+        JobSpec,
+        Nesterov,
+        Progress,
+        ProgressResponse,
+        ProgressResponseKind,
+        Receive,
+        Reference,
+        Send,
+    )
+    from hypha_tpu.network import MemoryTransport, Node
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor
+
+    seen: list[tuple] = []  # (event, round, files in work_dir, file == resident)
+    state = {}
+
+    def look(event, rnd):
+        momentum = state["momentum"]
+        work_dir = momentum.file.parent
+        same = None
+        if momentum.file.is_file():
+            on_disk = load_file(str(momentum.file))
+            same = set(on_disk) == set(momentum.tree) and all(
+                np.array_equal(on_disk[k], momentum.tree[k]) for k in on_disk
+            )
+        seen.append((event, rnd, sorted(p.name for p in work_dir.iterdir()), same))
+
+    outer = ParameterServerExecutor._outer_step
+
+    def outer_spy(self, received, momentum, *a, **k):
+        state["momentum"] = momentum
+        return outer(self, received, momentum, *a, **k)
+
+    commit = DurablePS.commit_round
+
+    def commit_spy(self, round_num, *a, **k):
+        look("commit", round_num)
+        return commit(self, round_num, *a, **k)
+
+    bcast = ParameterServerExecutor._broadcast
+
+    async def bcast_spy(self, cfg, wire, round_num, *a, **k):
+        look("broadcast", round_num)
+        return await bcast(self, cfg, wire, round_num, *a, **k)
+
+    monkeypatch.setattr(ParameterServerExecutor, "_outer_step", outer_spy)
+    monkeypatch.setattr(DurablePS, "commit_round", commit_spy)
+    monkeypatch.setattr(ParameterServerExecutor, "_broadcast", bcast_spy)
+
+    async def main():
+        hub = MemoryTransport()
+        nodes = {n: Node(hub.shared(), peer_id=n) for n in ("ps", "w1", "sched")}
+        for n in nodes.values():
+            await n.start()
+        for x in nodes.values():
+            for y in nodes.values():
+                if x is not y:
+                    x.add_peer_addr(y.peer_id, y.listen_addrs[0])
+
+        async def on_progress(peer, progress):
+            done = progress.round >= 1
+            return ProgressResponse(
+                kind=ProgressResponseKind.DONE if done else ProgressResponseKind.OK
+            )
+
+        nodes["sched"].on(PROTOCOL_PROGRESS, Progress).respond_with(on_progress)
+        ref = Reference.from_peers(["w1"], "updates")
+        spec = JobSpec(
+            job_id="agg-m",
+            executor=Executor(
+                kind="aggregate", name="parameter-server",
+                aggregate=AggregateExecutorConfig(
+                    updates=Receive(ref), results=Send(ref),
+                    optimizer=Nesterov(lr=0.7, momentum=0.9), num_workers=1,
+                    checkpoint_dir=str(tmp_path / "ckpt") if checkpoint else None,
+                ),
+            ),
+        )
+        pse = ParameterServerExecutor(nodes["ps"], tmp_path / "work")
+        execution = await pse.execute("agg-m", spec, "sched")
+        f = tmp_path / "d.st"
+        save_file({"w": np.ones(8, np.float32), "b": np.full(4, 2.0, np.float32)}, str(f))
+        for rnd in range(2):
+            header = {"resource": "updates", "name": "delta", "num_samples": 4,
+                      "round": rnd}
+            await retry(lambda: nodes["w1"].push("ps", header, f),
+                        attempts=3, base_delay=0.05)
+            push = await nodes["w1"].next_push(timeout=10)
+            await push.save_to(tmp_path / f"u{rnd}.st")
+        status = await asyncio.wait_for(execution.wait(), 10)
+        assert status.state == "completed"
+        for n in nodes.values():
+            await n.stop()
+
+    run(main())
+    events = [(e, r) for e, r, _, _ in seen]
+    if checkpoint:
+        assert events == [("commit", 0), ("broadcast", 0), ("commit", 1), ("broadcast", 1)]
+        assert all(same is True for _, _, _, same in seen)
+        assert all("momentum.safetensors" in files for _, _, files, _ in seen)
+        assert (tmp_path / "ckpt" / "momentum.safetensors").is_file()
+    else:
+        assert events == [("broadcast", 0), ("broadcast", 1)]
+        for _, rnd, files, same in seen:
+            assert same is None
+            # The round's delta and its update, and nothing else of that size.
+            kept = [f for f in files if not f.startswith("delta-")]
+            assert kept == [f"update-{rnd}.safetensors"], files

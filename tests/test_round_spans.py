@@ -5,7 +5,9 @@ memory fabric, once with the round trace on and once with it off. On: every
 phase span of docs/observability.md's table is there, under its parent, in
 its parent's interval, tagged with its round, and each round has one ``step``
 span an inner step whose median is the line's ``median_step_s``. Off: the
-same numbers are on the lines the roles log, as ``key=value``.
+same numbers are on the lines the roles log, as ``key=value``. The momentum
+file's two spans exist only where the file is read or written: a third pair
+of jobs runs under a ``checkpoint_dir``, the second of them warm-started.
 """
 
 from __future__ import annotations
@@ -36,10 +38,7 @@ REPO = Path(__file__).resolve().parent.parent
 ROUNDS = 2
 # parent span name -> its phase spans (docs/observability.md)
 CHILDREN = {
-    "outer_step": [
-        "outer_step.mean", "outer_step.load_momentum", "outer_step.nesterov",
-        "outer_step.save_update", "outer_step.save_momentum",
-    ],
+    "outer_step": ["outer_step.nesterov", "outer_step.save_update"],
     "fold": ["fold.read", "fold.accumulate"],
     "encode": ["encode.extract", "encode.write"],
     "merge": ["merge.read", "merge.apply"],
@@ -47,10 +46,13 @@ CHILDREN = {
 }
 ROUND_LEVEL = ["notify", "await_update"]  # children of the round itself
 MOVES_DATA = [
-    "outer_step.mean", "outer_step.load_momentum", "outer_step.nesterov",
-    "outer_step.save_update", "outer_step.save_momentum", "fold.read",
+    "outer_step.nesterov", "outer_step.save_update", "fold.read",
     "encode.extract", "encode.write", "merge.read",
 ]
+# Emitted only when the momentum file is read or written: under a
+# checkpoint_dir, and the read only where a file was there before round 0.
+MOMENTUM_FILE = ["outer_step.load_momentum", "outer_step.save_momentum"]
+NEW_OUTER_FIELDS = ["threads", "momentum_resident", "momentum_saved"]
 ROUND_FIELDS = {"steps_sum_s", "max_step_s", "status_s", "input_wait_s",
                 "median_step_s", "first_step_s", "wall_s", "steps", "tokens"}
 SYNC_FIELDS = {"round", "encode_s", "upload_s", "wait_s", "merge_s", "cleanup_s",
@@ -75,7 +77,7 @@ class _Lines(logging.Handler):
         self.lines.append(record.getMessage())
 
 
-async def _job(tmp_path):
+async def _job(tmp_path, checkpoint_dir=None):
     hub = MemoryTransport()
     gw = Gateway(hub.shared(), peer_id="gw")
     await gw.start()
@@ -99,6 +101,7 @@ async def _job(tmp_path):
     await sched.wait_for_bootstrap()
     job = diloco_job(rounds=ROUNDS)
     job.resources.num_workers = 1
+    job.checkpoint_dir = checkpoint_dir
     try:
         return await Orchestrator(sched).run(job, auction_timeout=1.5)
     finally:
@@ -109,7 +112,7 @@ async def _job(tmp_path):
         await gw.stop()
 
 
-def _run_job(tmp_path) -> list[str]:
+def _run_job(tmp_path, checkpoint_dir=None) -> list[str]:
     """The job's log lines (worker, parameter server, arbiters, scheduler)."""
     handler = _Lines()
     loggers = [logging.getLogger(n) for n in LOGGERS]
@@ -120,7 +123,7 @@ def _run_job(tmp_path) -> list[str]:
     try:
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(arbiter, "LEASE_TIMEOUT_S", LEASE_S)
-            result = run(_job(tmp_path))
+            result = run(_job(tmp_path, checkpoint_dir))
     finally:
         for lg, level in zip(loggers, levels):
             lg.removeHandler(handler)
@@ -137,10 +140,30 @@ def traced(tmp_path_factory):
         lines = _run_job(tmp)
     finally:
         trace.disable()
-    spans = []
-    for path in sorted((tmp / "spans").glob("spans-*.jsonl")):
-        spans += [json.loads(x) for x in path.read_text().splitlines()]
-    return spans, lines
+    return _spans(tmp), lines
+
+
+def _spans(tmp) -> list[dict]:
+    return [json.loads(x) for path in sorted((tmp / "spans").glob("spans-*.jsonl"))
+            for x in path.read_text().splitlines()]
+
+
+@pytest.fixture(scope="module")
+def checkpointed(tmp_path_factory):
+    """Two traced jobs, one after the other, under one ``checkpoint_dir``:
+    the second starts from the momentum the first left there. Returns the
+    spans and lines of each."""
+    tmp = tmp_path_factory.mktemp("checkpointed")
+    jobs = []
+    for n in ("first", "warm"):
+        (tmp / n).mkdir()
+        trace.enable(tmp / n / "spans", node="sched")
+        try:
+            lines = _run_job(tmp / n, checkpoint_dir=str(tmp / "ckpt"))
+        finally:
+            trace.disable()
+        jobs.append((_spans(tmp / n), lines))
+    return jobs
 
 
 @pytest.fixture(scope="module")
@@ -196,15 +219,16 @@ def test_children_lie_inside_their_parent_and_share_its_node(traced, parent, chi
 @pytest.mark.parametrize("name", MOVES_DATA)
 def test_a_span_that_moves_data_says_how_much(traced, name):
     spans, _ = traced
-    # Round 0 has no momentum file to load yet.
-    rounds = [1] if name == "outer_step.load_momentum" else range(ROUNDS)
-    for rnd in rounds:
-        for s in _named(spans, name, rnd):
+    for rnd in range(ROUNDS):
+        found = _named(spans, name, rnd)
+        assert found
+        for s in found:
             assert s["attrs"]["bytes"] > 0 and s["attrs"].get("leaves", 1) > 0, s
 
 
 @pytest.mark.parametrize("name", sorted({c for cs in CHILDREN.values() for c in cs}
-                                        | set(ROUND_LEVEL) | {"cleanup", "input_wait"}))
+                                        | set(ROUND_LEVEL) | set(MOMENTUM_FILE)
+                                        | {"cleanup", "input_wait"}))
 def test_the_docs_table_names_the_span(name):
     table = (REPO / "docs" / "observability.md").read_text()
     assert re.search(rf"^\| `{re.escape(name)}` \|", table, re.M), name
@@ -237,6 +261,82 @@ def test_merge_apply_says_that_it_ends_at_dispatch(traced):
     assert all(s["attrs"]["ends_at"] == "dispatch" for s in _named(spans, "merge.apply"))
     assert all(isinstance(s["attrs"]["native"], bool)
                for s in _named(spans, "outer_step.nesterov"))
+
+
+@pytest.mark.parametrize("attr,want", [("threads", int), ("fused_mean", True),
+                                       ("in_place", True)])
+def test_the_fused_pass_says_what_it_is(traced, attr, want):
+    spans, _ = traced
+    found = _named(spans, "outer_step.nesterov")
+    assert len(found) == ROUNDS
+    for s in found:
+        got = s["attrs"][attr]
+        if want is int:
+            # The PS node was given two cores (and a tiny model's leaves
+            # run on the caller alone).
+            assert type(got) is int and 1 <= got <= 2, s
+        else:
+            assert got is want, s
+
+
+@pytest.mark.parametrize("name", ["outer_step.mean"] + MOMENTUM_FILE)
+def test_a_phase_that_did_not_run_has_no_span_and_reads_zero(traced, name):
+    """No checkpoint_dir: the momentum file has no reader, so it is neither
+    read nor written, and the mean is inside the fused pass. A zero-length
+    span would say work was done."""
+    spans, lines = traced
+    assert not _named(spans, name)
+    key = {"outer_step.mean": "mean_s", "outer_step.load_momentum": "load_s",
+           "outer_step.save_momentum": "save_momentum_s"}[name]
+    assert [o[key] for o in logs.outer_steps("\n".join(lines))] == [0.0] * ROUNDS
+
+
+@pytest.mark.parametrize("field", NEW_OUTER_FIELDS)
+def test_the_outer_line_says_where_the_momentum_is(traced, untraced, checkpointed, field):
+    """``threads``, ``momentum_resident`` (0 in the round that created or
+    loaded it) and ``momentum_saved`` (the job has a checkpoint_dir), with
+    tracing on and off."""
+    plain = [logs.outer_steps("\n".join(x)) for x in (traced[1], untraced)]
+    saved = [logs.outer_steps("\n".join(lines)) for _, lines in checkpointed]
+    for outers in plain + saved:
+        assert len(outers) == ROUNDS
+        want = {"threads": [1] * ROUNDS, "momentum_resident": [0, 1],
+                "momentum_saved": [int(outers in saved)] * ROUNDS}[field]
+        assert [o[field] for o in outers] == want
+
+
+@pytest.mark.parametrize("name", MOMENTUM_FILE)
+def test_under_a_checkpoint_dir_the_momentum_file_has_its_span(checkpointed, name):
+    """Written in every round, before the round's commit and broadcast; read
+    once, in round 0 of the job that found a file there."""
+    for job, (spans, _) in enumerate(checkpointed):
+        by_id = {s["span_id"]: s for s in spans}
+        found = _named(spans, name)
+        if name == "outer_step.save_momentum":
+            assert sorted(s["attrs"]["round"] for s in found) == list(range(ROUNDS))
+        else:
+            assert [s["attrs"]["round"] for s in found] == ([0] if job else [])
+        for s in found:
+            parent = by_id[s["parent_id"]]
+            assert parent["name"] == "outer_step" and parent["node"] == s["node"] == "ps"
+            assert parent["mono_start_ns"] <= s["mono_start_ns"]
+            assert s["mono_end_ns"] <= parent["mono_end_ns"]
+            assert s["ok"] and s["attrs"]["bytes"] > 0 and s["attrs"]["leaves"] > 0
+            bcast = _named(spans, "broadcast", s["attrs"]["round"])
+            assert bcast and all(s["mono_end_ns"] <= b["mono_start_ns"] for b in bcast)
+
+
+def test_under_a_checkpoint_dir_the_lines_time_the_momentum_file(checkpointed):
+    """The line's ``load_s`` and ``save_momentum_s`` are those spans' seconds
+    (0.000 where there is no span), and ``mean_s`` stays 0."""
+    keys = dict(zip(MOMENTUM_FILE, ("load_s", "save_momentum_s")))
+    for spans, lines in checkpointed:
+        for o in logs.outer_steps("\n".join(lines)):
+            assert o["mean_s"] == 0
+            for name, key in keys.items():
+                secs = sum((s["mono_end_ns"] - s["mono_start_ns"]) / 1e9
+                           for s in _named(spans, name, o["round"]))
+                assert abs(secs - o[key]) < 1e-3, (name, o)
 
 
 def test_one_step_span_a_step_and_their_median_is_the_lines(traced):
