@@ -2,5 +2,6 @@
 
 ``BENCHMARK.json`` at the root names the cells; everything that belongs to
 one configuration, one traffic mix or one per-layer metric is a data file
-here, found by that name. ``python perfbench/run.py --help``.
+here, found by that name, a configuration's plain reference among them
+(``reference/``). ``python perfbench/run.py --help``.
 """

@@ -39,6 +39,12 @@ FIRST_RUN_S = 1170.0
 # A lost lease is tried again while a whole attempt still fits before that.
 LATER_RETRY_S = 160.0
 FIRST_RETRY_S = 700.0
+# The configuration's reference runs after the roles are gone: its own time
+# limit, and never past the contract's end.
+LATER_REFERENCE_S = 90.0
+FIRST_REFERENCE_S = 400.0
+LATER_END_S = 352.0
+FIRST_END_S = 1190.0
 
 
 class RunFailure(Exception):
@@ -69,6 +75,8 @@ class Run:
     spans: list = field(default_factory=list)
     profile: dict | None = None
     checks: dict = field(default_factory=dict)
+    margins: dict = field(default_factory=dict)  # check -> the number compared and its limits
+    reference: dict | None = None  # what the configuration's reference printed
     attempts: int = 1  # cluster starts; 2 after a lease lost in set-up
 
 
@@ -223,8 +231,9 @@ import numpy as np
 from hypha_tpu import codec, native
 print(json.dumps({"ps_kernels": native.native_available(),
                   "cbor_codec": codec.native_codec_active()}), flush=True)
-m, g = np.zeros(1 << 16, np.float32), np.ones(1 << 16, np.float32)
-native.nesterov_update(m, g, 0.7, 0.9)
+acc, m = np.ones(1 << 21, np.float32), np.zeros(1 << 21, np.float32)
+native.fused_mean_nesterov(acc, 2.0, m, 0.7, 0.9, 2)  # the PS's own call, on two threads
+assert abs(float(acc[-1]) - 0.665) < 1e-6 and float(m[0]) == 0.5
 assert codec.loads(codec.dumps({"k": [1, 2.5, "x"]})) == {"k": [1, 2.5, "x"]}
 print("probed", flush=True)
 """
@@ -286,6 +295,40 @@ def reduce_profile(root: Path, out_dir: Path, env: dict) -> dict | None:
     return out
 
 
+def run_reference(root: Path, cell, seed: int, timeout: float) -> dict:
+    """Round 0's first loss by the configuration's plain reference
+    (``perfbench/reference/``), in a process of its own on the chip that
+    ``w0`` has released: every role has been waited for by now, so nothing
+    of the program is alive and libtpu is mapped by no one. Never raises:
+    a reference that could not run says why under ``error``, and the run
+    fails by ``reference_ran``, not by the comparison."""
+    out: dict = {}
+    t0 = time.monotonic()
+    if timeout < 15.0:
+        out["error"] = f"no time left for the reference ({timeout:.0f} s)"
+    else:
+        env = dict(os.environ)
+        env["PYTHONPATH"] = str(root) + os.pathsep + env.get("PYTHONPATH", "")
+        try:
+            r = subprocess.run(
+                [sys.executable, "-m", "perfbench.reference", "--workload", cell.name,
+                 "--seed", str(seed), "--root", str(root)],
+                cwd=str(root), capture_output=True, text=True, timeout=timeout, env=env,
+            )
+            out = json.loads(r.stdout.strip().splitlines()[-1])
+            if not isinstance(out, dict):
+                raise ValueError(out)
+        except subprocess.TimeoutExpired:
+            out["error"] = f"the reference did not end within {timeout:.0f} s"
+        except (IndexError, ValueError):
+            out["error"] = f"return code {r.returncode}: {r.stderr[-1500:]}"
+    out["wall_s"] = time.monotonic() - t0
+    if "loss" not in out:
+        print(f"perfbench: the reference did not run: {out.get('error') or out.get('skipped')}",
+              file=sys.stderr)
+    return out
+
+
 def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
              t_wall: float, root: Path) -> Run:
     """Run the cell once. Never raises for a failed run: the cause is in
@@ -312,6 +355,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
         report_failure(run)
         print("perfbench: starting the cluster once more", file=sys.stderr)
     measure.from_logs(run, run.texts, cell.traffic, seconds)
+    if cell.config["checks"].get("reference") and run.rounds:
+        # Only now: the window is closed, the peak is read and w0 is gone.
+        end = t_start + (FIRST_END_S if first else LATER_END_S)
+        limit = FIRST_REFERENCE_S if first else LATER_REFERENCE_S
+        run.reference = run_reference(
+            root, cell, seed, min(limit, end - time.monotonic()))
     if trace:
         run.spans = logs.read_spans(out_dir / "spans")
         run.profile = reduce_profile(root, out_dir, dict(os.environ))

@@ -12,23 +12,47 @@ from __future__ import annotations
 from pathlib import Path
 
 
-def write_dataset(directory: Path, traffic: dict, seed: int) -> int:
-    """Write the slices; returns the number of sequences."""
+def slices(traffic: dict, seed: int):
+    """The data set's slices in order, each ``[rows_per_slice, sequence]``."""
     import numpy as np
-    from safetensors.numpy import save_file
 
     spec = traffic["data"]
     if spec["generator"] != "counting":
         raise ValueError(f"unknown data generator {spec['generator']!r}")
     rows, seq, mod = spec["rows_per_slice"], traffic["sequence"], spec["modulus"]
     rng = np.random.default_rng(seed)
-    directory.mkdir(parents=True, exist_ok=True)
-    slices = -(-spec["sequences"] // rows)
-    for i in range(slices):
+    for _ in range(-(-spec["sequences"] // rows)):
         starts = rng.integers(0, mod, (rows, 1))
-        ids = ((starts + np.arange(seq)) % mod).astype(np.int32)
+        yield ((starts + np.arange(seq)) % mod).astype(np.int32)
+
+
+def write_dataset(directory: Path, traffic: dict, seed: int) -> int:
+    """Write the slices; returns the number of sequences."""
+    from safetensors.numpy import save_file
+
+    directory.mkdir(parents=True, exist_ok=True)
+    count = 0
+    for i, ids in enumerate(slices(traffic, seed)):
         save_file({"input_ids": ids}, str(directory / f"slice_{i:04d}.safetensors"))
-    return slices * rows
+        count += len(ids)
+    return count
+
+
+def first_batch(traffic: dict, seed: int):
+    """The rows of the worker's first step: the scheduler hands a job's
+    first request slice 0 (``SliceTracker.next``: the lowest free index),
+    the worker reads a slice's rows in order and its first batch is the
+    first ``batch`` of them (``tests/perfbench/test_reference.py`` holds the
+    program to that). A reference computes round 0's first loss on these."""
+    batch = traffic["batch"]
+    if batch > traffic["data"]["rows_per_slice"]:
+        raise ValueError("the first batch would span two slices")
+    return next(slices(traffic, seed))[:batch]
+
+
+def model_seed(seed: int) -> int:
+    """``job.model_seed`` of a run: the key the worker's ``model.init`` gets."""
+    return seed % 2**31
 
 
 def job_sets(config: dict, traffic: dict, seed: int) -> list[str]:
@@ -39,7 +63,7 @@ def job_sets(config: dict, traffic: dict, seed: int) -> list[str]:
         "job.dataset=counting",
         "job.model_type=causal-lm",
         *config["job_sets"],
-        f"job.model_seed={seed % 2**31}",
+        f"job.model_seed={model_seed(seed)}",
         "job.update_rounds=100000",
         "job.num_workers=1",
         # The auction sizes the batch as offered/required chips: one chip

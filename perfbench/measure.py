@@ -81,36 +81,92 @@ def round_checks(run, r: dict) -> dict[str, bool]:
     }
 
 
-def trajectory_checks(run, bands: dict) -> dict[str, bool]:
-    """The mix's own bands on the losses (traffic file, ``checks``), taken
-    from runs on the chip over many seeds. They hold the training the cell
-    measures to the training that was recorded: not a reference, but a band
-    that a step which learns at another pace, or an outer update that throws
-    the worker off, leaves. A mix without a band is not held to one."""
-    out: dict[str, bool] = {}
-    r0 = run.rounds[0] if run.rounds else None
+def descent_sum(r0: dict) -> float:
+    """The losses of round 0's steps after the first, summed: how fast the
+    seeded model learns the counting data, first loss apart."""
+    return r0["loss_mean"] * r0["steps"] - r0["loss_first"]
+
+
+def margins(run, config: dict, traffic: dict) -> dict[str, dict]:
+    """Every number that ``correct`` holds to a limit, under its check's
+    name: ``value`` and the ``low`` and ``high`` it may not pass (``high`` is
+    exclusive where ``strict``). A check whose number does not exist yet (no
+    round 0, no measured round, a reference that did not run) is absent, and
+    fails in ``checks``. The note ``checks`` carries them and stderr ends in
+    them, so that a near miss is on record with its number."""
+    out: dict[str, dict] = {}
+    r0 = run.rounds[0] if run.rounds and run.rounds[0]["round"] == 0 else None
+    c, bands, m = config["checks"], traffic.get("checks", {}), run.measured
+    if r0 is None:
+        return out
+    if r0["first_step_s"] > 0 and m:
+        out["no_recompile_in_window"] = {
+            "value": max(r["first_step_s"] for r in m) / r0["first_step_s"],
+            "high": 0.5, "strict": True, "of": "round 0's first_step_s"}
+    if c.get("reference"):
+        ref = (run.reference or {}).get("loss")
+        if isinstance(ref, float) and math.isfinite(ref):
+            out["first_loss_as_reference"] = {
+                "value": abs(r0["loss_first"] - ref), "high": c["reference_tolerance"],
+                "program": r0["loss_first"], "reference": ref}
+    else:
+        target = math.log(c["vocabulary"]) + c["loss_first_offset"]
+        out["first_loss_near_ln_vocabulary"] = {
+            "value": r0["loss_first"], "low": target - c["loss_first_tolerance"],
+            "high": target + c["loss_first_tolerance"]}
+    if m:
+        out["loss_fell"] = {"value": m[-1]["loss_mean"], "high": r0["loss_mean"], "strict": True}
     band = bands.get("descent_after_first_step")
     if band:
-        # The losses of round 0's steps after the first, summed: how fast
-        # the seeded model learns the counting data, first loss apart.
-        rest = r0["loss_mean"] * r0["steps"] - r0["loss_first"] if r0 else math.nan
-        out["descent_as_recorded"] = band["low"] <= rest <= band["high"]
-    ceiling = bands.get("loss_first_after_outer_step_max")
-    if ceiling is not None:
-        out["loss_stays_down_after_outer_step"] = bool(run.measured) and all(
-            r["loss_first"] <= ceiling for r in run.measured
-        )
+        out["descent_as_recorded"] = {
+            "value": descent_sum(r0), "low": band["low"], "high": band["high"]}
+    share = bands.get("loss_first_after_outer_step_share")
+    if share is not None and m:
+        # The faults this names (an outer update not applied, or applied with
+        # the wrong sign) put a round's first loss back at round 0's or
+        # above; a share of the run's own first loss needs no vocabulary.
+        out["loss_stays_down_after_outer_step"] = {
+            "value": max(r["loss_first"] for r in m), "high": share * r0["loss_first"],
+            "share": share, "of": r0["loss_first"]}
     return out
 
 
+def inside(margin: dict) -> bool:
+    v = margin["value"]
+    if not math.isfinite(v) or v < margin.get("low", -math.inf):
+        return False
+    high = margin.get("high", math.inf)
+    return v < high if margin.get("strict") else v <= high
+
+
 def checks(run, config: dict, traffic: dict) -> dict[str, bool]:
-    """Tentpole 1's conditions for ``correct``, each by name."""
+    """Tentpole 1's conditions for ``correct``, each by name. The ones that
+    compare a number are ``margins``' (set on ``run.margins``)."""
     m, r0 = run.measured, (run.rounds[0] if run.rounds else None)
     per_round = [round_checks(run, r) for r in m]
     outer = {o["round"]: o for o in run.outer}
     dev = run.device or {}
-    c = config["checks"]
-    target = math.log(c["vocabulary"]) + c["loss_first_offset"]
+    held = run.margins = margins(run, config, traffic)
+
+    def holds(name: str) -> bool:
+        return name in held and inside(held[name])
+
+    if config["checks"].get("reference"):
+        # The reference replaces the band around ln V. One that could not
+        # run fails under its own name, never under the comparison's.
+        first_loss = {"reference_ran": "first_loss_as_reference" in held}
+        if first_loss["reference_ran"]:
+            first_loss["first_loss_as_reference"] = (
+                holds("first_loss_as_reference") and r0["nonfinite"] == 0)
+    else:
+        first_loss = {"first_loss_near_ln_vocabulary":
+                      holds("first_loss_near_ln_vocabulary") and r0["nonfinite"] == 0}
+    # The mix's own bands on the losses (traffic file, ``checks``), taken from
+    # runs on the chip over many seeds: not a reference, but what a step that
+    # learns at another pace, or an outer update that throws the worker off,
+    # leaves. A mix without a band is not held to one.
+    bands = {"descent_after_first_step": "descent_as_recorded",
+             "loss_first_after_outer_step_share": "loss_stays_down_after_outer_step"}
     return {
         "rounds_measured": bool(m),
         "rounds_in_order": [r["round"] for r in run.rounds] == list(range(len(run.rounds))),
@@ -122,11 +178,10 @@ def checks(run, config: dict, traffic: dict) -> dict[str, bool]:
             outer.get(r["round"], {}).get("native_kernels") is True
             and outer.get(r["round"], {}).get("native_cbor") is True for r in m
         ),
-        "first_loss_near_ln_vocabulary": r0 is not None
-        and r0["nonfinite"] == 0
-        and abs(r0["loss_first"] - target) <= c["loss_first_tolerance"],
-        "loss_fell": bool(m) and m[-1]["loss_mean"] < r0["loss_mean"],
-        **trajectory_checks(run, traffic.get("checks", {})),
+        **first_loss,
+        "loss_fell": holds("loss_fell"),
+        **{check: holds(check) for key, check in bands.items()
+           if traffic.get("checks", {}).get(key) is not None},
         "attention_is_compiled_flash": (run.attention or "").startswith(
             "pallas flash kernel, compiled"
         ),

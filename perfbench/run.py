@@ -72,7 +72,9 @@ def main(argv: list[str] | None = None) -> int:
         note({"phase": "round", "measured": bool(mine), "wall_by_harness": mine.get("wall"), **r})
     for o in run.outer:
         note({"phase": "outer_step", **o})
-    note({"phase": "checks", **run.checks})
+    note({"phase": "checks", **run.checks, "margins": run.margins})
+    if run.reference is not None:
+        note({"phase": "reference", **run.reference})
     # In a traced run this is what tracing cost: set it beside the plain run's.
     note({"phase": "end_to_end", "trace": trace, "cause": run.cause,
           "cluster_starts": run.attempts,
@@ -86,6 +88,10 @@ def main(argv: list[str] | None = None) -> int:
         print(f"perfbench: incorrect: checks failed: {broken}; cause: {run.cause}; "
               f"rounds closed: {sorted(run.arrivals)}; measured: {len(run.measured)}",
               file=sys.stderr)
+    # Each number compared beside its limit: the last lines of stderr.
+    for name, held in run.margins.items():
+        print(f"perfbench: compared: {name}: {json.dumps(held)} -> "
+              f"{'inside' if measure.inside(held) else 'OUTSIDE'}", file=sys.stderr)
     device = run.device or {}
     if device.get("platform") != "tpu" or device.get("count", 0) < cell.chips:
         print(

@@ -146,7 +146,14 @@ def test_configuration_entry_and_file_agree(config):
     assert all(NAME.match(k) and k in body for k in config["reduced"])
     assert not any(re.search(r"(_dim|_rank|hidden_size|intermediate_size)$", k)
                    for k in config["reduced"])
-    assert isinstance(body["assumed"], dict) and body["checks"]["loss_first_tolerance"] > 0
+    assert isinstance(body["assumed"], dict)
+    checks = body["checks"]
+    if "reference" in checks:  # a module of the benchmark's own, and a tolerance with its reason
+        assert (REPO / "perfbench" / "reference" / f"{checks['reference']}.py").is_file()
+        assert 0 < checks["reference_tolerance"] < 0.03 and len(checks["reference_reason"]) > 100
+        assert "loss_first_tolerance" not in checks  # the reference replaces the band
+    else:
+        assert checks["loss_first_tolerance"] > 0
 
 
 def test_manifest_shape():

@@ -133,7 +133,13 @@ def test_a_traced_result_carries_the_per_layer_metrics_and_the_profile():
                    "breakdown": {"device_ops": [["fusion", 0.49]], "idle_gaps": []}}
     result = measure.result(run, CELL, trace=True, layer_values=readers.read_all(CELL, run))
     assert set(result) == RESULT_KEYS | {"breakdown"}
-    assert set(result["metrics"]) == {e["name"] for e, _ in CELL.per_layer}
+    # This record is of PR 23's program, before the phase spans, the step
+    # record and the lease lines: it gives the metrics of that PR and leaves
+    # the later ones out. test_split_metrics.py holds every listed metric to
+    # the record of this tree.
+    listed = {e["name"] for e, _ in CELL.per_layer}
+    assert set(PER_LAYER) | {"mfu_step", "in_step_share", "inner_gap_ms", "dispatch_s"} <= set(
+        result["metrics"]) <= listed
     assert result["device"]["busy_s"] == 1.5 and result["device"]["window_s"] == 14.2
 
 
@@ -231,12 +237,13 @@ def test_a_faulty_record_ends_in_the_contracts_line_with_correct_false(fault, ca
 
 
 @pytest.mark.parametrize("bands,failing", [
-    # Round 0 of the record: 8 x 9.6043 - 10.9979 = 65.84; round 1 starts at 7.59.
+    # Round 0 of the record: 8 x 9.6043 - 10.9979 = 65.84; round 1 starts at
+    # 7.59, 0.69 of round 0's 10.9979.
     ({"descent_after_first_step": {"low": 60.0, "high": 70.0},
-      "loss_first_after_outer_step_max": 8.0}, set()),
+      "loss_first_after_outer_step_share": 0.75}, set()),
     ({"descent_after_first_step": {"low": 66.0, "high": 70.0}}, {"descent_as_recorded"}),
     ({"descent_after_first_step": {"low": 60.0, "high": 65.0}}, {"descent_as_recorded"}),
-    ({"loss_first_after_outer_step_max": 5.0}, {"loss_stays_down_after_outer_step"}),
+    ({"loss_first_after_outer_step_share": 0.45}, {"loss_stays_down_after_outer_step"}),
     ({}, set()),  # a mix without a band is not held to one
 ], ids=["inside", "learns_slower", "learns_faster", "thrown_off_by_the_update", "no_band"])
 def test_the_mixs_bands_hold_the_losses_to_what_was_recorded(bands, failing):
@@ -247,6 +254,54 @@ def test_the_mixs_bands_hold_the_losses_to_what_was_recorded(bands, failing):
     assert result["correct"] is (not failing)
     named = {"descent_as_recorded", "loss_stays_down_after_outer_step"} & set(run.checks)
     assert len(named) == len([k for k in bands if k != "why"])
+    for name in named:  # the number is on the note beside its limits
+        assert measure.inside(run.margins[name]) is run.checks[name]
+
+
+def first_losses(*after: float, round0: float = 11.2) -> str:
+    """The recorded worker log with round 0 starting at ``round0`` and the
+    measured rounds at ``after``; a round not given is cut, with all after it."""
+    w0 = cut(W0, r"(round 0 done: .*loss_first=)10\.9979", rf"\g<1>{round0:.4f}")
+    for n, was in ((1, "7.5907"), (2, "4.7926"), (3, "2.4101")):
+        if n <= len(after):
+            w0 = cut(w0, rf"(round {n} done: .*loss_first=){was}", rf"\g<1>{after[n - 1]:.4f}")
+        else:
+            w0 = cut(w0, rf".*round {n} done: .*\n")
+    return w0
+
+
+@pytest.mark.parametrize("after,share,holds", [
+    # The largest of about 95 sound seeds, and the two outliers that the old
+    # ceiling of 0.1 refused or nearly did (PERF.md 6): all far below a
+    # tenth of round 0's 11.2.
+    ((0.0101,), 0.1, True),
+    ((0.0813,), 0.1, True),
+    ((0.2864,), 0.1, True),
+    ((0.0032, 0.2864, 0.0101), 0.1, True),
+    ((1.1199,), 0.1, True),  # the edge: 0.1 x 11.2
+    ((1.1300,), 0.1, False),
+    # An outer update that was not applied, or applied with the wrong sign,
+    # puts the round back at round 0's first loss or above.
+    ((11.2,), 0.1, False),
+    ((0.0032, 11.07), 0.1, False),  # any one measured round
+    ((11.2 * 0.2,), 0.1, False),
+    ((11.2 * 0.2,), 0.3, True),
+    ((), 0.1, False),  # no measured round: nothing shows that it held
+], ids=["largest_sound", "seed_2147488203", "seed_2147485132", "three_rounds", "edge",
+        "past_the_edge", "back_at_round_0", "second_round_back", "a_fifth", "a_fifth_of_0.3",
+        "no_measured_round"])
+def test_the_ceiling_after_the_outer_step_is_a_share_of_the_runs_own_first_loss(after, share, holds):
+    cell = dataclasses.replace(CELL, traffic={
+        **CELL.traffic, "checks": {"loss_first_after_outer_step_share": share}})
+    run = record(first_losses(*after), cell=cell)
+    measure.result(run, cell, trace=False)
+    assert run.checks["loss_stays_down_after_outer_step"] is holds
+    if after:
+        held = run.margins["loss_stays_down_after_outer_step"]
+        assert held["value"] == pytest.approx(max(after), abs=1e-4) and held["high"] == pytest.approx(share * 11.2)
+        assert (held["share"], held["of"]) == (share, 11.2)
+    else:
+        assert "loss_stays_down_after_outer_step" not in run.margins
 
 
 @pytest.mark.parametrize("mix", ["mistral-7b-d1.sync-h8", "mistral-7b-d1.steps"])
@@ -254,7 +309,10 @@ def test_each_cell_of_the_manifest_has_its_bands_and_they_hold_its_recorded_runs
     bands = manifest.resolve(mix, REPO).traffic["checks"]
     low, high = bands["descent_after_first_step"]["low"], bands["descent_after_first_step"]["high"]
     assert 0 < low < high < 1.75 * low  # a third of the way up or down already fails
-    assert 0 < bands["loss_first_after_outer_step_max"] <= 0.1
+    # Four times the largest sound reading on record (0.2864 of 11.2), a
+    # tenth of what the faults it names read; no absolute ceiling beside it.
+    assert 0.05 <= bands["loss_first_after_outer_step_share"] <= 0.2
+    assert "loss_first_after_outer_step_max" not in bands
 
 
 def test_a_traffic_key_that_nothing_reads_is_refused(tmp_path):

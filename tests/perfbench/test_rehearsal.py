@@ -107,8 +107,13 @@ def test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give(traced):
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     names = {m["name"] for m in manifest["per_layer"]}
     # No peak for a CPU and no memory statistic from it: those two are left
-    # out of the line, as a reader that finds nothing must.
-    assert set(result["metrics"]) == names - {"mfu_step", "hbm_peak_gb"}
+    # out of the line, as a reader that finds nothing must. And at this size
+    # a run may be over before a lease is first renewed (every 20 s), and the
+    # worker's clean-up takes under the 10 ms from which it is a span.
+    never = {"mfu_step", "hbm_peak_gb"}
+    not_always = {"lease_margin_min_s", "renew_late_max_s", "sync_cleanup_s"}
+    assert names - never - not_always <= set(result["metrics"]) <= names - never
+    assert len(names) == 33
     assert "no peak FLOP/s known for device_kind 'cpu'" in r.stderr
 
 
