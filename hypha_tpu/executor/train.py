@@ -31,6 +31,8 @@ __all__ = [
     "make_loss_fn",
     "chunked_causal_ce",
     "make_train_step",
+    "make_routed_train_step",
+    "ROUTING_FIELDS",
 ]
 
 
@@ -39,14 +41,21 @@ class TrainState(struct.PyTreeNode):
     params: Any
     opt_state: Any
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
+    # Variable collections beside ``params`` that the step updates by a rule
+    # of its own (afmoe's selection bias): not differentiated, no AdamW
+    # moments, no part of the pseudo-gradient. None for every other model.
+    extras: Any = None
 
     @classmethod
-    def create(cls, params, tx: optax.GradientTransformation) -> "TrainState":
+    def create(
+        cls, params, tx: optax.GradientTransformation, extras: Any = None
+    ) -> "TrainState":
         return cls(
             step=jnp.zeros((), jnp.int32),
             params=params,
             opt_state=tx.init(params),
             tx=tx,
+            extras=extras,
         )
 
     def apply_gradients(self, grads) -> "TrainState":
@@ -254,6 +263,66 @@ def make_train_step(
             "total_loss": total,
             "aux_loss": aux,
             "grad_norm": optax.global_norm(grads),
+        }
+        return new_state, metrics
+
+    return jax.jit(step, donate_argnums=(0,) if donate else ())
+
+
+# What a routed step's ``metrics["host"]`` vector holds, in order: the loss
+# and the step's routing counters, so one device-to-host transfer fetches all.
+ROUTING_FIELDS = (
+    "loss", "pairs_routed", "pairs_computed", "load_max", "tokens_elsewhere",
+)
+
+
+def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True):
+    """The jitted step of a model with routed experts and a selection bias
+    (``models/afmoe.py``): ``model.apply`` returns ``(out, stats)`` and
+    ``state.extras`` holds the ``moe_state`` collection.
+
+    The loss is the chunked causal cross-entropy over the final hidden states
+    (``with_head=False``), so the [B, S, vocab] logits never exist. The bias is
+    updated from the step's counts after the optimizer
+    (``models.afmoe.update_bias``); it gets no gradient and no moments. The
+    routing counters ride in ``metrics["host"]`` (``ROUTING_FIELDS``) beside
+    the loss: pairs summed and ``load_max`` maximised over the expert layers.
+    """
+    from ..models.afmoe import STATE, update_bias
+
+    body = model.clone(with_head=False)
+    coeff = model.config.load_balance_coeff
+
+    def loss_fn(params, extras, ids):
+        hidden, stats = body.apply({**params, **extras}, ids)
+        head = params["params"]["lm_head"].astype(hidden.dtype)
+        loss = chunked_causal_ce(hidden[:, :-1], head, ids[:, 1:], chunk=loss_chunk)
+        return loss, stats
+
+    def step(state: TrainState, batch) -> tuple:
+        ids = batch["input_ids"] if "input_ids" in batch else batch["inputs"]
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, state.extras, ids
+        )
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads)
+            new_state = new_state.replace(
+                extras={STATE: update_bias(state.extras[STATE], stats["chosen"], coeff)}
+            )
+        counters = {
+            "pairs_routed": stats["pairs_routed"].sum(),
+            "pairs_computed": stats["pairs_computed"].sum(),
+            "load_max": stats["load_max"].max(),
+            "tokens_elsewhere": stats["tokens_elsewhere"].sum(),
+        }
+        metrics = {
+            "loss": loss,
+            "total_loss": loss,
+            "aux_loss": jnp.float32(0),
+            "grad_norm": optax.global_norm(grads),
+            "host": jnp.stack(
+                [loss] + [counters[k].astype(jnp.float32) for k in ROUTING_FIELDS[1:]]
+            ),
         }
         return new_state, metrics
 

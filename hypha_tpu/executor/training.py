@@ -75,7 +75,13 @@ from .diloco import (
     merge_update_f32,
 )
 from .serialization import flat_leaf_map, flatten_tree, replace_leaves, unflatten_like
-from .train import TrainState, build_optimizer, make_train_step
+from .train import (
+    ROUTING_FIELDS,
+    TrainState,
+    build_optimizer,
+    make_routed_train_step,
+    make_train_step,
+)
 
 __all__ = ["run_training", "main", "TrainResult"]
 
@@ -819,8 +825,23 @@ def run_training(
             )
         params = adapters
 
+    # A model may keep variable collections beside ``params`` that its own
+    # step updates (afmoe's selection bias, ``moe_state``): they ride in
+    # ``state.extras``, outside the gradient, AdamW and the pseudo-gradient
+    # (anchor, delta and merge below only ever see ``state.params``). They are
+    # worker-local and, today, not part of a train checkpoint.
+    extras = None
+    if isinstance(params, dict) and set(params) - {"params"}:
+        extras = {k: v for k, v in params.items() if k != "params"}
+        params = {"params": params["params"]}
+        if cfg.lora or cfg.sharding:
+            raise ValueError(
+                f"job {spec.job_id}: a model with {sorted(extras)} state runs "
+                "unsharded and without LoRA"
+            )
+
     tx = build_optimizer(cfg.optimizer, cfg.scheduler)
-    state = TrainState.create(params, tx)
+    state = TrainState.create(params, tx, extras)
 
     # Resume (net-new vs reference): a re-dispatched executor picks up the
     # last completed round's params + optimizer state instead of θ₀.
@@ -912,6 +933,8 @@ def run_training(
 
             def step(state, batch):
                 return lora_step(state, frozen, batch)
+        elif extras is not None:
+            step = make_routed_train_step(model)
         else:
             step = make_train_step(model.apply, loss_kind, **step_kwargs)
 
@@ -1801,6 +1824,22 @@ def run_training(
     def mh_bound(what: str) -> float:
         return mh_timeout if compiled_once[what] else mh_grace
 
+    # A routed model's step packs its counters beside the loss
+    # (``metrics["host"]``, ROUTING_FIELDS): the one transfer that fetches the
+    # loss fetches them, and the round sums them for a line of its own.
+    routing = dict.fromkeys(("steps", *ROUTING_FIELDS[1:]), 0)
+
+    def fetch_loss(metrics) -> float:
+        host = metrics.get("host")
+        if host is None:
+            return float(metrics["loss"])
+        got = dict(zip(ROUTING_FIELDS, np.asarray(host).tolist()))
+        routing["steps"] += 1
+        for key in ("pairs_routed", "pairs_computed", "tokens_elsewhere"):
+            routing[key] += int(got[key])
+        routing["load_max"] = max(routing["load_max"], int(got["load_max"]))
+        return got["loss"]
+
     def run_one(batch):
         """Broadcast + dispatch + host fetch: every phase that can block on
         a dead follower, so the deadline covers all of them."""
@@ -1808,7 +1847,7 @@ def run_training(
             mh.step(batch)  # followers dispatch the same step
         new_state, metrics = step(state, place(batch))
         step_clock["dispatched"] = time.monotonic()
-        return new_state, metrics, float(metrics["loss"])
+        return new_state, metrics, fetch_loss(metrics)
 
     def run_one_deferred(batch):
         """Device double-buffering (input_pipeline): dispatch the step and
@@ -1829,7 +1868,7 @@ def run_training(
     def flush_pending_loss() -> None:
         while pending_metrics:
             metrics = pending_metrics.pop(0)
-            loss = float(metrics["loss"])
+            loss = fetch_loss(metrics)
             round_losses.append(loss)
             result.losses.append(loss)
 
@@ -1865,6 +1904,27 @@ def run_training(
             sum(step_times), max(step_times),
             round_mark["status_s"], round_mark["input_wait_s"],
         )
+        if routing["steps"]:
+            # The routed experts' counters, on a line of their own: pairs
+            # computed must equal pairs routed (nothing dropped); a held
+            # expert's mean load is pairs over steps x expert layers x held.
+            mcfg = model.config
+            layers = mcfg.num_layers - mcfg.num_dense_layers
+            cells = routing["steps"] * layers
+            load_mean = routing["pairs_computed"] / max(cells * mcfg.held, 1)
+            log.info(
+                "round %d routing: steps=%d expert_layers=%d experts_held=%d "
+                "pairs_routed=%d pairs_computed=%d pairs_per_token=%.4f "
+                "load_max=%d load_mean=%.2f load_max_over_mean=%.3f "
+                "tokens_elsewhere=%d",
+                result.rounds - 1, routing["steps"], layers, mcfg.held,
+                routing["pairs_routed"], routing["pairs_computed"],
+                routing["pairs_computed"] / max(round_mark["tokens"] * layers, 1),
+                routing["load_max"], load_mean,
+                routing["load_max"] / max(load_mean, 1e-9),
+                routing["tokens_elsewhere"],
+            )
+            routing.update(dict.fromkeys(routing, 0))
         step_times.clear()
         round_mark.update(
             t0=now, rounds=result.rounds, losses=len(result.losses), tokens=0,

@@ -7,6 +7,7 @@ MXU-sized matmuls, sharding-friendly param trees); anything else resolves
 through the registry's HF-conversion fallback (hypha_tpu.models.registry).
 """
 
+from .afmoe import Afmoe, AfmoeConfig
 from .lenet import LeNet, LeNetConfig
 from .gpt2 import GPT2, GPT2Config
 from .llama import Llama, LlamaConfig
@@ -14,6 +15,8 @@ from .mixtral import Mixtral, MixtralConfig
 from .registry import build_model, resolve_model_type
 
 __all__ = [
+    "Afmoe",
+    "AfmoeConfig",
     "LeNet",
     "LeNetConfig",
     "GPT2",

@@ -318,10 +318,11 @@ class _Attention(nn.Module):
             if window is not None and S > window:
                 # Mistral local attention: position i sees (i-window, i].
                 # The window threads through attn_impl when the kernel
-                # supports it; otherwise the fused-iota dense path runs (the
-                # flash/ring kernels don't take a window yet — warn, don't
-                # silently alter the objective OR silently drop the
-                # installed kernel).
+                # takes one (the flash kernel does, and skips the tiles
+                # outside the band); otherwise the fused-iota dense path
+                # runs (the ring kernel takes none — warn, don't silently
+                # alter the objective OR silently drop the installed
+                # kernel).
                 impl = self.attn_impl or dot_product_attention
                 if _accepts_kw(impl, "window"):
                     attn = impl(q, k, v, causal=True, window=window)

@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import Any
 
 from ..messages import ModelType
+from .afmoe import Afmoe, AfmoeConfig
 from .gpt2 import GPT2, GPT2Config
 from .lenet import LeNet, LeNetConfig
 from .llama import Llama, LlamaConfig
@@ -31,6 +32,7 @@ _PRESETS = {
     "llama": {"tiny": LlamaConfig.tiny, "llama2-7b": LlamaConfig.llama2_7b},
     "mixtral": {"tiny": MixtralConfig.tiny, "8x7b": MixtralConfig.mixtral_8x7b},
     "lenet": {"default": LeNetConfig},
+    "afmoe": {"tiny": AfmoeConfig.tiny},
 }
 
 FAMILIES = {
@@ -45,6 +47,10 @@ FAMILIES = {
     "qwen3": (Llama, LlamaConfig),
     "gemma": (Llama, LlamaConfig),
     "mixtral": (Mixtral, MixtralConfig),
+    # Arcee's afmoe (Trinity): sigmoid-routed experts with a selection bias,
+    # a shared expert, gated attention, window (RoPE) and full (NoPE) layers;
+    # one rank's share of the experts by ``experts_held``/``expert_offset``.
+    "afmoe": (Afmoe, AfmoeConfig),
     "lenet": (LeNet, LeNetConfig),
 }
 

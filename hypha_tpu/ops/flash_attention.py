@@ -61,16 +61,47 @@ __all__ = ["flash_attention"]
 _NEG_INF = float("-inf")
 
 
-def _causal_mask(qi, kj, block_q, block_k):
-    """[BQ, BK] bool: query position >= key position for this tile pair."""
+def _causal_mask(qi, kj, block_q, block_k, window=None):
+    """[BQ, BK] bool: query position >= key position for this tile pair;
+    with a ``window``, also key position > query position - window."""
     qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kpos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return qpos >= kpos
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (kpos > qpos - window)
 
 
-def _block_needed(qi, kj, block_q, block_k):
-    """False when the k tile lies strictly above the causal diagonal."""
-    return kj * block_k <= qi * block_q + block_q - 1
+def _block_needed(qi, kj, block_q, block_k, window=None):
+    """False when the k tile lies strictly above the causal diagonal or,
+    with a ``window``, wholly below the band (its last key is no later than
+    the tile's first query minus the window)."""
+    needed = kj * block_k <= qi * block_q + block_q - 1
+    if window is None:
+        return needed
+    return needed & (kj * block_k + block_k - 1 > qi * block_q - window)
+
+
+def _k_band(qi, block_q, block_k, window):
+    """First and last k tile that a q tile's band touches."""
+    lo = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return lo, (qi * block_q + block_q - 1) // block_k
+
+
+def _k_index_map(kv, block_q, block_k, window):
+    """The k/v tiles' index map over a (batch·head, q tile, k tile) grid. With
+    a ``window``, a tile outside the band is held at the band's nearest tile:
+    a block index that does not change is not fetched again, so what the
+    kernel skips is not loaded either."""
+    if window is None:
+        return lambda b, i, j: (kv(b), j, 0)
+    return lambda b, i, j: (kv(b), jnp.clip(j, *_k_band(i, block_q, block_k, window)), 0)
+
+
+def _q_band(kj, block_q, block_k, window, num_q):
+    """First and last q tile whose band touches a k tile."""
+    lo = kj * block_k // block_q
+    hi = (kj * block_k + block_k - 2 + window) // block_q
+    return lo, jnp.minimum(hi, num_q - 1)
 
 
 _LANES = 128  # TPU vector lane width: row stats are carried lane-replicated
@@ -114,7 +145,7 @@ def _pick_block(dim: int, cap: int) -> int | None:
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, block_q, block_k, scale, causal, num_k,
+    *, block_q, block_k, scale, causal, num_k, window=None,
 ):
     import jax.experimental.pallas as pl
 
@@ -131,7 +162,7 @@ def _fwd_kernel(
         # Non-causal: every tile contributes; causal: skip above-diagonal
         # tiles (the DMA still happens — grids are dense — but the FLOPs
         # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k))(fn) if causal else fn()
+        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
 
     @_run
     def _body():
@@ -145,7 +176,7 @@ def _fwd_kernel(
         v = v_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_causal_mask(qi, kj, block_q, block_k), s, _NEG_INF)
+            s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
         m = m_scr[...]  # [BQ, 128] lane-replicated
         m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
         # Fully-masked rows would give exp(-inf - -inf) = nan; clamp.
@@ -176,7 +207,7 @@ def _fwd_kernel(
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dq_scr,
-    *, block_q, block_k, scale, causal, num_k,
+    *, block_q, block_k, scale, causal, num_k, window=None,
 ):
     import jax.experimental.pallas as pl
 
@@ -191,7 +222,7 @@ def _dq_kernel(
         # Non-causal: every tile contributes; causal: skip above-diagonal
         # tiles (the DMA still happens — grids are dense — but the FLOPs
         # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k))(fn) if causal else fn()
+        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
 
     @_run
     def _body():
@@ -206,7 +237,7 @@ def _dq_kernel(
         )[:, None]  # Δ, recomputed in-VMEM
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_causal_mask(qi, kj, block_q, block_k), s, _NEG_INF)
+            s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
@@ -222,7 +253,7 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, block_q, block_k, scale, causal, num_q, reps,
+    *, block_q, block_k, scale, causal, num_q, reps, window=None,
 ):
     import jax.experimental.pallas as pl
 
@@ -242,7 +273,7 @@ def _dkv_kernel(
         # Non-causal: every tile contributes; causal: skip above-diagonal
         # tiles (the DMA still happens — grids are dense — but the FLOPs
         # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k))(fn) if causal else fn()
+        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
 
     @_run
     def _body():
@@ -257,7 +288,7 @@ def _dkv_kernel(
         )[:, None]  # Δ, recomputed in-VMEM
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
-            s = jnp.where(_causal_mask(qi, kj, block_q, block_k), s, _NEG_INF)
+            s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)  # [BQ, BK]
         pc = p.astype(do.dtype)
         dv_scr[...] += jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
@@ -298,9 +329,15 @@ def _kv_index(n_heads: int, n_kv: int):
     return lambda b: (b // n_heads) * n_kv + (b % n_heads) // reps
 
 
-def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv):
+def _fwd_impl(
+    q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
+    window=None,
+):
     """q: [B·H, S, D], k/v: [B·Hkv, S, D] → (o [B·H, Sq, D],
-    lse f32 [B·H, Sq, 128] lane-replicated — see layout note in module doc)."""
+    lse f32 [B·H, Sq, 128] lane-replicated — see layout note in module doc).
+
+    ``window=None`` builds the call as it was before the window existed
+    (``_k_index_map`` has what a window changes)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -310,6 +347,8 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
     grid = (bh, num_q, num_k)
     kv = _kv_index(n_heads, n_kv)
     kwargs = _tpu_kwargs(interpret)
+    band = {} if window is None else {"window": window}
+    k_map = _k_index_map(kv, block_q, block_k, window)
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel,
@@ -318,12 +357,13 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
             scale=scale,
             causal=causal,
             num_k=num_k,
+            **band,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, d), k_map),
+            pl.BlockSpec((1, block_k, d), k_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -344,7 +384,8 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
 
 
 def _bwd_impl(
-    q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret, n_heads, n_kv
+    q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
+    window=None,
 ):
     """Cotangents: dq [B·H, Sq, D]; dk/dv [B·Hkv, Sk, D] (GQA cotangents
     accumulate over the query heads sharing each kv head inside the dkv
@@ -358,8 +399,10 @@ def _bwd_impl(
     reps = n_heads // n_kv
     kv = _kv_index(n_heads, n_kv)
 
+    band = {} if window is None else {"window": window}
+    k_map = _k_index_map(kv, block_q, block_k, window)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0))
+    k_spec = pl.BlockSpec((1, block_k, d), k_map)
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kwargs = _tpu_kwargs(interpret)
 
@@ -371,6 +414,7 @@ def _bwd_impl(
             scale=scale,
             causal=causal,
             num_k=num_k,
+            **band,
         ),
         grid=(bh, num_q, num_k),
         in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, row_spec],
@@ -389,10 +433,16 @@ def _bwd_impl(
             return b
         return (b // n_kv) * n_heads + (b % n_kv) * reps + r // num_q
 
-    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, r: (qh(b, r), r % num_q, 0))
+    def q_tile(j, r):
+        i = r % num_q
+        if window is not None:
+            i = jnp.clip(i, *_q_band(j, block_q, block_k, window, num_q))
+        return i
+
+    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, r: (qh(b, r), q_tile(j, r), 0))
     k_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
     row_spec_t = pl.BlockSpec(
-        (1, block_q, _LANES), lambda b, j, r: (qh(b, r), r % num_q, 0)
+        (1, block_q, _LANES), lambda b, j, r: (qh(b, r), q_tile(j, r), 0)
     )
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -403,6 +453,7 @@ def _bwd_impl(
             causal=causal,
             num_q=num_q,
             reps=reps,
+            **band,
         ),
         grid=(bh_kv, num_k, reps * num_q),
         in_specs=[q_spec_t, k_spec_t, k_spec_t, q_spec_t, q_spec_t, row_spec_t],
@@ -421,36 +472,42 @@ def _bwd_impl(
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(
     q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-    interpret, n_heads, n_kv,
+    interpret, n_heads, n_kv, window,
 ):
     o, _ = _fwd_impl(
-        q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
+        q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv, window
     )
     return o
 
 
 def _flash_fwd(
     q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-    interpret, n_heads, n_kv,
+    interpret, n_heads, n_kv, window,
 ):
     o, lse = _fwd_impl(
-        q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv
+        q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv, window
     )
     return o, (q, k, v, o, lse)
 
 
+def _scope(name: str, window: int | None) -> str:
+    """The scope a kernel's device events carry: a windowed call says so
+    (``flash_attention_w2048``), so a trace tells the two kinds of layer apart."""
+    return name if window is None else f"{name}_w{window}"
+
+
 def _flash_bwd(
     causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, interpret,
-    n_heads, n_kv, res, do,
+    n_heads, n_kv, window, res, do,
 ):
     q, k, v, o, lse = res
-    with jax.named_scope("flash_attention_bwd"):
+    with jax.named_scope(_scope("flash_attention_bwd", window)):
         return _bwd_impl(
             q, k, v, o, lse, do, causal, scale, bwd_block_q, bwd_block_k,
-            interpret, n_heads, n_kv,
+            interpret, n_heads, n_kv, window,
         )
 
 
@@ -469,8 +526,15 @@ def flash_attention(
     block_q_bwd: int | None = None,
     block_k_bwd: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention with the framework's [B, S, H, D] convention and GQA.
+
+    ``window`` (causal only) is local attention: query i sees keys in
+    (i - window, i]. Tiles wholly outside that band are neither computed nor
+    loaded, in the forward and both backward kernels, so a window layer at
+    S > window costs about (window + block) / S of a full layer. ``None``, or
+    a window that no query of this length reaches, is the program without it.
 
     Differentiable: a custom VJP runs the recomputation backward kernels, so
     this is safe inside the jitted ``value_and_grad`` train step. Tiling
@@ -493,6 +557,11 @@ def flash_attention(
     """
     B, Sq, H, D = q.shape
     _, Sk, Hkv, _ = k.shape
+    if window is not None:
+        if not causal or Sq != Sk or window < 1:
+            raise ValueError("window needs causal self-attention and window >= 1")
+        if window >= Sq:
+            window = None  # never cuts: the plain causal program
     explicit_q, explicit_k = block_q is not None, block_k is not None
     if block_q is None:
         block_q = _pick_block(Sq, 512)
@@ -506,7 +575,7 @@ def flash_attention(
         or D > 128
     ):
         return dot_product_attention(
-            q, k, v, causal=causal, softmax_scale=softmax_scale
+            q, k, v, causal=causal, softmax_scale=softmax_scale, window=window
         )
     # Backward kernels tile independently (their dataflow differs: dq is
     # q-major, dk/dv k-major): on the r3 bench chip, (512, 512) bwd tiles
@@ -545,10 +614,10 @@ def flash_attention(
 
     # The scope names the forward kernel's device events; the backward
     # kernels are traced from _flash_bwd, under a scope of their own.
-    with jax.named_scope("flash_attention"):
+    with jax.named_scope(_scope("flash_attention", window)):
         out = _flash(
             to_bhsd(q), to_bhsd(k), to_bhsd(v),
             causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
-            interpret, H, Hkv,
+            interpret, H, Hkv, window,
         )
         return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

@@ -1,0 +1,145 @@
+"""The numbers ``trinity-mini-d5`` brings: its ``flops`` group against an
+independent count from the source's keys, the kernels' operations and bytes
+against hand counts, and the readers of the seven per-layer metrics of
+ISSUE 29 on one recorded step of the cell (``data/recorded_afmoe/``)."""
+
+from __future__ import annotations
+
+import json
+import types
+
+import pytest
+
+from perfbench import flops, kernel_counts, manifest
+from perfbench.readers import device_scope, read_spec
+
+from perfbench_helpers import DATA as FIXTURES, REPO
+
+CONFIG = json.loads((REPO / "perfbench" / "configs" / "trinity-mini-d5.json").read_text())
+TRAFFIC = manifest.load_traffic(REPO / "perfbench" / "traffic" / "trinity-mini-d5.steps.json")
+WAITING = json.loads((FIXTURES / "afmoe_layer_metrics.json").read_text())
+RECORDED = FIXTURES / "recorded_afmoe"
+
+
+def test_the_flops_group_gives_the_count_from_the_sources_keys():
+    c, s = CONFIG, TRAFFIC["sequence"]
+    d, hd = c["hidden_size"], c["head_dim"]
+    q, kv = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
+    attention = d * q * 3 + d * kv * 2  # q, gate, o; k, v
+    dense = 3 * d * c["intermediate_size"]
+    expert = 3 * d * c["moe_intermediate_size"]
+    routed = c["share"]["experts_routed"]
+    pairs_here = c["num_experts_per_tok"] * c["num_experts"] / routed  # half a pair a token
+    expert_layer = expert * c["num_shared_experts"] + d * routed + pairs_here * expert
+    layers, leading = c["num_hidden_layers"], c["num_dense_layers"]
+    active = (layers * attention + leading * dense + (layers - leading) * expert_layer
+              + c["vocab_size"] * d)
+    assert active == 264_110_080 == flops.matmul_params(c["flops"])
+    kinds = [c["layer_types"][i] for i in c["layers_run"]]
+    seen = sum(min(s, c["sliding_window"]) if k == "sliding_attention" else s for k in kinds)
+    mine = 6 * active + 12 * q * seen
+    assert mine == flops.flops_per_token(c["flops"], s) == 2_389_966_848
+    # the formula with this model's layers as they are would read too high
+    naive = dict(c["flops"], layers=5, mlp_width=c["intermediate_size"], mlp_matrices=3)
+    assert 1.5 < flops.flops_per_token(naive, s) / mine < 1.7
+
+
+def test_the_parameters_are_the_issues_arithmetic():
+    c = CONFIG
+    d, f = c["hidden_size"], c["moe_intermediate_size"]
+    attention = d * 4096 * 3 + d * 512 * 2 + 2 * 128  # and the two head norms
+    norms = 4 * d
+    dense = attention + norms + 3 * d * c["intermediate_size"]
+    expert_layer = attention + norms + 3 * d * f + d * 128 + c["num_experts"] * 3 * d * f
+    total = dense + 4 * expert_layer + 2 * c["vocab_size"] * d + d
+    assert total == 504_147_200  # what the AOT compile's state holds (PERF.md 4)
+
+
+def test_a_band_is_counted_by_the_pairs_it_holds():
+    assert kernel_counts.band_pairs(4, None) == 10  # 1 + 2 + 3 + 4
+    assert kernel_counts.band_pairs(4, 2) == 1 + 2 + 2 + 2
+    assert kernel_counts.band_pairs(4, 9) == 10
+    assert kernel_counts.band_pairs(8192, 2048) == sum(min(i + 1, 2048) for i in range(8192))
+    full = kernel_counts.flash_attention(1, 8192, 32, 4, 128, None)
+    cut = kernel_counts.flash_attention(1, 8192, 32, 4, 128, 2048)
+    assert cut["flops"] / full["flops"] == pytest.approx(0.4375, abs=0.0002)  # ISSUE 29's 0.44
+    assert full["bytes"] == cut["bytes"] == (6 * 32 + 6 * 4) * 8192 * 128 * 2
+
+
+def test_flash_attention_by_hand():
+    # one head of size 2, three positions, no window: 6 pairs; forward 4 x 2 a
+    # pair (two products), backward 10 x 2 (five)
+    c = kernel_counts.flash_attention(1, 3, 1, 1, 2, None, element_bytes=2)
+    assert c["flops"] == 6 * (8 + 20)
+    assert c["bytes"] == (3 * 2 * 2) * (4 + 8)  # q, k, v, o; then q, k, v, o, dO, dQ, dK, dV
+
+
+def test_the_grouped_product_by_hand():
+    # 5 pairs, width 4, expert width 3, 2 experts held, 1 layer: three products
+    # of 2 x 4 x 3 a pair forward, six backward
+    c = kernel_counts.grouped_swiglu(5, 4, 3, 2, 1, element_bytes=2)
+    assert c["flops"] == 5 * 9 * (2 * 4 * 3)
+    assert c["bytes"] == 3 * (3 * 2 * 4 * 3 * 2) + 4 * (5 * 4 * 2)
+    assert kernel_counts.roofline_share({"flops": 197e12, "bytes": 0.0}, 2.0, 197e12, 819e9) == 50.0
+    assert kernel_counts.roofline_share({"flops": 0.0, "bytes": 819e9}, 4.0, 197e12, 819e9) == 25.0
+    with pytest.raises(KeyError):
+        kernel_counts.peak_bytes_per_s("cpu")
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """One traced step of the cell (H 8 then, so the mix says 1 step here)."""
+    cell = types.SimpleNamespace(config=CONFIG, traffic=dict(TRAFFIC, inner_steps=1))
+    line = ("2026-09-28 00:57:39,731 hypha.executor.training INFO round 1 routing: steps=8 "
+            "expert_layers=4 experts_held=8 pairs_routed=213030 pairs_computed=213030 "
+            "pairs_per_token=0.8126 load_max=3593 load_mean=832.15 load_max_over_mean=4.318 "
+            "tokens_elsewhere=110487\n")
+    run = types.SimpleNamespace(out_dir=RECORDED, texts={"w0": line}, measured=[{"round": 1}],
+                                device={"kind": "TPU v5 lite", "count": 1})
+    return cell, run
+
+
+def test_the_waiting_entries_and_specs_agree_and_name_readers_that_exist():
+    assert [e["name"] for e in WAITING["entries"]] == list(WAITING["specs"]) and len(WAITING["specs"]) == 7
+    listed = {m["name"] for m in manifest.load_manifest(REPO)["per_layer"]}
+    for entry in WAITING["entries"]:
+        spec = WAITING["specs"][entry["name"]]
+        assert entry["name"] not in listed  # PERF.md 7 says which files bar the way
+        assert (spec["layer"], spec["unit"], spec["moves"]) == (entry["layer"], entry["unit"], "tokens_per_s")
+        assert entry["workloads"] == ["trinity-mini-d5.steps"]
+        assert (REPO / "perfbench" / "readers" / f"{spec['reader']}.py").is_file()
+        assert entry["name"].endswith("_roofline") == (entry["unit"] == "%")
+
+
+def test_the_seven_metrics_read_the_recorded_step(recorded):
+    cell, run = recorded
+    values: dict = {}
+    for name, spec in WAITING["specs"].items():
+        values[name] = read_spec(spec, run, cell, values)
+    assert values["moe_pairs_per_token"] == 0.8126 and values["moe_load_max_over_mean"] == 4.318
+    # milliseconds a step, from the device's events of that step
+    assert 40 < values["flash_window_ms"] < 60 and 15 < values["moe_experts_ms"] < 30
+    assert 20 < values["moe_route_ms"] < 40
+    for share in ("moe_experts_roofline", "flash_window_roofline"):
+        assert 5 < values[share] < 100, (share, values[share])
+
+
+def test_a_while_and_the_operations_inside_it_count_once(recorded):
+    events = device_scope.device_events(device_scope.load(RECORDED))
+    inside = device_scope.busy_seconds(events, ["moe_combine"], [])
+    both = device_scope.busy_seconds(events, ["moe_combine"], ["while"])
+    loops = device_scope.busy_seconds(events, [], ["while"])
+    assert 0 < inside < loops and both == pytest.approx(loops)
+    assert device_scope.busy_seconds(events, ["no_such_scope"], []) == 0.0
+    # a scope is a whole component of the path: "router" is not "moe_router"
+    assert device_scope.matches({"name": "fusion.1", "args": {"tf_op": "jit(step)/x/router/dot:"}}, ["router"], [])
+    assert not device_scope.matches({"name": "fusion.1", "args": {"tf_op": "jit(step)/moe_router/dot:"}}, ["router"], [])
+
+
+def test_with_no_trace_and_on_a_program_without_the_scopes_the_readers_return_nothing(recorded, tmp_path):
+    cell, run = recorded
+    gone = types.SimpleNamespace(**{**vars(run), "out_dir": tmp_path, "texts": {"w0": ""}})
+    values: dict = {}
+    for name, spec in WAITING["specs"].items():
+        values[name] = read_spec(spec, gone, cell, values)
+    assert set(values.values()) == {None}

@@ -47,26 +47,60 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-# GPT-2-small's step (B16 S1024 H12 D64) and Mistral-7B's long-context GQA
-# (B2 S4096 H32/Hkv8 D128).
-FLASH_SHAPES = {"gpt2": (16, 1024, 12, 12, 64), "mistral": (2, 4096, 32, 8, 128)}
+# GPT-2-small's step (B16 S1024 H12 D64), Mistral-7B's long-context GQA
+# (B2 S4096 H32/Hkv8 D128), and Trinity-Mini's two kinds of layer at S 8192
+# (H32/Hkv4 D128): the window of 2048 that cuts, and the full layer.
+FLASH_SHAPES = {
+    "gpt2": (16, 1024, 12, 12, 64, None), "mistral": (2, 4096, 32, 8, 128, None),
+    "trinity_window": (1, 8192, 32, 4, 128, 2048), "trinity_full": (1, 8192, 32, 4, 128, None),
+}
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("shape", list(FLASH_SHAPES))
 def test_flash_attention_compiles_for_v5e(chip, shape, direction):
-    B, S, H, Hkv, D = FLASH_SHAPES[shape]
+    B, S, H, Hkv, D, window = FLASH_SHAPES[shape]
     q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=chip)
     kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16, sharding=chip)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False)
+        return flash_attention(q, k, v, causal=True, interpret=False, window=window)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
     assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
+    """One rank's routed experts at Trinity-Mini's widths: 8192 tokens, 8 of
+    128 experts held, 8 choices a token, the worst-case 65536 sorted pairs
+    walked in chunks of 2048 by a loop the compiler keeps as a loop, around
+    its own grouped-matmul call."""
+    from hypha_tpu.ops.grouped_matmul import grouped_swiglu
+
+    T, D, F, G, N = 8192, 2048, 1024, 8, 8192 * 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (
+        sds((T, D), jnp.bfloat16), sds((G, D, F), jnp.bfloat16), sds((G, D, F), jnp.bfloat16),
+        sds((G, F, D), jnp.bfloat16), sds((N,), jnp.int32), sds((N,), jnp.float32),
+        sds((G,), jnp.int32),
+    )
+
+    def fwd(x, wg, wu, wd, tok, wt, sizes):
+        return grouped_swiglu(x, wg, wu, wd, tok, wt, sizes)
+
+    def loss(x, wg, wu, wd, tok, wt, sizes):
+        return fwd(x, wg, wu, wd, tok, wt, sizes).sum()
+
+    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2, 3, 5))
+    text = _compiled_text(fn, *args)
+    assert "ragged-dot" in text and " while(" in text
 
 
 @pytest.mark.parametrize("quant", ["bf16", "int8"])
