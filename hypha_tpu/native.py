@@ -6,7 +6,8 @@ Nesterov over mmapped SafeTensors). The C++ equivalents live in
 ``native/``:
 
   * ``hypha_ps.cpp``          — flat f32 kernels (weighted sum, Nesterov,
-    and the PS's outer step: mean + Nesterov in place, threaded);
+    and the PS's fold and outer step: a delta scaled into the round's sum,
+    mean + Nesterov, both in place and threaded);
   * ``hypha_safetensors.cpp`` — mmap'd SafeTensors reader (own JSON header
     parser), writer, and ``ps_outer_step``: the WHOLE outer step over the
     delta files, zero-copy;
@@ -34,6 +35,7 @@ __all__ = [
     "weighted_sum",
     "nesterov_update",
     "fused_mean_nesterov",
+    "fold_scaled",
     "native_available",
     "ps_outer_step",
     "send_file_fd",
@@ -89,6 +91,11 @@ def _load() -> ctypes.CDLL | None:
             ctypes.c_float, ctypes.c_float, ctypes.c_int64,
         ]
         lib.fused_mean_nesterov_inplace_f32.restype = ctypes.c_int64
+        lib.fold_scaled_f32.argtypes = [
+            _F32P, _F32P, ctypes.c_float, ctypes.c_int64, ctypes.c_int,
+            ctypes.c_int64,
+        ]
+        lib.fold_scaled_f32.restype = ctypes.c_int64
         lib.st_open.argtypes = [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int]
         lib.st_open.restype = ctypes.c_void_p
         lib.st_close.argtypes = [ctypes.c_void_p]
@@ -224,6 +231,49 @@ def fused_mean_nesterov(
         np.multiply(mm, mu, out=tmp)
         np.add(tmp, g, out=g)
         np.multiply(g, lr, out=g)
+    return 1
+
+
+def fold_scaled(
+    acc: np.ndarray,
+    x: np.ndarray,
+    scale: float,
+    overwrite: bool,
+    threads: int = 1,
+) -> int:
+    """One delta leaf folded into the round's partial sum where the sum
+    lies: ``acc <- scale * x`` when ``overwrite`` (a round's first fold),
+    else ``acc <- acc + scale * x``, the product rounded to f32 before the
+    sum. Bit-equal to numpy's ``prev += np.float32(scale) * x`` whatever
+    ``threads`` is. When overwriting, ``x`` may be ``acc`` itself (bytes
+    read straight into the sum's buffer are scaled where they landed).
+    Nothing leaf-sized is allocated. Returns the number of threads that
+    ran (1 on the numpy fallback).
+    """
+    a = _in_place_f32(acc, "acc")
+    if x.dtype != np.float32 or not x.flags.c_contiguous:
+        raise ValueError("x must be a C-contiguous float32 array")
+    v = x.reshape(-1)
+    if a.size != v.size:
+        raise ValueError(f"acc size {a.size} != x size {v.size}")
+    scale = np.float32(scale)
+    lib = _load()
+    if lib is not None:
+        return int(
+            lib.fold_scaled_f32(
+                _ptr(a), _ptr(v), scale, a.size, int(overwrite), threads
+            )
+        )
+    with np.errstate(all="ignore"):  # as silent about inf and NaN as the kernel
+        if overwrite:
+            np.multiply(v, scale, out=a)
+            return 1
+        scratch = np.empty(min(a.size, _FALLBACK_BLOCK), np.float32)
+        for lo in range(0, a.size, _FALLBACK_BLOCK):
+            dst = a[lo:lo + _FALLBACK_BLOCK]
+            tmp = scratch[:dst.size]
+            np.multiply(v[lo:lo + _FALLBACK_BLOCK], scale, out=tmp)
+            np.add(dst, tmp, out=dst)
     return 1
 
 

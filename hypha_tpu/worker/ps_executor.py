@@ -57,7 +57,7 @@ import os
 import shutil
 import time
 import uuid
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 
 import numpy as np
@@ -103,7 +103,7 @@ from ..stream import (
     top_targets,
     with_serve_leaves,
 )
-from ..stream.accum import RoundAccum
+from ..stream.accum import RoundAccum, SumBuffers
 from ..stream.reduce import tree_broadcast
 from ..telemetry import trace
 from ..telemetry.flight import FLIGHT
@@ -267,7 +267,8 @@ class _ElasticState:
 
 @dataclass
 class _OuterMomentum:
-    """The job's Nesterov momentum: resident in the PS for the job's life.
+    """The job's resident outer state: the Nesterov momentum, for the job's
+    life, and the buffers each round's partial sum is kept in (``sums``).
 
     ``file`` is read once, by the first outer step, where something put a
     file there before it (the warm start from ``checkpoint_dir``,
@@ -275,13 +276,20 @@ class _OuterMomentum:
     the first round that carries it. ``save`` is whether the job has a
     ``checkpoint_dir``: the durable commit and ``_checkpoint_momentum``
     are the file's only readers, so only then does an outer step write
-    it. ``threads`` is what the fused pass may use.
+    it. ``threads`` is what the fused pass and the fold may use. ``sums``
+    is where every :class:`RoundAccum` of the job leases its leaves and
+    where ``_outer_step`` puts them back once the update is on disk, so a
+    round's sum lies at the last round's addresses.
     """
 
     file: Path
     save: bool
     threads: int = 1
     tree: dict[str, np.ndarray] | None = None
+    sums: SumBuffers = field(init=False)
+
+    def __post_init__(self) -> None:
+        self.sums = SumBuffers(self.threads)
 
 
 def _fire_once(fn):
@@ -591,7 +599,7 @@ class ParameterServerExecutor(JobExecutor):
                 accum = recovered_accums.pop(round_num, None)
                 preloaded_folded = accum is not None
                 if accum is None:
-                    accum = _RoundAccum()
+                    accum = _RoundAccum(momentum.sums)
                 if dur is not None:
                     await asyncio.to_thread(dur.note_open, round_num)
                 # Per-peer arrival lags (collect start -> delta accepted):
@@ -863,7 +871,7 @@ class ParameterServerExecutor(JobExecutor):
         for rec in resume.committed:
             rnd = int(rec["round"])
             frag = int(rec.get("fragment", 0))
-            accum = _RoundAccum()
+            accum = _RoundAccum(momentum.sums)
             for fold, sign in dur.replay_ops(rnd):
                 await asyncio.to_thread(
                     accum.fold, dur.deltas_dir / fold.file, fold.samples,
@@ -969,7 +977,7 @@ class ParameterServerExecutor(JobExecutor):
                 # Rebuild the in-flight accumulator by replaying the EXACT
                 # fold/un-fold sequence (replay_ops): bit-identical to the
                 # crashed process's partial sum, duplicates included.
-                accum = accums.setdefault(rnd, _RoundAccum())
+                accum = accums.setdefault(rnd, _RoundAccum(momentum.sums))
                 for fold, sign in dur.replay_ops(rnd):
                     await asyncio.to_thread(
                         accum.fold, dur.deltas_dir / fold.file, fold.samples,
@@ -1201,7 +1209,10 @@ class ParameterServerExecutor(JobExecutor):
         ``prefolded`` marks a tree-reduce partial: already Σ samples·Δθ,
         added verbatim (scaled only by ``sign``). ``span_attrs`` opens a
         round-trace ``fold`` span around the work (accept-path folds
-        only; un-folds and replays stay spanless).
+        only; un-folds and replays stay spanless). What the fold did
+        (``direct``: leaves that went from the file into resident memory
+        in one pass; ``resident``: leaves whose buffer was there and not
+        allocated) goes on that span and on the ``ps fold:`` line.
         """
         if accum is None:
             return
@@ -1210,10 +1221,22 @@ class ParameterServerExecutor(JobExecutor):
             if span_attrs is not None and sign > 0
             else None
         )
-        await asyncio.to_thread(
+        did = await asyncio.to_thread(
             accum.fold, entry[0], entry[1], sign, prefolded, fold_span
         )
+        if fold_span is not None:
+            fold_span.attributes.update(
+                leaves=did.leaves, direct=did.direct, resident=did.resident
+            )
         trace.finish(fold_span)
+        attrs = span_attrs or {}
+        log.info(
+            "ps fold: round=%s peer=%s sign=%d bytes=%d leaves=%d direct=%d "
+            "resident=%d read_s=%.3f accumulate_s=%.3f threads=%d",
+            attrs.get("round", "-"), attrs.get("peer", "-"), sign, did.bytes,
+            did.leaves, did.direct, did.resident, did.read_s,
+            did.accumulate_s, did.threads,
+        )
 
     async def _collect_round(
         self,
@@ -1762,7 +1785,7 @@ class ParameterServerExecutor(JobExecutor):
                         if sharded and sync_mode == "stream"
                         else None
                     ),
-                    ptrace=ptrace,
+                    ptrace=ptrace, sums=momentum.sums,
                 )
                 trace.reparent(qw_span, ptrace.ctx(round_num))
                 trace.finish(qw_span)
@@ -1943,6 +1966,7 @@ class ParameterServerExecutor(JobExecutor):
         sharded: bool = False,
         arrivals: "dict[str, float] | None" = None,
         ptrace: "_PsTrace | None" = None,
+        sums: "SumBuffers | None" = None,
     ) -> dict[str, tuple[Path, float]]:
         """Gather one round's FRAGMENT deltas: peer -> (path, samples).
 
@@ -2064,7 +2088,9 @@ class ParameterServerExecutor(JobExecutor):
                 )
                 await push.read_all()
                 continue
-            accum = accums.setdefault(delta_round, _RoundAccum())
+            accum = accums.get(delta_round)
+            if accum is None:
+                accum = accums[delta_round] = _RoundAccum(sums)
             bucket = (
                 received
                 if delta_round == round_num
@@ -2478,7 +2504,7 @@ class ParameterServerExecutor(JobExecutor):
             )
 
         if accum is None or accum.folds == 0:
-            accum = _RoundAccum() if accum is None else accum
+            accum = _RoundAccum(momentum.sums) if accum is None else accum
             for path, samples in received.values():
                 accum.fold(path, samples)
         update, denom = accum.take()
@@ -2535,6 +2561,9 @@ class ParameterServerExecutor(JobExecutor):
             save_file(update, str(out))
             ph.set("bytes", out.stat().st_size)
             ph.set("leaves", len(update))
+        # The update is on disk and nothing reads the arrays again: the
+        # next round's sum is folded into these pages.
+        accum.release(update)
         if momentum.save:
             momentum_tmp = work_dir / "momentum.next.safetensors"
             with phase("save_momentum", "save_momentum_s") as ph:

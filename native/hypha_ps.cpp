@@ -21,6 +21,29 @@
 #include <thread>
 #include <vector>
 
+// Runs body(lo, hi) over [0, n) split among up to `threads` threads, the
+// caller's included. A thread gets at least 2^19 elements (a leaf under
+// about a million runs on the caller alone), and ranges start on a cache
+// line. Returns the number of threads that ran.
+template <typename Body>
+static int64_t split_over_threads(int64_t n, int64_t threads, Body body) {
+  const int64_t kMinPerThread = int64_t{1} << 19;
+  int64_t t = std::max<int64_t>(1, std::min(threads, n / kMinPerThread));
+  int64_t per = ((n + t - 1) / t + 15) / 16 * 16;
+  std::vector<std::thread> pool;
+  int64_t lo = 0;
+  for (int64_t k = 1; k < t && lo + per < n; ++k, lo += per) {
+    try {
+      pool.emplace_back(body, lo, lo + per);
+    } catch (const std::system_error &) {
+      break;  // no thread to be had: the caller does the rest itself
+    }
+  }
+  body(lo, n);
+  for (auto &th : pool) th.join();
+  return static_cast<int64_t>(pool.size()) + 1;
+}
+
 extern "C" {
 
 // dst[i] = sum_k weights[k] * srcs[k][i]
@@ -85,28 +108,44 @@ static void mean_nesterov_range(float *__restrict__ acc,
   }
 }
 
-// A thread gets at least 2^19 elements (a leaf under about a million runs on
-// the caller alone), and ranges start on a cache line. Returns the number
-// of threads that ran, the caller's included.
 int64_t fused_mean_nesterov_inplace_f32(float *acc, float denom,
                                         float *momentum, int64_t n, float lr,
                                         float mu, int64_t threads) {
-  const int64_t kMinPerThread = int64_t{1} << 19;
-  int64_t t = std::max<int64_t>(1, std::min(threads, n / kMinPerThread));
-  int64_t per = ((n + t - 1) / t + 15) / 16 * 16;
-  std::vector<std::thread> pool;
-  int64_t lo = 0;
-  for (int64_t k = 1; k < t && lo + per < n; ++k, lo += per) {
-    try {
-      pool.emplace_back(mean_nesterov_range, acc, momentum, lo, lo + per,
-                        denom, lr, mu);
-    } catch (const std::system_error &) {
-      break;  // no thread to be had: the caller does the rest itself
+  return split_over_threads(n, threads, [=](int64_t lo, int64_t hi) {
+    mean_nesterov_range(acc, momentum, lo, hi, denom, lr, mu);
+  });
+}
+
+// One delta folded into the round's partial sum where the sum lies
+// (RoundAccum): the round's first fold overwrites, acc = scale * x, and a
+// later one adds, acc = acc + scale * x. The product is rounded to f32
+// before the sum, two roundings and no fused multiply-add, so the sum is
+// bit-equal to numpy's `prev += scale * x`, which the durable journal's
+// replay and the reduce tree rest on. g++ contracts a*b+c by default in
+// C++ (-ffp-contract=fast, ISO mode or not), also across statements, so
+// the adding loop turns contraction off for itself; tests/test_native.py
+// holds it. When overwriting, x may be acc itself: the bytes of a delta
+// read straight into the sum's buffer are scaled where they landed.
+static void scale_range(float *acc, const float *x, int64_t lo, int64_t hi,
+                        float scale) {
+  for (int64_t i = lo; i < hi; ++i) acc[i] = scale * x[i];
+}
+
+__attribute__((optimize("fp-contract=off"))) static void add_scaled_range(
+    float *__restrict__ acc, const float *__restrict__ x, int64_t lo,
+    int64_t hi, float scale) {
+  for (int64_t i = lo; i < hi; ++i) acc[i] = acc[i] + scale * x[i];
+}
+
+int64_t fold_scaled_f32(float *acc, const float *x, float scale, int64_t n,
+                        int overwrite, int64_t threads) {
+  return split_over_threads(n, threads, [=](int64_t lo, int64_t hi) {
+    if (overwrite) {
+      scale_range(acc, x, lo, hi, scale);
+    } else {
+      add_scaled_range(acc, x, lo, hi, scale);
     }
-  }
-  mean_nesterov_range(acc, momentum, lo, n, denom, lr, mu);
-  for (auto &th : pool) th.join();
-  return static_cast<int64_t>(pool.size()) + 1;
+  });
 }
 
 // BF16 variant for the wire-format deltas: a 7B round ships ~13.5 GB per

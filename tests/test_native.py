@@ -176,6 +176,86 @@ def test_fused_in_place_pass_keeps_the_leaf_shape(kernel_backend):
     np.testing.assert_array_equal(acc, np.full((3, 5), 1.5, np.float32))
 
 
+def _bits(a: np.ndarray) -> np.ndarray:
+    """Every bit of an f32 array, NaNs as one value: which NaN an
+    operation hands on is the processor's choice, not the kernel's."""
+    out = np.ascontiguousarray(a, np.float32).reshape(-1).view(np.uint32).copy()
+    out[np.isnan(a.reshape(-1))] = 0x7FC00000
+    return out
+
+
+_SPECIAL = np.asarray(
+    [-0.0, 0.0, np.inf, -np.inf, np.nan, 1e-45, -1e-45, 3.4e38, -3.4e38], np.float32
+)
+
+
+@pytest.mark.parametrize("threads", [1, 2])
+@pytest.mark.parametrize("size", [1, 4096, 2**19 - 1, 2**20 + 7, 2**22 + 3])
+def test_fold_scaled_is_bit_equal_to_the_numpy_expression(kernel_backend, size, threads):
+    """The fold's kernel against ``prev = s * x`` and ``prev += s * x``
+    (numpy: the product rounded, then the sum), an overwrite, two adds and
+    an un-fold in a row with zeros' signs, infinities and NaNs among the
+    values, over a leaf under and over what one thread takes."""
+    rng = np.random.default_rng(size + threads)
+    xs = [rng.standard_normal(size).astype(np.float32) for _ in range(4)]
+    for x in xs:
+        k = min(size, _SPECIAL.size)
+        x[rng.choice(size, k, replace=False)] = _SPECIAL[:k]
+    scales = [np.float32(24.0), np.float32(7.0), np.float32(1.0), np.float32(-24.0)]
+    acc = np.full(size, np.nan, np.float32)  # stale: the overwrite ignores it
+    want = None
+    for n, (x, scale) in enumerate(zip(xs, scales)):
+        x_before = x.copy()
+        with np.errstate(invalid="ignore", over="ignore"):
+            if want is None:
+                want = scale * x
+            else:
+                want += scale * x
+        used = native.fold_scaled(acc, x, scale, overwrite=n == 0, threads=threads)
+        assert np.array_equal(_bits(acc), _bits(want)), n
+        assert x.tobytes() == x_before.tobytes()
+        assert used == (max(1, min(threads, size >> 19)) if kernel_backend == "native" else 1)
+
+
+def test_fold_scaled_scales_bytes_where_they_landed(kernel_backend):
+    """Overwriting, the source may be the sum's own buffer."""
+    rng = np.random.default_rng(3)
+    acc = rng.standard_normal((3, 2**19 + 1)).astype(np.float32)
+    acc[0, :4] = [-0.0, 0.0, np.inf, np.nan]
+    want = np.float32(12.5) * acc
+    native.fold_scaled(acc, acc, 12.5, overwrite=True, threads=2)
+    assert acc.shape == want.shape and np.array_equal(_bits(acc), _bits(want))
+
+
+def test_fold_scaled_rounds_the_product_before_the_sum(kernel_backend):
+    """Two roundings: a fused multiply-add would keep the product's low
+    bits and give 2**-24 here, not 0. g++ contracts by default, so the
+    kernel's adding loop turns it off for itself; this holds it."""
+    x = np.full(64, 1 + 2.0**-12, np.float32)
+    acc = np.full(64, -(1 + 2.0**-11), np.float32)
+    native.fold_scaled(acc, x, np.float32(1 + 2.0**-12), overwrite=False)
+    assert acc.tobytes() == np.zeros(64, np.float32).tobytes()
+
+
+@pytest.mark.parametrize("fault", ["short_x", "float64_acc", "float64_x", "read_only", "strided"])
+def test_fold_scaled_refuses_what_it_cannot_write_over(kernel_backend, fault):
+    acc, x = np.ones(64, np.float32), np.ones(64, np.float32)
+    if fault == "short_x":
+        x = np.ones(63, np.float32)
+    elif fault == "float64_acc":
+        acc = acc.astype(np.float64)
+    elif fault == "float64_x":
+        x = x.astype(np.float64)
+    elif fault == "read_only":
+        acc.flags.writeable = False
+    else:
+        x = np.ones(128, np.float32)[::2]
+    before = acc.copy()
+    with pytest.raises(ValueError):
+        native.fold_scaled(acc, x, 2.0, overwrite=False, threads=2)
+    np.testing.assert_array_equal(acc, before)
+
+
 def test_send_file_fd_socketpair(tmp_path):
     payload = os.urandom(1 << 20) + b"tail"
     src = tmp_path / "blob.bin"

@@ -8,6 +8,7 @@ Reference: crates/worker/src/executor/parameter_server.rs (golden test
 from __future__ import annotations
 
 import asyncio
+import functools
 import io
 
 import numpy as np
@@ -656,3 +657,305 @@ def test_momentum_is_on_disk_before_commit_and_broadcast_exactly_under_a_checkpo
             # The round's delta and its update, and nothing else of that size.
             kept = [f for f in files if not f.startswith("delta-")]
             assert kept == [f"update-{rnd}.safetensors"], files
+
+
+# ---------------------------------------------------------------------------
+# The fold as one pass into resident buffers
+# ---------------------------------------------------------------------------
+
+from test_native import _bits  # noqa: E402  (every bit of an f32 array, NaNs as one value)
+
+
+def _same_bits(got: dict, want: dict) -> None:
+    assert set(got) == set(want)
+    for key in want:
+        assert got[key].shape == want[key].shape and got[key].dtype == np.float32, key
+        assert np.array_equal(_bits(got[key]), _bits(want[key])), key
+
+
+def _special_delta(rng, keys=LEAVES):
+    d = _delta(rng, keys)
+    flat = d["h_0/attn"] if "h_0/attn" in d else next(iter(d.values())).reshape(-1)
+    vals = [-0.0, 0.0, np.inf, -np.inf, np.nan, 1e-45, 3.4e38]
+    flat[: len(vals)] = vals[: flat.size]
+    return d
+
+
+def _write(path, tree, codec="none"):
+    from hypha_tpu import compress
+
+    compress.write_delta(path, tree, codec)
+    return path
+
+
+def _numpy_sum(ops):
+    """Today's arithmetic, written out: ``prev = s·Δ`` for a round's first
+    delta and ``prev += s·Δ`` after, s = f32(sign·samples), or f32(sign) for
+    a prefolded partial."""
+    want = None
+    with np.errstate(invalid="ignore", over="ignore"):
+        for tree, samples, sign, prefolded in ops:
+            scale = np.float32(sign) if prefolded else np.float32(sign * samples)
+            if want is None:
+                want = {k: scale * np.asarray(v, np.float32) for k, v in tree.items()}
+            else:
+                for k, v in tree.items():
+                    want[k] += scale * np.asarray(v, np.float32)
+    return want
+
+
+@functools.lru_cache(maxsize=None)
+def _fold_cases():
+    """Made once: no fold writes to what it is given."""
+    rng = np.random.default_rng(30)
+    d = [_special_delta(rng) if n == 1 else _delta(rng) for n in range(5)]
+    partial = _numpy_sum([(d[3], 5.0, 1.0, False), (d[4], 11.0, 1.0, False)])
+    return {
+        "one_delta": [(d[0], 24.0, 1.0, False)],
+        "two_deltas": [(d[0], 24.0, 1.0, False), (d[1], 7.0, 1.0, False)],
+        "four_deltas": [(d[n], s, 1.0, False) for n, s in enumerate((24.0, 7.0, 40.0, 3.0))],
+        "unfold_of_a_replaced_duplicate": [
+            (d[0], 24.0, 1.0, False), (d[2], 7.0, 1.0, False),
+            (d[0], 24.0, -1.0, False), (d[3], 24.0, 1.0, False),
+        ],
+        "prefolded_partial_first": [(partial, 16.0, 1.0, True), (d[0], 24.0, 1.0, False)],
+        "prefolded_partial_later_and_unfolded": [
+            (d[0], 24.0, 1.0, False), (partial, 16.0, 1.0, True), (partial, 16.0, -1.0, True),
+        ],
+        "signed_zeros_infinities_and_nans": [(d[1], 3.0, 1.0, False), (d[1], 3.0, 1.0, False)],
+    }
+
+
+@pytest.mark.parametrize("entry", ["file", "tree"])
+@pytest.mark.parametrize("threads", [1, 2])
+@pytest.mark.parametrize("case", sorted(_fold_cases()))
+def test_the_fold_is_bit_equal_to_the_numpy_expression(
+    tmp_path, kernel_backend, case, threads, entry
+):
+    """Files take the one-pass path (read into the sum's buffer, or into
+    the staging leaf, and scaled or added there); trees the decoded one.
+    ``h_0/attn`` is over what one thread takes, the others under."""
+    from hypha_tpu.stream.accum import RoundAccum, SumBuffers
+
+    ops = _fold_cases()[case]
+    accum = RoundAccum(SumBuffers(threads))
+    for n, (tree, samples, sign, prefolded) in enumerate(ops):
+        if entry == "file":
+            did = accum.fold(_write(tmp_path / f"d{n}.st", tree), samples, sign, prefolded)
+            assert did.direct == did.leaves == len(LEAVES)
+            assert did.threads == (threads if kernel_backend == "native" else 1)
+        else:
+            accum.fold_tree(tree, samples, sign, prefolded)
+    _same_bits(accum.partial(), _numpy_sum(ops))
+    assert accum.total_samples == sum(s * g for _, s, g, _ in ops)
+    assert accum.folds == sum(1 if g > 0 else -1 for _, _, g, _ in ops)
+
+
+@pytest.mark.parametrize("entry", ["file", "tree"])
+def test_a_kept_buffer_is_overwritten_by_a_rounds_first_fold_never_added_to(
+    tmp_path, kernel_backend, entry
+):
+    from hypha_tpu.stream.accum import RoundAccum, SumBuffers
+
+    pool = SumBuffers(2)
+    rng = np.random.default_rng(5)
+    first = RoundAccum(pool)
+    first.fold_tree(_delta(rng), 8.0)
+    taken, _ = first.take()
+    for buf in taken.values():
+        buf.fill(np.nan)  # what the outer step left there is stale
+    first.release(taken)
+    tree = _special_delta(rng)
+    second = RoundAccum(pool)
+    if entry == "file":
+        did = second.fold(_write(tmp_path / "d.st", tree), 3.0)
+        assert did.resident == did.direct == did.leaves == len(LEAVES)
+    else:
+        second.fold_tree(tree, 3.0)
+    got = second.partial()
+    _same_bits(got, _numpy_sum([(tree, 3.0, 1.0, False)]))
+    for key, buf in taken.items():
+        assert got[key] is buf  # the last round's pages
+
+
+def _ps_and_momentum(tmp_path, threads=2):
+    from hypha_tpu.worker.ps_executor import ParameterServerExecutor, _OuterMomentum
+
+    ps = ParameterServerExecutor(node=None, work_root=tmp_path)
+    return ps, _OuterMomentum(tmp_path / "momentum.safetensors", save=False, threads=threads)
+
+
+def test_round_twos_sum_lies_at_round_ones_addresses_and_leaves_its_update_alone(
+    tmp_path, kernel_backend
+):
+    """The job's buffers outlive the round's accumulator: lease, outer step
+    in place, file on disk, given back, leased again."""
+    from hypha_tpu.stream.accum import RoundAccum
+
+    ps, momentum = _ps_and_momentum(tmp_path)
+    rng = np.random.default_rng(11)
+    addresses, updates = [], []
+    for rnd in range(3):
+        accum = RoundAccum(momentum.sums)
+        did = accum.fold(_write(tmp_path / f"delta-{rnd}.st", _delta(rng)), 16.0)
+        assert did.direct == did.leaves == len(LEAVES)
+        assert did.resident == (len(LEAVES) if rnd else 0)
+        addresses.append({k: v.ctypes.data for k, v in accum.partial().items()})
+        # The last round's update is a file: this round's fold, into the
+        # buffers it was computed in, cannot have changed it.
+        assert [p.read_bytes() for p, _ in updates] == [b for _, b in updates]
+        out = ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, accum)
+        updates.append((out, out.read_bytes()))
+    assert addresses[0] == addresses[1] == addresses[2]
+    assert len({b for _, b in updates}) == 3
+
+
+def test_a_fragment_round_leases_and_touches_only_its_keys(tmp_path):
+    from hypha_tpu.stream.accum import RoundAccum
+
+    ps, momentum = _ps_and_momentum(tmp_path)
+    frags = [("wte", "bias"), ("h_0/attn", "scale")]
+    rng = np.random.default_rng(4)
+    held = {}
+    for rnd, keys in enumerate(frags):
+        accum = RoundAccum(momentum.sums)
+        accum.fold(_write(tmp_path / f"d{rnd}.st", _delta(rng, keys)), 8.0)
+        held.update(accum.partial())
+        ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, accum)
+    others = {k: held[k].copy() for k in frags[1]}
+    accum = RoundAccum(momentum.sums)
+    did = accum.fold(_write(tmp_path / "d2.st", _delta(rng, frags[0])), 8.0)
+    assert did.leaves == did.resident == len(frags[0])
+    assert {k: v.ctypes.data for k, v in accum.partial().items()} == {
+        k: held[k].ctypes.data for k in frags[0]
+    }
+    for k in frags[1]:
+        np.testing.assert_array_equal(held[k], others[k])
+
+
+def test_two_sums_open_over_the_same_keys_do_not_share_a_buffer(tmp_path):
+    """Pipelined stream rounds: the second open sum gets buffers of its
+    own, which the job then keeps too."""
+    from hypha_tpu.stream.accum import RoundAccum, SumBuffers
+
+    pool = SumBuffers()
+    rng = np.random.default_rng(6)
+    trees = [_delta(rng), _delta(rng)]
+    open_sums = [RoundAccum(pool), RoundAccum(pool)]
+    for n, (accum, tree) in enumerate(zip(open_sums, trees)):
+        accum.fold(_write(tmp_path / f"d{n}.st", tree), 4.0)
+    a, b = (s.partial() for s in open_sums)
+    for key in LEAVES:
+        assert not np.shares_memory(a[key], b[key])
+    _same_bits(a, _numpy_sum([(trees[0], 4.0, 1.0, False)]))
+    _same_bits(b, _numpy_sum([(trees[1], 4.0, 1.0, False)]))
+    for accum in open_sums:
+        accum.release(accum.take()[0])
+    again = [RoundAccum(pool), RoundAccum(pool)]
+    for accum in again:
+        assert accum.fold(_write(tmp_path / "d.st", trees[0]), 4.0).resident == len(LEAVES)
+
+
+def _faulty(tmp_path, fault, tree):
+    """A delta file that must be refused, and what the refusal says."""
+    import json
+    import struct
+
+    from safetensors.numpy import save_file
+
+    path = tmp_path / f"{fault}.st"
+    if fault == "mismatched_shape":
+        save_file({**tree, "wte": np.zeros((32, 64), np.float32)}, str(path))
+        return path, "mismatched shape"
+    if fault == "mismatched_keys":
+        save_file({k: v for k, v in tree.items() if k != "bias"}, str(path))
+        return path, "mismatched keys"
+    save_file(tree, str(path))
+    blob = path.read_bytes()
+    if fault == "truncated":
+        path.write_bytes(blob[:-1000])
+        return path, "do not fit"
+    n = struct.unpack("<Q", blob[:8])[0]
+    header = json.loads(blob[8:8 + n])
+    # "F32" over two bytes an element: the header's dtype is not the data's.
+    begin, end = header["scale"]["data_offsets"]
+    header["scale"]["data_offsets"] = [begin, end - 2]
+    head = json.dumps(header, separators=(",", ":")).encode().ljust(n)
+    assert len(head) == n
+    path.write_bytes(blob[:8] + head + blob[8 + n:])
+    return path, "do not fit"
+
+
+# A round's first delta is what the others are matched against, so only a
+# file that is wrong in itself can be refused there.
+@pytest.mark.parametrize("fault,folded_before", [
+    ("truncated", 0), ("wrong_dtype", 0), ("truncated", 1), ("wrong_dtype", 1),
+    ("mismatched_shape", 1), ("mismatched_keys", 1),
+])
+def test_a_delta_that_does_not_fit_is_refused_before_the_sum_is_written(
+    tmp_path, fault, folded_before
+):
+    from hypha_tpu.stream.accum import RoundAccum, SumBuffers
+
+    rng = np.random.default_rng(8)
+    pool = SumBuffers(2)
+    accum = RoundAccum(pool)
+    ops = []
+    if folded_before:
+        ops.append((_delta(rng), 24.0, 1.0, False))
+        accum.fold(_write(tmp_path / "good.st", ops[0][0]), 24.0)
+        before = {k: v.copy() for k, v in accum.partial().items()}
+    path, says = _faulty(tmp_path, fault, _delta(rng))
+    with pytest.raises(ValueError, match=says):
+        accum.fold(path, 8.0)
+    assert accum.folds == folded_before and accum.total_samples == 24.0 * folded_before
+    if folded_before:
+        _same_bits(accum.partial(), before)
+    else:
+        with pytest.raises(ValueError, match="no deltas folded"):
+            accum.partial()
+    # And the round goes on as if the refused delta had never come.
+    ops.append((_delta(rng), 7.0, 1.0, False))
+    accum.fold(_write(tmp_path / "next.st", ops[-1][0]), 7.0)
+    _same_bits(accum.partial(), _numpy_sum(ops))
+
+
+@pytest.mark.parametrize("later", [False, True], ids=["first_fold", "later_fold"])
+@pytest.mark.parametrize("codec", ["none", "bf16", "int8", "mixed"])
+def test_the_path_is_chosen_from_the_files_own_header(tmp_path, kernel_backend, codec, later):
+    """All-F32 SafeTensors goes straight into the sum; bf16 SafeTensors, an
+    HQD1 frame and a file with one bf16 tensor among f32 ones are decoded
+    as ever and land in the same resident buffers with today's sums."""
+    import ml_dtypes
+    from safetensors.numpy import save_file
+
+    from hypha_tpu import compress
+    from hypha_tpu.stream.accum import RoundAccum, SumBuffers
+
+    pool = SumBuffers(2)
+    rng = np.random.default_rng(12)
+    warm = RoundAccum(pool)
+    warm.fold_tree(_delta(rng), 1.0)
+    kept, _ = warm.take()
+    warm.release(kept)
+    accum = RoundAccum(pool)
+    ops = []
+    if later:
+        ops.append((_delta(rng), 24.0, 1.0, False))
+        accum.fold_tree(ops[0][0], 24.0)
+    tree = _delta(rng)
+    path = tmp_path / "delta.bin"
+    if codec == "mixed":
+        save_file({**tree, "bias": tree["bias"].astype(ml_dtypes.bfloat16)}, str(path))
+    else:
+        compress.write_delta(path, tree, codec)
+    decoded = compress.read_delta(path)
+    did = accum.fold(path, 8.0)
+    assert did.leaves == did.resident == len(LEAVES)
+    assert did.direct == (len(LEAVES) if codec == "none" else 0)
+    assert did.bytes == path.stat().st_size
+    ops.append((decoded, 8.0, 1.0, False))
+    got = accum.partial()
+    _same_bits(got, _numpy_sum(ops))
+    for key, buf in kept.items():
+        assert got[key] is buf

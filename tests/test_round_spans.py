@@ -256,6 +256,36 @@ def test_existing_spans_keep_names_and_attributes(traced):
             assert keys <= set(s["attrs"]), s
 
 
+@pytest.mark.parametrize("which", ["traced", "untraced"])
+@pytest.mark.parametrize("field", ["direct", "resident", "leaves", "read_s", "accumulate_s"])
+def test_the_fold_says_whether_it_went_in_one_pass_into_resident_buffers(request, which, field):
+    """The job sends f32 SafeTensors deltas: every leaf goes from the file
+    into the sum's buffer in one pass (``direct``), and from round 1 on
+    that buffer is the one round 0 left (``resident``). On the ``ps fold:``
+    line with tracing on and off, and on the ``fold`` span."""
+    got = request.getfixturevalue(which)
+    spans, lines = got if which == "traced" else ([], got)
+    folds = _fields(lines, r"ps fold: .*")
+    assert [f["round"] for f in folds] == list(range(ROUNDS))
+    assert all(f["peer"] == "w0" and f["sign"] == 1 and f["bytes"] > 0 for f in folds)
+    leaves = folds[0]["leaves"]
+    assert leaves > 0
+    want = {"direct": [leaves] * ROUNDS, "resident": [0, leaves], "leaves": [leaves] * ROUNDS}
+    for rnd, f in enumerate(folds):
+        if field in want:
+            assert f[field] == want[field][rnd], f
+            for s in _named(spans, "fold", rnd):
+                assert s["attrs"][field] == want[field][rnd], s
+        else:
+            assert f[field] >= 0
+            name = {"read_s": "fold.read", "accumulate_s": "fold.accumulate"}[field]
+            found = _named(spans, name, rnd)
+            if spans:
+                assert len(found) == leaves  # one a leaf on the one-pass path
+                secs = sum((s["mono_end_ns"] - s["mono_start_ns"]) / 1e9 for s in found)
+                assert abs(secs - f[field]) < 1e-3, (name, f)
+
+
 def test_merge_apply_says_that_it_ends_at_dispatch(traced):
     spans, _ = traced
     assert all(s["attrs"]["ends_at"] == "dispatch" for s in _named(spans, "merge.apply"))
