@@ -33,9 +33,11 @@ from __future__ import annotations
 from .feedback import ErrorFeedback
 from .frame import (
     MAGIC,
+    ReadStats,
     frame_tag,
     is_frame,
     read_delta,
+    read_delta_into,
     read_frame,
     write_delta,
     write_frame,
@@ -55,6 +57,8 @@ __all__ = [
     "write_frame",
     "read_frame",
     "read_delta",
+    "read_delta_into",
+    "ReadStats",
     "write_delta",
     "is_frame",
     "frame_tag",

@@ -25,10 +25,12 @@ publishes a torn frame.
 
 from __future__ import annotations
 
+import json
 import os
 import struct
+from dataclasses import dataclass
 from pathlib import Path
-from typing import Any
+from typing import Any, Callable
 
 import numpy as np
 
@@ -41,6 +43,8 @@ __all__ = [
     "write_frame",
     "read_frame",
     "read_delta",
+    "read_delta_into",
+    "ReadStats",
     "write_delta",
     "frame_tag",
 ]
@@ -236,3 +240,106 @@ def read_delta(path: Path | str) -> dict[str, np.ndarray]:
     from safetensors.numpy import load_file
 
     return dict(load_file(str(path)))
+
+
+@dataclass
+class ReadStats:
+    """What one read of a delta file did. ``direct``: leaves whose bytes
+    went from the file into memory the caller keeps in one pass.
+    ``resident``: leaves whose buffer was there before this read (kept from
+    an earlier round) and not allocated by it."""
+
+    bytes: int = 0
+    leaves: int = 0
+    direct: int = 0
+    resident: int = 0
+
+    def add(self, other: "ReadStats") -> None:
+        """Count one more file of the same round."""
+        self.bytes += other.bytes
+        self.leaves += other.leaves
+        self.direct += other.direct
+        self.resident += other.resident
+
+
+def f32_layout(path: Path) -> dict[str, tuple[tuple, int, int]] | None:
+    """``{key: (shape, file offset, bytes)}`` in file order when ``path``
+    is a SafeTensors file whose tensors are all ``F32``, by the file's own
+    header; None for anything else (an HQD1 frame, bf16, a header this
+    cannot read), which :func:`read_delta` decodes or refuses. An all-F32
+    header that does not fit its file raises ``ValueError``."""
+    size = os.path.getsize(path)
+    with open(path, "rb") as fp:
+        head = fp.read(8)
+        n = int.from_bytes(head, "little")
+        if len(head) < 8 or n > size - 8:  # an HQD1 frame's magic reads as a huge n
+            return None
+        try:
+            header = json.loads(fp.read(n))
+            header.pop("__metadata__", None)
+            entries = sorted(
+                (tuple(info["data_offsets"]), key, info["dtype"], tuple(info["shape"]))
+                for key, info in header.items()
+            )
+        except (ValueError, AttributeError, KeyError, TypeError):
+            return None
+    if any(dtype != "F32" for _, _, dtype, _ in entries):
+        return None
+    layout = {}
+    for (begin, end), key, _, shape in entries:
+        nbytes = 4 * int(np.prod(shape, dtype=np.int64))
+        if begin < 0 or end - begin != nbytes or 8 + n + end > size:
+            raise ValueError(
+                f"delta {key!r}: {nbytes} bytes of {shape} at {begin}:{end} "
+                f"do not fit {path.name} ({size} bytes)"
+            )
+        layout[key] = (shape, 8 + n + begin, nbytes)
+    return layout
+
+
+def read_exact(fd: int, offset: int, dst: np.ndarray) -> None:
+    """Fill ``dst`` with the file's bytes from ``offset``: reads into memory
+    that exists (no mapping of the file, no ``bytes`` in between), each
+    with the interpreter lock released."""
+    view = memoryview(dst.reshape(-1)).cast("B")
+    done = 0
+    while done < len(view):
+        got = os.preadv(fd, [view[done:]], offset + done)
+        if got <= 0:
+            raise ValueError(f"delta file ends {len(view) - done} bytes early")
+        done += got
+
+
+def read_delta_into(
+    path: Path | str,
+    lease: Callable[[str, tuple], tuple[np.ndarray, bool]],
+) -> tuple[dict[str, np.ndarray], ReadStats]:
+    """:func:`read_delta` into memory the caller keeps from one file to the
+    next: the same keys, shapes, dtype and bytes.
+
+    A plain SafeTensors file whose tensors are all F32 (what ``delta_codec``
+    none sends; known from the file's own header) goes a leaf at a time
+    from the file into the f32 buffer ``lease(key, shape)`` returns, with
+    whether that buffer was kept from an earlier call
+    (:meth:`~hypha_tpu.stream.accum.SumBuffers.lease`): no mapping of the
+    file and, once the buffers exist, nothing parameter-sized allocated.
+    The tree returned is those buffers, so it is only good until the caller
+    has them written again. Anything else comes back as :func:`read_delta`
+    gives it, fresh, with ``direct == 0``.
+    """
+    path = Path(path)
+    stats = ReadStats(bytes=path.stat().st_size)
+    layout = f32_layout(path)
+    if layout is None:
+        flat = read_delta(path)
+    else:
+        flat = {}
+        with open(path, "rb", buffering=0) as fp:
+            for key, (shape, offset, nbytes) in layout.items():
+                flat[key], kept = lease(key, shape)
+                stats.resident += kept
+                if nbytes:
+                    read_exact(fp.fileno(), offset, flat[key])
+        stats.direct = len(flat)
+    stats.leaves = len(flat)
+    return flat, stats

@@ -59,6 +59,7 @@ from .. import compress
 from ..ft.durable import RESYNC_KEY, restart_signal, stale_scheduler_response
 from ..ft.rejoin import CATCHUP_KEY
 from ..stream import SYNC_MODES, effective_fragments, fragment_due, merge_corrected
+from ..stream.accum import SumBuffers
 from ..stream.partition import partition_names, shard_of
 from ..worker.connectors import shard_route
 from ..telemetry import trace
@@ -1282,14 +1283,48 @@ def run_training(
             annotation=jax.profiler.TraceAnnotation(name),
         )
 
-    def log_sync(done_round: int, bytes_up: int, bytes_down: int) -> None:
+    def log_sync(done_round: int, bytes_up: int, read: compress.ReadStats) -> None:
         log.info(
             "sync done: round=%d encode_s=%.3f upload_s=%.3f wait_s=%.3f "
-            "merge_s=%.3f cleanup_s=%.3f bytes_up=%d bytes_down=%d",
+            "merge_s=%.3f cleanup_s=%.3f bytes_up=%d bytes_down=%d "
+            "leaves=%d direct=%d resident=%d",
             done_round, sync.get("encode_s", 0.0), sync.get("upload_s", 0.0),
             sync.get("wait_s", 0.0), sync.get("merge_s", 0.0),
-            sync.get("cleanup_s", 0.0), bytes_up, bytes_down,
+            sync.get("cleanup_s", 0.0), bytes_up, read.bytes,
+            read.leaves, read.direct, read.resident,
         )
+
+    # Where the blocking syncs keep the broadcast update on the host: one
+    # f32 buffer a leaf for the life of the job. Round 0 allocates them and
+    # faults them in; every later ``merge.read`` finds pages that exist. THE
+    # INVARIANT: these buffers are written in ``merge.read`` and nowhere
+    # else, and the tree ``read_update`` returns IS these buffers, so
+    # nothing may hold it past the round. merge_update is dispatched with
+    # them as host arguments and returns before the device has taken them
+    # (a CPU backend may alias them outright); they are next written in the
+    # NEXT round's ``merge.read``, which runs after that round's
+    # ``encode.extract`` has fetched (``device_get``; ``mh.gather`` on a
+    # multi-process replica) values computed from the merged parameters, so
+    # the transfer is long over. ``mh.merge`` encodes the tree as it sends
+    # it, and a follower merges the copy it received.
+    update_buffers = SumBuffers()
+
+    def read_update(path: Path, read: compress.ReadStats) -> dict[str, np.ndarray]:
+        """``merge.read``: one broadcast update file onto the host, its
+        counts added to ``read``. An all-F32 SafeTensors file goes a leaf at
+        a time into ``update_buffers``; anything else (an HQD1 frame
+        dequantizes to f32, bf16 loads as bf16) is decoded into a fresh
+        tree as ever. The file says which."""
+        flat, stats = compress.read_delta_into(path, update_buffers.lease)
+        read.add(stats)
+        if stats.direct:
+            # Lent, not handed over: the next round's read leases them again.
+            update_buffers.give_back(flat)
+        return flat
+
+    def note_read(ph, read: compress.ReadStats) -> None:
+        for name in ("bytes", "leaves", "direct", "resident"):
+            ph.set(name, getattr(read, name))
 
     def await_round_update(delta_path: Path) -> tuple[dict, dict]:
         """Results-stream events until this round's update broadcast:
@@ -1461,13 +1496,10 @@ def run_training(
             parent=meta.get(TRACEPARENT_KEY) or round_tp, key="merge_s",
             round=round_num,
         ) as mrg:
+            read = compress.ReadStats()
             with sync_phase("merge.read", parent=mrg.span) as ph:
-                # read_delta sniffs the format: a quantized (HQD1) broadcast
-                # dequantizes to f32, a SafeTensors one loads as before.
-                flat = compress.read_delta(update_file)
-                bytes_down = update_file.stat().st_size
-                ph.set("bytes", bytes_down)
-                ph.set("leaves", len(flat))
+                flat = read_update(update_file, read)
+                note_read(ph, read)
             # Ends when the merge and the new anchor are DISPATCHED: nothing
             # here waits for the device, and a span must not add a
             # synchronisation. What the device still owes is paid in the
@@ -1505,7 +1537,7 @@ def run_training(
             # accumulates one full-parameter-sized file per round under
             # work_dir/incoming.
             update_file.unlink(missing_ok=True)
-        log_sync(round_num, bytes_up, bytes_down)
+        log_sync(round_num, bytes_up, read)
         resp = send_status_gated(
             Progress(
                 kind=ProgressKind.UPDATE_RECEIVED, job_id=spec.job_id,
@@ -1694,20 +1726,18 @@ def run_training(
             "merge", parent=round_tp, key="merge_s", round=round_num
         ) as mrg:
             combined: dict = {}
+            read = compress.ReadStats()
             with sync_phase("merge.read", parent=mrg.span) as ph:
-                bytes_down = 0
                 for p in sorted(got):
-                    flat = compress.read_delta(got[p])
+                    flat = read_update(got[p], read)
                     if set(flat) != set(parts[p]):
                         raise ValueError(
                             f"part {p} placement mismatch: update carries "
                             f"{len(flat)} tensors, worker expects {len(parts[p])}"
                         )
                     combined.update(flat)
-                    bytes_down += got[p].stat().st_size
                     got[p].unlink(missing_ok=True)
-                ph.set("bytes", bytes_down)
-                ph.set("leaves", len(combined))
+                note_read(ph, read)
             # Ends at dispatch, as in do_update.
             with sync_phase(
                 "merge.apply", parent=mrg.span,
@@ -1727,7 +1757,7 @@ def run_training(
         ):
             for path in paths.values():
                 path.unlink(missing_ok=True)
-        log_sync(round_num, bytes_up, bytes_down)
+        log_sync(round_num, bytes_up, read)
         resp = send_status_gated(
             Progress(
                 kind=ProgressKind.UPDATE_RECEIVED, job_id=spec.job_id,

@@ -32,8 +32,6 @@ change the estimator's variance, never its expectation.
 from __future__ import annotations
 
 import contextlib
-import json
-import os
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Callable
@@ -41,6 +39,7 @@ from typing import Callable
 import numpy as np
 
 from .. import compress, native
+from ..compress.frame import ReadStats, f32_layout, read_exact
 from ..telemetry import trace
 
 __all__ = ["FoldStats", "RoundAccum", "SumBuffers"]
@@ -97,67 +96,14 @@ class SumBuffers:
 
 
 @dataclass
-class FoldStats:
-    """What one fold did. ``direct``: leaves whose bytes went from the file
-    into resident memory in one pass. ``resident``: leaves whose buffer in
-    the sum was there before this fold (kept from an earlier round, or
-    part of this round's sum already) and not allocated by it."""
+class FoldStats(ReadStats):
+    """What one fold did: a read's counts and the seconds of its two
+    phases. ``resident`` here also counts a leaf that is part of this
+    round's sum already."""
 
-    bytes: int = 0
-    leaves: int = 0
-    direct: int = 0
-    resident: int = 0
     read_s: float = 0.0
     accumulate_s: float = 0.0
     threads: int = 1
-
-
-def _f32_layout(path: Path) -> dict[str, tuple[tuple, int, int]] | None:
-    """``{key: (shape, file offset, bytes)}`` in file order when ``path``
-    is a SafeTensors file whose tensors are all ``F32``, by the file's own
-    header; None for anything else (an HQD1 frame, bf16, a header this
-    cannot read), which :func:`compress.read_delta` decodes or refuses. An
-    all-F32 header that does not fit its file raises ``ValueError``."""
-    size = os.path.getsize(path)
-    with open(path, "rb") as fp:
-        head = fp.read(8)
-        n = int.from_bytes(head, "little")
-        if len(head) < 8 or n > size - 8:  # an HQD1 frame's magic reads as a huge n
-            return None
-        try:
-            header = json.loads(fp.read(n))
-            header.pop("__metadata__", None)
-            entries = sorted(
-                (tuple(info["data_offsets"]), key, info["dtype"], tuple(info["shape"]))
-                for key, info in header.items()
-            )
-        except (ValueError, AttributeError, KeyError, TypeError):
-            return None
-    if any(dtype != "F32" for _, _, dtype, _ in entries):
-        return None
-    layout = {}
-    for (begin, end), key, _, shape in entries:
-        nbytes = 4 * int(np.prod(shape, dtype=np.int64))
-        if begin < 0 or end - begin != nbytes or 8 + n + end > size:
-            raise ValueError(
-                f"delta {key!r}: {nbytes} bytes of {shape} at {begin}:{end} "
-                f"do not fit {path.name} ({size} bytes)"
-            )
-        layout[key] = (shape, 8 + n + begin, nbytes)
-    return layout
-
-
-def _read_exact(fd: int, offset: int, dst: np.ndarray) -> None:
-    """Fill ``dst`` with the file's bytes from ``offset``: reads into memory
-    that exists (no mapping of the file, no ``bytes`` in between), each
-    with the interpreter lock released."""
-    view = memoryview(dst.reshape(-1)).cast("B")
-    done = 0
-    while done < len(view):
-        got = os.preadv(fd, [view[done:]], offset + done)
-        if got <= 0:
-            raise ValueError(f"delta file ends {len(view) - done} bytes early")
-        done += got
 
 
 class RoundAccum:
@@ -213,7 +159,7 @@ class RoundAccum:
                 f"fold.{name}", parent=span, attrs=attrs, into=times, key=f"{name}_s"
             )
 
-        layout = _f32_layout(path)
+        layout = f32_layout(path)
         if layout is None:
             with phase("read", {"bytes": stats.bytes}) as ph:
                 tree = compress.read_delta(path)
@@ -227,7 +173,7 @@ class RoundAccum:
                     _, offset, nbytes = layout[key]
                     if nbytes:
                         with phase("read", {"bytes": nbytes}):
-                            _read_exact(fp.fileno(), offset, landing)
+                            read_exact(fp.fileno(), offset, landing)
                     return landing
 
                 stats.direct = len(layout)

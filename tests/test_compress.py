@@ -20,7 +20,9 @@ from hypha_tpu.compress import (
     effective_codec,
     is_frame,
     read_delta,
+    read_delta_into,
     read_frame,
+    write_delta,
     write_frame,
 )
 from hypha_tpu.compress import quant
@@ -189,6 +191,85 @@ def test_read_delta_dispatches_on_magic(tmp_path):
     write_frame(q, tree, "int4", chunk=64)
     got_q = read_delta(q)
     assert got_q["w"].dtype == np.float32
+
+
+def _update_tree():
+    rng = np.random.default_rng(5)
+    return {
+        "blocks_0/attn/kernel": rng.standard_normal((257, 130)).astype(np.float32),
+        "scalar": np.float32(-0.0),
+        "empty": np.zeros((0, 4), np.float32),
+        "bias": rng.standard_normal(7).astype(np.float32),
+    }
+
+
+class _Pool:
+    """The lease a job's ``SumBuffers`` gives, with what it handed out."""
+
+    def __init__(self):
+        from hypha_tpu.stream.accum import SumBuffers
+
+        self.buffers = SumBuffers()
+        self.leased = {}
+
+    def lease(self, key, shape):
+        buf, kept = self.buffers.lease(key, shape)
+        self.leased[key] = buf
+        return buf, kept
+
+
+@pytest.mark.parametrize("leaf", sorted(_update_tree()))
+@pytest.mark.parametrize("reads", [1, 2])
+def test_read_delta_into_gives_what_read_delta_gives_in_leased_buffers(tmp_path, leaf, reads):
+    """An all-F32 SafeTensors file (a scalar comes back ``(1,)``, an empty
+    tensor stays empty): keys, shapes, dtype and bytes as ``read_delta``'s,
+    every leaf in the buffer that was leased for it; the second read of a
+    job finds the first one's buffers."""
+    path = tmp_path / "update.safetensors"
+    pool = _Pool()
+    for n in range(reads):
+        tree = {k: np.asarray(v) + np.float32(n) for k, v in _update_tree().items()}
+        write_delta(path, tree, "none")
+        want = read_delta(path)
+        got, stats = read_delta_into(path, pool.lease)
+        assert list(got) == list(want)
+        assert got[leaf] is pool.leased[leaf]
+        assert got[leaf].shape == want[leaf].shape and got[leaf].dtype == np.float32
+        assert got[leaf].tobytes() == want[leaf].tobytes()
+        assert stats.leaves == stats.direct == len(want) == 4
+        assert stats.bytes == path.stat().st_size
+        assert stats.resident == (len(want) if n else 0)
+        pool.buffers.give_back(got)
+    assert got["scalar"].shape == (1,) and got["empty"].shape == (0, 4)
+
+
+@pytest.mark.parametrize("codec", ["bf16", "int8", "int4"])
+def test_read_delta_into_leaves_other_formats_to_read_delta(tmp_path, codec):
+    """A bf16 SafeTensors file and an HQD1 frame come back exactly as
+    ``read_delta`` gives them, and nothing is leased."""
+    path = tmp_path / "update.safetensors"
+    write_delta(path, _update_tree(), codec)
+    want = read_delta(path)
+    pool = _Pool()
+    got, stats = read_delta_into(path, pool.lease)
+    assert not pool.leased and stats.direct == 0 and stats.resident == 0
+    assert stats.leaves == len(want) and stats.bytes == path.stat().st_size
+    assert list(got) == list(want)
+    for key in want:
+        assert got[key].dtype == want[key].dtype and got[key].shape == want[key].shape
+        assert got[key].tobytes() == want[key].tobytes()
+    if codec == "bf16":
+        assert got["bias"].dtype != np.float32
+
+
+@pytest.mark.parametrize("cut", [1, 4 * 130, 4 * 257 * 130])
+def test_read_delta_into_refuses_a_file_shorter_than_its_header_says(tmp_path, cut):
+    path = tmp_path / "update.safetensors"
+    write_delta(path, _update_tree(), "none")
+    data = path.read_bytes()
+    path.write_bytes(data[:-cut])
+    with pytest.raises(ValueError, match="do not fit"):
+        read_delta_into(path, _Pool().lease)
 
 
 def test_frame_rejects_malformed(tmp_path):

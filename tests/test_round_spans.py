@@ -112,8 +112,9 @@ async def _job(tmp_path, checkpoint_dir=None):
         await gw.stop()
 
 
-def _run_job(tmp_path, checkpoint_dir=None) -> list[str]:
-    """The job's log lines (worker, parameter server, arbiters, scheduler)."""
+def _run_job(tmp_path, checkpoint_dir=None, patch=None) -> list[str]:
+    """The job's log lines (worker, parameter server, arbiters, scheduler).
+    ``patch(mp)`` sets what a test wants replaced while the job runs."""
     handler = _Lines()
     loggers = [logging.getLogger(n) for n in LOGGERS]
     levels = [lg.level for lg in loggers]
@@ -123,6 +124,8 @@ def _run_job(tmp_path, checkpoint_dir=None) -> list[str]:
     try:
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(arbiter, "LEASE_TIMEOUT_S", LEASE_S)
+            if patch is not None:
+                patch(mp)
             result = run(_job(tmp_path, checkpoint_dir))
     finally:
         for lg, level in zip(loggers, levels):
@@ -284,6 +287,104 @@ def test_the_fold_says_whether_it_went_in_one_pass_into_resident_buffers(request
                 assert len(found) == leaves  # one a leaf on the one-pass path
                 secs = sum((s["mono_end_ns"] - s["mono_start_ns"]) / 1e9 for s in found)
                 assert abs(secs - f[field]) < 1e-3, (name, f)
+
+
+@pytest.mark.parametrize("which", ["traced", "untraced"])
+@pytest.mark.parametrize("field", ["direct", "resident", "leaves", "bytes"])
+def test_the_merge_read_says_whether_it_went_into_buffers_the_job_kept(request, which, field):
+    """The PS broadcasts f32 SafeTensors: every leaf goes from the file
+    into a host buffer of the job in one pass (``direct``), and from round 1
+    on that buffer is the one round 0 made (``resident``). On the worker's
+    ``sync done:`` line with tracing on and off, and on ``merge.read``."""
+    got = request.getfixturevalue(which)
+    spans, lines = got if which == "traced" else ([], got)
+    syncs = _fields(lines, r"sync done: .*")
+    assert [s["round"] for s in syncs] == list(range(ROUNDS))
+    leaves = syncs[0]["leaves"]
+    assert leaves > 0
+    want = {"direct": [leaves] * ROUNDS, "resident": [0, leaves],
+            "leaves": [leaves] * ROUNDS,
+            "bytes": [s["bytes_down"] for s in syncs]}
+    for rnd, s in enumerate(syncs):
+        if field != "bytes":
+            assert s[field] == want[field][rnd], s
+        found = _named(spans, "merge.read", rnd)
+        assert len(found) == (1 if spans else 0)
+        for span in found:
+            assert span["attrs"][field] == want[field][rnd], span
+
+
+@pytest.fixture(scope="module")
+def merges(tmp_path_factory):
+    """One more job, with every merge of the worker on record: the
+    parameters that went in, the tree ``merge_update`` was given (the job's
+    own host buffers), what came out (a copy made on the device as the
+    round's anchor is, dispatched and not waited for: the next step donates
+    the merged arrays themselves), and what ``read_delta`` makes of the
+    same broadcast file, fresh, read before the job's own reader runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from hypha_tpu import compress
+    from hypha_tpu.executor import training
+
+    record = {"fresh": [], "merge": []}
+    read_into, merge_update = compress.read_delta_into, training.merge_update
+
+    def spy_read(path, lease):
+        record["fresh"].append(compress.read_delta(path))
+        return read_into(path, lease)
+
+    def spy_merge(params, update):
+        out = merge_update(params, update)
+        record["merge"].append((params, update, jax.tree.map(jnp.copy, out)))
+        return out
+
+    def patch(mp):
+        mp.setattr(compress, "read_delta_into", spy_read)
+        mp.setattr(training, "merge_update", spy_merge)
+
+    _run_job(tmp_path_factory.mktemp("merges"), patch=patch)
+    assert len(record["fresh"]) == len(record["merge"]) == ROUNDS
+    return record
+
+
+@pytest.mark.parametrize("rnd", range(ROUNDS))
+def test_the_merged_parameters_are_what_a_merge_through_read_delta_gives(merges, rnd):
+    """Bit for bit, in every round, and read only now, after the job: round
+    0's merged parameters have outlived round 1's read, which wrote round
+    1's update over the buffers round 0's merge was dispatched with."""
+    import jax
+    import numpy as np
+
+    from hypha_tpu.executor.diloco import merge_update
+    from hypha_tpu.executor.serialization import flatten_tree, unflatten_like
+
+    params, _, out = merges["merge"][rnd]
+    want = merge_update(params, unflatten_like(merges["fresh"][rnd], params))
+    got, want = flatten_tree(jax.device_get(out)), flatten_tree(jax.device_get(want))
+    assert list(got) == list(want)
+    for key in want:
+        assert got[key].dtype == want[key].dtype and got[key].shape == want[key].shape
+        assert got[key].tobytes() == want[key].tobytes(), key
+    assert any(np.any(want[k] != v) for k, v in flatten_tree(jax.device_get(params)).items())
+
+
+def test_every_round_merges_from_the_same_host_buffers(merges):
+    """The tree ``merge_update`` is given is the job's buffers, the same
+    arrays in every round, and by the job's end they hold the last round's
+    update and no longer round 0's."""
+    import jax
+    import numpy as np
+
+    flat = [jax.tree.leaves(update) for _, update, _ in merges["merge"]]
+    assert all(type(leaf) is np.ndarray and leaf.dtype == np.float32 for leaf in flat[0])
+    for later in flat[1:]:
+        assert len(later) == len(flat[0])
+        assert all(a is b for a, b in zip(flat[0], later))
+    held = {leaf.tobytes() for leaf in flat[0]}
+    assert held == {v.tobytes() for v in merges["fresh"][-1].values()}
+    assert held != {v.tobytes() for v in merges["fresh"][0].values()}
 
 
 def test_merge_apply_says_that_it_ends_at_dispatch(traced):
