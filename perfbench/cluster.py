@@ -9,6 +9,7 @@ a set finds the chip and libtpu's lock free.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import re
@@ -45,6 +46,17 @@ LATER_REFERENCE_S = 90.0
 FIRST_REFERENCE_S = 400.0
 LATER_END_S = 352.0
 FIRST_END_S = 1190.0
+# Where the roles' files go: memory, not the checkout's file layer, whose
+# write-back and unlinking were the benchmark's noise (PERF.md 2). It is the
+# one ground every run is measured on: where it is absent or short the run
+# fails with the cause (``no_work_ground``), it does not measure elsewhere.
+# A test puts a temporary directory here.
+SHM = Path("/dev/shm")
+# Free there before a run starts: twice what the largest cell holds at once
+# (delta, the PS's copy, update, the worker's copy and the data: 8.1 GB).
+WORK_FREE_BYTES = 16 * 10**9
+OWNER = "owner"  # in a work directory: pid and start time of the harness that made it
+STALE_WITHOUT_OWNER_S = 60.0
 
 
 class RunFailure(Exception):
@@ -140,18 +152,31 @@ class Cluster:
         self.root, self.out_dir, self.trace = root, out_dir, trace
         self.children: dict[str, Child] = {}
         self.pgid = 0
-        # Data and the roles' work directories, inside the checkout and named
-        # to the roles relative to it (their working directory): the worker
-        # binds a unix socket three levels down, whose path may have 107
-        # bytes, and a checkout or a TMPDIR can be any length.
+        # Data and the roles' work directories, named to the roles relative
+        # to the checkout (their working directory): the worker binds a unix
+        # socket three levels down, whose path may have 107 bytes, and a
+        # checkout or a TMPDIR can be any length. ``open_work_dir`` makes it.
         self.work = Path("chiprun_out") / "pb-run"
         self.run_dir = root / self.work
-        shutil.rmtree(self.run_dir, ignore_errors=True)
-        self.run_dir.mkdir(parents=True)
+        self.work_dir: Path | None = None  # what run_dir links to, once made
         self.env = dict(os.environ)
         self.env["PYTHONPATH"] = str(root) + os.pathsep + self.env.get("PYTHONPATH", "")
         self.env["PYTHONUNBUFFERED"] = "1"
         self.gateway = f"127.0.0.1:{free_port()}"
+
+    def open_work_dir(self) -> None:
+        """``run_dir`` as a link to a fresh directory of this checkout's own
+        on ``SHM``. What a killed run left goes first: under this checkout's
+        name, and under any name whose harness is dead (a run cut by SIGKILL
+        cannot clean up, and what it leaves there is memory)."""
+        sweep_work_dirs()
+        target = work_dir_of(self.root)
+        remove_work_dir(self.run_dir, target)
+        self.run_dir.parent.mkdir(parents=True, exist_ok=True)
+        self.work_dir = target  # from here on ``close`` removes it
+        target.mkdir()
+        (target / OWNER).write_text(owner_mark(os.getpid()) or "")
+        self.run_dir.symlink_to(target)
 
     def start(self, name: str, *cli: str) -> Child:
         env, entry = dict(self.env), ["-m", "hypha_tpu"]
@@ -221,8 +246,69 @@ class Cluster:
             codes[n] = c.proc.returncode
             if not c.log.closed:
                 c.log.close()
-        shutil.rmtree(self.run_dir, ignore_errors=True)
+        remove_work_dir(self.run_dir, self.work_dir)
         return codes
+
+
+def work_dir_of(root: Path) -> Path:
+    """This checkout's own directory on ``SHM``, named from its path: the
+    parent's checkout and the change's on one machine share nothing."""
+    return SHM / ("perfbench-" + hashlib.sha256(str(root.resolve()).encode()).hexdigest()[:16])
+
+
+def remove_work_dir(run_dir: Path, target: Path | None) -> None:
+    """The link (``shutil.rmtree`` refuses one) or the directory, and what
+    the link pointed to."""
+    if run_dir.is_symlink():
+        run_dir.unlink()
+    else:
+        shutil.rmtree(run_dir, ignore_errors=True)
+    if target is not None:
+        shutil.rmtree(target, ignore_errors=True)
+
+
+def no_work_ground() -> str | None:
+    """Why no run can be measured on this machine, or ``None``."""
+    if not SHM.is_dir():
+        return f"{SHM} is absent"
+    free = shutil.disk_usage(SHM).free
+    if free < WORK_FREE_BYTES:
+        return (f"{SHM} has {free / 1e9:.1f} GB free, a run wants "
+                f"{WORK_FREE_BYTES / 1e9:.1f} GB for the roles' files")
+    return None
+
+
+def owner_mark(pid: int) -> str | None:
+    """A process by pid and start time (a pid alone comes round again), or
+    ``None`` where there is no such process."""
+    try:
+        stat = Path(f"/proc/{pid}/stat").read_text()
+        return f"{pid} {stat.rsplit(')', 1)[1].split()[19]}"
+    except (OSError, IndexError):
+        return None
+
+
+def is_stale(work_dir: Path) -> bool:
+    """Whether the harness that made ``work_dir`` is gone."""
+    try:
+        mark = (work_dir / OWNER).read_text()
+        return owner_mark(int(mark.split()[0])) != mark
+    except (OSError, ValueError, IndexError):
+        pass  # no owner yet, or never: stale once it has lain a while
+    try:
+        return time.time() - work_dir.stat().st_mtime > STALE_WITHOUT_OWNER_S
+    except OSError:
+        return False
+
+
+def sweep_work_dirs() -> None:
+    """Remove every checkout's work directory on ``SHM`` whose harness is
+    dead: the driver's later checkouts have other paths and so other names,
+    and what a cut run left is 8 GB of memory until someone frees it."""
+    for d in SHM.glob("perfbench-*"):
+        if d.is_dir() and not d.is_symlink() and is_stale(d):
+            print(f"perfbench: removing {d}, left by a run that was killed", file=sys.stderr)
+            shutil.rmtree(d, ignore_errors=True)
 
 
 PROBE = """
@@ -290,9 +376,13 @@ def reduce_profile(root: Path, out_dir: Path, env: dict) -> dict | None:
     except (IndexError, ValueError):
         print(f"perfbench: trace reduction failed: {r.stderr[-2000:]}", file=sys.stderr)
         return None
-    if "error" not in out:  # reduced: the raw trace is tens of megabytes a round
-        shutil.rmtree(out_dir / "profile" / "plugins", ignore_errors=True)
     return out
+
+
+def drop_raw_trace(out_dir: Path) -> None:
+    """Once the readers of device events have run: the raw trace is tens of
+    megabytes a round, and a run writes little that stays."""
+    shutil.rmtree(out_dir / "profile" / "plugins", ignore_errors=True)
 
 
 def run_reference(root: Path, cell, seed: int, timeout: float) -> dict:
@@ -378,6 +468,7 @@ def _attempt(cell, seed: int, seconds: float, run: Run, root: Path,
     run.cause, run.arrivals = None, {}
     cluster = Cluster(root, out_dir, trace)
     try:
+        cluster.open_work_dir()
         build_native(root, cluster.env)
         data_dir = cluster.run_dir / "counting"
         data.write_dataset(data_dir, cell.traffic, seed)

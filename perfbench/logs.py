@@ -17,6 +17,9 @@ NAMED_CAUSES = (
     # An offer that was not taken expires too, and says only "lease X
     # expired"; the job's own lease lost is the cancel line.
     ("lease expired", r"cancelling job \S+ \(lease \S+ expired\)"),
+    # The scheduler ending on a request about a lease that the other side no
+    # longer knew (PERF.md 7, the auction's race): named, not tried again.
+    ("a request about a lease was refused", r"RequestError: '[0-9a-f-]{36}'"),
     ("no route to ps", r"no route to ps"),
     ("device out of memory", r"RESOURCE_EXHAUSTED|[Oo]ut of memory"),
 )

@@ -12,15 +12,13 @@ merged, so a ``while`` and the operations inside it count once, and the sum
 is divided by the cell's steps a round: seconds a step, times ``scale``.
 
 It reads ``profile/plugins/profile/*/*.trace.json.gz`` under the run's output
-directory and returns ``None`` where that is not there. Today it never is
-when readers run: ``cluster.reduce_profile`` deletes the raw trace once
-``xplane.py`` has reduced it (PERF.md 7), so the metrics that use this reader
-are not in ``BENCHMARK.json`` yet; their specs and entries wait in
-``tests/perfbench/data/afmoe_layer_metrics.json``.
+directory and returns ``None`` where that is not there. The harness keeps the
+raw trace until every reader has run and removes it then (``run.py``).
 """
 
 from __future__ import annotations
 
+import functools
 import gzip
 import json
 from pathlib import Path
@@ -52,12 +50,15 @@ def busy_seconds(events: list[dict], scopes: list[str], names: list[str]) -> flo
     return sum(b - a for a, b in merge(spans)) / 1e9
 
 
+@functools.lru_cache(maxsize=1)  # a round's trace is parsed once, not once a metric
+def _parsed(path: Path) -> dict:
+    with gzip.open(path) as f:
+        return json.load(f)
+
+
 def load(out_dir: Path) -> dict | None:
     files = sorted(Path(out_dir).glob("profile/plugins/profile/*/*.trace.json.gz"))
-    if not files:
-        return None
-    with gzip.open(files[-1]) as f:
-        return json.load(f)
+    return _parsed(files[-1]) if files else None
 
 
 def read(spec: dict, run, cell, values: dict) -> float | None:

@@ -5,7 +5,9 @@
 chip. The last line of stdout is the contract's JSON object; everything else
 is on earlier lines, on stderr, or under ``chiprun_out/perfbench/``. With no
 TPU behind worker ``w0`` (a CPU rehearsal) the run still completes, says what
-it found on stderr, prints no result and exits 3.
+it found on stderr, prints no result and exits 3. Without a memory-backed
+``/dev/shm`` with room for the roles' files it does not start: one ground for
+every run (PERF.md 2), no result, exit 3.
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ from perfbench import cluster, manifest, measure, readers  # noqa: E402
 EXIT_FAILED_RUN = 1
 EXIT_USAGE = 2
 EXIT_NO_ACCELERATOR = 3
+EXIT_NO_GROUND = 3  # as off the TPU: this machine cannot give the cell's numbers
 
 
 def note(obj: dict) -> None:
@@ -55,6 +58,11 @@ def main(argv: list[str] | None = None) -> int:
         print(f"perfbench: {e.args[0]}", file=sys.stderr)
         return EXIT_USAGE
 
+    short = cluster.no_work_ground()
+    if short:
+        print(f"perfbench: no ground to measure on: {short}; no result", file=sys.stderr)
+        return EXIT_NO_GROUND
+
     def on_signal(signum, _frame):  # unwind through the finallys
         raise SystemExit(128 + signum)
 
@@ -65,19 +73,25 @@ def main(argv: list[str] | None = None) -> int:
           "seconds": seconds, "trace": trace})
     run = cluster.run_cell(cell, args.seed, seconds, trace, t_start, t_wall, ROOT)
     e2e = measure.end_to_end(run)
-    layer = readers.read_all(cell, run) if trace else None
+    layer = None
+    if trace:
+        try:
+            layer = readers.read_all(cell, run)
+        finally:
+            cluster.drop_raw_trace(run.out_dir)
     result = measure.result(run, cell, trace, layer)
     for r in run.rounds:
         mine = next((m for m in run.measured if m["round"] == r["round"]), {})
         note({"phase": "round", "measured": bool(mine), "wall_by_harness": mine.get("wall"), **r})
     for o in run.outer:
         note({"phase": "outer_step", **o})
-    note({"phase": "checks", **run.checks, "margins": run.margins})
+    later = measure.later_rounds(run)
+    note({"phase": "checks", **run.checks, "margins": run.margins, "later_rounds": later})
     if run.reference is not None:
         note({"phase": "reference", **run.reference})
     # In a traced run this is what tracing cost: set it beside the plain run's.
     note({"phase": "end_to_end", "trace": trace, "cause": run.cause,
-          "cluster_starts": run.attempts,
+          "cluster_starts": run.attempts, "work_fs": "shm",
           "libtpu_mapped_by": run.holders, "attention": run.attention,
           "logs": str(run.out_dir), **e2e})
     if trace and run.profile:
@@ -87,6 +101,10 @@ def main(argv: list[str] | None = None) -> int:
         broken = [k for k, ok in run.checks.items() if not ok]
         print(f"perfbench: incorrect: checks failed: {broken}; cause: {run.cause}; "
               f"rounds closed: {sorted(run.arrivals)}; measured: {len(run.measured)}",
+              file=sys.stderr)
+    if later:
+        print(f"perfbench: not compared: first loss of the measured rounds {later['first_loss']}: "
+              f"the largest {later['largest']} in round {later['largest_in_round']}",
               file=sys.stderr)
     # Each number compared beside its limit: the last lines of stderr.
     for name, held in run.margins.items():

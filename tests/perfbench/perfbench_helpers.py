@@ -13,7 +13,7 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent.parent
 DATA = Path(__file__).resolve().parent / "data"
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def make_root(tmp: Path) -> Path:

@@ -1,7 +1,9 @@
 """The numbers ``trinity-mini-d5`` brings: its ``flops`` group against an
 independent count from the source's keys, the kernels' operations and bytes
-against hand counts, and the readers of the seven per-layer metrics of
-ISSUE 29 on one recorded step of the cell (``data/recorded_afmoe/``)."""
+against hand counts, and the readers of the cell's own seven per-layer
+device and routing metrics (listed since PR 37, each with a ``workloads`` list)
+on one recorded step of the cell (``data/recorded_afmoe/``). A later configuration that
+brings metrics of its own brings a test like this one beside its record."""
 
 from __future__ import annotations
 
@@ -17,7 +19,11 @@ from perfbench_helpers import DATA as FIXTURES, REPO
 
 CONFIG = json.loads((REPO / "perfbench" / "configs" / "trinity-mini-d5.json").read_text())
 TRAFFIC = manifest.load_traffic(REPO / "perfbench" / "traffic" / "trinity-mini-d5.steps.json")
-WAITING = json.loads((FIXTURES / "afmoe_layer_metrics.json").read_text())
+CELL_NAME = "trinity-mini-d5.steps"
+# The metrics only this cell reports, with their specs, in the manifest's order.
+OWN = {e["name"]: (e, s) for e, s in manifest.resolve(CELL_NAME, REPO).per_layer
+       if e.get("workloads") == [CELL_NAME]}
+SPECS = {name: spec for name, (_, spec) in OWN.items()}
 RECORDED = FIXTURES / "recorded_afmoe"
 
 
@@ -99,22 +105,29 @@ def recorded():
     return cell, run
 
 
-def test_the_waiting_entries_and_specs_agree_and_name_readers_that_exist():
-    assert [e["name"] for e in WAITING["entries"]] == list(WAITING["specs"]) and len(WAITING["specs"]) == 7
-    listed = {m["name"] for m in manifest.load_manifest(REPO)["per_layer"]}
-    for entry in WAITING["entries"]:
-        spec = WAITING["specs"][entry["name"]]
-        assert entry["name"] not in listed  # PERF.md 7 says which files bar the way
+def test_the_cells_own_seven_are_listed_and_their_specs_name_readers_that_exist():
+    assert list(OWN) == [
+        "moe_pairs_per_token", "moe_load_max_over_mean", "moe_route_ms", "moe_experts_ms",
+        "moe_experts_roofline", "flash_window_ms", "flash_window_roofline"]
+    assert not (FIXTURES / "afmoe_layer_metrics.json").exists()  # they wait no longer
+    for name, (entry, spec) in OWN.items():
         assert (spec["layer"], spec["unit"], spec["moves"]) == (entry["layer"], entry["unit"], "tokens_per_s")
-        assert entry["workloads"] == ["trinity-mini-d5.steps"]
         assert (REPO / "perfbench" / "readers" / f"{spec['reader']}.py").is_file()
-        assert entry["name"].endswith("_roofline") == (entry["unit"] == "%")
+        assert name.endswith("_roofline") == (entry["unit"] == "%")
+    # Mistral's cells do not report them: 33 there, 40 here.
+    other = manifest.resolve("mistral-7b-d1.steps", REPO)
+    assert not set(OWN) & {e["name"] for e, _ in other.per_layer}
+    cell = manifest.resolve(CELL_NAME, REPO)
+    assert len(other.per_layer) + len(OWN) == len(cell.per_layer)
+    # and every cell reports the same three end-to-end metrics
+    assert [e["name"] for e in cell.end_to_end] == [e["name"] for e in other.end_to_end] == [
+        "tokens_per_s", "sync_exposed_s", "setup_s"]
 
 
 def test_the_seven_metrics_read_the_recorded_step(recorded):
     cell, run = recorded
     values: dict = {}
-    for name, spec in WAITING["specs"].items():
+    for name, spec in SPECS.items():
         values[name] = read_spec(spec, run, cell, values)
     assert values["moe_pairs_per_token"] == 0.8126 and values["moe_load_max_over_mean"] == 4.318
     # milliseconds a step, from the device's events of that step
@@ -140,6 +153,6 @@ def test_with_no_trace_and_on_a_program_without_the_scopes_the_readers_return_no
     cell, run = recorded
     gone = types.SimpleNamespace(**{**vars(run), "out_dir": tmp_path, "texts": {"w0": ""}})
     values: dict = {}
-    for name, spec in WAITING["specs"].items():
+    for name, spec in SPECS.items():
         values[name] = read_spec(spec, gone, cell, values)
     assert set(values.values()) == {None}

@@ -206,8 +206,8 @@ FAULTS = {
     "zeroed_head_or_other_weights": dict(
         w0=cut(W0, r"(round 0 done: .*loss_first=)10\.9979", r"\g<1>10.8249"),
         failing={"first_loss_near_ln_vocabulary"}, attempted=3, failed=0),
-    "loss_did_not_fall": dict(
-        w0=cut(W0, r"(round 3 done: .*loss_mean=)1\.0076", r"\g<1>9.9000"),
+    "loss_did_not_fall": dict(  # round 1's mean: the limit reads that round alone
+        w0=cut(W0, r"(round 1 done: .*loss_mean=)8\.0927", r"\g<1>9.9000"),
         failing={"loss_fell"}, attempted=3, failed=0),
 }
 
@@ -281,15 +281,20 @@ def first_losses(*after: float, round0: float = 11.2) -> str:
     ((1.1199,), 0.1, True),  # the edge: 0.1 x 11.2
     ((1.1300,), 0.1, False),
     # An outer update that was not applied, or applied with the wrong sign,
-    # puts the round back at round 0's first loss or above.
+    # puts round 1 back at round 0's first loss or above.
     ((11.2,), 0.1, False),
-    ((0.0032, 11.07), 0.1, False),  # any one measured round
+    # Round 1 alone is held (PR 37): what the outer step's momentum does to a
+    # later round in some seeds (14.46 in round 2 of seed 2147602303) is on
+    # the note, and is no longer a run's fault.
+    ((0.0032, 11.07), 0.1, True),
+    ((0.0637, 14.4626), 0.1, True),
+    ((11.07, 0.0032), 0.1, False),
     ((11.2 * 0.2,), 0.1, False),
     ((11.2 * 0.2,), 0.3, True),
     ((), 0.1, False),  # no measured round: nothing shows that it held
 ], ids=["largest_sound", "seed_2147488203", "seed_2147485132", "three_rounds", "edge",
-        "past_the_edge", "back_at_round_0", "second_round_back", "a_fifth", "a_fifth_of_0.3",
-        "no_measured_round"])
+        "past_the_edge", "back_at_round_0", "second_round_back", "seed_2147602303",
+        "first_round_back", "a_fifth", "a_fifth_of_0.3", "no_measured_round"])
 def test_the_ceiling_after_the_outer_step_is_a_share_of_the_runs_own_first_loss(after, share, holds):
     cell = dataclasses.replace(CELL, traffic={
         **CELL.traffic, "checks": {"loss_first_after_outer_step_share": share}})
@@ -298,10 +303,123 @@ def test_the_ceiling_after_the_outer_step_is_a_share_of_the_runs_own_first_loss(
     assert run.checks["loss_stays_down_after_outer_step"] is holds
     if after:
         held = run.margins["loss_stays_down_after_outer_step"]
-        assert held["value"] == pytest.approx(max(after), abs=1e-4) and held["high"] == pytest.approx(share * 11.2)
-        assert (held["share"], held["of"]) == (share, 11.2)
+        assert held["value"] == pytest.approx(after[0], abs=1e-4) and held["high"] == pytest.approx(share * 11.2)
+        assert (held["share"], held["of"], held["round"]) == (share, 11.2, 1)
+        later = measure.later_rounds(run)  # nothing hidden: every round, and the largest
+        assert later["first_loss"] == {n: pytest.approx(v, abs=1e-4) for n, v in enumerate(after, 1)}
+        assert later["largest"] == pytest.approx(max(after), abs=1e-4)
+        assert after[later["largest_in_round"] - 1] == max(after)
     else:
         assert "loss_stays_down_after_outer_step" not in run.margins
+        assert measure.later_rounds(run) == {}
+
+
+CLOSES = {2: 0.0026, 3: 0.0007, 4: 0.0003}  # the largest on record first (81 later rounds, PR 37)
+
+
+def four_rounds(first: dict, mean: dict | None = None, last: dict | None = None) -> str:
+    """The recorded worker log with a fourth measured round (a copy of round
+    3's line, 14.6 s later, so a window of 70 s holds it) and the given first,
+    mean and closing losses by round: the window of ``sync-h8`` since PR 33.
+    The record is gpt2-medium's, which learns slowly: the later rounds close
+    as the cells of the manifest do unless ``last`` says otherwise."""
+    line = logs.find_line(W0, r"round 3 done: ")
+    stamp = re.match(r"\S+ \S+", line).group(0)
+    later = re.sub(r"(\d\d):(\d\d),", lambda m: f"{int(m[1]):02d}:{int(m[2]) + 14:02d},", stamp)
+    assert logs.line_time(later + " x") == pytest.approx(logs.line_time(stamp + " x") + 14.0)
+    w0 = W0 + line.replace(stamp, later).replace("round 3 done", "round 4 done") + "\n"
+    for n, v in first.items():
+        w0 = cut(w0, rf"(round {n} done: .*loss_first=)[0-9.]+", rf"\g<1>{v:.4f}")
+    for n, v in (mean or {}).items():
+        w0 = cut(w0, rf"(round {n} done: .*loss_mean=)[0-9.]+", rf"\g<1>{v:.4f}")
+    for n, v in {**CLOSES, **(last or {})}.items():
+        w0 = cut(w0, rf"(round {n} done: .*loss_last=)[0-9.]+", rf"\g<1>{v:.4f}")
+    return w0
+
+
+def four_round_run(first: dict, mean: dict | None = None, last: dict | None = None):
+    ps = PS + "".join(
+        re.sub(r"round=3\b", "round=4", x).replace("round 3 delta", "round 4 delta") + "\n"
+        for x in PS.splitlines() if re.search(r"round=3\b|round 3 delta", x))
+    cell = dataclasses.replace(CELL, traffic={
+        **CELL.traffic, "checks": {"loss_first_after_outer_step_share": 0.1}})
+    run = record(four_rounds(first, mean, last), ps, seconds=70.0, cell=cell)
+    assert [r["round"] for r in run.measured] == [1, 2, 3, 4]
+    return run, cell, measure.result(run, cell, trace=False)
+
+
+SOUND = {0: 11.0844, 1: 0.0637, 2: 0.0102, 3: 0.0053, 4: 0.0059}
+
+
+@pytest.mark.parametrize("thrown_off_in,correct", [(3, True), (2, True), (4, True), (1, False)])
+def test_a_later_round_thrown_off_by_the_outer_steps_momentum_is_on_the_note_and_only_round_1_is_held(
+        thrown_off_in, correct):
+    run, cell, result = four_round_run({**SOUND, thrown_off_in: 14.4626})
+    assert result["correct"] is correct and result["attempted"] == 4, run.checks
+    assert run.checks["loss_stays_down_after_outer_step"] is correct
+    held = run.margins["loss_stays_down_after_outer_step"]
+    assert held["round"] == 1 and held["high"] == pytest.approx(1.10844)
+    assert held["value"] == (14.4626 if thrown_off_in == 1 else 0.0637)
+    later = measure.later_rounds(run)
+    assert (later["largest"], later["largest_in_round"]) == (14.4626, thrown_off_in)
+    assert later["first_loss"][thrown_off_in] == 14.4626 and sorted(later["first_loss"]) == [1, 2, 3, 4]
+    # the result's last key: each number compared beside its limits, nothing else
+    assert list(result)[-1] == "compared" and result["compared"]["loss_stays_down_after_outer_step"] == {
+        "value": held["value"], "high": held["high"]}
+    # the per-round checks still hold every measured round
+    assert all(run.checks[k] for k in ("work_as_the_cell_says", "one_delta_per_round",
+                                       "one_outer_update_per_round", "losses_finite"))
+
+
+@pytest.mark.parametrize("last,correct,worst", [
+    ({}, True, (0.0026, 2)),  # as on record: 0.0026 at most over 81 later rounds
+    ({3: 0.0548}, True, (0.0548, 3)),  # as a round from scratch closes (round 0 of Trinity)
+    ({3: 1.1084}, True, (1.1084, 3)),  # the ceiling itself: 0.1 x round 0's first loss
+    ({4: 1.1085}, False, (1.1085, 4)),
+    ({2: 8.1008}, False, (8.1008, 2)),  # thrown to 8.10 and stayed there
+    ({1: 9.9}, True, (0.0026, 2)),  # round 1 is held by its first loss and its mean, not here
+], ids=["recorded", "from_scratch", "at_the_ceiling", "over_it", "stays_off", "round_1"])
+def test_a_later_round_has_to_close_down_again(last, correct, worst):
+    """From round 2 on the first loss is on the note and not held; what is
+    held is that the round comes back: its closing loss under the share of
+    round 0's first loss that holds round 1's first."""
+    run, cell, result = four_round_run({**SOUND, 2: 8.1008}, last=last)
+    assert result["correct"] is correct and run.checks["later_rounds_close_down"] is correct
+    held = run.margins["later_rounds_close_down"]
+    assert (held["value"], held["round"]) == worst and held["high"] == pytest.approx(1.10844)
+    assert result["compared"]["later_rounds_close_down"] == {"value": worst[0], "high": held["high"]}
+    assert run.checks["loss_stays_down_after_outer_step"] and run.checks["loss_fell"]
+
+
+def test_a_window_of_one_round_has_no_later_round_to_hold():
+    cell = dataclasses.replace(CELL, traffic={
+        **CELL.traffic, "checks": {"loss_first_after_outer_step_share": 0.1}})
+    run = record(four_rounds(SOUND), seconds=1.0, cell=cell)
+    measure.result(run, cell, trace=False)
+    assert [r["round"] for r in run.measured] == [1]
+    assert "later_rounds_close_down" not in run.margins and "later_rounds_close_down" not in run.checks
+    assert run.checks["loss_stays_down_after_outer_step"]
+
+
+@pytest.mark.parametrize("mean,fell", [
+    ({1: 0.30, 4: 9.9}, True),  # the last round's mean is not the one compared
+    ({1: 9.7, 4: 0.01}, False),  # round 0's mean is 9.6043
+    ({1: 9.6043}, False),  # strictly below
+], ids=["last_round_up", "round_1_up", "equal"])
+def test_loss_fell_reads_round_1s_mean(mean, fell):
+    run, cell, result = four_round_run(SOUND, mean)
+    assert run.checks["loss_fell"] is fell and result["correct"] is fell
+    held = run.margins["loss_fell"]
+    assert (held["value"], held["high"], held["round"]) == (mean[1], 9.6043, 1)
+
+
+def test_a_later_round_still_fails_what_is_held_of_every_round():
+    w0 = cut(four_rounds(SOUND), r"(round 4 done: .*)nonfinite=0", r"\1nonfinite=3")
+    cell = dataclasses.replace(CELL, traffic={
+        **CELL.traffic, "checks": {"loss_first_after_outer_step_share": 0.1}})
+    run = record(w0, seconds=70.0, cell=cell)
+    result = measure.result(run, cell, trace=False)
+    assert result["correct"] is False and not run.checks["losses_finite"]
 
 
 @pytest.mark.parametrize("mix", ["mistral-7b-d1.sync-h8", "mistral-7b-d1.steps"])
@@ -334,6 +452,13 @@ def test_deltas_are_counted_in_every_sync_modes_wording():
     ("w0", "jaxlib.xla_extension.XlaRuntimeError: RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm", "device out of memory (in the w0 log)"),
     # An offer that was not taken expires too: that is no cause.
     ("ps", "hypha.worker.arbiter INFO lease caec1269 expired", None),
+    # ... unless the scheduler was about to take it (my chip run, PR 37, seed 2147730001):
+    # named, by what the scheduler's log ends in, and not tried again (test_retry.py).
+    ("scheduler", 'x\n  File "/r/hypha_tpu/scheduler/worker_handle.py", line 60, in create\n'
+     "    timeout = await handle._renew()\n  File \"/r/hypha_tpu/network/node.py\", line 897, in "
+     "_request_inner\n    raise RequestError(reply.get(\"error\", \"remote error\"))\n"
+     "hypha_tpu.network.node.RequestError: 'd11362ad-fa58-42fa-9898-3f85e7773e1c'",
+     "a request about a lease was refused (in the scheduler log)"),
 ])
 def test_known_causes_are_named_from_the_logs(role, line, named):
     assert logs.named_cause({role: f"2026-09-27 00:59:32,863 {line}\n"}) == named

@@ -105,7 +105,8 @@ def test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give(traced):
     assert r.returncode == 3, r.stderr[-3000:]
     result = rehearsal_result(r.stderr)
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    names = {m["name"] for m in manifest["per_layer"]}
+    # What every cell reports; a metric with a ``workloads`` list is its own cell's.
+    names = {m["name"] for m in manifest["per_layer"] if "workloads" not in m}
     # No peak for a CPU and no memory statistic from it: those two are left
     # out of the line, as a reader that finds nothing must. And at this size
     # a run may be over before a lease is first renewed (every 20 s), and the
@@ -113,7 +114,7 @@ def test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give(traced):
     never = {"mfu_step", "hbm_peak_gb"}
     not_always = {"lease_margin_min_s", "renew_late_max_s", "sync_cleanup_s"}
     assert names - never - not_always <= set(result["metrics"]) <= names - never
-    assert len(names) == 33
+    assert len(names) == len(manifest["per_layer"]) - 7  # Trinity's seven are not this cell's
     assert "no peak FLOP/s known for device_kind 'cpu'" in r.stderr
 
 
@@ -129,6 +130,87 @@ def test_a_traced_run_reads_the_programs_spans_and_opens_the_profiler(traced):
     device = rehearsal_result(r.stderr)["device"]
     assert device["busy_s"] is None and device["window_s"] is None
     assert notes(r.stdout)["profile"]["error"] == "no device events"
+    # The raw trace stayed until the readers had run, and is gone now.
+    assert not (out / "profile" / "plugins").exists()
+
+
+def _nothing_of_the_work_directory_is_left(root: Path) -> bool:
+    from perfbench import cluster
+
+    link = root / "chiprun_out" / "pb-run"
+    return not link.is_symlink() and not link.exists() and not cluster.work_dir_of(root).exists()
+
+
+def test_the_roles_files_lived_off_the_checkout_and_nothing_of_them_is_left(plain, traced):
+    for root, r in (plain, traced):
+        assert notes(r.stdout)["end_to_end"]["work_fs"] == "shm"
+        assert _nothing_of_the_work_directory_is_left(root)
+
+
+def test_a_run_ended_by_sigterm_in_mid_round_leaves_no_process_link_or_files(bench_root):
+    from perfbench import cluster
+
+    proc = subprocess.Popen(
+        bench_cmd(bench_root, "--workload", "tiny-gpt2.h4", "--seed", "5",
+                  "--seconds", "60", "--trace", "0"),
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        cwd=str(bench_root), env=bench_env(),
+    )
+    try:
+        w0_log = bench_root / "chiprun_out/perfbench/tiny-gpt2.h4/plain/w0.log"
+        end = time.monotonic() + 240
+        while time.monotonic() < end:
+            if w0_log.is_file() and "round 0 done" in w0_log.read_text(errors="replace"):
+                break
+            assert proc.poll() is None, proc.stderr.read()[-3000:]
+            time.sleep(0.2)
+        link = bench_root / "chiprun_out" / "pb-run"
+        assert link.is_symlink() and link.resolve() == cluster.work_dir_of(bench_root)
+        assert (cluster.work_dir_of(bench_root) / "counting").is_dir()
+        assert not cluster.is_stale(cluster.work_dir_of(bench_root))  # its harness is alive
+        proc.send_signal(signal.SIGTERM)
+        stdout, stderr = proc.communicate(timeout=120)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.communicate()
+    assert proc.returncode == 128 + signal.SIGTERM, stderr[-2000:]
+    assert not any(RESULT_KEYS <= set(json.loads(x)) for x in stdout.splitlines())
+    assert processes_under(bench_root) == []
+    assert _nothing_of_the_work_directory_is_left(bench_root)
+
+
+def test_what_a_run_ended_by_sigkill_leaves_is_swept_by_the_next_run_of_any_checkout(bench_root):
+    """SIGKILL, or the driver's time limit: the harness cannot clean up, the
+    roles go with it (``PR_SET_PDEATHSIG``), and its work directory stays in
+    memory under a name only that checkout would look for. Its owner's mark
+    names a dead process, so the next run on the machine, from whatever
+    checkout, removes it first (``cluster.sweep_work_dirs``)."""
+    from perfbench import cluster
+
+    proc = subprocess.Popen(
+        bench_cmd(bench_root, "--workload", "tiny-gpt2.h4", "--seed", "6",
+                  "--seconds", "60", "--trace", "0"),
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, cwd=str(bench_root), env=bench_env(),
+    )
+    left = cluster.work_dir_of(bench_root)
+    try:
+        end = time.monotonic() + 240
+        while time.monotonic() < end and not (left / "counting").is_dir():
+            assert proc.poll() is None
+            time.sleep(0.2)
+        assert not cluster.is_stale(left)
+    finally:
+        proc.kill()
+        proc.wait()
+    end = time.monotonic() + 30
+    while processes_under(bench_root) and time.monotonic() < end:
+        time.sleep(0.2)
+    assert processes_under(bench_root) == []  # the roles went with their harness
+    assert left.is_dir() and cluster.is_stale(left)  # ... and this is what it could not remove
+    cluster.sweep_work_dirs()  # what every run does first, whatever its checkout
+    assert not left.exists()
+    (bench_root / "chiprun_out" / "pb-run").unlink()  # the dangling link is the checkout's own
 
 
 def _pid_of_role(root: Path, name: str) -> int | None:

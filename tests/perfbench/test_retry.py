@@ -50,6 +50,11 @@ def test_no_second_start_where_it_could_not_end_in_time(monkeypatch, tmp_path):
     assert seen == [1] and "lease expired" in run.cause
 
 
-def test_another_failure_is_not_tried_again(monkeypatch, tmp_path):
-    run, seen = run_with(monkeypatch, tmp_path, ["role w0 died (return code -9)", None])
-    assert seen == [1] and run.cause == "role w0 died (return code -9)"
+@pytest.mark.parametrize("cause", [
+    "role w0 died (return code -9)",
+    # the auction's race (PERF.md 7): a fault of the program, named and reported
+    "a request about a lease was refused (in the scheduler log); role scheduler died (return code 1)",
+], ids=["a_role_died", "the_auction"])
+def test_another_failure_is_not_tried_again(monkeypatch, tmp_path, cause):
+    run, seen = run_with(monkeypatch, tmp_path, [cause, None])
+    assert seen == [1] and run.cause == cause

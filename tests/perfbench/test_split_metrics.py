@@ -1,8 +1,11 @@
 """The per-layer metrics that read the phase spans and the lease lines, on a
 traced run of ``mistral-7b-d1.sync-h8`` recorded on the chip from this
 benchmark's own tree (``data/recorded_split/``, PR 28): every entry of
-``BENCHMARK.json`` reads a number there, each spec file resolves through
-``perfbench.manifest``, and the parts add up to their wholes."""
+``BENCHMARK.json`` that every cell reports reads a number there, each spec
+file resolves through ``perfbench.manifest``, and the parts add up to their
+wholes. An entry with a ``workloads`` list is held by a test beside its own
+cell's record (``test_afmoe_counts.py`` for Trinity's seven), so a later PR
+lists a metric by adding a spec, an entry and a test, and edits nothing here."""
 
 from __future__ import annotations
 
@@ -16,7 +19,9 @@ from perfbench_helpers import DATA as FIXTURES, REPO, RESULT_KEYS
 
 DATA = FIXTURES / "recorded_split"
 CELL_NAME = "mistral-7b-d1.sync-h8"
-LISTED = [e["name"] for e in manifest.load_manifest(REPO)["per_layer"]]
+PER_LAYER = manifest.load_manifest(REPO)["per_layer"]
+LISTED = [e["name"] for e in PER_LAYER if "workloads" not in e]  # every cell reports these
+OF_ONE_CELL = [e["name"] for e in PER_LAYER if "workloads" in e]
 # Entered in PR 25 (three) and in PR 28 (fifteen): the ones that read the
 # phase spans, the step record and the lease lines.
 NEW = [
@@ -64,8 +69,13 @@ def values(cell, run):
 
 def test_every_specified_metric_is_listed_but_the_two_no_cell_can_read():
     specs = {p.stem for p in (REPO / "perfbench" / "layer_metrics").glob("*.json")}
-    assert specs - set(LISTED) == UNLISTED and set(LISTED) <= specs
-    assert set(NEW) <= set(LISTED) and len(NEW) == len(set(NEW)) == 18 and len(LISTED) == 33
+    listed = set(LISTED) | set(OF_ONE_CELL)
+    assert specs - listed == UNLISTED and listed <= specs  # no count: a later PR adds its own
+    assert len(listed) == len(PER_LAYER) and set(NEW) <= set(LISTED)
+    assert len(NEW) == len(set(NEW)) == 18
+    # What every cell reports is what the recorded Mistral run can read: the 33.
+    assert CELL_NAME.startswith("mistral") and len(LISTED) == len(
+        manifest.resolve(CELL_NAME, REPO).per_layer)
     assert not (REPO / "perfbench" / "pending_per_layer.json").exists()
 
 
@@ -139,3 +149,25 @@ def test_the_lease_kept_its_margin_in_the_recorded_run(values):
     assert 9.0 < values["lease_margin_min_s"] <= 10.01
     assert 0 <= values["renew_late_max_s"] < 1.0
     assert values["lease_margin_min_s"] + values["renew_late_max_s"] <= 10.05
+
+
+def test_the_cleanup_is_the_span_and_0_where_the_program_dropped_it(cell, run, values):
+    """The ``cleanup`` span exists only from 10 ms (``trace.SLOW_CLEANUP_S``),
+    and on a memory-backed work directory an unlinking takes none: the spec's
+    ``absent`` says what the reader gives where ``w0`` wrote spans in the
+    measured rounds and none of that name, so the metric is in every traced
+    line. A run without spans still reads nothing."""
+    import dataclasses
+
+    assert values["sync_cleanup_s"] == pytest.approx(0.709, abs=0.001)  # rounds 1 and 2, not round 0's 0.732
+    spec = next(s for e, s in cell.per_layer if e["name"] == "sync_cleanup_s")
+    assert (spec["reader"], spec["name"], spec["node"], spec["absent"]) == ("span", "cleanup", "w0", 0.0)
+    dropped = dataclasses.replace(run, spans=[sp for sp in run.spans if sp.get("name") != "cleanup"])
+    assert readers.read_spec(spec, dropped, cell, {}) == 0.0
+    untraced = dataclasses.replace(run, spans=[])
+    assert readers.read_spec(spec, untraced, cell, {}) is None
+    only_ps = dataclasses.replace(run, spans=[sp for sp in run.spans if sp.get("node") != "w0"])
+    assert readers.read_spec(spec, only_ps, cell, {}) is None
+    # a span reader without ``absent`` says nothing where its span is not there
+    bare = {k: v for k, v in spec.items() if k != "absent"}
+    assert readers.read_spec(bare, dropped, cell, {}) is None
