@@ -52,8 +52,12 @@ FIRST_END_S = 1190.0
 # fails with the cause (``no_work_ground``), it does not measure elsewhere.
 # A test puts a temporary directory here.
 SHM = Path("/dev/shm")
-# Free there before a run starts: twice what the largest cell holds at once
-# (delta, the PS's copy, update, the worker's copy and the data: 8.1 GB).
+# Free there before a run starts, for every cell alike: a cell holds there at
+# once delta, the PS's copy, update, the worker's copy (16 B a parameter) and
+# the data, and the floor promises a quarter more than that to any cell whose
+# model fits the chip: 16 GB / 1.25 = 12.8 GB = 0.8 B parameters, where 16 GB
+# of HBM hold 0.77 B at 22 B each. tests/perfbench/test_work_dir.py holds each
+# cell of the manifest to it by its configuration's own count.
 WORK_FREE_BYTES = 16 * 10**9
 OWNER = "owner"  # in a work directory: pid and start time of the harness that made it
 STALE_WITHOUT_OWNER_S = 60.0
@@ -304,7 +308,8 @@ def is_stale(work_dir: Path) -> bool:
 def sweep_work_dirs() -> None:
     """Remove every checkout's work directory on ``SHM`` whose harness is
     dead: the driver's later checkouts have other paths and so other names,
-    and what a cut run left is 8 GB of memory until someone frees it."""
+    and what a cut run left is what the cell held, gigabytes of memory until
+    someone frees it."""
     for d in SHM.glob("perfbench-*"):
         if d.is_dir() and not d.is_symlink() and is_stale(d):
             print(f"perfbench: removing {d}, left by a run that was killed", file=sys.stderr)

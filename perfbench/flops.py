@@ -7,6 +7,11 @@ one: 6 per matmul parameter (forward 2, backward 4) plus attention's
 12 * layers * width * sequence, with no discount for the causal mask and
 nothing for recomputation. The sizes come from the configuration file's
 ``flops`` group, so a new configuration brings its own numbers, not code.
+Where not every layer attends to the whole sequence, the group states
+``attention_keys``: one entry a layer that is run, the keys a query of that
+layer is counted with (``null`` for a full layer, a window layer's window;
+each is cut to the sequence). Attention is then 12 * width * their sum, and
+``layers`` counts the matmuls alone.
 """
 
 from __future__ import annotations
@@ -43,4 +48,6 @@ def matmul_params(f: dict) -> int:
 
 def flops_per_token(f: dict, sequence: int) -> float:
     attn_width = f["heads"] * f["head_size"]
-    return 6.0 * matmul_params(f) + 12.0 * f["layers"] * attn_width * sequence
+    keys = f.get("attention_keys", [None] * f["layers"])  # absent: every layer full
+    seen = sum(sequence if k is None else min(sequence, k) for k in keys)
+    return 6.0 * matmul_params(f) + 12.0 * attn_width * seen

@@ -57,7 +57,10 @@ def grouped_swiglu(pairs: float, width: int, expert_width: int, held: int, layer
     forward (2 x width x expert_width each a pair), their data and weight
     gradients backward: 18 x width x expert_width a pair. Bytes: each layer's
     three weight tables read forward and backward and their gradients written
-    once; a pair's input read, output written, and the same for gradients."""
+    once; a pair's input read, output written, and the same for gradients. It
+    is the count of any gated expert of three products (gate, up, down),
+    whatever the activation on the gate: SiLU here, ReLU in another family;
+    the activation itself is elementwise and not counted."""
     table = 3 * held * width * expert_width * element_bytes
     rows = pairs * width * element_bytes
     return {

@@ -2,10 +2,15 @@
 
 ``{"reader": "kernel_roofline", "kernel": "grouped_swiglu" |
 "flash_attention_window", "time_ms": {"metric": "moe_experts_ms"}}``. The
-shapes come from the cell's configuration file (the source's keys) and its
-mix; the grouped product's pairs from the worker's ``round N routing`` lines
-of the measured rounds. ``None`` where the time, the counter or the device's
-peaks are not there.
+shapes come from the ``kernels`` group of the cell's configuration file, one
+entry for each kernel a metric of its cells names (``flash_attention_window``:
+``layers``, ``heads``, ``kv_heads``, ``head_size``, ``window``;
+``grouped_swiglu``: ``width``, ``expert_width``, ``held``, ``layers``), batch
+and sequence from the mix, and the grouped product's pairs from the worker's
+``round N routing`` lines of the measured rounds. No key of any source is
+read here: a new configuration brings its numbers, not a reader. ``None``
+where the time, the group or the entry, the counter or the device's peaks are
+not there.
 """
 
 from __future__ import annotations
@@ -16,25 +21,41 @@ from .. import flops, kernel_counts
 from . import log_field
 
 
+def _flash_attention_window(k: dict, run, cell) -> dict:
+    t = cell.traffic
+    one = kernel_counts.flash_attention(
+        t["batch"], t["sequence"], k["heads"], k["kv_heads"], k["head_size"], k["window"])
+    return {name: k["layers"] * v for name, v in one.items()}
+
+
+def _grouped_swiglu(k: dict, run, cell) -> dict | None:
+    rows = log_field.rows({"role": "w0", "line": r"round \d+ routing: .*"}, run)
+    rows = [r for r in rows if isinstance(r.get("pairs_computed"), int) and r.get("steps")]
+    if not rows:
+        return None
+    pairs = sum(r["pairs_computed"] for r in rows) / sum(r["steps"] for r in rows)
+    return kernel_counts.grouped_swiglu(pairs, k["width"], k["expert_width"], k["held"], k["layers"])
+
+
+# kernel -> (the keys its entry of the ``kernels`` group states, its count)
+KERNELS = {
+    "flash_attention_window": (("layers", "heads", "kv_heads", "head_size", "window"), _flash_attention_window),
+    "grouped_swiglu": (("width", "expert_width", "held", "layers"), _grouped_swiglu),
+}
+
+
 def counts(kernel: str, run, cell) -> dict | None:
-    c, t = cell.config, cell.traffic
-    if kernel == "flash_attention_window":
-        run_layers = c.get("layers_run", range(c["num_hidden_layers"]))
-        layers = sum(c["layer_types"][i] == "sliding_attention" for i in run_layers)
-        one = kernel_counts.flash_attention(
-            t["batch"], t["sequence"], c["num_attention_heads"], c["num_key_value_heads"],
-            c["head_dim"], c["sliding_window"])
-        return {k: layers * v for k, v in one.items()}
-    if kernel == "grouped_swiglu":
-        rows = log_field.rows({"role": "w0", "line": r"round \d+ routing: .*"}, run)
-        rows = [r for r in rows if isinstance(r.get("pairs_computed"), int) and r.get("steps")]
-        if not rows:
-            return None
-        pairs = sum(r["pairs_computed"] for r in rows) / sum(r["steps"] for r in rows)
-        return kernel_counts.grouped_swiglu(
-            pairs, c["hidden_size"], c["moe_intermediate_size"], c["num_experts"],
-            c["num_hidden_layers"] - c["num_dense_layers"])
-    raise ValueError(f"unknown kernel {kernel!r}")
+    """Operations and bytes of one step's calls of ``kernel`` in this cell."""
+    if kernel not in KERNELS:
+        raise ValueError(f"unknown kernel {kernel!r}")
+    keys, count = KERNELS[kernel]
+    shapes = cell.config.get("kernels", {}).get(kernel)
+    missing = [kernel] if shapes is None else [k for k in keys if k not in shapes]
+    if missing:  # as a device without peaks: said, and the metric left out
+        where = "kernels group" if shapes is None else f"kernels[{kernel!r}]"
+        print(f"perfbench: the configuration's {where} has no {missing[0]!r}", file=sys.stderr)
+        return None
+    return count(shapes, run, cell)
 
 
 def read(spec: dict, run, cell, values: dict) -> float | None:

@@ -13,7 +13,7 @@ import types
 import pytest
 
 from perfbench import flops, kernel_counts, manifest
-from perfbench.readers import device_scope, read_spec
+from perfbench.readers import device_scope, kernel_roofline, read_spec
 
 from perfbench_helpers import DATA as FIXTURES, REPO
 
@@ -135,6 +135,54 @@ def test_the_seven_metrics_read_the_recorded_step(recorded):
     assert 20 < values["moe_route_ms"] < 40
     for share in ("moe_experts_roofline", "flash_window_roofline"):
         assert 5 < values[share] < 100, (share, values[share])
+    # Digit for digit what the reader gave from the source's keys before PR 38
+    # took the shapes from the ``kernels`` group (read at ccfad04 from this record).
+    assert {k: repr(v) for k, v in values.items()} == {
+        "moe_pairs_per_token": "0.8126", "moe_load_max_over_mean": "4.318",
+        "moe_route_ms": "29.316408", "moe_experts_ms": "20.216935",
+        "moe_experts_roofline": "25.2389715146574",
+        "flash_window_ms": "52.711625", "flash_window_roofline": "32.429002171105765"}
+    assert kernel_roofline.counts("flash_attention_window", run, cell) == {
+        "flops": 3367489241088.0, "bytes": 1811939328.0}
+    assert kernel_roofline.counts("grouped_swiglu", run, cell) == {
+        "flops": 1005201653760.0, "bytes": 1644244992.0}
+
+
+def test_the_kernels_group_is_the_count_from_the_sources_keys():
+    """The group states numbers; that they are this configuration's is held
+    here, from the source's keys and the layers that are run."""
+    c, k = CONFIG, CONFIG["kernels"]
+    kinds = [c["layer_types"][i] for i in c["layers_run"]]
+    assert kinds == ["sliding_attention"] * 4 + ["full_attention"]  # as job_sets gives them to the program
+    assert k["flash_attention_window"] == {
+        "layers": kinds.count("sliding_attention"), "heads": c["num_attention_heads"],
+        "kv_heads": c["num_key_value_heads"], "head_size": c["head_dim"], "window": c["sliding_window"]}
+    assert k["grouped_swiglu"] == {
+        "width": c["hidden_size"], "expert_width": c["moe_intermediate_size"], "held": c["num_experts"],
+        "layers": c["num_hidden_layers"] - c["num_dense_layers"]}
+    assert set(k) == {s["kernel"] for s in SPECS.values() if s["reader"] == "kernel_roofline"}
+    assert len(c["kernels_why"]) > 100
+
+
+@pytest.mark.parametrize("missing", ["the_group", "the_entry", "a_key_of_the_entry"])
+def test_a_configuration_without_the_kernels_entry_reads_nothing_and_says_why(recorded, capsys, missing):
+    cell, run = recorded
+    config = {k: v for k, v in CONFIG.items() if k != "kernels"}
+    if missing == "the_entry":
+        config["kernels"] = {"grouped_swiglu": CONFIG["kernels"]["grouped_swiglu"]}
+    elif missing == "a_key_of_the_entry":
+        config["kernels"] = {"flash_attention_window": {"layers": 4, "heads": 32}}
+    bare = types.SimpleNamespace(config=config, traffic=cell.traffic)
+    values = {"flash_window_ms": 52.711625, "moe_experts_ms": 20.216935}
+    assert read_spec(SPECS["flash_window_roofline"], run, bare, values) is None
+    said = capsys.readouterr().err
+    assert said.startswith("perfbench: ") and (
+        "kv_heads" if missing == "a_key_of_the_entry" else "no 'flash_attention_window'") in said
+    # the other kernel's share is read where its entry is there
+    other = read_spec(SPECS["moe_experts_roofline"], run, bare, values)
+    assert (other == 25.2389715146574) if missing == "the_entry" else (other is None)
+    with pytest.raises(ValueError, match="unknown kernel"):
+        kernel_roofline.counts("no_such_kernel", run, cell)
 
 
 def test_a_while_and_the_operations_inside_it_count_once(recorded):

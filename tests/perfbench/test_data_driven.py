@@ -1,25 +1,23 @@
 """A later PR adds a cell, a configuration, a mix or a metric by adding files
 and manifest entries, and edits no file that is there. And the manifest as
-committed keeps to the contract's shapes."""
+committed keeps to the contract's shapes (the checks themselves are
+``manifest_checks.py``'s, so that ``test_fourth_cell.py`` can run them over a
+manifest a later PR would bring)."""
 
 from __future__ import annotations
 
 import hashlib
 import json
-import re
-import subprocess
 from pathlib import Path
 
 import pytest
 
+import manifest_checks
 from perfbench_helpers import (
     REPO, all_rounds_sound, failing_checks, rehearsal_result, run_bench,
 )
 
 MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
 def _digests(root: Path) -> dict[str, str]:
@@ -88,82 +86,25 @@ def test_a_new_cell_configuration_mix_and_metric_are_files_and_entries_only(benc
     assert {k: after[k] for k in before} == before  # nothing that was there changed
 
 
-def _metrics():
-    return MANIFEST["end_to_end"] + MANIFEST["per_layer"]
-
-
-@pytest.mark.parametrize("metric", _metrics(), ids=lambda m: m["name"])
+@pytest.mark.parametrize("metric", manifest_checks.metrics(MANIFEST), ids=lambda m: m["name"])
 def test_metric_entry_keeps_to_the_contract(metric):
-    allowed = {"name", "unit", "better", "source", "workloads"}
-    if metric in MANIFEST["end_to_end"]:
-        allowed |= {"bound"}
-        assert metric["source"] in {"host_clock", "device_trace"}
-        assert 0.01 <= metric["bound"] <= 0.1
-    else:
-        allowed |= {"layer", "moves"}
-        assert 1 <= len(metric["layer"]) <= 200 and "\n" not in metric["layer"]
-    assert set(metric) <= allowed
-    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
-    assert metric["better"] in {"lower", "higher"}
-    assert metric["source"] in SOURCES
+    manifest_checks.check_metric_entry(MANIFEST, metric)
 
 
 @pytest.mark.parametrize("metric", MANIFEST["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_moves_an_end_to_end_metric_of_every_cell_it_is_in(metric):
-    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
-    assert metric["moves"] in e2e
-    cells = metric.get("workloads") or [w["name"] for w in MANIFEST["workloads"]]
-    moved = e2e[metric["moves"]]
-    assert set(cells) <= set(moved.get("workloads") or [w["name"] for w in MANIFEST["workloads"]])
-    spec = json.loads((REPO / "perfbench" / "layer_metrics" / f"{metric['name']}.json").read_text())
-    assert (spec["layer"], spec["unit"], spec["moves"]) == (
-        metric["layer"], metric["unit"], metric["moves"],
-    )
-    assert (REPO / "perfbench" / "readers" / f"{spec['reader']}.py").is_file()
+    manifest_checks.check_per_layer_entry(MANIFEST, metric, REPO)
 
 
 @pytest.mark.parametrize("cell", MANIFEST["workloads"], ids=lambda w: w["name"])
 def test_cell_names_files_that_exist_and_git_would_commit(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(cell["name"]) and NAME.match(cell["config"]) and NAME.match(cell["traffic"])
-    assert cell["chips"] in (1, 4) and 1 <= len(cell["why"]) <= 200
-    config = next(c for c in MANIFEST["configs"] if c["name"] == cell["config"])
-    files = [config["file"], f"perfbench/traffic/{cell['traffic']}.json"]
-    for f in files:
-        assert (REPO / f).is_file(), f
-        assert any(f.startswith(p + "/") for p in MANIFEST["paths"])
-    ignored = subprocess.run(
-        ["git", "check-ignore", *files], cwd=str(REPO), capture_output=True, text=True,
-    )
-    assert ignored.returncode == 1 and ignored.stdout == "", ignored.stdout
+    manifest_checks.check_cell_entry(MANIFEST, cell, REPO)
 
 
 @pytest.mark.parametrize("config", MANIFEST["configs"], ids=lambda c: c["name"])
 def test_configuration_entry_and_file_agree(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    body = json.loads((REPO / config["file"]).read_text())
-    assert body["source"] == config["source"] and body["reduced"] == config["reduced"]
-    assert all(NAME.match(k) and k in body for k in config["reduced"])
-    assert not any(re.search(r"(_dim|_rank|hidden_size|intermediate_size)$", k)
-                   for k in config["reduced"])
-    assert isinstance(body["assumed"], dict)
-    checks = body["checks"]
-    if "reference" in checks:  # a module of the benchmark's own, and a tolerance with its reason
-        assert (REPO / "perfbench" / "reference" / f"{checks['reference']}.py").is_file()
-        assert 0 < checks["reference_tolerance"] < 0.03 and len(checks["reference_reason"]) > 100
-        assert "loss_first_tolerance" not in checks  # the reference replaces the band
-    else:
-        assert checks["loss_first_tolerance"] > 0
+    manifest_checks.check_configuration(config, REPO)
 
 
 def test_manifest_shape():
-    assert set(MANIFEST) == {
-        "command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
-    }
-    assert "setup_s" in {m["name"] for m in MANIFEST["end_to_end"]}
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    names = [m["name"] for m in _metrics()]
-    assert len(names) == len(set(names))
-    assert len((REPO / "BENCHMARK.json").read_bytes()) <= 64 * 1024
-    four = [w for w in MANIFEST["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(MANIFEST["workloads"]) // 4)
+    manifest_checks.check_shape(MANIFEST, REPO)

@@ -13,7 +13,8 @@ import json
 
 import pytest
 
-from perfbench import cluster, logs, manifest, measure, readers
+import manifest_checks
+from perfbench import cluster, flops, logs, manifest, measure, readers
 
 from perfbench_helpers import DATA as FIXTURES, REPO, RESULT_KEYS
 
@@ -38,9 +39,6 @@ PARTS = {  # whole -> its parts, which leave it only its self time
     "sync_encode_s": ["sync_extract_s", "sync_write_s"],
     "sync_merge_s": ["sync_merge_read_s", "sync_merge_apply_s"],
 }
-# Specified and in no cell's reach: their spans exist only when the PS reads
-# or writes its momentum file, which it does under a ``checkpoint_dir`` alone.
-UNLISTED = {"ps_step_load_s", "ps_step_save_momentum_s"}
 
 
 @pytest.fixture(scope="module")
@@ -68,9 +66,8 @@ def values(cell, run):
 
 
 def test_every_specified_metric_is_listed_but_the_two_no_cell_can_read():
-    specs = {p.stem for p in (REPO / "perfbench" / "layer_metrics").glob("*.json")}
+    manifest_checks.check_specs_are_listed(manifest.load_manifest(REPO), REPO)
     listed = set(LISTED) | set(OF_ONE_CELL)
-    assert specs - listed == UNLISTED and listed <= specs  # no count: a later PR adds its own
     assert len(listed) == len(PER_LAYER) and set(NEW) <= set(LISTED)
     assert len(NEW) == len(set(NEW)) == 18
     # What every cell reports is what the recorded Mistral run can read: the 33.
@@ -133,6 +130,17 @@ def test_the_wait_is_the_await_update_span_and_most_of_the_exposed_sync(values, 
     assert values["sync_unaccounted_s"] == pytest.approx(values["sync_exposed_s"] - covered)
     assert values["sync_wait_s"] > 0.5 * values["sync_exposed_s"]
     assert abs(values["sync_unaccounted_s"]) < 0.1 * values["sync_exposed_s"]
+
+
+def test_the_steps_share_of_the_peak_reads_what_it_read_before_the_group_could_state_attention_keys(values, cell):
+    """``mfu_step`` of this record, digit for digit as at ccfad04: neither
+    configuration's ``flops`` group states ``attention_keys``, and without the
+    key ``flops.flops_per_token`` is the formula it was."""
+    assert "attention_keys" not in cell.config["flops"]
+    assert repr(values["mfu_step"]) == "66.34856090084989" and values["step_ms"] == 287.85
+    per_token = 6.0 * flops.matmul_params(cell.config["flops"]) + 12.0 * 1 * 4096 * 4096
+    assert flops.flops_per_token(cell.config["flops"], 4096) == per_token == 2_296_381_440
+    assert values["mfu_step"] == 100 * per_token * (4 * 4096) / (287.85 / 1000) / 197e12
 
 
 def test_the_step_record_agrees_with_the_round_line(values):

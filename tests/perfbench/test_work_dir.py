@@ -13,6 +13,7 @@ import time
 
 import pytest
 
+import manifest_checks
 from perfbench import cluster, manifest, run as bench_run
 from perfbench_helpers import REPO
 
@@ -97,7 +98,7 @@ def test_without_the_ground_there_is_no_run_and_no_result(
 
 def test_with_room_the_ground_is_there(shm):
     assert cluster.no_work_ground() is None
-    assert cluster.WORK_FREE_BYTES == 16 * GB  # twice the 8.1 GB the largest cell holds at once
+    assert cluster.WORK_FREE_BYTES == 16 * GB  # a quarter over what any cell that fits the chip holds at once
 
 
 def test_close_leaves_neither_link_nor_target_and_may_be_called_twice(tmp_path, shm):
@@ -143,7 +144,7 @@ def dead_pid() -> int:
 def test_what_another_checkouts_killed_run_left_is_swept(tmp_path, shm, capsys, left_by, swept):
     """The driver's later checkouts have other paths, so other names: each
     run removes every work directory whose harness is gone, whatever its
-    name, or 8 GB of memory a cut run would stay until the machine goes."""
+    name, or the gigabytes a cut run held would stay until the machine goes."""
     other = shm / "perfbench-0123456789abcdef"
     (other / "w0").mkdir(parents=True)
     (other / "w0" / "delta-3.safetensors").write_bytes(b"\0" * 4096)
@@ -196,14 +197,32 @@ def test_the_signal_handlers_systemexit_unwinds_through_close(tmp_path, shm, mon
     assert not (root / "chiprun_out" / "pb-run").exists() and list(shm.iterdir()) == []
 
 
-@pytest.mark.parametrize("cell_name", [w["name"] for w in manifest.load_manifest(REPO)["workloads"]])
-def test_what_a_cell_of_the_manifest_holds_there_at_once_is_half_the_floor(cell_name):
+@pytest.mark.parametrize("cell_name", manifest_checks.cell_names(manifest.load_manifest(REPO)))
+def test_what_a_cell_of_the_manifest_holds_there_at_once_fits_the_floor_with_a_quarter_to_spare(cell_name):
     """Delta, the PS's copy, the update, the worker's copy (4 x 4 B a
-    parameter; PERF.md 4 has the counts) and the data: the fixed floor is
-    twice that for the largest cell, and no configuration has to state it."""
-    cell = manifest.resolve(cell_name, REPO)
-    parameters = {"mistral-7b-d1": 480_260_096, "trinity-mini-d5": 504_147_200}[cell_name.split(".")[0]]
-    data = 4 * cell.traffic["data"]["sequences"] * cell.traffic["sequence"]
-    need = 16 * parameters + data
-    assert 7.6 * GB < need < 8.2 * GB and 2 * need <= cluster.WORK_FREE_BYTES + 0.4 * GB
-    assert "parameters" not in cell.config
+    parameter, the count from the file the configuration brings under
+    ``data/parameters/``) and the data, and a quarter more, are within the
+    one fixed floor: 16 GB / 1.25 = 12.8 GB = 0.8 B parameters, and 16 GB of
+    HBM hold 0.77 B at 22 B each, so no configuration that fits the chip
+    breaks it, and none has to state a size to the harness."""
+    manifest_checks.check_work_dir_floor(manifest.load_manifest(REPO), cell_name, REPO)
+
+
+def test_a_configuration_without_its_count_is_told_which_file_to_add(tmp_path):
+    m = manifest.load_manifest(REPO)
+    cell = m["workloads"][0]
+    with pytest.raises(AssertionError, match=(
+            f"add tests/perfbench/data/parameters/{cell['config']}.json")):
+        manifest_checks.check_work_dir_floor(m, cell["name"], tmp_path)
+
+
+def test_a_cell_too_large_for_the_floor_is_refused_with_its_size(tmp_path, monkeypatch):
+    m = manifest.load_manifest(REPO)
+    name = m["workloads"][0]["name"]
+    held = manifest_checks.check_work_dir_floor(m, name, REPO)
+    assert 16 * 480_260_096 < held < 16 * 480_260_096 + GB  # the count's 16 B and the data
+    monkeypatch.setattr(cluster, "WORK_FREE_BYTES", int(1.25 * held) - 1)
+    with pytest.raises(AssertionError, match="GB in its work directory at once"):
+        manifest_checks.check_work_dir_floor(m, name, REPO)
+    monkeypatch.setattr(cluster, "WORK_FREE_BYTES", int(1.25 * held) + 1)
+    manifest_checks.check_work_dir_floor(m, name, REPO)
