@@ -44,6 +44,7 @@ __all__ = [
     "read_frame",
     "read_delta",
     "read_delta_into",
+    "frame_f32",
     "ReadStats",
     "write_delta",
     "frame_tag",
@@ -295,6 +296,35 @@ def f32_layout(path: Path) -> dict[str, tuple[tuple, int, int]] | None:
             )
         layout[key] = (shape, 8 + n + begin, nbytes)
     return layout
+
+
+def frame_f32(tree: dict[str, np.ndarray]) -> tuple[bytes, list[memoryview]]:
+    """The SafeTensors framing of an all-f32 tree with none of its bytes
+    moved: :func:`f32_layout`'s mirror on the sending side.
+
+    Returns the file's head (the 8-byte length and the JSON header: the
+    leaves in the tree's own order, ``F32``, ``data_offsets``; padded with
+    spaces to a multiple of 8 as ``save_file`` pads) and one byte view a
+    leaf, of the leaf's own memory, in that order. Head and views written
+    one after the other are a file ``safetensors`` loads and
+    :func:`f32_layout` takes. A leaf has to be C-contiguous ``float32``:
+    anything else would have to be copied, which is what this is for not
+    doing."""
+    header: dict[str, dict] = {}
+    views: list[memoryview] = []
+    end = 0
+    for key, leaf in tree.items():
+        if leaf.dtype != np.float32 or not leaf.flags.c_contiguous:
+            raise ValueError(f"update {key!r}: not a C-contiguous float32 array")
+        begin, end = end, end + leaf.nbytes
+        header[key] = {
+            "dtype": "F32", "shape": list(leaf.shape), "data_offsets": [begin, end],
+        }
+        if leaf.nbytes:  # a view of nothing cannot be cast
+            views.append(memoryview(leaf.reshape(-1)).cast("B"))
+    body = json.dumps(header, separators=(",", ":")).encode()
+    body += b" " * (-len(body) % 8)
+    return len(body).to_bytes(8, "little") + body, views
 
 
 def read_exact(fd: int, offset: int, dst: np.ndarray) -> None:

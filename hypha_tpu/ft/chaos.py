@@ -348,12 +348,20 @@ class ChaosController:
 
     @staticmethod
     def _throttled_source(source, rate_bps: float):
-        """Wrap a push source (bytes | file path) in an async iterator that
-        trickles chunks at ``rate_bps`` BITS/second — the receiver sees
-        the cap DURING the transfer (its save_to measures it), not as an
-        up-front delay it cannot attribute to the link."""
+        """Wrap a push source (bytes | file path | async iterator, as the
+        PS's broadcast from memory) in an async iterator that trickles
+        chunks at ``rate_bps`` BITS/second — the receiver sees the cap
+        DURING the transfer (its save_to measures it), not as an up-front
+        delay it cannot attribute to the link."""
 
         async def gen():
+            if hasattr(source, "__aiter__"):
+                async for piece in source:
+                    for i in range(0, len(piece), _THROTTLE_CHUNK):
+                        chunk = piece[i : i + _THROTTLE_CHUNK]
+                        await asyncio.sleep(len(chunk) * 8.0 / rate_bps)
+                        yield chunk
+                return
             if isinstance(source, (bytes, bytearray, memoryview)):
                 data = bytes(source)
                 for i in range(0, max(len(data), 1), _THROTTLE_CHUNK):

@@ -87,6 +87,13 @@ class Stream:
         """Tear down both directions."""
         await self.close()
 
+    def borrow_writes(self) -> None:
+        """From here on ``write`` is handed views of memory their owner
+        writes again once the push has ended: a ``write`` that has returned
+        holds none of it, and a ``close`` after one that was cut short
+        (cancelled, timed out) drops what is still queued. Nothing to do
+        where ``write`` copies (memory, mux): the default."""
+
     # -- framing ------------------------------------------------------------
     async def write_frame(self, obj: Any) -> int:
         return await write_frame(self, obj)
@@ -226,6 +233,7 @@ class _TcpStream(Stream):
     def __init__(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter) -> None:
         self._reader = reader
         self._writer = writer
+        self._borrowed = False
 
     async def read(self, n: int = 65536) -> bytes:
         return await self._reader.read(n)
@@ -234,7 +242,20 @@ class _TcpStream(Stream):
         self._writer.write(data)
         await self._writer.drain()
 
+    def borrow_writes(self) -> None:
+        # The transport queues what a send did not take as a view of the
+        # caller's memory. With no room allowed in that queue ``drain``
+        # returns only once it is empty.
+        self._borrowed = True
+        self._writer.transport.set_write_buffer_limits(high=0)
+
     async def close(self) -> None:
+        if self._borrowed and self._writer.transport.get_write_buffer_size():
+            # A write was cut short. What is queued would be sent from
+            # memory that is about to hold something else: the reader gets
+            # a reset, not a clean end after bytes nobody vouches for.
+            self._writer.transport.abort()
+            return
         try:
             if self._writer.can_write_eof():
                 self._writer.write_eof()

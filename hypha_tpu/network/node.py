@@ -583,6 +583,9 @@ class _CountingStream(Stream):
         await self._inner.write(data)
         self._node.bytes_out += len(data)
 
+    def borrow_writes(self) -> None:
+        self._inner.borrow_writes()
+
     def raw_socket_handoff(self):
         inner = getattr(self._inner, "raw_socket_handoff", None)
         return inner() if inner is not None else None
@@ -615,6 +618,9 @@ class _RelayStream(Stream):
 
     async def write(self, data: bytes) -> None:
         await self._inner.write(data)
+
+    def borrow_writes(self) -> None:
+        self._inner.borrow_writes()
 
     async def close(self) -> None:
         await self._inner.close()
@@ -1729,7 +1735,12 @@ class Node:
 
     async def push(self, peer_id: str, resource: Any, source) -> int:
         """Open a push stream: header frame, then raw bytes from ``source``
-        (bytes | file path | async byte iterator). Returns bytes sent."""
+        (bytes | file path | async byte iterator). Returns bytes sent.
+
+        An iterator may give views of memory its owner writes again once
+        this call has ended (the PS's update, pushed from the buffers it
+        was computed in): they are never joined or copied here, and when
+        this returns or raises the stream holds none of them."""
         stream = await self._stream_to(peer_id, PROTOCOL_PUSH)
         try:
             await stream.write_frame(messages.encode(resource))
@@ -1745,6 +1756,7 @@ class Node:
                 # its true rate on the bandwidth gauges, not as one burst
                 # at completion (the metrics plane's link rollups compare
                 # rates across peers).
+                stream.borrow_writes()
                 n = await self._write_source(_CountingStream(stream, self), source)
             return n
         finally:

@@ -71,8 +71,20 @@ def _push_deadline() -> float:
         return PUSH_RETRY_DEADLINE_DEFAULT
 
 
-def push_timeout(path: Path, base: float = 60.0) -> float:
-    """Per-attempt wall-clock bound for a parameter-sized push: a push
+def payload_size(source: "Path | int") -> int:
+    """What a push of ``source`` sends: a file's size (0 where it is gone),
+    or the byte count itself for a push from memory."""
+    if isinstance(source, int):
+        return source
+    try:
+        return source.stat().st_size
+    except OSError:
+        return 0
+
+
+def push_timeout(source: "Path | int", base: float = 60.0) -> float:
+    """Per-attempt wall-clock bound for a parameter-sized push (``source``:
+    the file pushed, or the byte count of a push from memory): a push
     black-holed by a partition that drops packets without RST must fail
     fast enough to retry (the deadline is only consulted BETWEEN
     attempts), but a legitimately slow multi-GB transfer must never be
@@ -85,11 +97,7 @@ def push_timeout(path: Path, base: float = 60.0) -> float:
             return float(env)
         except ValueError:
             pass
-    try:
-        size = path.stat().st_size
-    except OSError:
-        size = 0
-    return base + size / (10 * 1024 * 1024)
+    return base + payload_size(source) / (10 * 1024 * 1024)
 
 
 def shard_route(

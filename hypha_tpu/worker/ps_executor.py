@@ -59,6 +59,7 @@ import time
 import uuid
 from dataclasses import dataclass, field
 from pathlib import Path
+from typing import AsyncIterator
 
 import numpy as np
 from safetensors.numpy import load_file, save_file
@@ -66,6 +67,7 @@ from safetensors.numpy import load_file, save_file
 from .. import aio
 from .. import compress
 from .. import native
+from ..compress.frame import frame_f32
 from ..codec import native_codec_active
 from ..ft.adaptive import LinkTable
 from ..ft.durable import (
@@ -93,7 +95,7 @@ from ..messages import (
     TransferStrategy,
 )
 from ..network.node import Node, RequestError
-from .connectors import push_timeout
+from .connectors import payload_size, push_timeout
 from ..stream import (
     effective_fragments,
     fragment_due,
@@ -126,6 +128,11 @@ _ELASTIC_TICK_S = 0.5
 # Broadcast fan-out width: enough concurrent streams to fill the uplink
 # without opening one per peer on a wide job.
 _BROADCAST_CONCURRENCY = 8
+
+# A push from memory goes out in views of this many bytes: the event loop
+# turns between two of them, so a lease renewal or a second peer's push is
+# never kept waiting for a whole parameter-sized write.
+_PUSH_SLICE = 4 * 1024 * 1024
 
 # Elastic drain slack: a delta whose payload is still streaming when the
 # round deadline passes gets this much extra wall-clock to finish before
@@ -278,8 +285,9 @@ class _OuterMomentum:
     are the file's only readers, so only then does an outer step write
     it. ``threads`` is what the fused pass and the fold may use. ``sums``
     is where every :class:`RoundAccum` of the job leases its leaves and
-    where ``_outer_step`` puts them back once the update is on disk, so a
-    round's sum lies at the last round's addresses.
+    where the round's update puts them back once its last reader has
+    ended (:meth:`_Update.retire`), so a round's sum lies at the last
+    round's addresses.
     """
 
     file: Path
@@ -290,6 +298,67 @@ class _OuterMomentum:
 
     def __post_init__(self) -> None:
         self.sums = SumBuffers(self.threads)
+
+
+class _Update:
+    """One round's outer update where the fused pass left it: the sum's
+    resident f32 buffers, framed as the SafeTensors file they used to be
+    copied into.
+
+    ``name`` is the wire's name as ever (``update-<round>.safetensors``: a
+    worker's node saves a push under its header's name) and ``nbytes`` what
+    a push of it sends. :meth:`views` gives that file's bytes as they lie,
+    never joined and never copied. The file itself is written for a reader
+    that needs one (:meth:`ensure_file`: a durable job's hard link, a codec
+    that encodes from it, a broadcast tree that hands its relays a path)
+    and is then what gets pushed, byte for byte as before there was a
+    choice. Whoever closes the round calls :meth:`retire` once every reader
+    has ended (a push done, failed, or cancelled and reaped): the buffers
+    go back to the job's sums and the file, if there is one, goes. A fold
+    that opens before then leases buffers of its own (:class:`SumBuffers`),
+    so no reader ever sees the next round's sum.
+    """
+
+    def __init__(
+        self, tree: dict[str, np.ndarray], path: Path, accum: "_RoundAccum"
+    ) -> None:
+        self.tree = tree
+        self.path = path
+        self.name = path.name
+        self.file: Path | None = None
+        self._accum: "_RoundAccum | None" = accum
+        self._head, self._views = frame_f32(tree)
+        self.nbytes = len(self._head) + sum(len(v) for v in self._views)
+
+    def ensure_file(self) -> Path:
+        """The update as a file, ``save_file``'s bytes, written once."""
+        if self.file is None:
+            if self._accum is None:
+                raise RuntimeError(f"{self.name}: retired, its buffers are gone")
+            save_file(self.tree, str(self.path))
+            self.file = self.path
+        return self.file
+
+    async def views(self) -> AsyncIterator[memoryview]:
+        """The framed update from its first byte, for one attempt of a push."""
+        yield memoryview(self._head)
+        for view in self._views:
+            for off in range(0, len(view), _PUSH_SLICE):
+                yield view[off : off + _PUSH_SLICE]
+                await asyncio.sleep(0)
+
+    def retire(self) -> None:
+        if self._accum is not None:
+            self._accum.release(self.tree)
+            self._accum = None
+        if self.file is not None:
+            self.file.unlink(missing_ok=True)
+            self.file = None
+
+
+def _wire_file(wire: "Path | _Update") -> Path:
+    """The wire as a file, for a reader that takes a path."""
+    return wire.ensure_file() if isinstance(wire, _Update) else wire
 
 
 def _fire_once(fn):
@@ -640,10 +709,16 @@ class ParameterServerExecutor(JobExecutor):
                     attrs={"round": round_num}, node=ptrace.node,
                 )
                 quality = {} if report_s else None
-                update_path = await asyncio.to_thread(
+                # The update stays where the outer step computed it, and
+                # is a file only for a reader that needs one: the durable
+                # commit's hard link, a codec that encodes from it (the
+                # job's, or the per-link ones below). A broadcast tree
+                # asks for it when it comes to that (_broadcast).
+                update = await asyncio.to_thread(
                     self._outer_step,
                     received, momentum, lr, mu, work_dir, round_num,
                     accum, quality, outer_span,
+                    file=dur is not None or bcast_codec != "none" or link is not None,
                 )
                 trace.finish(outer_span)
                 if link is not None:
@@ -657,6 +732,7 @@ class ParameterServerExecutor(JobExecutor):
                     # DONE check); a change to either copy's ordering —
                     # especially notify-BEFORE-broadcast, see the race
                     # note below — must be mirrored here.
+                    update_path = update.ensure_file()
                     if elastic is not None:
                         await asyncio.to_thread(
                             elastic.catchup.accumulate, update_path
@@ -683,7 +759,7 @@ class ParameterServerExecutor(JobExecutor):
                     with ptrace.phase("cleanup", round_num, min_s=trace.SLOW_CLEANUP_S):
                         for path, _ in received.values():
                             path.unlink(missing_ok=True)
-                        update_path.unlink(missing_ok=True)
+                        update.retire()
                     round_num += 1
                     if elastic is not None:
                         await self._serve_joins(elastic, cfg, round_num, work_dir)
@@ -691,9 +767,9 @@ class ParameterServerExecutor(JobExecutor):
                         execution.finish("completed")
                         return
                     continue
-                wire_path, sent_update = await asyncio.to_thread(
+                wire, sent_update = await asyncio.to_thread(
                     self._encode_broadcast,
-                    update_path, bcast_codec, bcast_ef, work_dir, round_num,
+                    update, bcast_codec, bcast_ef, work_dir, round_num,
                 )
                 if elastic is not None:
                     # The running Σ of updates is the rejoin catch-up payload
@@ -702,21 +778,19 @@ class ParameterServerExecutor(JobExecutor):
                     # DECODED update is accumulated, not the f32 one:
                     # θ_r must equal what workers actually merged. The
                     # encode already produced the decoded tree — never
-                    # re-read and re-dequantize a parameter-sized frame.
-                    if sent_update is None:
-                        await asyncio.to_thread(
-                            elastic.catchup.accumulate, wire_path
-                        )
-                    else:
-                        await asyncio.to_thread(
-                            elastic.catchup.accumulate_tree, sent_update
-                        )
+                    # re-read and re-dequantize a parameter-sized frame:
+                    # where the wire is the f32 update itself, that tree is
+                    # the one in memory.
+                    await asyncio.to_thread(
+                        elastic.catchup.accumulate_tree,
+                        update.tree if sent_update is None else sent_update,
+                    )
                 if dur is not None:
                     # Durable commit: wire file retained for restart
                     # re-broadcast, outer-state checkpoint when due, then
                     # the fsync'd commit record.
                     wire_name = await asyncio.to_thread(
-                        dur.store_wire, round_num, wire_path
+                        dur.store_wire, round_num, _wire_file(wire)
                     )
                     await asyncio.to_thread(
                         dur.commit_round, round_num, 0, wire_name,
@@ -746,7 +820,7 @@ class ParameterServerExecutor(JobExecutor):
                 # UpdateReceived parks on their side, so the ordering race
                 # this comment guards cannot bite while it is down.
                 bcast_static = _fire_once(
-                    lambda _w=wire_path, _r=round_num: self._broadcast(
+                    lambda _w=wire, _r=round_num: self._broadcast(
                         cfg, _w, _r, elastic,
                         extra_header=(
                             {GENERATION_KEY: dur.generation}
@@ -777,11 +851,13 @@ class ParameterServerExecutor(JobExecutor):
                         # references them until a checkpoint covers the round.
                         for path, _ in received.values():
                             path.unlink(missing_ok=True)
-                    # Broadcast done (and catch-up folded): a long job must
-                    # not accumulate two parameter-sized files per round.
-                    update_path.unlink(missing_ok=True)
-                    if wire_path != update_path:
-                        wire_path.unlink(missing_ok=True)
+                    # Broadcast done (and catch-up folded): every push has
+                    # ended, so the update's buffers go back to the sums
+                    # for the next round's fold, and a long job must not
+                    # accumulate two parameter-sized files per round.
+                    update.retire()
+                    if wire is not update:
+                        wire.unlink(missing_ok=True)
                 round_num += 1
                 if elastic is not None:
                     await self._serve_joins(elastic, cfg, round_num, work_dir)
@@ -877,9 +953,9 @@ class ParameterServerExecutor(JobExecutor):
                     accum.fold, dur.deltas_dir / fold.file, fold.samples,
                     sign, fold.prefold,
                 )
-            update_path = await asyncio.to_thread(
+            update = await asyncio.to_thread(
                 self._outer_step,
-                {}, momentum, lr, mu, work_dir, rnd, accum,
+                {}, momentum, lr, mu, work_dir, rnd, accum, file=True,
             )
             if quant and frag not in bcast_efs:
                 bcast_efs[frag] = compress.ErrorFeedback()
@@ -890,26 +966,22 @@ class ParameterServerExecutor(JobExecutor):
                 if stream
                 else None
             )
-            wire_path, sent = await asyncio.to_thread(
+            wire, sent = await asyncio.to_thread(
                 self._encode_broadcast,
-                update_path, bcast_codec, bcast_efs.get(frag), work_dir,
+                update, bcast_codec, bcast_efs.get(frag), work_dir,
                 rnd, tag,
             )
             if rnd == dur.newest_commit(frag):
-                await asyncio.to_thread(dur.store_wire, rnd, wire_path)
+                await asyncio.to_thread(dur.store_wire, rnd, _wire_file(wire))
             if elastic is not None:
-                frag_id = frag if stream else None
-                if sent is None:
-                    await asyncio.to_thread(
-                        elastic.catchup.accumulate, wire_path, frag_id
-                    )
-                else:
-                    await asyncio.to_thread(
-                        elastic.catchup.accumulate_tree, sent, frag_id
-                    )
-            update_path.unlink(missing_ok=True)
-            if wire_path != update_path:
-                wire_path.unlink(missing_ok=True)
+                await asyncio.to_thread(
+                    elastic.catchup.accumulate_tree,
+                    update.tree if sent is None else sent,
+                    frag if stream else None,
+                )
+            update.retire()
+            if wire is not update:
+                wire.unlink(missing_ok=True)
             round_num = rnd + 1
         FT_METRICS.ps_recoveries.add(1)
         FLIGHT.record(
@@ -1808,19 +1880,20 @@ class ParameterServerExecutor(JobExecutor):
                     if getattr(cfg, "report_metrics_s", None)
                     else None
                 )
-                update_path = await asyncio.to_thread(
+                update = await asyncio.to_thread(
                     self._outer_step,
                     received, momentum, lr, mu, work_dir, round_num,
                     accum, quality, outer_span,
+                    file=dur is not None or bcast_codec != "none",
                 )
                 trace.finish(outer_span)
                 if frag not in bcast_efs:
                     bcast_efs[frag] = (
                         compress.ErrorFeedback() if quant else None
                     )
-                wire_path, sent_update = await asyncio.to_thread(
+                wire, sent_update = await asyncio.to_thread(
                     self._encode_broadcast,
-                    update_path, bcast_codec, bcast_efs[frag], work_dir,
+                    update, bcast_codec, bcast_efs[frag], work_dir,
                     round_num, tag.header(),
                 )
                 if elastic is not None:
@@ -1828,17 +1901,14 @@ class ParameterServerExecutor(JobExecutor):
                     # never from the background broadcast, whose completion
                     # order is unordered across fragments. Before the
                     # durable commit, whose checkpoint must contain it.
-                    if sent_update is None:
-                        await asyncio.to_thread(
-                            elastic.catchup.accumulate, wire_path, frag
-                        )
-                    else:
-                        await asyncio.to_thread(
-                            elastic.catchup.accumulate_tree, sent_update, frag
-                        )
+                    await asyncio.to_thread(
+                        elastic.catchup.accumulate_tree,
+                        update.tree if sent_update is None else sent_update,
+                        frag,
+                    )
                 if dur is not None:
                     wire_name = await asyncio.to_thread(
-                        dur.store_wire, round_num, wire_path
+                        dur.store_wire, round_num, _wire_file(wire)
                     )
                     await asyncio.to_thread(
                         dur.commit_round, round_num, frag, wire_name,
@@ -1876,7 +1946,7 @@ class ParameterServerExecutor(JobExecutor):
                 if sharded:
                     bcast_header[SHARD_KEY] = shard
                 async def _spawn_bcast(
-                    _u=update_path, _w=wire_path, _rcv=received,
+                    _u=update, _w=wire, _rcv=received,
                     _r=round_num, _tag=tag, _frag=frag,
                     _peers=bcast_peers, _hdr=bcast_header,
                 ) -> None:
@@ -2230,8 +2300,8 @@ class ParameterServerExecutor(JobExecutor):
     async def _broadcast_and_cleanup(
         self,
         cfg,
-        update_path: Path,
-        wire_path: Path,
+        update: _Update,
+        wire: "Path | _Update",
         received: dict[str, tuple[Path, float]],
         round_num: int,
         tag: FragmentTag,
@@ -2242,7 +2312,8 @@ class ParameterServerExecutor(JobExecutor):
         keep_received: bool = False,
         traceparent: str | None = None,
     ) -> None:
-        """One round's backgrounded fan-out plus its file retirement.
+        """One round's backgrounded fan-out, after which the update's
+        buffers go back to the sums and the round's files are retired.
 
         ``after`` chains this fan-out behind the SAME fragment's previous
         broadcast: without the barrier, a slow peer link could deliver
@@ -2257,7 +2328,7 @@ class ParameterServerExecutor(JobExecutor):
             await aio.wait_quiet(after)
         try:
             await self._broadcast(
-                cfg, wire_path, round_num, elastic,
+                cfg, wire, round_num, elastic,
                 extra_header=header if header is not None else tag.header(),
                 peers_override=peers,
                 traceparent=traceparent,
@@ -2267,9 +2338,9 @@ class ParameterServerExecutor(JobExecutor):
             if not keep_received:
                 for path, _ in received.values():
                     path.unlink(missing_ok=True)
-            update_path.unlink(missing_ok=True)
-            if wire_path != update_path:
-                wire_path.unlink(missing_ok=True)
+            update.retire()
+            if wire is not update:
+                wire.unlink(missing_ok=True)
 
     async def _save_delta_bounded(
         self, push, dest_dir: Path, delta_round: int, *,
@@ -2463,7 +2534,8 @@ class ParameterServerExecutor(JobExecutor):
         accum: "_RoundAccum | None" = None,
         stats: dict | None = None,
         parent: "trace.TraceSpan | None" = None,
-    ) -> Path:
+        file: bool = False,
+    ) -> _Update:
         """Nesterov over the round's sample-weighted mean pseudo-gradient.
 
         The streaming path hands in an accumulator that already folded
@@ -2472,7 +2544,13 @@ class ParameterServerExecutor(JobExecutor):
         Nesterov recurrence IN PLACE: the momentum is updated where it
         lies in ``momentum`` (resident for the job, only this round's keys
         touched, so a fragment round costs its fragment) and the update is
-        written over the sum — nothing parameter-sized is allocated here.
+        written over the sum — nothing parameter-sized is allocated here,
+        and nothing parameter-sized is written: the update is returned
+        where it lies (:class:`_Update`), framed for the broadcast to push
+        from, and is a file only where the caller knows of a reader that
+        needs one (``file``: a durable job, a wire codec, per-link codecs)
+        or a later one asks (``ensure_file``). The caller owns the buffers
+        until it ``retire``s the update.
         C++ kernel over the leaf's elements in threads
         (native.fused_mean_nesterov), numpy fallback. Callers without an
         accumulator (tests, the degenerate path) fold the received files
@@ -2509,7 +2587,6 @@ class ParameterServerExecutor(JobExecutor):
                 accum.fold(path, samples)
         update, denom = accum.take()
         nbytes = sum(int(a.nbytes) for a in update.values())
-        out = work_dir / f"update-{round_num}.safetensors"
         resident = momentum.tree is not None and all(
             key in momentum.tree for key in update
         )
@@ -2557,13 +2634,16 @@ class ParameterServerExecutor(JobExecutor):
             stats["delta_norm"] = float(np.sqrt(g_sq) / denom)
             stats["update_norm"] = float(np.sqrt(u_sq))
             stats["accepted"] = float(len(received))
-        with phase("save_update", "save_update_s") as ph:
-            save_file(update, str(out))
-            ph.set("bytes", out.stat().st_size)
-            ph.set("leaves", len(update))
-        # The update is on disk and nothing reads the arrays again: the
-        # next round's sum is folded into these pages.
-        accum.release(update)
+        with phase(
+            "save_update", "save_update_s",
+            {"in_memory": not file, "leaves": len(update)},
+        ) as ph:
+            out = _Update(
+                update, work_dir / f"update-{round_num}.safetensors", accum
+            )
+            if file:
+                out.ensure_file()
+            ph.set("bytes", out.nbytes)
         if momentum.save:
             momentum_tmp = work_dir / "momentum.next.safetensors"
             with phase("save_momentum", "save_momentum_s") as ph:
@@ -2577,39 +2657,42 @@ class ParameterServerExecutor(JobExecutor):
             "ps outer step: round=%d deltas=%d tensors=%d native_kernels=%s "
             "native_cbor=%s wall_s=%.3f mean_s=%.3f load_s=%.3f "
             "nesterov_s=%.3f save_update_s=%.3f save_momentum_s=%.3f bytes=%d "
-            "threads=%d momentum_resident=%d momentum_saved=%d",
+            "threads=%d momentum_resident=%d momentum_saved=%d "
+            "update_in_memory=%d",
             round_num, len(received), len(update), native.native_available(),
             native_codec_active(), time.monotonic() - t0,
             times["mean_s"], times["load_s"], times["nesterov_s"],
             times["save_update_s"], times["save_momentum_s"], nbytes,
-            threads, resident, momentum.save,
+            threads, resident, momentum.save, not file,
         )
         return out
 
     @staticmethod
     def _encode_broadcast(
-        update_path: Path,
+        update: _Update,
         codec: str,
         ef: "compress.ErrorFeedback | None",
         work_dir: Path,
         round_num: int,
         tag: dict | None = None,
-    ) -> tuple[Path, "dict[str, np.ndarray] | None"]:
+    ) -> tuple["Path | _Update", "dict[str, np.ndarray] | None"]:
         """Re-encode the f32 update for the wire per the job's codec.
 
         int8/int4 write an HQD1 frame of Q(update + residual) and keep the
-        new residual; bf16 casts the SafeTensors payload. "none" broadcasts
-        the f32 file untouched (the seed's format). ``tag`` stamps a
-        streaming round's (round, fragment) identity into HQD1 frames.
-        Returns the wire path plus the update AS RECEIVERS WILL DECODE IT
+        new residual; bf16 casts the SafeTensors payload; both read the
+        update's file, which is written here if it is not there. "none"
+        broadcasts the f32 update untouched (the seed's format): the wire
+        is the update itself, from memory or from its file. ``tag`` stamps
+        a streaming round's (round, fragment) identity into HQD1 frames.
+        Returns the wire plus the update AS RECEIVERS WILL DECODE IT
         (None for "none") so the catch-up sum never re-reads and
         re-dequantizes the frame.
         """
         if codec == "none":
-            return update_path, None
+            return update, None
         wire = work_dir / f"update-{round_num}.wire.safetensors"
         sent = compress.write_delta(
-            wire, dict(load_file(str(update_path))), codec, ef=ef, tag=tag
+            wire, dict(load_file(str(update.ensure_file()))), codec, ef=ef, tag=tag
         )
         return wire, sent
 
@@ -2723,7 +2806,7 @@ class ParameterServerExecutor(JobExecutor):
     async def _broadcast(
         self,
         cfg,
-        update_path: Path,
+        wire: "Path | _Update",
         round_num: int,
         elastic: "_ElasticState | None" = None,
         extra_header: dict | None = None,
@@ -2748,12 +2831,19 @@ class ParameterServerExecutor(JobExecutor):
         stamps the round's trace context into the push header and, with
         ``span_round``, wraps the fan-out in a ``broadcast`` span —
         resync/catch-up/re-broadcast callers pass neither and keep their
-        exact header bytes."""
+        exact header bytes.
+
+        ``wire`` is a file, or the round's f32 update where the outer step
+        left it (:class:`_Update`): that one is pushed from its file if
+        somebody has had it written, else straight from memory, each
+        attempt from the first byte. A worker cannot tell the two apart.
+        The caller retires the update after this returns or raises, by
+        when every push has ended."""
         peers = cfg.results.ref.peers or []
         strategy = cfg.results.ref.strategy or TransferStrategy.ALL
         header = {
             "resource": cfg.results.ref.resource or "results",
-            "name": update_path.name,
+            "name": wire.name,
             "round": round_num,
         }
         if extra_header:
@@ -2798,12 +2888,15 @@ class ParameterServerExecutor(JobExecutor):
             and strategy != TransferStrategy.ANY
             and len(peers) > 1
         ):
+            # A relay is handed a path, and hands its own node one.
+            wire = await asyncio.to_thread(_wire_file, wire)
             bcast_span = (
                 trace.begin(
                     "broadcast", parent=traceparent,
                     attrs={
                         "round": span_round, "peers": len(peers),
-                        "tree": True,
+                        "tree": True, "source": "file",
+                        "bytes": payload_size(wire),
                     },
                     node=self._trace_node(),
                 )
@@ -2824,7 +2917,7 @@ class ParameterServerExecutor(JobExecutor):
                 targets = top_targets(bcast_groups, peers)
                 delivered, lost = await tree_broadcast(
                     self.node, header, str(header.get("resource", "results")),
-                    bcast_groups, targets, update_path,
+                    bcast_groups, targets, wire,
                     allowed=set(peers),
                     concurrency=_BROADCAST_CONCURRENCY,
                     what="ps tree broadcast", logger=log,
@@ -2837,10 +2930,20 @@ class ParameterServerExecutor(JobExecutor):
             finally:
                 trace.finish(bcast_span)
             return
+        if isinstance(wire, _Update) and wire.file is not None:
+            wire = wire.file
+        in_memory = isinstance(wire, _Update)
+        # A new iterator each attempt: a retry sends the whole frame.
+        source = wire.views if in_memory else (lambda: wire)
+        size = payload_size(wire.nbytes if in_memory else wire)
         bcast_span = (
             trace.begin(
                 "broadcast", parent=traceparent,
-                attrs={"round": span_round, "peers": len(peers)},
+                attrs={
+                    "round": span_round, "peers": len(peers),
+                    "source": "memory" if in_memory else "file",
+                    "bytes": size,
+                },
                 node=self._trace_node(),
             )
             if span_round is not None
@@ -2855,9 +2958,9 @@ class ParameterServerExecutor(JobExecutor):
                     # blip; a genuinely dead peer is still tolerated — it
                     # catches up from the next round's broadcast.
                     await aio.retry(
-                        lambda: self.node.push(peer, header, update_path),
+                        lambda: self.node.push(peer, header, source()),
                         attempts=2, base_delay=0.25,
-                        attempt_timeout=push_timeout(update_path),
+                        attempt_timeout=push_timeout(size),
                         retry_on=(RequestError, OSError),
                         what=f"broadcast to {peer}", logger=log,
                     )

@@ -249,7 +249,7 @@ def test_quorum_aggregation_closes_at_deadline_with_3_of_4(tmp_path):
         received, _OuterMomentum(tmp_path / "momentum.safetensors", save=False),
         0.7, 0.9, tmp_path, 0,
     )
-    update = load_file(str(out))["w"]
+    update = load_file(str(out.ensure_file()))["w"]
     np.testing.assert_allclose(update, np.full((3,), 0.7 * 1.9 * 2.0), rtol=1e-6)
 
 
@@ -340,7 +340,7 @@ def test_elastic_duplicate_resend_replaces_cleanly(tmp_path):
         0.7, 0.9, tmp_path, 0, accum,
     )
     np.testing.assert_allclose(
-        load_file(str(out))["w"], np.full(3, 0.7 * 1.9 * 4.0), rtol=1e-6
+        load_file(str(out.ensure_file()))["w"], np.full(3, 0.7 * 1.9 * 4.0), rtol=1e-6
     )
 
 

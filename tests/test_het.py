@@ -379,7 +379,7 @@ def test_collector_defaults_bit_exact_with_explicit_none(tmp_path):
             received, _OuterMomentum(sub / "momentum.safetensors", save=False),
             0.7, 0.9, sub, 0,
         )
-        outs.append(Path(out).read_bytes())
+        outs.append(out.ensure_file().read_bytes())
     assert outs[0] == outs[1]
 
 

@@ -399,7 +399,7 @@ def test_three_rounds_over_resident_momentum_write_the_file_based_paths_bytes(
         )
         accum = _folded(np.random.default_rng(rnd))
         got = ps._outer_step({}, momentum, 0.7, 0.9, new_dir, rnd, accum)
-        assert got.read_bytes() == want.read_bytes(), rnd
+        assert got.ensure_file().read_bytes() == want.read_bytes(), rnd
         assert accum.folds == 0  # taken: nobody reads a sum written over
         with pytest.raises(ValueError, match="no deltas folded"):
             accum.mean()
@@ -440,7 +440,7 @@ def test_a_momentum_file_put_there_before_the_first_round_is_read_once(tmp_path,
         got = ps._outer_step(
             {}, momentum, 0.7, 0.9, new_dir, rnd, _folded(np.random.default_rng(rnd))
         )
-        assert got.read_bytes() == want.read_bytes()
+        assert got.ensure_file().read_bytes() == want.read_bytes()
         line = _outer_line(caplog)
         assert (line["load_s"] > 0) == (rnd == 0)
         assert line["momentum_resident"] == rnd
@@ -487,7 +487,7 @@ def test_a_fragment_round_touches_only_its_keys_momentum(tmp_path, caplog):
     rng = np.random.default_rng(4)
     for rnd, keys in enumerate(frags):  # each fragment's first round
         out = ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, _folded(rng, keys))
-        assert set(load_file(str(out))) == set(keys)
+        assert set(load_file(str(out.ensure_file()))) == set(keys)
         assert _outer_line(caplog)["momentum_resident"] == 0
     assert set(momentum.tree) == set(LEAVES)
     held = dict(momentum.tree)
@@ -516,7 +516,7 @@ def test_the_metrics_planes_norms_need_no_mean_tree(tmp_path):
     )
     from safetensors.numpy import load_file
 
-    update = load_file(str(out))
+    update = load_file(str(out.ensure_file()))
     g = np.sqrt(sum(float(np.vdot(v, v)) for v in mean.values()))
     u = np.sqrt(sum(float(np.vdot(v, v)) for v in update.values()))
     assert stats["delta_norm"] == pytest.approx(g, rel=1e-5)
@@ -541,8 +541,8 @@ def test_momentum_is_on_disk_before_commit_and_broadcast_exactly_under_a_checkpo
 ):
     """A whole aggregate job, two rounds. With a checkpoint_dir the momentum
     file is there, equal to the resident tree, when the durable commit runs
-    and when the broadcast starts; without one it is never written and the
-    work dir holds one parameter-sized file fewer."""
+    and when the broadcast starts; without one it is never written, and
+    neither is the update's file: the work dir holds the delta alone."""
     from safetensors.numpy import load_file, save_file
 
     from hypha_tpu.ft.durable import DurablePS
@@ -649,14 +649,16 @@ def test_momentum_is_on_disk_before_commit_and_broadcast_exactly_under_a_checkpo
         assert events == [("commit", 0), ("broadcast", 0), ("commit", 1), ("broadcast", 1)]
         assert all(same is True for _, _, _, same in seen)
         assert all("momentum.safetensors" in files for _, _, files, _ in seen)
+        # A durable job's commit hard-links the wire: there the update is a file.
+        assert all(f"update-{rnd}.safetensors" in files for _, rnd, files, _ in seen)
         assert (tmp_path / "ckpt" / "momentum.safetensors").is_file()
     else:
         assert events == [("broadcast", 0), ("broadcast", 1)]
         for _, rnd, files, same in seen:
             assert same is None
-            # The round's delta and its update, and nothing else of that size.
-            kept = [f for f in files if not f.startswith("delta-")]
-            assert kept == [f"update-{rnd}.safetensors"], files
+            # The round's delta, and nothing else of that size: the update
+            # is broadcast from the buffers it was computed in.
+            assert [f for f in files if not f.startswith("delta-")] == [], files
 
 
 # ---------------------------------------------------------------------------
@@ -789,7 +791,7 @@ def test_round_twos_sum_lies_at_round_ones_addresses_and_leaves_its_update_alone
     tmp_path, kernel_backend
 ):
     """The job's buffers outlive the round's accumulator: lease, outer step
-    in place, file on disk, given back, leased again."""
+    in place, the update read where it lies, retired, leased again."""
     from hypha_tpu.stream.accum import RoundAccum
 
     ps, momentum = _ps_and_momentum(tmp_path)
@@ -801,13 +803,16 @@ def test_round_twos_sum_lies_at_round_ones_addresses_and_leaves_its_update_alone
         assert did.direct == did.leaves == len(LEAVES)
         assert did.resident == (len(LEAVES) if rnd else 0)
         addresses.append({k: v.ctypes.data for k, v in accum.partial().items()})
-        # The last round's update is a file: this round's fold, into the
-        # buffers it was computed in, cannot have changed it.
-        assert [p.read_bytes() for p, _ in updates] == [b for _, b in updates]
         out = ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, accum)
-        updates.append((out, out.read_bytes()))
+        # The update is the sum's own pages, and a file only when asked.
+        assert {k: v.ctypes.data for k, v in out.tree.items()} == addresses[-1]
+        assert not list(tmp_path.glob("update-*"))
+        updates.append(out.ensure_file().read_bytes())
+        # Its last reader has ended: the pages go back, the file goes.
+        out.retire()
+        assert not list(tmp_path.glob("update-*"))
     assert addresses[0] == addresses[1] == addresses[2]
-    assert len({b for _, b in updates}) == 3
+    assert len(set(updates)) == 3
 
 
 def test_a_fragment_round_leases_and_touches_only_its_keys(tmp_path):
@@ -821,7 +826,7 @@ def test_a_fragment_round_leases_and_touches_only_its_keys(tmp_path):
         accum = RoundAccum(momentum.sums)
         accum.fold(_write(tmp_path / f"d{rnd}.st", _delta(rng, keys)), 8.0)
         held.update(accum.partial())
-        ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, accum)
+        ps._outer_step({}, momentum, 0.7, 0.9, tmp_path, rnd, accum).retire()
     others = {k: held[k].copy() for k in frags[1]}
     accum = RoundAccum(momentum.sums)
     did = accum.fold(_write(tmp_path / "d2.st", _delta(rng, frags[0])), 8.0)

@@ -436,6 +436,30 @@ def test_the_outer_line_says_where_the_momentum_is(traced, untraced, checkpointe
         assert [o[field] for o in outers] == want
 
 
+@pytest.mark.parametrize("which", ["traced", "untraced", "checkpointed"])
+def test_the_update_is_broadcast_from_memory_unless_a_reader_needs_a_file(request, which):
+    """A plain job: ``update_in_memory=1`` on the ``ps outer step:`` line with
+    tracing on and off, ``in_memory`` on ``outer_step.save_update`` (which
+    now times the framing), ``source=memory`` on ``broadcast``, whose
+    ``bytes`` are what the worker's ``sync done:`` line counts as received.
+    Under a checkpoint_dir the durable commit hard-links the update's file,
+    so there is one and it is what is pushed."""
+    got = request.getfixturevalue(which)
+    jobs = got if which == "checkpointed" else [got if which == "traced" else ([], got)]
+    in_memory = which != "checkpointed"
+    for spans, lines in jobs:
+        outers = logs.outer_steps("\n".join(lines))
+        assert [o["update_in_memory"] for o in outers] == [int(in_memory)] * ROUNDS
+        syncs = _fields(lines, r"sync done: .*")
+        for rnd in range(ROUNDS if spans else 0):
+            (saved,) = _named(spans, "outer_step.save_update", rnd)
+            (bcast,) = _named(spans, "broadcast", rnd)
+            assert saved["attrs"]["in_memory"] is in_memory
+            assert saved["attrs"]["leaves"] == syncs[rnd]["leaves"]
+            assert bcast["attrs"]["source"] == ("memory" if in_memory else "file")
+            assert saved["attrs"]["bytes"] == bcast["attrs"]["bytes"] == syncs[rnd]["bytes_down"]
+
+
 @pytest.mark.parametrize("name", MOMENTUM_FILE)
 def test_under_a_checkpoint_dir_the_momentum_file_has_its_span(checkpointed, name):
     """Written in every round, before the round's commit and broadcast; read
