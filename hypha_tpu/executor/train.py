@@ -42,7 +42,7 @@ class TrainState(struct.PyTreeNode):
     opt_state: Any
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
     # Variable collections beside ``params`` that the step updates by a rule
-    # of its own (afmoe's selection bias): not differentiated, no AdamW
+    # of its own (a routed model's selection bias): not differentiated, no AdamW
     # moments, no part of the pseudo-gradient. None for every other model.
     extras: Any = None
 
@@ -278,24 +278,27 @@ ROUTING_FIELDS = (
 
 def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True):
     """The jitted step of a model with routed experts and a selection bias
-    (``models/afmoe.py``): ``model.apply`` returns ``(out, stats)`` and
+    (``models/routed.py``): ``model.apply`` returns ``(out, stats)`` and
     ``state.extras`` holds the ``moe_state`` collection.
 
     The loss is the chunked causal cross-entropy over the final hidden states
-    (``with_head=False``), so the [B, S, vocab] logits never exist. The bias is
-    updated from the step's counts after the optimizer
-    (``models.afmoe.update_bias``); it gets no gradient and no moments. The
-    routing counters ride in ``metrics["host"]`` (``ROUTING_FIELDS``) beside
-    the loss: pairs summed and ``load_max`` maximised over the expert layers.
+    (``with_head=False``), so the [B, S, vocab] logits never exist. The head is
+    the leaf of ``params`` that the model names (``model.head_leaf``): a head
+    of its own, or the embedding where the two are tied, whose gradient then
+    has two sources. The bias is updated from the step's counts after the
+    optimizer (``models.routed.update_bias``); it gets no gradient and no
+    moments. The routing counters ride in ``metrics["host"]``
+    (``ROUTING_FIELDS``) beside the loss: pairs summed and ``load_max``
+    maximised over the expert layers.
     """
-    from ..models.afmoe import STATE, update_bias
+    from ..models.routed import STATE, update_bias
 
     body = model.clone(with_head=False)
     coeff = model.config.load_balance_coeff
 
     def loss_fn(params, extras, ids):
         hidden, stats = body.apply({**params, **extras}, ids)
-        head = params["params"]["lm_head"].astype(hidden.dtype)
+        head = params["params"][model.head_leaf].astype(hidden.dtype)
         loss = chunked_causal_ce(hidden[:, :-1], head, ids[:, 1:], chunk=loss_chunk)
         return loss, stats
 

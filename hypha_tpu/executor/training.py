@@ -675,6 +675,16 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
         source = None  # weights are loaded by the builder; skip the overwrite
 
     model, _mcfg = build_model(model_spec, attn_impl)
+    kinds = getattr(_mcfg, "layer_types", None)
+    if isinstance(kinds, (list, tuple)) and kinds:
+        # A stack of more than one kind of layer says what it holds. The
+        # fallbacks' configurations are a library's: no field is taken for granted.
+        kinds, head_dim = list(kinds), getattr(_mcfg, "head_dim", None)
+        log.info(
+            "operators: %s%s",
+            " ".join(f"{k}={kinds.count(k)}" for k in dict.fromkeys(kinds)),
+            f" head_dim={head_dim}" if isinstance(head_dim, int) else "",
+        )
     model_type = resolve_model_type(model_spec.get("model_type", ModelType.CAUSAL_LM))
     causal_lm = model_type not in _non_causal_types()
     has_aux = isinstance(model, Mixtral)
@@ -827,7 +837,7 @@ def run_training(
         params = adapters
 
     # A model may keep variable collections beside ``params`` that its own
-    # step updates (afmoe's selection bias, ``moe_state``): they ride in
+    # step updates (a routed model's selection bias, ``moe_state``): they ride in
     # ``state.extras``, outside the gradient, AdamW and the pseudo-gradient
     # (anchor, delta and merge below only ever see ``state.params``). They are
     # worker-local and, today, not part of a train checkpoint.

@@ -8,6 +8,7 @@ through the registry's HF-conversion fallback (hypha_tpu.models.registry).
 """
 
 from .afmoe import Afmoe, AfmoeConfig
+from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .lenet import LeNet, LeNetConfig
 from .gpt2 import GPT2, GPT2Config
 from .llama import Llama, LlamaConfig
@@ -17,6 +18,8 @@ from .registry import build_model, resolve_model_type
 __all__ = [
     "Afmoe",
     "AfmoeConfig",
+    "Lfm2Moe",
+    "Lfm2MoeConfig",
     "LeNet",
     "LeNetConfig",
     "GPT2",

@@ -23,6 +23,12 @@ The layer, as the published ``modeling_afmoe.py`` computes it:
   lives in the ``moe_state`` collection, beside ``params``: the train step
   keeps it out of the gradient, of AdamW and of the pseudo-gradient.
 
+The routed layer (``_MoE``), the SwiGLU, ``STATE`` and :func:`update_bias` live
+in ``routed.py`` and serve two families, this one and ``lfm2_moe.py``. The two
+differ there in three numbers of their configurations: afmoe has one shared
+expert, renormalises with ``route_eps`` 1e-20 and scales by ``route_scale``
+2.826; lfm2_moe has no shared expert, ``route_eps`` 1e-6 and a scale of 1.
+
 **One rank's share.** ``experts_held`` and ``expert_offset`` say which experts
 are here. The router keeps its ``num_experts`` outputs and its
 ``experts_per_token``; this rank computes the shared expert and the part of
@@ -37,21 +43,20 @@ Training only: there is no cached decode here yet.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable
+from typing import Callable, ClassVar
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.grouped_matmul import grouped_swiglu, sort_pairs
 from ..ops.rmsnorm import rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from .llama import _RMSNorm
+from .routed import STATE, _MoE, _SwiGLU, update_bias
 
 __all__ = ["Afmoe", "AfmoeConfig", "STATE", "update_bias"]
 
-STATE = "moe_state"  # the variable collection of the selection biases
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
@@ -78,6 +83,7 @@ class AfmoeConfig:
     rms_eps: float = 1e-5
     route_norm: bool = True
     route_scale: float = 2.826
+    route_eps: float = 1e-20  # added to the chosen scores' sum before dividing
     load_balance_coeff: float = 1e-3
     mup_enabled: bool = True  # embeddings scaled by sqrt(hidden_size)
     max_seq_len: int = 131_072
@@ -116,17 +122,6 @@ class AfmoeConfig:
         )
 
 
-class _SwiGLU(nn.Module):
-    width: int
-    dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, x):
-        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
-        act = nn.silu(dense(self.width, "gate_proj")(x)) * dense(self.width, "up_proj")(x)
-        return dense(x.shape[-1], "down_proj")(act)
-
-
 class _Attention(nn.Module):
     config: AfmoeConfig
     kind: str
@@ -160,57 +155,6 @@ class _Attention(nn.Module):
         return dense(E, "o_proj")(attn)
 
 
-class _MoE(nn.Module):
-    """Shared expert plus this rank's part of the routed sum. Returns the
-    output and the step's routing counts (see :class:`Afmoe`)."""
-
-    config: AfmoeConfig
-
-    @nn.compact
-    def __call__(self, m):
-        cfg = self.config
-        dtype = jnp.dtype(cfg.dtype)
-        B, S, D = m.shape
-        E, K, G, F = cfg.num_experts, cfg.experts_per_token, cfg.held, cfg.moe_intermediate_size
-        x = m.reshape(B * S, D)
-        with jax.named_scope("router"):
-            w_r = self.param("router", nn.initializers.lecun_normal(), (D, E), jnp.float32)
-            bias = self.variable(STATE, "expert_bias", jnp.zeros, (E,), jnp.float32).value
-            scores = jax.nn.sigmoid(
-                jnp.dot(x.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
-            )
-            _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), K)
-            w = jnp.take_along_axis(scores, idx, axis=-1)
-            if cfg.route_norm:
-                w = w / (w.sum(-1, keepdims=True) + 1e-20)
-            w = w * cfg.route_scale
-        with jax.named_scope("shared_expert"):
-            shared = _SwiGLU(F * cfg.num_shared_experts, dtype, name="shared_experts")(m)
-        init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (G, D, F), jnp.float32)
-        w_up = self.param("experts_up", init, (G, D, F), jnp.float32)
-        w_down = self.param("experts_down", init, (G, F, D), jnp.float32)
-        with jax.named_scope("moe_dispatch"):
-            order, sizes = sort_pairs(idx, cfg.expert_offset, G)
-            tokens, weights = order // K, w.reshape(-1)[order]
-        routed = grouped_swiglu(
-            x, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype),
-            tokens, weights, sizes, chunk=cfg.moe_chunk,
-        )
-        with jax.named_scope("router"):
-            experts = jnp.arange(E, dtype=idx.dtype)
-            chosen = jnp.sum(idx[..., None] == experts, axis=(0, 1), dtype=jnp.int32)
-            held = (idx >= cfg.expert_offset) & (idx < cfg.expert_offset + G)
-            stats = {
-                "chosen": chosen,  # [E]: tokens each expert was chosen for
-                "pairs_routed": jnp.sum(held, dtype=jnp.int32),  # by the choice
-                "pairs_computed": jnp.sum(sizes),  # by what the product walked
-                "load_max": jnp.max(sizes),
-                "tokens_elsewhere": jnp.sum(~held.any(-1), dtype=jnp.int32),
-            }
-        return shared + routed.reshape(B, S, D).astype(dtype), stats
-
-
 class _Block(nn.Module):
     config: AfmoeConfig
     layer: int
@@ -235,6 +179,7 @@ class _Block(nn.Module):
 class Afmoe(nn.Module):
     config: AfmoeConfig = AfmoeConfig()
     attn_impl: Callable | None = None
+    head_leaf: ClassVar[str] = "lm_head"  # the routed step's loss reads it
     # with_head=False returns the final hidden states for the chunked loss
     # (executor.train.chunked_causal_ce), as in llama.py.
     with_head: bool = True
@@ -272,19 +217,3 @@ class Afmoe(nn.Module):
             return x, stats
         with jax.named_scope("lm_head"):
             return jnp.einsum("bse,ve->bsv", x.astype(jnp.float32), lm_head), stats
-
-
-def update_bias(state, chosen: jnp.ndarray, coeff: float):
-    """One step of the selection bias, as torchtitan's: with ``chosen``
-    [layers, experts] the tokens each expert was chosen for,
-    ``d = coeff * sign(mean(c) - c)`` and ``b += d - mean(d)``, layer by
-    layer. ``state`` is the ``moe_state`` collection, ``{"layers_<i>": ...}``;
-    row j of ``chosen`` is the j-th expert layer's."""
-    c = chosen.astype(jnp.float32)
-    d = coeff * jnp.sign(c.mean(-1, keepdims=True) - c)
-    d = d - d.mean(-1, keepdims=True)
-    layers = sorted(state, key=lambda name: int(name.rsplit("_", 1)[1]))
-    return {
-        name: jax.tree.map(lambda b, row=d[j]: b + row, state[name])
-        for j, name in enumerate(layers)
-    }

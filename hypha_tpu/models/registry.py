@@ -3,7 +3,7 @@
 The reference maps 38 ``ModelType`` variants to HF ``AutoModelFor*`` classes
 (executors/accelerate/.../model.py:48-123). Here every variant resolves:
 the flagship families (GPT-2, Llama + its Mistral/Qwen2/Gemma descendants,
-Mixtral, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
+Mixtral, afmoe, lfm2_moe, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
 head resolve through the hf fallback family (torch checkpoints convert via
 ``from_pt``); the remaining torch-only-head types resolve through the
 ``heads`` family — JAX task heads over Flax backbones (models/heads.py),
@@ -22,6 +22,7 @@ from ..messages import ModelType
 from .afmoe import Afmoe, AfmoeConfig
 from .gpt2 import GPT2, GPT2Config
 from .lenet import LeNet, LeNetConfig
+from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .llama import Llama, LlamaConfig
 from .mixtral import Mixtral, MixtralConfig
 
@@ -33,6 +34,7 @@ _PRESETS = {
     "mixtral": {"tiny": MixtralConfig.tiny, "8x7b": MixtralConfig.mixtral_8x7b},
     "lenet": {"default": LeNetConfig},
     "afmoe": {"tiny": AfmoeConfig.tiny},
+    "lfm2_moe": {"tiny": Lfm2MoeConfig.tiny},
 }
 
 FAMILIES = {
@@ -51,6 +53,10 @@ FAMILIES = {
     # a shared expert, gated attention, window (RoPE) and full (NoPE) layers;
     # one rank's share of the experts by ``experts_held``/``expert_offset``.
     "afmoe": (Afmoe, AfmoeConfig),
+    # Liquid's lfm2_moe (LFM2-24B-A2B): a layer's operator is a gated short
+    # convolution or QK-normed GQA with RoPE, by ``layer_types``; the routed
+    # experts of afmoe with no shared expert; the head tied to the embedding.
+    "lfm2_moe": (Lfm2Moe, Lfm2MoeConfig),
     "lenet": (LeNet, LeNetConfig),
 }
 

@@ -1,0 +1,125 @@
+"""The routed expert layer that the sparse families share (``afmoe.py``,
+``lfm2_moe.py``), as one rank of an expert-parallel deployment computes it,
+and the selection bias that is no parameter.
+
+``s = sigmoid(m W_r)`` in float32 over all ``num_experts``; the top
+``experts_per_token`` of ``s + b`` are chosen (``b``, the selection bias,
+enters the choice and not the weight); the chosen scores are normalised
+(``route_norm``: ``s / (sum s + route_eps)``) and scaled (``route_scale``);
+the output is the weighted sum of the chosen experts' SwiGLUs, plus a shared
+expert where the family has one. The configuration is whatever dataclass the
+family brings, read by these fields: ``dtype``, ``num_experts``,
+``experts_per_token``, ``held``, ``expert_offset``, ``moe_intermediate_size``,
+``moe_chunk``, ``num_shared_experts``, ``route_norm``, ``route_scale``,
+``route_eps``. What the two families differ in is three of them: afmoe has one
+shared expert, ``route_eps`` 1e-20 and ``route_scale`` 2.826; lfm2_moe has no
+shared expert (no ``shared_experts`` parameters and no ``shared_expert``
+scope exist then), ``route_eps`` 1e-6 and ``route_scale`` 1.
+
+The bias lives in the ``moe_state`` collection, beside ``params``: the train
+step keeps it out of the gradient, of AdamW and of the pseudo-gradient, and
+moves it after each optimizer step by :func:`update_bias`.
+
+**One rank's share.** ``held`` and ``expert_offset`` say which experts are
+here. The router keeps its ``num_experts`` outputs and its
+``experts_per_token``; this rank computes the part of the routed sum that its
+own experts give (``ops/grouped_matmul.py``: no pair is dropped). What the
+experts held elsewhere would add is left out.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.grouped_matmul import grouped_swiglu, sort_pairs
+
+__all__ = ["STATE", "update_bias"]
+
+STATE = "moe_state"  # the variable collection of the selection biases
+
+
+class _SwiGLU(nn.Module):
+    width: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+        act = nn.silu(dense(self.width, "gate_proj")(x)) * dense(self.width, "up_proj")(x)
+        return dense(x.shape[-1], "down_proj")(act)
+
+
+class _MoE(nn.Module):
+    """This rank's part of the routed sum, plus the shared expert where the
+    family has one. Returns the output and the step's routing counts:
+    ``chosen`` [experts] and the scalars ``pairs_routed``, ``pairs_computed``,
+    ``load_max``, ``tokens_elsewhere``."""
+
+    config: Any
+
+    @nn.compact
+    def __call__(self, m):
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        B, S, D = m.shape
+        E, K, G, F = cfg.num_experts, cfg.experts_per_token, cfg.held, cfg.moe_intermediate_size
+        x = m.reshape(B * S, D)
+        with jax.named_scope("router"):
+            w_r = self.param("router", nn.initializers.lecun_normal(), (D, E), jnp.float32)
+            bias = self.variable(STATE, "expert_bias", jnp.zeros, (E,), jnp.float32).value
+            scores = jax.nn.sigmoid(
+                jnp.dot(x.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
+            )
+            _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), K)
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            if cfg.route_norm:
+                w = w / (w.sum(-1, keepdims=True) + cfg.route_eps)
+            w = w * cfg.route_scale
+        shared = None
+        if cfg.num_shared_experts:
+            with jax.named_scope("shared_expert"):
+                shared = _SwiGLU(F * cfg.num_shared_experts, dtype, name="shared_experts")(m)
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("experts_gate", init, (G, D, F), jnp.float32)
+        w_up = self.param("experts_up", init, (G, D, F), jnp.float32)
+        w_down = self.param("experts_down", init, (G, F, D), jnp.float32)
+        with jax.named_scope("moe_dispatch"):
+            order, sizes = sort_pairs(idx, cfg.expert_offset, G)
+            tokens, weights = order // K, w.reshape(-1)[order]
+        routed = grouped_swiglu(
+            x, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype),
+            tokens, weights, sizes, chunk=cfg.moe_chunk,
+        )
+        with jax.named_scope("router"):
+            experts = jnp.arange(E, dtype=idx.dtype)
+            chosen = jnp.sum(idx[..., None] == experts, axis=(0, 1), dtype=jnp.int32)
+            held = (idx >= cfg.expert_offset) & (idx < cfg.expert_offset + G)
+            stats = {
+                "chosen": chosen,  # [E]: tokens each expert was chosen for
+                "pairs_routed": jnp.sum(held, dtype=jnp.int32),  # by the choice
+                "pairs_computed": jnp.sum(sizes),  # by what the product walked
+                "load_max": jnp.max(sizes),
+                "tokens_elsewhere": jnp.sum(~held.any(-1), dtype=jnp.int32),
+            }
+        out = routed.reshape(B, S, D).astype(dtype)
+        return (out if shared is None else shared + out), stats
+
+
+def update_bias(state, chosen: jnp.ndarray, coeff: float):
+    """One step of the selection bias, as torchtitan's: with ``chosen``
+    [layers, experts] the tokens each expert was chosen for,
+    ``d = coeff * sign(mean(c) - c)`` and ``b += d - mean(d)``, layer by
+    layer. ``state`` is the ``moe_state`` collection, ``{"layers_<i>": ...}``;
+    row j of ``chosen`` is the j-th expert layer's."""
+    c = chosen.astype(jnp.float32)
+    d = coeff * jnp.sign(c.mean(-1, keepdims=True) - c)
+    d = d - d.mean(-1, keepdims=True)
+    layers = sorted(state, key=lambda name: int(name.rsplit("_", 1)[1]))
+    return {
+        name: jax.tree.map(lambda b, row=d[j]: b + row, state[name])
+        for j, name in enumerate(layers)
+    }

@@ -11,6 +11,7 @@ unless HYPHA_ALLOW_TPU=1 targets the on-chip kernel tests.
 
 import os
 import sys
+import traceback
 
 import pytest
 
@@ -45,6 +46,37 @@ def pytest_configure(config):
         "fault: chaos/fault-injection tests (hypha_tpu.ft) — filter with "
         "-m fault / -m 'not fault'",
     )
+
+
+# Two tests under ``tests/perfbench/`` each hold one literal count of the
+# manifest's entries that stops being true when a cell is added, and a PR that
+# adds a cell may not edit a file the benchmark already has (PR 40; PERF.md 7
+# asks the next ``benchmark`` PR to make both counts relative and to take this
+# hook out). Only a failure *at that one line* is expected: any other assertion
+# of either test fails as it always did. What stands after the line in each test
+# does not run while the line fails, so ``tests/perfbench/test_relative_counts.py``
+# holds every assertion of both again, over the same fixtures, with the counts
+# relative to the manifest. A repaired test passes and the hook does nothing.
+_COUNTS_A_NEW_CELL_OUTDATES = {
+    "tests/perfbench/test_rehearsal.py::test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give":
+        ('len(manifest["per_layer"]) - 7',
+         "Trinity's seven were the only entries with a workloads list"),
+    "tests/perfbench/test_fourth_cell.py::test_nothing_that_was_there_is_touched_and_the_manifest_gains_entries_only":
+        ('len(m["workloads"]) == 4 and len(m["configs"]) == 3',
+         "the manifest had 3 workloads and 2 configurations before the toy cell"),
+}
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_pyfunc_call(pyfuncitem):
+    try:
+        return (yield)
+    except AssertionError as e:
+        stale = _COUNTS_A_NEW_CELL_OUTDATES.get(pyfuncitem.nodeid)
+        at = traceback.extract_tb(e.__traceback__)[-1]
+        if stale and at.name == pyfuncitem.name and stale[0] in (at.line or ""):
+            pytest.xfail(f"holds the literal count `{stale[0]}`: {stale[1]}")
+        raise
 
 
 @pytest.fixture(params=["native", "numpy"])

@@ -282,13 +282,19 @@ def test_one_bias_update_is_the_formula():
                                    atol=1e-7)
 
 
-def test_the_routed_step_learns_updates_the_bias_and_keeps_it_out_of_adamw(ids):
+@pytest.mark.parametrize("family", ["afmoe", "lfm2_moe"])
+def test_the_routed_step_learns_updates_the_bias_and_keeps_it_out_of_adamw(ids, family):
+    """One step for every family with routed experts: the head's leaf is asked
+    of the model (afmoe: ``lm_head``; lfm2_moe: the embedding, tied)."""
     import optax
 
     from hypha_tpu.executor.train import ROUTING_FIELDS, TrainState, make_routed_train_step
 
-    model, cfg = _tiny(experts_held=2, expert_offset=4)
+    model, cfg = build_model({"family": family, "preset": "tiny", "config": {
+        "dtype": "float32", "experts_held": 2, "expert_offset": 4}})
     variables = model.init(jax.random.key(0), ids)
+    assert model.head_leaf in variables["params"]
+    assert ("lm_head" in variables["params"]) == (family == "afmoe")
     state = TrainState.create({"params": variables["params"]}, optax.adamw(3e-3),
                               {STATE: variables[STATE]})
     assert STATE not in str(jax.tree.structure(state.opt_state))

@@ -48,11 +48,14 @@ def _compiled_text(fn, *shapes) -> str:
 
 
 # GPT-2-small's step (B16 S1024 H12 D64), Mistral-7B's long-context GQA
-# (B2 S4096 H32/Hkv8 D128), and Trinity-Mini's two kinds of layer at S 8192
-# (H32/Hkv4 D128): the window of 2048 that cuts, and the full layer.
+# (B2 S4096 H32/Hkv8 D128), Trinity-Mini's two kinds of layer at S 8192
+# (H32/Hkv4 D128): the window of 2048 that cuts, and the full layer; and
+# LFM2-24B-A2B's full layer (B2 S8192 H32/Hkv8 D64): head size 64 with four
+# query heads to a key head, half the 128 lanes a tile.
 FLASH_SHAPES = {
     "gpt2": (16, 1024, 12, 12, 64, None), "mistral": (2, 4096, 32, 8, 128, None),
     "trinity_window": (1, 8192, 32, 4, 128, 2048), "trinity_full": (1, 8192, 32, 4, 128, None),
+    "lfm2_full": (2, 8192, 32, 8, 64, None),
 }
 
 
