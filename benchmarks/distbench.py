@@ -144,7 +144,7 @@ def main() -> None:
         "ab_interleaved": ab,
         "protocol": "median of %d reps, 1 GiB over 8 parallel push streams; "
         "receiver = 4 MiB buffered reads + thread-offloaded writes "
-        "(default; HYPHA_RAW_DRAIN=1 opts into the raw-socket mmap drain)"
+        "(default; HYPHA_RAW_DRAIN=1 opts into the raw-socket drain thread)"
         % args.stream_reps,
     }
 

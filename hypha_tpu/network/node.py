@@ -379,59 +379,83 @@ def _write_and_hash(f, data: bytes, hasher) -> None:
     hasher.update(data)
 
 
-def _drain_socket_to_file(sock, buffered: bytes, path) -> int:
-    """Blocking drain: recv_into an mmap of ``path`` until EOF.
+# One piece of a thread-side drain: what a ``recv_into`` may take at once.
+_DRAIN_PIECE = 1 << 22
+
+
+def _drain_socket_to_file(sock, buffered: bytes, f) -> tuple[int, float, float]:
+    """Blocking drain: ``recv_into`` one kept buffer and write it to ``f``
+    until EOF. Returns the byte count and the seconds spent receiving
+    (waiting for the sender among them) and writing.
 
     Runs in a worker thread with reading paused on the asyncio transport
     (fabric.raw_socket_handoff), so this thread is the socket's only
-    reader. The file is grown in 64 MiB steps and truncated to the exact
-    byte count at EOF; ``recv_into`` against the mmap writes kernel
-    buffers straight into the page cache.
+    reader. It owns ``f`` from here on and closes it, also on the way out
+    of an error: the caller may be cancelled while this runs and must not
+    close a file another thread is writing. The file is truncated to the
+    byte count at EOF (a file written over may have been longer). The
+    buffer is this call's own 4 MiB and not a map of the file: a map of a
+    fresh file pays a page fault a page, and on the chip's host read
+    2.1-2.3 s for 1.92 GB where this reads 1.3-1.5 s into a fresh file and
+    0.6-0.7 s over one that is there (PERF.md, PR 41).
 
     asyncio hands out a TransportSocket that forbids mode changes (and the
     O_NONBLOCK status is shared with the transport's writer side anyway),
     so the fd is dup()ed into a real socket object and drained
-    non-blocking with select() — which also gives the idle timeout a
+    non-blocking with poll() — which also gives the idle timeout a
     thread needs, since it can't be cancelled and a dead sender must
     surface as ConnectionError instead of a leaked thread."""
-    import mmap as _mmap
     import os
     import select as _select
 
-    grow = 64 << 20
+    clock = time.perf_counter
     total = 0
+    read_s = write_s = 0.0
     s = socket.socket(fileno=os.dup(sock.fileno()))
     try:
-        with open(path, "wb+") as f:
+        with f:
             if buffered:
                 f.write(buffered)
                 total = len(buffered)
-            f.truncate(total + grow)
-            mm = _mmap.mmap(f.fileno(), 0)
-            try:
-                while True:
-                    if total == mm.size():
-                        f.truncate(total + grow)
-                        mm.resize(total + grow)
-                    try:
-                        n = s.recv_into(memoryview(mm)[total:])
-                    except (BlockingIOError, InterruptedError):
-                        # poll, not select: select() raises on fds >= 1024,
-                        # and a large fleet's node can easily sit above that.
-                        p = _select.poll()
-                        p.register(s, _select.POLLIN)
-                        if not p.poll(60_000):
-                            raise ConnectionError("push drain timed out")
-                        continue
-                    if n == 0:
-                        break
-                    total += n
-            finally:
-                mm.close()
+            view = memoryview(bytearray(_DRAIN_PIECE))
+            # poll, not select: select() raises on fds >= 1024, and a
+            # large fleet's node can easily sit above that.
+            poller = _select.poll()
+            poller.register(s, _select.POLLIN)
+            while True:
+                t0 = clock()
+                try:
+                    n = s.recv_into(view)
+                except (BlockingIOError, InterruptedError):
+                    if not poller.poll(60_000):
+                        raise ConnectionError("push drain timed out")
+                    read_s += clock() - t0
+                    continue
+                t1 = clock()
+                read_s += t1 - t0
+                if n == 0:
+                    break
+                f.write(view[:n])
+                write_s += clock() - t1
+                total += n
             f.truncate(total)
     finally:
         s.close()
-    return total
+    return total, read_s, write_s
+
+
+def _open_dest(path, over, buffering: int = -1):
+    """Open what a push is written to; the second value says whether the
+    pages under it exist. With ``over`` that is the spare, where it lies
+    and without truncation (``save_to`` gives it ``path``'s name once the
+    payload is whole); where it cannot be opened (the spare is gone),
+    and without one, a fresh file at ``path``."""
+    if over is not None:
+        try:
+            return open(over, "r+b", buffering=buffering), True
+        except OSError:
+            pass
+    return open(path, "wb", buffering=buffering), False
 
 
 @dataclass(slots=True)
@@ -442,6 +466,12 @@ class PushStream:
     resource: Any
     stream: Stream
     _done: Callable[[], None] = field(default=lambda: None)
+    # What the last save_to did, for the receiver's span and log: seconds
+    # receiving, seconds writing, and whether the file written was one
+    # that was there (``over``).
+    read_s: float = 0.0
+    write_s: float = 0.0
+    recycled: bool = False
 
     async def read_all(self, chunk: int = 1 << 20) -> bytes:
         parts = []
@@ -453,75 +483,114 @@ class PushStream:
         self.finish()
         return b"".join(parts)
 
-    async def save_to(self, path, chunk: int = 1 << 22, hasher=None) -> int:
+    async def save_to(
+        self, path, chunk: int = 1 << 22, hasher=None, over=None
+    ) -> int:
         """Stream to disk without buffering the whole payload (the reference
         file-mediates all tensor transfers, bridge.rs:392-504).
 
         Default path: 4 MiB buffered reads with thread-offloaded writes —
         chunk size, not the thread hop, is the first-order cost (r4 sweep).
 
-        Opt-in fast path (``HYPHA_RAW_DRAIN=1``, plain-TCP push connections
-        only): the raw socket is handed to a dedicated thread that
-        ``recv_into``s an mmap of the destination file — one
-        kernel→page-cache copy, zero event-loop involvement. This closes
-        DISTBENCH r4's named double-copy gap and measures ~26% faster on a
-        CLEAN page cache (972 vs 771 MB/s singles), but under sustained
-        writeback pressure on a slow virtio disk the mmap page-fault path
-        throttles harder than write() and LOSES (DISTBENCH_r05 A/B:
-        ~220-530 vs ~760-780 sustained) — so it stays off by default and
-        is the right switch only for hosts with fast local disks. TLS /
-        mux / relay streams always use the buffered path (their bytes
-        must pass through the event loop).
+        Thread-side drain (plain-TCP push connections only): the raw socket
+        is handed to a dedicated thread that ``recv_into``s one kept buffer
+        and writes it (``_drain_socket_to_file``) — zero event-loop
+        involvement, which is what bounds the default path on the chip's
+        host (the loop's thread receives 1.92 GB in 1.5 s whatever the file
+        costs). Taken when ``over`` is given, and as an opt-in
+        (``HYPHA_RAW_DRAIN=1``) for every other push: into a *fresh* file it
+        was measured against the default only on CPU-era hosts, in an
+        earlier form that mapped the file (26% faster on a clean page
+        cache, slower under sustained writeback on a slow virtio disk:
+        DISTBENCH r4, r5; not measured on the chip's host but for one
+        reading, PERF.md PR 41), so there it stays off by default. TLS /
+        mux / relay streams always use the buffered path (their bytes must
+        pass through the event loop), with ``over`` too.
 
         ``hasher``: optional hashlib object updated with every chunk as it
         is written — a receiver that needs a digest of the payload (the
         durable PS journal's dedup key) gets it in the same pass instead
         of re-reading the file; requesting one forces the buffered path,
-        since the raw-drain handoff never surfaces the bytes."""
+        since the raw-drain handoff never surfaces the bytes.
+
+        ``over``: a file on ``path``'s file system that the caller is done
+        with (the PS's delta of the last round). The payload is written
+        over it from offset 0, so it lands in pages that exist and not in
+        fresh ones (a parameter-sized pass into fresh memory runs at about
+        1 GB/s on the chip's host, into pages that exist at several); at
+        EOF it is truncated to the payload's length, whatever the spare's
+        was, and renamed onto ``path``. A push that ends any other way (the
+        sender gone, cancellation, a timeout) would leave the spare's tail
+        behind its head: ``path`` never names such a file, and the spare
+        is unlinked before the error goes on. ``read_s``, ``write_s`` and
+        ``recycled`` say afterwards what this call did; on the buffered
+        path the two times are the loop's awaits and overlap the loop's own
+        receiving, in the drain thread they part socket from file."""
         import os as _os
 
         handoff = None
-        if hasher is None and _os.environ.get("HYPHA_RAW_DRAIN") == "1":
+        if hasher is None and (
+            over is not None or _os.environ.get("HYPHA_RAW_DRAIN") == "1"
+        ):
             handoff = getattr(self.stream, "raw_socket_handoff", None)
         handoff = handoff() if handoff is not None else None
-        if handoff is not None:
-            sock, buffered = handoff
-            try:
-                total = await asyncio.to_thread(
-                    _drain_socket_to_file, sock, buffered, path
-                )
-            finally:
-                # finish() even on a failed drain — otherwise the accept
-                # semaphore slot leaks and _handle_push waits forever; 8
-                # timed-out senders would wedge all inbound pushes.
-                self.finish()
-            credit = getattr(self.stream, "credit_inbound", None)
-            if credit is not None:
-                credit(total)
-            return total
         loop = asyncio.get_running_loop()
+        clock = time.perf_counter
         total = 0
+        self.read_s = self.write_s = 0.0
+        self.recycled = False
+        whole = False
+        # The drain thread writes what one recv_into took, unbuffered.
+        buffering = 0 if handoff is not None else -1
         try:
             # open() seeks/stats on the calling thread — off the loop too.
-            f = await asyncio.to_thread(open, path, "wb")
-            try:
-                while True:
-                    data = await self.stream.read(chunk)
-                    if not data:
-                        break
-                    if hasher is None:
-                        await loop.run_in_executor(None, f.write, data)
-                    else:
-                        await loop.run_in_executor(
-                            None, _write_and_hash, f, data, hasher
-                        )
-                    total += len(data)
-            finally:
-                await asyncio.to_thread(f.close)
+            f, self.recycled = await asyncio.to_thread(
+                _open_dest, path, over, buffering
+            )
+            if handoff is not None:
+                sock, buffered = handoff
+                total, self.read_s, self.write_s = await asyncio.to_thread(
+                    _drain_socket_to_file, sock, buffered, f
+                )
+                credit = getattr(self.stream, "credit_inbound", None)
+                if credit is not None:
+                    credit(total)
+            else:
+                try:
+                    while True:
+                        t0 = clock()
+                        data = await self.stream.read(chunk)
+                        t1 = clock()
+                        self.read_s += t1 - t0
+                        if not data:
+                            break
+                        if hasher is None:
+                            await loop.run_in_executor(None, f.write, data)
+                        else:
+                            await loop.run_in_executor(
+                                None, _write_and_hash, f, data, hasher
+                            )
+                        self.write_s += clock() - t1
+                        total += len(data)
+                    if self.recycled:
+                        await asyncio.to_thread(f.truncate, total)
+                finally:
+                    await asyncio.to_thread(f.close)
+            if self.recycled:
+                await asyncio.to_thread(_os.replace, over, path)
+            whole = True
         finally:
-            # Same wedge as the raw path: a sender dying mid-push must
-            # still release the accept-semaphore slot, or ACCEPT_LIMIT
-            # failed senders stop all inbound pushes.
+            if over is not None and not whole:
+                # The head is this push's and the tail the spare's: no
+                # reader may find that, under either name.
+                for partial in (over, path):
+                    try:
+                        _os.unlink(partial)
+                    except OSError:
+                        pass
+            # A sender dying mid-push must still release the
+            # accept-semaphore slot on either path, or ACCEPT_LIMIT failed
+            # senders stop all inbound pushes (_handle_push waits forever).
             self.finish()
         return total
 

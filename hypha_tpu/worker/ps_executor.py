@@ -506,6 +506,14 @@ class ParameterServerExecutor(JobExecutor):
             await asyncio.to_thread(shutil.rmtree, work_dir, ignore_errors=True)
             return
         round_num = 0
+        # The delta files the last round left, kept for the next round's
+        # pushes to be saved over (_spool_deltas). Only where the plain
+        # collector fills ``received`` and no journal names the files: a
+        # durable job's accepted files are never written over, and the
+        # elastic collector reads a partial file's size as bytes drained.
+        spares: "list[Path] | None" = (
+            [] if dur is None and elastic is None else None
+        )
         # Routed consumer: only this job's pseudo-gradients (matched on the
         # Receive reference's resource tag) reach this loop, so a colocated
         # train job's bridge — or another PS job — never eats our deltas.
@@ -695,6 +703,7 @@ class ParameterServerExecutor(JobExecutor):
                         preloaded=preload.pop(round_num, None),
                         preloaded_folded=preloaded_folded,
                         link=link, arrivals=arrivals, ptrace=ptrace,
+                        spares=spares,
                     )
                 # Round 0's root context only arrives inside the first
                 # delta's header — late-bind the wait span to it.
@@ -757,8 +766,7 @@ class ParameterServerExecutor(JobExecutor):
                     ptrace.adopt(response, round_num + 1)
                     await bcast_adaptive()
                     with ptrace.phase("cleanup", round_num, min_s=trace.SLOW_CLEANUP_S):
-                        for path, _ in received.values():
-                            path.unlink(missing_ok=True)
+                        self._spool_deltas(received, work_dir, spares)
                         update.retire()
                     round_num += 1
                     if elastic is not None:
@@ -849,8 +857,7 @@ class ParameterServerExecutor(JobExecutor):
                     if dur is None:
                         # Durable runs keep the delta files — the journal
                         # references them until a checkpoint covers the round.
-                        for path, _ in received.values():
-                            path.unlink(missing_ok=True)
+                        self._spool_deltas(received, work_dir, spares)
                     # Broadcast done (and catch-up folded): every push has
                     # ended, so the update's buffers go back to the sums
                     # for the next round's fold, and a long job must not
@@ -1325,6 +1332,7 @@ class ParameterServerExecutor(JobExecutor):
         link: "LinkTable | None" = None,
         arrivals: "dict[str, float] | None" = None,
         ptrace: "_PsTrace | None" = None,
+        spares: "list[Path] | None" = None,
     ) -> dict[str, tuple[Path, float]]:
         """Gather one pseudo-gradient per worker: peer -> (path, samples).
 
@@ -1334,6 +1342,9 @@ class ParameterServerExecutor(JobExecutor):
         only the missing workers are waited for. ``link`` feeds the
         measured-bandwidth table as each delta streams in; ``arrivals``
         (when given) records each peer's collect-start -> accepted lag.
+        ``spares`` are the delta files the last round left
+        (``_spool_deltas``; never a durable job's): each accepted push
+        takes one to be saved over, while there are any.
         """
         t_open = asyncio.get_running_loop().time()
         received: dict[str, tuple[Path, float]] = dict(preloaded or {})
@@ -1418,6 +1429,7 @@ class ParameterServerExecutor(JobExecutor):
                 ),
                 hasher=hasher, name_key=key, link=link,
                 trace_node=ptrace.node if ptrace is not None else None,
+                over=spares.pop() if spares else None,
             )
             if arrivals is not None:
                 lag = asyncio.get_running_loop().time() - t_open
@@ -2342,6 +2354,37 @@ class ParameterServerExecutor(JobExecutor):
             if wire is not update:
                 wire.unlink(missing_ok=True)
 
+    @staticmethod
+    def _spool_deltas(
+        received: dict[str, tuple[Path, float]],
+        work_dir: Path,
+        spares: "list[Path] | None",
+    ) -> None:
+        """A closed round's delta files, done with: kept as spares for the
+        next round's pushes to be saved over, or unlinked.
+
+        A parameter-sized write into a fresh file costs page faults at
+        about 1 GB/s on the chip's host, and one over pages that exist a
+        fraction of that, so where ``spares`` is a list (a plain blocking
+        job without a journal) the files move into ``work_dir/spare`` and
+        ``_collect_round`` hands them out one a push. The spool never
+        holds more files than this round received: what is over, and
+        everything where ``spares`` is None, is unlinked as before. It
+        goes with ``work_dir`` when the job ends.
+        """
+        spool = work_dir / "spare"
+        for path, _ in received.values():
+            if spares is not None and len(spares) < len(received):
+                try:
+                    spool.mkdir(exist_ok=True)
+                    spare = spool / path.name
+                    os.replace(path, spare)
+                    spares.append(spare)
+                    continue
+                except OSError:
+                    pass
+            path.unlink(missing_ok=True)
+
     async def _save_delta_bounded(
         self, push, dest_dir: Path, delta_round: int, *,
         suffix: str, hasher, key: str,
@@ -2417,6 +2460,7 @@ class ParameterServerExecutor(JobExecutor):
         hasher=None, name_key: "str | None" = None,
         link: "LinkTable | None" = None,
         trace_node: "str | None" = None,
+        over: "Path | None" = None,
     ) -> tuple[Path, float]:
         """Save one pseudo-gradient push; returns (path, sample weight).
 
@@ -2431,7 +2475,11 @@ class ParameterServerExecutor(JobExecutor):
         own direct delta. ``link`` (ft.adaptive) times the save — the
         push streams the payload, so the wall-clock of draining it to
         disk IS the link — and feeds the per-peer bandwidth EWMA the
-        codec ladder keys on.
+        codec ladder keys on. ``over`` is a spare the job's spool kept
+        (``_spool_deltas``): the push is saved over it and not into a
+        fresh file (``PushStream.save_to``). What the save did (``pages``:
+        ``recycled`` or ``fresh``; ``read_s`` receiving, ``write_s``
+        writing) goes on the span and on the ``ps upload:`` line.
         """
         name = hashlib.sha256((name_key or push.peer).encode()).hexdigest()[:24]
         dest = work_dir / f"delta-{round_num}-{name}{name_suffix}.safetensors"
@@ -2445,23 +2493,34 @@ class ParameterServerExecutor(JobExecutor):
             attrs={"round": round_num, "peer": push.peer},
             node=trace_node,
         )
-        t0 = time.monotonic() if link is not None else 0.0
-        nbytes = await push.save_to(dest, hasher=hasher)
+        t0 = time.monotonic()
+        if over is None:
+            # As every other collector calls it (and every stand-in for a
+            # push that a test hands them).
+            nbytes = await push.save_to(dest, hasher=hasher)
+        else:
+            nbytes = await push.save_to(dest, hasher=hasher, over=over)
+        wall_s = time.monotonic() - t0
+        try:
+            size = int(nbytes) if nbytes else dest.stat().st_size
+        except (TypeError, ValueError, OSError):
+            size = 0
+        pages = "recycled" if getattr(push, "recycled", False) else "fresh"
+        read_s = float(getattr(push, "read_s", 0.0))
+        write_s = float(getattr(push, "write_s", 0.0))
         if up_span is not None:
-            try:
-                up_span.set_attribute(
-                    "bytes", int(nbytes) if nbytes else dest.stat().st_size
-                )
-            except (TypeError, ValueError, OSError):
-                pass
+            up_span.attributes.update(
+                bytes=size, pages=pages,
+                read_s=round(read_s, 6), write_s=round(write_s, 6),
+            )
         trace.finish(up_span)
-        if link is not None:
-            try:
-                size = int(nbytes) if nbytes else dest.stat().st_size
-            except (TypeError, ValueError, OSError):
-                size = 0
-            if size > 0:
-                link.observe(push.peer, size, time.monotonic() - t0)
+        log.info(
+            "ps upload: round=%s peer=%s bytes=%d pages=%s wall_s=%.3f "
+            "read_s=%.3f write_s=%.3f",
+            round_num, push.peer, size, pages, wall_s, read_s, write_s,
+        )
+        if link is not None and size > 0:
+            link.observe(push.peer, size, wall_s)
         samples = 1.0
         if isinstance(push.resource, dict):
             try:
