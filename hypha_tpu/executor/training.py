@@ -197,12 +197,35 @@ class _RoundTrace:
         """Inject the round context into a push header (no-op when off)."""
         return trace.inject(meta, self.ctx(round_num))
 
+    def clock_mark(self, round_num: int) -> None:
+        """One moment on three clocks: a zero-length profiler annotation
+        ``hypha_clock wall_ns=… mono_ns=…`` (a flag check while no profiler
+        session is open) whose name carries the two clocks the spans are
+        written in, read immediately before it is entered, and an instant
+        span ``clock_mark`` with the same two numbers. A profiler trace that
+        holds one gives the offset between the device's time base and the
+        spans' clocks to the cost of one call."""
+        if not self.on:
+            return
+        import jax
+
+        wall_ns, mono_ns = time.time_ns(), time.monotonic_ns()
+        with jax.profiler.TraceAnnotation(
+            f"hypha_clock wall_ns={wall_ns} mono_ns={mono_ns}"
+        ):
+            pass
+        trace.instant(
+            "clock_mark", parent=self.ctx(round_num), node=self.node,
+            attrs={"round": round_num, "wall_ns": wall_ns, "mono_ns": mono_ns},
+        )
+
     def batch(self, round_num: int) -> None:
         """First batch of a round opens its ``inner_steps`` span."""
         if not self.on:
             return
         if self.inner is None or self.inner_round != round_num:
             self.close_inner()
+            self.clock_mark(round_num)
             self.inner = trace.begin(
                 "inner_steps",
                 parent=self.ctx(round_num),
@@ -1281,16 +1304,19 @@ def run_training(
     # off, and the same interval into a span when it is on. This process
     # holds the chip, so a phase also enters the profiler's trace under its
     # span's name (a flag check while no profiler session is open).
+    # ``usage``: the span carries the process's CPU seconds and page faults
+    # over the phase (telemetry.trace); the phases that move or touch a
+    # parameter-sized payload ask for it.
     sync: dict[str, float] = {}
 
     def sync_phase(
         name: str, *, parent=None, key: str | None = None, min_s: float = 0.0,
-        **attrs,
+        usage: bool = False, **attrs,
     ):
         return trace.phase(
             name, parent=parent, attrs=attrs, node=rtrace.node,
             into=sync if key else None, key=key, min_s=min_s,
-            annotation=jax.profiler.TraceAnnotation(name),
+            annotation=jax.profiler.TraceAnnotation(name), usage=usage,
         )
 
     def log_sync(done_round: int, bytes_up: int, read: compress.ReadStats) -> None:
@@ -1396,6 +1422,7 @@ def run_training(
         """Ship Δθ, wait for the PS broadcast, merge. True = next round."""
         nonlocal state, anchor, host_anchor, round_num, round_samples
         rtrace.close_inner()
+        rtrace.clock_mark(round_num)
         round_tp = rtrace.ctx(round_num)
         send_status_gated(
             Progress(
@@ -1406,12 +1433,12 @@ def run_training(
         sync.clear()
         host_params = None
         with sync_phase(
-            "encode", parent=round_tp, key="encode_s",
+            "encode", parent=round_tp, key="encode_s", usage=True,
             round=round_num, codec=wire_codec,
         ) as enc:
             # Ends when the bytes are on the host: device_get blocks on the
             # subtraction and the device-to-host copy.
-            with sync_phase("encode.extract", parent=enc.span) as ph:
+            with sync_phase("encode.extract", parent=enc.span, usage=True) as ph:
                 if mh is not None:
                     # Collective Δθ: the allgather every process joins
                     # (OP_GATHER), then host-side subtraction against the
@@ -1430,7 +1457,7 @@ def run_training(
                     host_delta = jax.device_get(delta)
                 ph.set("bytes", _tree_nbytes(host_delta))
             delta_path = work_dir / f"delta-{round_num}.safetensors"
-            with sync_phase("encode.write", parent=enc.span) as ph:
+            with sync_phase("encode.write", parent=enc.span, usage=True) as ph:
                 # One send-side entry point for every codec
                 # (hypha_tpu.compress): int8/int4 ship Q(Δθ + e) as an HQD1
                 # frame and keep e' = (Δθ + e) − Q(Δθ + e) for the next
@@ -1504,10 +1531,10 @@ def run_training(
             # Parent under the broadcast's context when the PS stamped
             # one (the same round trace), else the scheduler's round.
             parent=meta.get(TRACEPARENT_KEY) or round_tp, key="merge_s",
-            round=round_num,
+            usage=True, round=round_num,
         ) as mrg:
             read = compress.ReadStats()
-            with sync_phase("merge.read", parent=mrg.span) as ph:
+            with sync_phase("merge.read", parent=mrg.span, usage=True) as ph:
                 flat = read_update(update_file, read)
                 note_read(ph, read)
             # Ends when the merge and the new anchor are DISPATCHED: nothing
@@ -1515,7 +1542,7 @@ def run_training(
             # synchronisation. What the device still owes is paid in the
             # next round's first step (its fetch_s).
             with sync_phase(
-                "merge.apply", parent=mrg.span,
+                "merge.apply", parent=mrg.span, usage=True,
                 ends_at="dispatch", leaves=len(flat),
             ):
                 if mh is not None:
@@ -1622,10 +1649,10 @@ def run_training(
         )
         sync.clear()
         with sync_phase(
-            "encode", parent=round_tp, key="encode_s",
+            "encode", parent=round_tp, key="encode_s", usage=True,
             round=round_num, codec=wire_codec,
         ) as enc:
-            with sync_phase("encode.extract", parent=enc.span) as ph:
+            with sync_phase("encode.extract", parent=enc.span, usage=True) as ph:
                 delta = extract_delta(state.params, anchor)
                 host_delta = jax.device_get(delta)
                 ph.set("bytes", _tree_nbytes(host_delta))
@@ -1733,11 +1760,12 @@ def run_training(
         # re-flatten and rebuild the whole parameter tree per part) —
         # then re-anchor ONCE (blocking semantics: no drift correction).
         with sync_phase(
-            "merge", parent=round_tp, key="merge_s", round=round_num
+            "merge", parent=round_tp, key="merge_s", usage=True,
+            round=round_num,
         ) as mrg:
             combined: dict = {}
             read = compress.ReadStats()
-            with sync_phase("merge.read", parent=mrg.span) as ph:
+            with sync_phase("merge.read", parent=mrg.span, usage=True) as ph:
                 for p in sorted(got):
                     flat = read_update(got[p], read)
                     if set(flat) != set(parts[p]):
@@ -1750,7 +1778,7 @@ def run_training(
                 note_read(ph, read)
             # Ends at dispatch, as in do_update.
             with sync_phase(
-                "merge.apply", parent=mrg.span,
+                "merge.apply", parent=mrg.span, usage=True,
                 ends_at="dispatch", leaves=len(combined),
             ):
                 params_flat = flat_leaf_map(state.params)

@@ -35,6 +35,7 @@ from dataclasses import dataclass, field
 from typing import Any, AsyncIterator, Awaitable, Callable
 
 from .. import aio, messages
+from ..telemetry import trace
 from ..telemetry.ft_metrics import SCALE_METRICS
 from .fabric import MAX_FRAME, FrameError, Stream, Transport, copy_stream
 
@@ -467,11 +468,12 @@ class PushStream:
     stream: Stream
     _done: Callable[[], None] = field(default=lambda: None)
     # What the last save_to did, for the receiver's span and log: seconds
-    # receiving, seconds writing, and whether the file written was one
-    # that was there (``over``).
+    # receiving, seconds writing, whether the file written was one that
+    # was there (``over``), and whether the drain thread took the socket.
     read_s: float = 0.0
     write_s: float = 0.0
     recycled: bool = False
+    threaded: bool = False
 
     async def read_all(self, chunk: int = 1 << 20) -> bytes:
         parts = []
@@ -522,10 +524,11 @@ class PushStream:
         was, and renamed onto ``path``. A push that ends any other way (the
         sender gone, cancellation, a timeout) would leave the spare's tail
         behind its head: ``path`` never names such a file, and the spare
-        is unlinked before the error goes on. ``read_s``, ``write_s`` and
-        ``recycled`` say afterwards what this call did; on the buffered
-        path the two times are the loop's awaits and overlap the loop's own
-        receiving, in the drain thread they part socket from file."""
+        is unlinked before the error goes on. ``read_s``, ``write_s``,
+        ``recycled`` and ``threaded`` say afterwards what this call did; on
+        the buffered path the two times are the loop's awaits and overlap
+        the loop's own receiving, in the drain thread they part socket from
+        file."""
         import os as _os
 
         handoff = None
@@ -539,6 +542,7 @@ class PushStream:
         total = 0
         self.read_s = self.write_s = 0.0
         self.recycled = False
+        self.threaded = handoff is not None
         whole = False
         # The drain thread writes what one recv_into took, unbuffered.
         buffering = 0 if handoff is not None else -1
@@ -698,6 +702,9 @@ class _RelayStream(Stream):
         await self._inner.abort()
 
 
+LOOP_WATCH_TASK = "loop-watch"  # the task's name, on a traced node only
+
+
 class Node:
     """One fabric identity: listen addresses, peerstore, typed services."""
 
@@ -779,6 +786,9 @@ class Node:
         for addr in listen or ["", ]:
             bound = await self.transport.listen(addr, self._on_stream)
             self.listen_addrs.append(bound)
+        if trace.active() is not None:
+            # Traced roles say when this loop was held (``loop_stall``).
+            self._spawn(trace.watch_loop(self.peer_id), what=LOOP_WATCH_TASK)
         if self._bootstrap_addrs:
             self._spawn(self._bootstrap_loop())
             if self._relay_listen:
@@ -1802,17 +1812,30 @@ class Node:
 
     # --------------------------------------------------------- tensor streams
 
-    async def push(self, peer_id: str, resource: Any, source) -> int:
+    async def push(
+        self, peer_id: str, resource: Any, source, timing: dict | None = None
+    ) -> int:
         """Open a push stream: header frame, then raw bytes from ``source``
         (bytes | file path | async byte iterator). Returns bytes sent.
+
+        ``timing``, where a caller hands one (a traced broadcast), is left
+        with what the attempt's seconds went to, as far as it got:
+        ``connect_s`` (dial and open the stream, to the header frame
+        written), ``send_s`` (the payload) and ``close_s``.
 
         An iterator may give views of memory its owner writes again once
         this call has ended (the PS's update, pushed from the buffers it
         was computed in): they are never joined or copied here, and when
         this returns or raises the stream holds none of them."""
+        if timing is None:
+            timing = {}  # read by nobody
+        clock = time.perf_counter
+        t0 = clock()
         stream = await self._stream_to(peer_id, PROTOCOL_PUSH)
         try:
             await stream.write_frame(messages.encode(resource))
+            t1 = clock()
+            timing["connect_s"] = t1 - t0
             if isinstance(
                 source, (bytes, bytearray, memoryview, str)
             ) or hasattr(source, "__fspath__"):
@@ -1827,9 +1850,12 @@ class Node:
                 # rates across peers).
                 stream.borrow_writes()
                 n = await self._write_source(_CountingStream(stream, self), source)
+            timing["send_s"] = clock() - t1
             return n
         finally:
+            t2 = clock()
             await stream.close()
+            timing["close_s"] = clock() - t2
 
     async def _write_source(self, stream: Stream, source) -> int:
         """Stream bytes | file path | async iterator | Stream into ``stream``."""

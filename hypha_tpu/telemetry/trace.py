@@ -35,12 +35,30 @@ branch on config themselves.
 :class:`phase` is the one timer of a phase inside a larger span: the same
 pair of clock readings feeds the role's log line (tracing on or off) and,
 when tracing is on, the child span.
+
+Three records say what a span's seconds cannot (all of them only while
+tracing is on; off, none of their sites reads a clock or a counter):
+
+  * a span opened with ``usage=True`` carries what its interval cost the
+    *process*, all threads: ``cpu_user_s``, ``cpu_sys_s`` and ``minflt`` as
+    differences of ``getrusage(RUSAGE_SELF)`` between its opening and the
+    moment its end is marked, and ``maxrss_kb`` as read at the end. Where a
+    role's phases follow one another the process's difference is the
+    phase's; two such spans of one process that overlap each carry the sum;
+  * :func:`watch_loop` is the task a node runs on its event loop: it writes
+    the instant record ``loop_stall`` where the loop woke it late;
+  * :func:`instant` writes a record whose start is its end, stamped with
+    the ``round`` of the last span written for its node, so that a reader
+    of the measured rounds finds it and no reader of intervals can hand it
+    idle time.
 """
 
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import json
+import resource
 import threading
 import time
 from dataclasses import dataclass, field
@@ -66,7 +84,10 @@ __all__ = [
     "traceparent_of",
     "reparent",
     "phase",
+    "instant",
+    "watch_loop",
     "SLOW_CLEANUP_S",
+    "LOOP_WATCH_S",
 ]
 
 # One id generator for the whole telemetry package: os.urandom, NOT the
@@ -110,6 +131,9 @@ class TraceSpan:
     end_ns: int | None = None
     end_mono_ns: int | None = None
     status_ok: bool = True
+    # getrusage at the opening of a ``usage=True`` span, until its end is
+    # marked (never serialized).
+    usage0: Any = None
 
     @property
     def traceparent(self) -> str:
@@ -158,6 +182,8 @@ class NodeTracing:
         self.node = str(node)
         self._lock = threading.Lock()
         self._files: dict[str, IO[str]] = {}
+        # node -> the ``round`` of the last span written for it
+        self._last_round: dict[str, Any] = {}
         self._closed = False
 
     # ------------------------------------------------------------- spans
@@ -167,9 +193,11 @@ class NodeTracing:
         parent: "TraceSpan | str | None" = None,
         attrs: dict | None = None,
         node: str | None = None,
+        usage: bool = False,
     ) -> TraceSpan:
         """Open a span. ``parent`` is a local span, a wire traceparent
-        string, or None (starts a fresh trace)."""
+        string, or None (starts a fresh trace). ``usage``: the span carries
+        what its interval cost the process (module docstring)."""
         if isinstance(parent, TraceSpan):
             trace_id, parent_id = parent.trace_id, parent.span_id
         else:
@@ -187,6 +215,7 @@ class NodeTracing:
             start_ns=time.time_ns(),
             start_mono_ns=time.monotonic_ns(),
             attributes=dict(attrs or {}),
+            usage0=resource.getrusage(resource.RUSAGE_SELF) if usage else None,
         )
 
     def finish(self, span: TraceSpan, ok: bool = True) -> TraceSpan:
@@ -203,8 +232,9 @@ class NodeTracing:
         parent: "TraceSpan | str | None" = None,
         attrs: dict | None = None,
         node: str | None = None,
+        usage: bool = False,
     ):
-        s = self.begin(name, parent=parent, attrs=attrs, node=node)
+        s = self.begin(name, parent=parent, attrs=attrs, node=node, usage=usage)
         try:
             yield s
         except BaseException:
@@ -212,6 +242,26 @@ class NodeTracing:
             raise
         finally:
             self.finish(s)
+
+    def instant(
+        self,
+        name: str,
+        parent: "TraceSpan | str | None" = None,
+        attrs: dict | None = None,
+        node: str | None = None,
+    ) -> TraceSpan:
+        """Write a record of one moment: its end is its start. One that
+        names no ``round`` gets the round of the last span written for its
+        node, where there was one."""
+        s = self.begin(name, parent=parent, attrs=attrs, node=node)
+        s.end_ns, s.end_mono_ns = s.start_ns, s.start_mono_ns
+        if "round" not in s.attributes:
+            with self._lock:
+                rnd = self._last_round.get(s.node)
+            if rnd is not None:
+                s.attributes["round"] = rnd
+        self._write(s)
+        return s
 
     # --------------------------------------------------------------- io
     def _write(self, span: TraceSpan) -> None:
@@ -225,6 +275,9 @@ class NodeTracing:
                 path = self.trace_dir / f"spans-{safe}.jsonl"
                 f = open(path, "a", encoding="utf-8")
                 self._files[span.node] = f
+            rnd = span.attributes.get("round")
+            if rnd is not None:
+                self._last_round[span.node] = rnd
             f.write(line)
             f.flush()
 
@@ -285,12 +338,13 @@ def begin(
     parent: "TraceSpan | str | None" = None,
     attrs: dict | None = None,
     node: str | None = None,
+    usage: bool = False,
 ) -> TraceSpan | None:
     """Open a span iff tracing is on; None otherwise (pass to finish)."""
     t = active()
     if t is None:
         return None
-    return t.begin(name, parent=parent, attrs=attrs, node=node)
+    return t.begin(name, parent=parent, attrs=attrs, node=node, usage=usage)
 
 
 def finish(span: "TraceSpan | None", ok: bool = True) -> None:
@@ -307,14 +361,51 @@ def span(
     parent: "TraceSpan | str | None" = None,
     attrs: dict | None = None,
     node: str | None = None,
+    usage: bool = False,
 ):
     """Context-managed span; yields None (and records nothing) when off."""
     t = active()
     if t is None:
         yield None
         return
-    with t.span(name, parent=parent, attrs=attrs, node=node) as s:
+    with t.span(name, parent=parent, attrs=attrs, node=node, usage=usage) as s:
         yield s
+
+
+def instant(
+    name: str,
+    parent: "TraceSpan | str | None" = None,
+    attrs: dict | None = None,
+    node: str | None = None,
+) -> None:
+    """A record of one moment (:meth:`NodeTracing.instant`); nothing when off."""
+    t = active()
+    if t is not None:
+        t.instant(name, parent=parent, attrs=attrs, node=node)
+
+
+# A node's watch task sleeps this long, and a wake-up this late or later is
+# a ``loop_stall``: a twentieth of a second is what a lease's renewal or a
+# push's next chunk can wait for without anyone noticing.
+LOOP_WATCH_S = 0.050
+
+
+async def watch_loop(node: str) -> None:
+    """Say when this event loop was held: sleep :data:`LOOP_WATCH_S`, and
+    where the loop hands control back that long or more after it was due,
+    write the instant record ``loop_stall`` with ``lag_s`` and
+    ``due_mono_ns`` (the loop stood still from then to the record's own
+    time). A node starts it only while tracing is on; it ends by
+    cancellation."""
+    while True:
+        due = time.monotonic_ns() + int(LOOP_WATCH_S * 1e9)
+        await asyncio.sleep(LOOP_WATCH_S)
+        lag_s = (time.monotonic_ns() - due) / 1e9
+        if lag_s >= LOOP_WATCH_S:
+            instant(
+                "loop_stall", attrs={"lag_s": lag_s, "due_mono_ns": due},
+                node=node,
+            )
 
 
 def inject(header: dict, context: "TraceSpan | str | None") -> dict:
@@ -360,6 +451,15 @@ def _mark_end(span: "TraceSpan | None") -> None:
     if span is not None:
         span.end_ns = time.time_ns()
         span.end_mono_ns = time.monotonic_ns()
+        if span.usage0 is not None:
+            u0, u1 = span.usage0, resource.getrusage(resource.RUSAGE_SELF)
+            span.usage0 = None
+            span.attributes.update(
+                cpu_user_s=round(u1.ru_utime - u0.ru_utime, 6),
+                cpu_sys_s=round(u1.ru_stime - u0.ru_stime, 6),
+                minflt=u1.ru_minflt - u0.ru_minflt,
+                maxrss_kb=u1.ru_maxrss,
+            )
 
 
 class phase:
@@ -378,12 +478,12 @@ class phase:
     which puts the phase into an open profiler session's trace on the
     device's time base and is a flag check when none is open. ``defer``
     leaves the ended span unwritten until :meth:`write`, for attributes
-    that are known only later.
+    that are known only later. ``usage`` is :func:`begin`'s.
     """
 
     __slots__ = (
         "name", "parent", "attrs", "node", "into", "key", "min_s",
-        "annotation", "defer", "span", "seconds", "_t0",
+        "annotation", "defer", "usage", "span", "seconds", "_t0",
     )
 
     def __init__(
@@ -398,6 +498,7 @@ class phase:
         min_s: float = 0.0,
         annotation: Any = None,
         defer: bool = False,
+        usage: bool = False,
     ) -> None:
         if isinstance(parent, TraceSpan):
             node = node or parent.node
@@ -405,7 +506,7 @@ class phase:
                 attrs = {"round": parent.attributes["round"], **(attrs or {})}
         self.name, self.parent, self.attrs, self.node = name, parent, attrs, node
         self.into, self.key, self.min_s = into, key or name, min_s
-        self.annotation, self.defer = annotation, defer
+        self.annotation, self.defer, self.usage = annotation, defer, usage
         self.span: TraceSpan | None = None
         self.seconds = 0.0
 
@@ -413,7 +514,8 @@ class phase:
         if self.annotation is not None:
             self.annotation.__enter__()
         self.span = begin(
-            self.name, parent=self.parent, attrs=self.attrs, node=self.node
+            self.name, parent=self.parent, attrs=self.attrs, node=self.node,
+            usage=self.usage,
         )
         self._t0 = (
             self.span.start_mono_ns if self.span is not None

@@ -22,6 +22,7 @@ import hashlib
 import logging
 import os
 import shutil
+import time
 import urllib.parse
 import urllib.request
 from pathlib import Path
@@ -41,6 +42,7 @@ from ..messages import (
     TransferStrategy,
 )
 from ..network.node import Node, PushStream, RequestError
+from ..telemetry import trace
 from ..telemetry.ft_metrics import DATA_METRICS
 
 __all__ = ["Connector", "ReceivedFile", "fetch_uri", "shard_route"]
@@ -313,6 +315,7 @@ class Connector:
         strategy = ref.strategy or TransferStrategy.ALL
         header = {**(meta or {}), "resource": resource, "name": path.name}
         deadline = _push_deadline()
+        attempts: dict[str, int] = {}  # peer -> pushes tried (traced sends)
         # Per-attempt bound: a push black-holed by a silent partition (no
         # RST, TCP retransmitting forever) must be cancelled and retried —
         # the deadline alone cannot interrupt an attempt in flight.
@@ -323,7 +326,7 @@ class Connector:
                 last: Exception | None = None
                 for peer in peers:
                     try:
-                        await self.node.push(peer, header, path)
+                        await self._push_once(peer, header, path, attempts)
                         return
                     except (RequestError, OSError) as e:
                         # OSError too: a peer that accepts the dial but
@@ -355,7 +358,7 @@ class Connector:
         for peer in peers:
             try:
                 await aio.retry(
-                    lambda p=peer: self.node.push(p, header, path),
+                    lambda p=peer: self._push_once(p, header, path, attempts),
                     base_delay=0.25, max_delay=5.0,
                     attempt_timeout=attempt_timeout,
                     deadline=max(
@@ -368,6 +371,28 @@ class Connector:
                 failures.append((peer, e))
         if failures:
             raise RequestError(f"send failures: {failures}")
+
+    async def _push_once(
+        self, peer: str, header: dict, path: Path, attempts: dict[str, int]
+    ) -> None:
+        """One attempt to push ``path`` to ``peer``. Traced, it is a ``send``
+        span of this node: the sender's end of the journey whose other end
+        is the receiver's ``upload`` (the PS) or ``receive`` (a worker),
+        under the trace the header names, whether the attempt ran to its
+        end, failed or was cancelled at its time limit."""
+        t = trace.active()
+        if t is None:
+            await self.node.push(peer, header, path)
+            return
+        attempts[peer] = attempts.get(peer, 0) + 1
+        attrs = {"peer": peer, "bytes": payload_size(path), "attempt": attempts[peer]}
+        if header.get("round") is not None:
+            attrs["round"] = header["round"]
+        with t.span(
+            "send", parent=header.get(trace.TRACEPARENT_KEY), attrs=attrs,
+            node=self.node.peer_id, usage=True,
+        ):
+            await self.node.push(peer, header, path)
 
     # ------------------------------------------------------------- receive
 
@@ -401,16 +426,61 @@ class Connector:
                         continue
                     resource, name = _push_names(push)
                     dest = dest_dir / f"{_safe_name(push.peer + '-' + name)}.bin"
-                    size = await push.save_to(dest)
+                    meta = push.resource if isinstance(push.resource, dict) else {}
+                    size = await self._save(push, dest, resource, meta)
                 except asyncio.CancelledError:
                     # Consumer went away mid-transfer: release the accept slot
                     # so the sender's connection isn't pinned forever.
                     push.finish()
                     raise
-                meta = push.resource if isinstance(push.resource, dict) else {}
                 yield ReceivedFile(dest, size, push.peer, resource, meta)
         finally:
             consumer.close()
+
+    async def _save(
+        self, push: PushStream, dest: Path, resource: str, meta: dict
+    ) -> int:
+        """Save an accepted push. Traced, it is a ``receive`` span of this
+        node, header arrival to payload on disk, under the trace the header
+        names: the receiver's end of the journey whose other end is the
+        sender's span (the PS's ``broadcast``), with what ``save_to`` says
+        of itself under the names the PS's ``upload`` span gives them. A
+        push that ends any other way (cancellation, a sender that went
+        away) leaves the span with ``ok`` false. Tracing on or off, the
+        ``push received:`` line says the same of a push that landed."""
+        t = trace.active()
+        span = None
+        if t is not None:
+            attrs = {"peer": push.peer, "resource": resource}
+            if meta.get("round") is not None:
+                attrs["round"] = meta["round"]
+            span = t.begin(
+                "receive", parent=meta.get(trace.TRACEPARENT_KEY), attrs=attrs,
+                node=self.node.peer_id, usage=True,
+            )
+        t0 = time.monotonic()
+        try:
+            size = await push.save_to(dest)
+        except BaseException:
+            trace.finish(span, ok=False)
+            raise
+        wall_s = time.monotonic() - t0
+        pages = "recycled" if push.recycled else "fresh"
+        path = "thread" if push.threaded else "loop"
+        if span is not None:
+            span.attributes.update(
+                bytes=size, pages=pages, path=path,
+                read_s=round(push.read_s, 6), write_s=round(push.write_s, 6),
+            )
+        trace.finish(span)
+        # The receiving end's line, as the PS's ``ps upload:``, tracing on or off.
+        log.info(
+            "push received: round=%s peer=%s bytes=%d pages=%s path=%s "
+            "wall_s=%.3f read_s=%.3f write_s=%.3f",
+            meta.get("round"), push.peer, size, pages, path, wall_s,
+            push.read_s, push.write_s,
+        )
+        return size
 
 
 def _push_names(push: PushStream) -> tuple[str, str]:

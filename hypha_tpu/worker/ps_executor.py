@@ -1296,7 +1296,10 @@ class ParameterServerExecutor(JobExecutor):
         if accum is None:
             return
         fold_span = (
-            trace.begin("fold", parent=parent, attrs=span_attrs, node=trace_node)
+            trace.begin(
+                "fold", parent=parent, attrs=span_attrs, node=trace_node,
+                usage=True,
+            )
             if span_attrs is not None and sign > 0
             else None
         )
@@ -2491,7 +2494,7 @@ class ParameterServerExecutor(JobExecutor):
             "upload",
             parent=_PsTrace.push_ctx(push),
             attrs={"round": round_num, "peer": push.peer},
-            node=trace_node,
+            node=trace_node, usage=True,
         )
         t0 = time.monotonic()
         if over is None:
@@ -2634,10 +2637,12 @@ class ParameterServerExecutor(JobExecutor):
             0.0,
         )
 
-        def phase(name: str, key: str, attrs: dict | None = None) -> trace.phase:
+        def phase(
+            name: str, key: str, attrs: dict | None = None, usage: bool = False
+        ) -> trace.phase:
             return trace.phase(
                 f"outer_step.{name}", parent=parent, attrs=attrs, into=times,
-                key=key,
+                key=key, usage=usage,
             )
 
         if accum is None or accum.folds == 0:
@@ -2674,6 +2679,7 @@ class ParameterServerExecutor(JobExecutor):
             "nesterov", "nesterov_s",
             {"native": native.native_available(), "fused_mean": True,
              "in_place": True, "bytes": nbytes, "leaves": len(update)},
+            usage=True,
         ) as ph:
             for key, acc in update.items():
                 acc = update[key] = np.require(acc, np.float32, ["C", "W"])
@@ -2957,7 +2963,7 @@ class ParameterServerExecutor(JobExecutor):
                         "tree": True, "source": "file",
                         "bytes": payload_size(wire),
                     },
-                    node=self._trace_node(),
+                    node=self._trace_node(), usage=True,
                 )
                 if span_round is not None
                 else None
@@ -3003,12 +3009,26 @@ class ParameterServerExecutor(JobExecutor):
                     "source": "memory" if in_memory else "file",
                     "bytes": size,
                 },
-                node=self._trace_node(),
+                node=self._trace_node(), usage=True,
             )
             if span_round is not None
             else None
         )
         sem = asyncio.Semaphore(_BROADCAST_CONCURRENCY)
+        # Traced: what each peer's push did, on the span that is there:
+        # ``attempts``, and of the last one ``connect_s`` (dial and stream,
+        # to the header frame written), ``send_s`` and ``close_s``.
+        pushes: dict[str, dict] = {}
+        if bcast_span is not None:
+            bcast_span.attributes["pushes"] = pushes
+
+        def push(peer: str):
+            if bcast_span is None:
+                return self.node.push(peer, header, source())
+            did = pushes[peer] = {
+                "attempts": pushes.get(peer, {}).get("attempts", 0) + 1
+            }
+            return self.node.push(peer, header, source(), timing=did)
 
         async def push_one(peer: str) -> bool:
             async with sem:
@@ -3017,7 +3037,7 @@ class ParameterServerExecutor(JobExecutor):
                     # blip; a genuinely dead peer is still tolerated — it
                     # catches up from the next round's broadcast.
                     await aio.retry(
-                        lambda: self.node.push(peer, header, source()),
+                        lambda: push(peer),
                         attempts=2, base_delay=0.25,
                         attempt_timeout=push_timeout(size),
                         retry_on=(RequestError, OSError),

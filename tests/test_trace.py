@@ -428,3 +428,345 @@ def test_scheduler_responses_untouched_when_off(tracing_off):
     assert resp is not None and resp.traceparent is None
     r = bs.on_progress("ps", Progress(kind=ProgressKind.UPDATED, round=0))
     assert r.traceparent is None
+
+
+# ---------------------------------------------------------------------------
+# PR 42: a span at each end of a push, what a phase cost the process, a loop
+# that says when it was held, one clock with the profiler's trace
+# ---------------------------------------------------------------------------
+
+
+def _read_spans(trace_dir) -> list[dict]:
+    out = []
+    for path in sorted(trace_dir.glob("spans-*.jsonl")):
+        out += [json.loads(x) for x in path.read_text().splitlines()]
+    return out
+
+
+async def _tcp_pair():
+    from hypha_tpu.network import Node, TcpTransport
+
+    a, b = Node(TcpTransport(), peer_id="a"), Node(TcpTransport(), peer_id="b")
+    await a.start(["127.0.0.1:0"])
+    await b.start(["127.0.0.1:0"])
+    a.add_peer_addr("b", b.listen_addrs[0])
+    return a, b
+
+
+def _push_through_the_connectors(tmp_path, meta: dict, nbytes: int = 9_000_001, spy=None):
+    """A file from node ``a`` to node ``b`` by ``Connector.send`` and
+    ``Connector.receive``, as a worker's bridge and a PS's broadcast's
+    receiver use them. Returns what ``b`` received."""
+    import asyncio
+
+    from hypha_tpu.messages import Receive, Reference, Send
+    from hypha_tpu.worker.connectors import Connector
+
+    payload = random.Random(7).randbytes(nbytes)
+    src = tmp_path / "delta-3.safetensors"
+    src.write_bytes(payload)
+
+    async def main():
+        a, b = await _tcp_pair()
+        if spy is not None:
+            spy(a)
+        try:
+            incoming = Connector(b).receive(
+                Receive(Reference.from_peers(["a"], "updates")), tmp_path / "incoming"
+            )
+            landed = asyncio.ensure_future(anext(incoming))
+            await Connector(a).send(
+                Send(Reference.from_peers(["b"], "updates")), src, "updates", meta
+            )
+            got = await asyncio.wait_for(landed, 30)
+            await incoming.aclose()
+            assert got.path.read_bytes() == payload and got.size == nbytes
+            return got, {n.peer_id: {t.get_name() for t in n._tasks} for n in (a, b)}
+        finally:
+            await a.stop()
+            await b.stop()
+
+    return asyncio.run(main())
+
+
+@pytest.mark.parametrize("path", ["loop", "thread"])
+def test_a_push_leaves_a_span_at_each_end_of_one_trace(tmp_path, tracing_on, monkeypatch, path):
+    if path == "thread":
+        monkeypatch.setenv("HYPHA_RAW_DRAIN", "1")
+    else:
+        monkeypatch.delenv("HYPHA_RAW_DRAIN", raising=False)
+    root = tracing_on.begin("round", attrs={"round": 3}, node="sched")
+    meta = trace.inject({"num_samples": 8.0, "round": 3}, root)
+    got, _ = _push_through_the_connectors(tmp_path, meta)
+    assert got.meta["round"] == 3
+    spans = _read_spans(tmp_path)
+    (send,) = [s for s in spans if s["name"] == "send"]
+    (receive,) = [s for s in spans if s["name"] == "receive"]
+    assert (send["node"], receive["node"]) == ("a", "b")
+    for s in (send, receive):
+        assert s["trace_id"] == root.trace_id and s["parent_id"] == root.span_id
+        assert s["ok"] is True and s["attrs"]["round"] == 3 and s["attrs"]["bytes"] == 9_000_001
+        assert {"cpu_user_s", "cpu_sys_s", "minflt", "maxrss_kb"} <= set(s["attrs"])
+    assert (send["attrs"]["peer"], send["attrs"]["attempt"]) == ("b", 1)
+    a = receive["attrs"]
+    assert (a["peer"], a["resource"], a["pages"], a["path"]) == ("a", "updates", "fresh", path)
+    took = (receive["mono_end_ns"] - receive["mono_start_ns"]) / 1e9
+    assert a["read_s"] >= 0 and a["write_s"] > 0
+    if path == "thread":  # there the two part socket from file, and nothing overlaps them
+        assert a["read_s"] + a["write_s"] <= took
+    # the header arrives after the sender began, the payload is on disk after it was sent
+    assert send["mono_start_ns"] <= receive["mono_start_ns"]
+
+
+def test_a_send_that_fails_and_a_receive_that_is_cut_are_spans_that_say_so(tmp_path, tracing_on, monkeypatch):
+    import asyncio
+
+    from hypha_tpu.messages import Receive, Reference, Send
+    from hypha_tpu.network.node import PushStream
+    from hypha_tpu.worker.connectors import Connector
+
+    monkeypatch.setenv("HYPHA_PUSH_RETRY_DEADLINE", "0.3")
+    src = tmp_path / "delta-0.safetensors"
+    src.write_bytes(b"x" * 1000)
+
+    async def gone(self, path, **_):
+        raise ConnectionError("the sender went away")
+
+    async def main():
+        a, b = await _tcp_pair()
+        try:
+            with pytest.raises(Exception):  # nobody listens at the address of "c"
+                a.add_peer_addr("c", "127.0.0.1:9")
+                await Connector(a).send(
+                    Send(Reference.from_peers(["c"], "updates")), src, "updates", {"round": 0}
+                )
+            monkeypatch.setattr(PushStream, "save_to", gone)
+            incoming = Connector(b).receive(
+                Receive(Reference.from_peers(["a"], "updates")), tmp_path / "incoming"
+            )
+            landed = asyncio.ensure_future(anext(incoming))
+            pushing = asyncio.ensure_future(a.push("b", {"resource": "updates", "round": 1}, b"y" * 10))
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(landed, 30)
+            await asyncio.gather(pushing, return_exceptions=True)
+        finally:
+            await a.stop()
+            await b.stop()
+
+    asyncio.run(main())
+    spans = _read_spans(tmp_path)
+    sends = [s for s in spans if s["name"] == "send"]
+    assert sends and all(s["ok"] is False and s["attrs"]["peer"] == "c" for s in sends)
+    assert [s["attrs"]["attempt"] for s in sends] == list(range(1, len(sends) + 1))
+    (receive,) = [s for s in spans if s["name"] == "receive"]
+    assert receive["ok"] is False and receive["attrs"]["round"] == 1 and "bytes" not in receive["attrs"]
+
+
+def test_the_broadcasts_timing_is_left_on_what_the_caller_hands_push(tmp_path):
+    import asyncio
+
+    async def main():
+        a, b = await _tcp_pair()
+        try:
+            timing: dict = {}
+            taken = asyncio.ensure_future(b.next_push())
+            n = await a.push("b", {"resource": "results"}, b"z" * 100_000, timing=timing)
+            push = await asyncio.wait_for(taken, 30)
+            assert len(await push.read_all()) == n == 100_000
+            return timing
+        finally:
+            await a.stop()
+            await b.stop()
+
+    timing = asyncio.run(main())
+    assert set(timing) == {"connect_s", "send_s", "close_s"} and all(v >= 0 for v in timing.values())
+
+
+def _burn(seconds: float) -> None:
+    import time as _time
+
+    end = _time.process_time() + seconds
+    while _time.process_time() < end:
+        sum(range(1000))
+
+
+@pytest.mark.parametrize("how", ["begin", "span", "phase"])
+def test_a_usage_span_carries_what_its_interval_cost_the_process(tmp_path, tracing_on, how):
+    import numpy as np
+
+    def busy_and_first_touch():
+        _burn(0.05)
+        fresh = np.empty(64 << 20, np.uint8)  # untouched anonymous memory
+        fresh[:: 4096] = 1  # a fault a page: 16 384 of 4 KiB, 32 where the kernel hands out 2 MiB
+        return fresh
+
+    if how == "begin":
+        s = trace.begin("encode", attrs={"round": 0}, usage=True)
+        keep = busy_and_first_touch()
+        trace.finish(s)
+    elif how == "span":
+        with trace.span("encode", attrs={"round": 0}, usage=True):
+            keep = busy_and_first_touch()
+    else:
+        with trace.phase("encode", attrs={"round": 0}, usage=True):
+            keep = busy_and_first_touch()
+    with trace.span("encode.plain", attrs={"round": 0}):
+        _burn(0.01)
+    del keep
+    with_usage, plain = _read_spans(tmp_path)
+    a = with_usage["attrs"]
+    assert a["cpu_user_s"] + a["cpu_sys_s"] >= 0.04
+    assert a["minflt"] >= 16 and a["maxrss_kb"] > 64 * 1024
+    took = (with_usage["mono_end_ns"] - with_usage["mono_start_ns"]) / 1e9
+    assert a["cpu_user_s"] + a["cpu_sys_s"] <= took * (1 + 8)  # the process's, every thread's
+    assert not {"cpu_user_s", "cpu_sys_s", "minflt", "maxrss_kb"} & set(plain["attrs"])
+
+
+def test_a_deferred_usage_phase_reads_the_counters_at_its_end_not_at_its_writing(tmp_path, tracing_on):
+    with trace.phase("step", usage=True, defer=True) as ph:
+        pass
+    _burn(0.05)  # after the interval: not the span's
+    ph.write()
+    (s,) = _read_spans(tmp_path)
+    assert s["attrs"]["cpu_user_s"] + s["attrs"]["cpu_sys_s"] < 0.04
+
+
+def _hold_the_loop(node_name: str, switch) -> list[dict]:
+    """A node's loop held 200 ms by a blocking call, after a span of round 5
+    was written for the node."""
+    import asyncio
+    import time as _time
+
+    from hypha_tpu.network import MemoryTransport, Node
+    from hypha_tpu.network.node import LOOP_WATCH_TASK
+
+    async def main():
+        node = Node(MemoryTransport().shared(), peer_id=node_name)
+        switch(node)
+        await node.start()
+        try:
+            assert [t.get_name() for t in node._tasks].count(LOOP_WATCH_TASK) == 1
+            await asyncio.sleep(0.12)  # the watch is asleep, on time so far
+            with trace.span("merge", attrs={"round": 5}, node=node_name):
+                pass
+            _time.sleep(0.2)  # the loop's thread stands still
+            await asyncio.sleep(0.12)
+        finally:
+            await node.stop()
+        assert not node._tasks
+
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("way", ["enable_before_main", "config_key"])
+def test_a_held_loop_writes_one_loop_stall_with_the_nodes_last_round(tmp_path, tracing_off, way):
+    from hypha_tpu import cli
+
+    telemetry = []
+
+    def switch(node):
+        if way == "enable_before_main":  # perfbench/traced_entry.py
+            trace.enable(tmp_path, node="w0")
+        else:  # telemetry.trace_dir through the call every role's runner makes
+            conf = _role_config("worker", f"telemetry.trace_dir={tmp_path}")
+            telemetry.append(cli._telemetry_for(conf, node))
+
+    try:
+        _hold_the_loop("w0", switch)
+    finally:
+        for t in telemetry:
+            t.shutdown()
+    stalls = [s for s in _read_spans(tmp_path) if s["name"] == "loop_stall"]
+    assert len(stalls) == 1, stalls
+    (s,) = stalls
+    assert s["node"] == "w0" and s["attrs"]["round"] == 5
+    assert 0.15 <= s["attrs"]["lag_s"] < 1.0
+    assert s["mono_start_ns"] == s["mono_end_ns"] and s["start_ns"] == s["end_ns"]
+    # the loop stood still from when the watch was due to the record's own time
+    assert s["mono_end_ns"] - s["attrs"]["due_mono_ns"] == pytest.approx(s["attrs"]["lag_s"] * 1e9, abs=5e6)
+
+
+def test_an_instant_record_keeps_a_round_it_names_and_has_none_before_any_span(tmp_path, tracing_on):
+    trace.instant("loop_stall", attrs={"lag_s": 0.1}, node="w9")
+    with trace.span("merge", attrs={"round": 2}, node="w9"):
+        pass
+    trace.instant("clock_mark", attrs={"round": 7}, node="w9")
+    trace.instant("loop_stall", attrs={"lag_s": 0.1}, node="w9")
+    first, _, named, last = _read_spans(tmp_path)
+    assert "round" not in first["attrs"] and named["attrs"]["round"] == 7
+    assert last["attrs"]["round"] == 7  # the last record written for the node
+
+
+def test_hypha_clock_is_in_a_profiler_sessions_trace_with_both_clocks(tmp_path, tracing_on):
+    import time as _time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from hypha_tpu.executor.training import _RoundTrace
+
+    rtrace = _RoundTrace("w0")
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    try:
+        before = _time.time_ns(), _time.monotonic_ns()
+        rtrace.clock_mark(3)
+        after = _time.time_ns(), _time.monotonic_ns()
+    finally:
+        jax.profiler.stop_trace()
+    (mark,) = [s for s in _read_spans(tmp_path) if s["name"] == "clock_mark"]
+    assert mark["node"] == "w0" and mark["attrs"]["round"] == 3
+    assert mark["mono_start_ns"] == mark["mono_end_ns"]
+    wall_ns, mono_ns = mark["attrs"]["wall_ns"], mark["attrs"]["mono_ns"]
+    assert before[0] <= wall_ns <= after[0] and before[1] <= mono_ns <= after[1]
+    (xplane,) = (tmp_path / "profile").glob("plugins/profile/*/*.xplane.pb")
+    names = [
+        ev.name
+        for plane in ProfileData.from_file(str(xplane)).planes
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("hypha_clock")
+    ]
+    assert names == [f"hypha_clock wall_ns={wall_ns} mono_ns={mono_ns}"]
+
+
+def test_off_nothing_is_read_started_or_sent(tmp_path, tracing_off, monkeypatch):
+    """Tracing off: no ``getrusage``, no watch task, no clock mark, and the
+    push header's bytes are the ones a program from before these spans sent."""
+    import resource
+
+    from hypha_tpu.executor.training import _RoundTrace
+    from hypha_tpu.network.node import LOOP_WATCH_TASK
+
+    def refused(*_):
+        raise AssertionError("getrusage read with tracing off")
+
+    monkeypatch.setattr(resource, "getrusage", refused)
+    headers = []
+
+    def spy(node):
+        push = node.push
+
+        async def push_spy(peer, header, source, **kw):
+            assert not kw  # no timing is asked of an untraced push
+            headers.append(messages.encode(header))
+            return await push(peer, header, source)
+
+        node.push = push_spy
+
+    got, tasks = _push_through_the_connectors(
+        tmp_path, {"num_samples": 8.0, "round": 2}, nbytes=100_000, spy=spy
+    )
+    assert headers == [codec.dumps(
+        {"num_samples": 8.0, "round": 2, "resource": "updates", "name": "delta-3.safetensors"}
+    )]
+    assert got.meta == {"num_samples": 8.0, "round": 2, "resource": "updates",
+                        "name": "delta-3.safetensors"}
+    assert all(LOOP_WATCH_TASK not in names for names in tasks.values())
+    for opened in (
+        trace.begin("encode", usage=True), trace.phase("encode", usage=True).__enter__().span
+    ):
+        assert opened is None
+    with trace.span("merge", usage=True) as s, trace.phase("merge.read", usage=True) as ph:
+        assert s is None and ph.span is None
+    _RoundTrace("w0").clock_mark(0)
+    trace.instant("loop_stall", attrs={"lag_s": 1.0}, node="w0")
+    assert not list(tmp_path.glob("spans-*.jsonl"))

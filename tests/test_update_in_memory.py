@@ -230,9 +230,9 @@ def _whole_job(tmp_path, monkeypatch, **cfg_kwargs):
         nodes["sched"].on(PROTOCOL_PROGRESS, Progress).respond_with(on_progress)
         push = nodes["ps"].push
 
-        async def push_spy(peer, header, source):
+        async def push_spy(peer, header, source, **timing):
             rounds[header["round"]]["source"] = source
-            return await push(peer, header, source)
+            return await push(peer, header, source, **timing)
 
         nodes["ps"].push = push_spy
         ref = Reference.from_peers(["w1"], "updates")
