@@ -189,6 +189,18 @@ class WeightStager:
         return ready
 
 
+def _read_and_unlink(path: Path) -> dict:
+    """Decode a wire and drop its name, in that order and in one thread. The
+    node that saved the file writes the stream's next push over it once
+    nobody else names it (``worker/connectors.py`` ``_claim_spare``), so the
+    name has to outlive the read, also where the caller is cancelled while
+    this thread still reads."""
+    try:
+        return read_delta(path)
+    finally:
+        path.unlink(missing_ok=True)
+
+
 class WeightSubscriber:
     """The receive loop: broadcast wire → stager → pool swap request.
 
@@ -271,8 +283,9 @@ class WeightSubscriber:
             except Exception:  # noqa: BLE001 — one bad wire, not the loop
                 self.decode_errors += 1
                 log.exception("weight-stream wire from %s failed", rf.from_peer)
-            finally:
-                Path(rf.path).unlink(missing_ok=True)
+            # Not on the way out of a cancellation: a read that is still in
+            # its thread drops the name itself (``_read_and_unlink``).
+            Path(rf.path).unlink(missing_ok=True)
 
     async def _handle(self, rf: Any) -> None:
         meta = rf.meta or {}
@@ -295,7 +308,7 @@ class WeightSubscriber:
             fid, total = 0, 1
         # Decode off the event loop: dequantize of a large fragment is
         # milliseconds of pure NumPy that must not stall other receives.
-        arrays = await asyncio.to_thread(read_delta, Path(rf.path))
+        arrays = await asyncio.to_thread(_read_and_unlink, Path(rf.path))
         self.fragments_received += 1
         self.bytes_received += int(rf.size or 0)
         for r, update in self.stager.offer(

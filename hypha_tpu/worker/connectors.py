@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import asyncio
 import hashlib
+import itertools
 import logging
 import os
 import shutil
@@ -53,6 +54,48 @@ log = logging.getLogger("hypha.worker.connector")
 def _safe_name(name: str) -> str:
     """Collapse any peer-supplied name to a flat digest-based filename."""
     return hashlib.sha256(name.encode()).hexdigest()[:32]
+
+
+# A name of its own for every spare a save claims (``_claim_spare``).
+_CLAIMS = itertools.count()
+
+
+def _claim_spare(spare: Path) -> Path | None:
+    """The file the stream's last push left, for this save to write over, if
+    the node is the only one that still names it; else None.
+
+    The spare is a second name (``_keep_spare``) of a file whose first name
+    went to a consumer. The rename takes it for this save alone: of two
+    saves that run at once one finds no spare and goes fresh. A link count
+    of 1 then says the consumer has unlinked its name, which every consumer
+    does after its last read, so the inode and its pages are this save's.
+    With 2 a reader may still hold it (a re-broadcast arriving during the
+    merge's read, a slice that is kept): the name is dropped, the file stays
+    the reader's, and the fresh file this save writes is the next spare."""
+    claimed = spare.with_name(f"{spare.stem}.{os.getpid()}-{next(_CLAIMS)}.over")
+    try:
+        os.rename(spare, claimed)
+        if os.stat(claimed).st_nlink == 1:
+            return claimed
+        os.unlink(claimed)
+    except OSError:
+        pass  # no spare: the stream's first push, or another save has it
+    return None
+
+
+def _keep_spare(dest: Path, spare: Path) -> None:
+    """Give the file that landed at ``dest`` a second name, the stream's one
+    spare, before a consumer hears of it: the pages stay when the consumer
+    unlinks ``dest``, for the stream's next push to land in. Where a link
+    cannot be had (EPERM, EXDEV, EMLINK, a save that ran at once was first)
+    there is none and the next push goes into a fresh file, as it always
+    did."""
+    try:
+        spare.parent.mkdir(exist_ok=True)
+        spare.unlink(missing_ok=True)  # an older one: its pages go with its last name
+        os.link(dest, spare)
+    except OSError:
+        pass
 
 
 # Outbound tensor pushes retry with jittered backoff (aio.retry) for up to
@@ -447,7 +490,14 @@ class Connector:
         of itself under the names the PS's ``upload`` span gives them. A
         push that ends any other way (cancellation, a sender that went
         away) leaves the span with ``ok`` false. Tracing on or off, the
-        ``push received:`` line says the same of a push that landed."""
+        ``push received:`` line says the same of a push that landed.
+
+        The payload is written over the file the last push of this stream
+        (sender and resource tag) left, once its consumer has unlinked it
+        (``_claim_spare``): ``pages=recycled``, and over plain TCP
+        ``path=thread``. The state is the file system's, under
+        ``dest``'s ``spare/``, and goes with the directory."""
+        spare = dest.parent / "spare" / f"{_safe_name(push.peer + '-' + resource)}.bin"
         t = trace.active()
         span = None
         if t is not None:
@@ -460,7 +510,10 @@ class Connector:
             )
         t0 = time.monotonic()
         try:
-            size = await push.save_to(dest)
+            # Not through a thread: from the rename on the spare is
+            # ``save_to``'s to write, name and unlink, with no await between.
+            size = await push.save_to(dest, over=_claim_spare(spare))
+            await asyncio.to_thread(_keep_spare, dest, spare)
         except BaseException:
             trace.finish(span, ok=False)
             raise

@@ -57,6 +57,14 @@ def pytest_configure(config):
 # does not run while the line fails, so ``tests/perfbench/test_relative_counts.py``
 # holds every assertion of both again, over the same fixtures, with the counts
 # relative to the manifest. A repaired test passes and the hook does nothing.
+#
+# Since PR 43 a third line of a file the benchmark has is outdated the same way,
+# by the program and not by the manifest: ``test_journey_metrics.py`` counts the
+# worker's ``push received:`` lines that read ``pages=fresh path=loop``, which
+# every round's did until the worker's node began to save a broadcast over the
+# file the last one left (``pages=recycled path=thread`` from round 1 on).
+# ``tests/test_receive_recycled.py`` holds every assertion of that test again,
+# over one more run of the same rehearsal, with the line as it reads now.
 _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_rehearsal.py::test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give":
         ('len(manifest["per_layer"]) - 7',
@@ -64,6 +72,9 @@ _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_fourth_cell.py::test_nothing_that_was_there_is_touched_and_the_manifest_gains_entries_only":
         ('len(m["workloads"]) == 4 and len(m["configs"]) == 3',
          "the manifest had 3 workloads and 2 configurations before the toy cell"),
+    "tests/perfbench/test_journey_metrics.py::test_the_rehearsals_roles_wrote_the_spans_from_both_ends":
+        ("pages=fresh path=loop",
+         "only round 0's broadcast lands in fresh pages through the loop since PR 43"),
 }
 
 
@@ -75,7 +86,7 @@ def pytest_pyfunc_call(pyfuncitem):
         stale = _COUNTS_A_NEW_CELL_OUTDATES.get(pyfuncitem.nodeid)
         at = traceback.extract_tb(e.__traceback__)[-1]
         if stale and at.name == pyfuncitem.name and stale[0] in (at.line or ""):
-            pytest.xfail(f"holds the literal count `{stale[0]}`: {stale[1]}")
+            pytest.xfail(f"holds the literal `{stale[0]}`: {stale[1]}")
         raise
 
 
