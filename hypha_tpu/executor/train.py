@@ -269,6 +269,40 @@ def make_train_step(
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
+def _head_loss(model, params, hidden, ids, chunk: int):
+    """The chunked causal loss of final hidden states against the leaf of
+    ``params`` the model names as its head (``model.head_leaf``)."""
+    head = params["params"][model.head_leaf].astype(hidden.dtype)
+    return chunked_causal_ce(hidden[:, :-1], head, ids[:, 1:], chunk=chunk)
+
+
+def make_chunked_train_step(model, *, loss_chunk: int = 512, donate: bool = True):
+    """The jitted step of a dense causal model that names its head
+    (``model.head_leaf``) and can stop before it (``with_head=False``): the
+    chunked causal cross-entropy over the final hidden states, so the
+    [B, S, vocab] logits never exist, through a head of its own or a tied
+    embedding. ``make_train_step``'s metrics; no ``extras``."""
+    body = model.clone(with_head=False)
+
+    def loss_fn(params, ids):
+        return _head_loss(model, params, body.apply(params, ids), ids, loss_chunk)
+
+    def step(state: TrainState, batch) -> tuple:
+        ids = batch["input_ids"] if "input_ids" in batch else batch["inputs"]
+        loss, grads = jax.value_and_grad(loss_fn)(state.params, ids)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads)
+        metrics = {
+            "loss": loss,
+            "total_loss": loss,
+            "aux_loss": jnp.float32(0),
+            "grad_norm": optax.global_norm(grads),
+        }
+        return new_state, metrics
+
+    return jax.jit(step, donate_argnums=(0,) if donate else ())
+
+
 # What a routed step's ``metrics["host"]`` vector holds, in order: the loss
 # and the step's routing counters, so one device-to-host transfer fetches all.
 ROUTING_FIELDS = (
@@ -298,9 +332,7 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
 
     def loss_fn(params, extras, ids):
         hidden, stats = body.apply({**params, **extras}, ids)
-        head = params["params"][model.head_leaf].astype(hidden.dtype)
-        loss = chunked_causal_ce(hidden[:, :-1], head, ids[:, 1:], chunk=loss_chunk)
-        return loss, stats
+        return _head_loss(model, params, hidden, ids, loss_chunk), stats
 
     def step(state: TrainState, batch) -> tuple:
         ids = batch["input_ids"] if "input_ids" in batch else batch["inputs"]

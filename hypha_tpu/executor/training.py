@@ -80,6 +80,7 @@ from .train import (
     ROUTING_FIELDS,
     TrainState,
     build_optimizer,
+    make_chunked_train_step,
     make_routed_train_step,
     make_train_step,
 )
@@ -702,11 +703,12 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
     if isinstance(kinds, (list, tuple)) and kinds:
         # A stack of more than one kind of layer says what it holds. The
         # fallbacks' configurations are a library's: no field is taken for granted.
-        kinds, head_dim = list(kinds), getattr(_mcfg, "head_dim", None)
+        kinds = list(kinds)
+        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "scan_chunk")}
         log.info(
             "operators: %s%s",
             " ".join(f"{k}={kinds.count(k)}" for k in dict.fromkeys(kinds)),
-            f" head_dim={head_dim}" if isinstance(head_dim, int) else "",
+            "".join(f" {k}={v}" for k, v in sizes.items() if isinstance(v, int)),
         )
     model_type = resolve_model_type(model_spec.get("model_type", ModelType.CAUSAL_LM))
     causal_lm = model_type not in _non_causal_types()
@@ -969,6 +971,13 @@ def run_training(
                 return lora_step(state, frozen, batch)
         elif extras is not None:
             step = make_routed_train_step(model)
+        elif (
+            getattr(model, "head_leaf", None) and causal_lm
+            and loss_kind == Loss.CROSS_ENTROPY and step_kwargs["loss_override"] is None
+        ):
+            # A dense model that names its head and can stop before it: the
+            # chunked loss, as the routed step's, and no [B, S, vocab] logits.
+            step = make_chunked_train_step(model)
         else:
             step = make_train_step(model.apply, loss_kind, **step_kwargs)
 

@@ -3,7 +3,7 @@
 The reference maps 38 ``ModelType`` variants to HF ``AutoModelFor*`` classes
 (executors/accelerate/.../model.py:48-123). Here every variant resolves:
 the flagship families (GPT-2, Llama + its Mistral/Qwen2/Gemma descendants,
-Mixtral, afmoe, lfm2_moe, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
+Mixtral, afmoe, lfm2_moe, phi4flash, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
 head resolve through the hf fallback family (torch checkpoints convert via
 ``from_pt``); the remaining torch-only-head types resolve through the
 ``heads`` family — JAX task heads over Flax backbones (models/heads.py),
@@ -25,6 +25,7 @@ from .lenet import LeNet, LeNetConfig
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .llama import Llama, LlamaConfig
 from .mixtral import Mixtral, MixtralConfig
+from .phi4flash import Phi4Flash, Phi4FlashConfig
 
 __all__ = ["build_model", "resolve_model_type", "FAMILIES"]
 
@@ -35,6 +36,7 @@ _PRESETS = {
     "lenet": {"default": LeNetConfig},
     "afmoe": {"tiny": AfmoeConfig.tiny},
     "lfm2_moe": {"tiny": Lfm2MoeConfig.tiny},
+    "phi4flash": {"tiny": Phi4FlashConfig.tiny},
 }
 
 FAMILIES = {
@@ -57,6 +59,12 @@ FAMILIES = {
     # convolution or QK-normed GQA with RoPE, by ``layer_types``; the routed
     # experts of afmoe with no shared expert; the head tied to the embedding.
     "lfm2_moe": (Lfm2Moe, Lfm2MoeConfig),
+    # Microsoft's phi4flash (Phi-4-mini-flash-reasoning, SambaY): Mamba and
+    # window differential attention, then one full layer and a cross-decoder of
+    # GMU and cross-attention layers that read the scan output and the keys and
+    # values of two earlier layers; a layer's kind by the source's rule on its
+    # index, ``layers_run`` the source layers a cut keeps.
+    "phi4flash": (Phi4Flash, Phi4FlashConfig),
     "lenet": (LeNet, LeNetConfig),
 }
 

@@ -7,6 +7,8 @@ tap 0 the oldest); ``y = C * c``. The gates carry no activation. Plain
 ``jax.numpy``: XLA fuses the shifts and products into a few elementwise
 passes, which is all a bandwidth-bound operator of three taps asks for. The
 operator's two projections are the caller's (``models/lfm2_moe.py``).
+``causal_taps`` is the convolution alone, which Mamba's layer
+(``models/phi4flash.py``: 4 taps, a bias and SiLU, no gates) shares.
 """
 
 from __future__ import annotations
@@ -14,17 +16,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["short_conv"]
+__all__ = ["causal_taps", "short_conv"]
+
+
+def causal_taps(z: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
+    """``z`` [B, S, D], ``taps`` [K, D] -> ``c_t = sum_j taps[j] * z_{t - (K - 1) + j}``."""
+    k, s = taps.shape[0], z.shape[1]
+    w = taps.astype(z.dtype)
+    padded = jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(w[j] * padded[:, j:j + s] for j in range(k))
 
 
 def short_conv(b: jnp.ndarray, c: jnp.ndarray, x: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
     """``b``, ``c``, ``x`` [B, S, D] (the in-projection's three parts, in the
     source's order), ``taps`` [K, D] -> [B, S, D] in the inputs' type."""
     with jax.named_scope("short_conv"):
-        k = taps.shape[0]
-        z = b * x
-        w = taps.astype(z.dtype)
-        padded = jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0)))
-        s = z.shape[1]
-        conv = sum(w[j] * padded[:, j:j + s] for j in range(k))
-        return c * conv
+        return c * causal_taps(b * x, taps)

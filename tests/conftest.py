@@ -65,6 +65,12 @@ def pytest_configure(config):
 # file the last one left (``pages=recycled path=thread`` from round 1 on).
 # ``tests/test_receive_recycled.py`` holds every assertion of that test again,
 # over one more run of the same rehearsal, with the line as it reads now.
+#
+# Since PR 44 a fourth: ``test_journey_metrics.py`` holds that PR 42's twelve are
+# the manifest's *last* entries and that it has 62, which the fifth cell's nine
+# entries, appended as the contract asks, outdate.
+# ``tests/perfbench/test_phi4flash_counts.py`` holds every assertion of that test
+# again with the twelve found where they stand.
 _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_rehearsal.py::test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give":
         ('len(manifest["per_layer"]) - 7',
@@ -75,6 +81,9 @@ _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_journey_metrics.py::test_the_rehearsals_roles_wrote_the_spans_from_both_ends":
         ("pages=fresh path=loop",
          "only round 0's broadcast lands in fresh pages through the loop since PR 43"),
+    "tests/perfbench/test_journey_metrics.py::test_the_twelve_are_the_last_entries_and_list_one_dense_and_one_sparse_cell":
+        ("len(per_layer) == 62",
+         "the twelve were the last of 62 entries until a fifth cell appended its own"),
 }
 
 

@@ -51,11 +51,15 @@ def _compiled_text(fn, *shapes) -> str:
 # (B2 S4096 H32/Hkv8 D128), Trinity-Mini's two kinds of layer at S 8192
 # (H32/Hkv4 D128): the window of 2048 that cuts, and the full layer; and
 # LFM2-24B-A2B's full layer (B2 S8192 H32/Hkv8 D64): head size 64 with four
-# query heads to a key head, half the 128 lanes a tile.
+# query heads to a key head, half the 128 lanes a tile; and
+# Phi-4-mini-flash-reasoning's differential attention as the program calls the
+# kernel (B1 S8192 H40/Hkv20 D64, two query heads to a key head): the window
+# of 512, which one forward tile of keys holds whole, and the full triangle.
 FLASH_SHAPES = {
     "gpt2": (16, 1024, 12, 12, 64, None), "mistral": (2, 4096, 32, 8, 128, None),
     "trinity_window": (1, 8192, 32, 4, 128, 2048), "trinity_full": (1, 8192, 32, 4, 128, None),
     "lfm2_full": (2, 8192, 32, 8, 64, None),
+    "phi4_window": (1, 8192, 40, 20, 64, 512), "phi4_full": (1, 8192, 40, 20, 64, None),
 }
 
 
@@ -104,6 +108,32 @@ def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2, 3, 5))
     text = _compiled_text(fn, *args)
     assert "ragged-dot" in text and " while(" in text
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_selective_scan_compiles_for_v5e(chip, direction):
+    """Mamba-1's scan at Phi-4-mini-flash-reasoning's widths: one sequence of
+    8192, d_inner 5120, state 16, bf16 inputs with the softplus taken inside:
+    the two kernels, a [16, 512] state tile a grid step and 128 unrolled
+    positions a chunk, which fit beside nothing but chunk boundaries (the
+    states of every position would be 2.7 GB)."""
+    from hypha_tpu.ops.selective_scan import selective_scan
+
+    B, S, D, N = 1, 8192, 5120, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (sds((B, S, D), jnp.bfloat16), sds((B, S, D), jnp.bfloat16), sds((D, N), jnp.float32),
+            sds((B, S, N), jnp.bfloat16), sds((B, S, N), jnp.bfloat16), sds((D,), jnp.float32))
+
+    def fwd(x, dt, a, b, c, d):
+        return selective_scan(x, dt, a, b, c, d, dt_softplus=True, interpret=False)[0]
+
+    fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=tuple(range(6)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9  # not the 2.7 GB of every state
 
 
 @pytest.mark.parametrize("quant", ["bf16", "int8"])
