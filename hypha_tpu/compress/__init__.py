@@ -17,7 +17,15 @@ Pieces:
   * :mod:`frame`    — the self-describing HQD1 wire container: magic +
     CBOR header (codec, chunk, tensor table) + packed payload. A receiver
     needs no out-of-band schema; plain SafeTensors files pass through
-    :func:`read_delta` untouched, so codecs interoperate per job.
+    :func:`read_delta` untouched, so codecs interoperate per job. It also
+    holds the plain f32 wire's two copy-free ends: :func:`write_delta`
+    writes a tree whose every leaf is ``float32`` on codec "none" from the
+    leaves' own memory (``frame_f32``'s head and views; a leaf that is not
+    C-contiguous copied first, it alone; over a file the caller hands it,
+    where it hands one), and :func:`read_delta_into` reads such a file
+    into buffers the caller keeps. Every other tree (bf16, int8/int4, a
+    leaf of another dtype) is encoded and saved through a copy of all of
+    it, as it always was.
   * :mod:`feedback` — the :class:`ErrorFeedback` residual accumulator used
     on BOTH ends: the worker folds its quantization error into the next
     round's delta, the parameter server folds broadcast quantization error

@@ -54,10 +54,12 @@ class Session:
         path: str,
         resource: str = "updates",
         meta: dict[str, Any] | None = None,
-    ) -> None:
+    ) -> bool:
         """Ship a work-dir file to peers (runs in the worker's background).
         ``meta`` rides the stream header (e.g. num_samples for the parameter
-        server's sample-weighted mean)."""
+        server's sample-weighted mean). True where the node holds a second
+        name for the file until the send has ended (the 202's ``held``):
+        the file's link count then says whether a send of it is open."""
         r = self._client.post(
             "/resources/send",
             json={
@@ -68,6 +70,7 @@ class Session:
             },
         )
         r.raise_for_status()
+        return bool(r.json().get("held"))
 
     def send_status(self, progress: Progress) -> ProgressResponse:
         """Report progress; returns the scheduler's control decision."""

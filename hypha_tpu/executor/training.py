@@ -29,6 +29,7 @@ crates/worker/src/executor/process.rs:124-137):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import logging
 import math
@@ -61,7 +62,7 @@ from ..ft.rejoin import CATCHUP_KEY
 from ..stream import SYNC_MODES, effective_fragments, fragment_due, merge_corrected
 from ..stream.accum import SumBuffers
 from ..stream.partition import partition_names, shard_of
-from ..worker.connectors import shard_route
+from ..worker.connectors import claim_spare, shard_route
 from ..telemetry import trace
 from ..telemetry.ft_metrics import (
     DATA_METRICS,
@@ -1328,15 +1329,19 @@ def run_training(
             annotation=jax.profiler.TraceAnnotation(name), usage=usage,
         )
 
-    def log_sync(done_round: int, bytes_up: int, read: compress.ReadStats) -> None:
+    def log_sync(
+        done_round: int, bytes_up: int, read: compress.ReadStats, pages: str = "fresh"
+    ) -> None:
+        """``pages``: whether the delta was written over the file the last
+        round's left (``recycled``) or into a new one (``fresh``)."""
         log.info(
             "sync done: round=%d encode_s=%.3f upload_s=%.3f wait_s=%.3f "
             "merge_s=%.3f cleanup_s=%.3f bytes_up=%d bytes_down=%d "
-            "leaves=%d direct=%d resident=%d",
+            "leaves=%d direct=%d resident=%d pages=%s",
             done_round, sync.get("encode_s", 0.0), sync.get("upload_s", 0.0),
             sync.get("wait_s", 0.0), sync.get("merge_s", 0.0),
             sync.get("cleanup_s", 0.0), bytes_up, read.bytes,
-            read.leaves, read.direct, read.resident,
+            read.leaves, read.direct, read.resident, pages,
         )
 
     # Where the blocking syncs keep the broadcast update on the host: one
@@ -1371,10 +1376,45 @@ def run_training(
         for name in ("bytes", "leaves", "direct", "resident"):
             ph.set(name, getattr(read, name))
 
-    def await_round_update(delta_path: Path) -> tuple[dict, dict]:
+    # The blocking sync's delta of the last round, still under the name it
+    # was sent by: the file this round's ``encode.write`` writes over, so
+    # that 4 B a parameter land in pages that exist and not in fresh ones.
+    # Kept only where the node held a second name through every send of it
+    # (``push_delta``), so that its link count says whether one is still
+    # open (``claim_spare``); it goes with the job's work directory.
+    spare_delta: Path | None = None
+
+    def push_delta(delta_path: Path) -> bool:
+        """Hand the node this round's delta by name (it sends in the
+        background). True where the node holds a second name for the file
+        until that send has ended, whatever this process does with its own
+        (``Bridge._send``); a session that says nothing holds none."""
+        return bool(
+            session.send_resource(
+                cfg.updates,
+                delta_path.name,
+                # The Send reference's resource tag routes the stream to the
+                # right consumer on the PS node (job-unique, set by the
+                # scheduler's orchestrator).
+                resource=cfg.updates.ref.resource or "updates",
+                # round tags the delta so an elastic parameter server can
+                # reject a stale one (arriving after its round aggregated at
+                # quorum) instead of folding it into the wrong mean. Traced
+                # jobs additionally stamp the round context so the parameter
+                # server's spans join the round's trace.
+                meta=rtrace.stamp(
+                    {"num_samples": float(round_samples), "round": round_num},
+                    round_num,
+                ),
+            )
+        )
+
+    def await_round_update(delta_path: Path) -> tuple[dict, dict, bool]:
         """Results-stream events until this round's update broadcast:
-        the event and its meta."""
+        the event, its meta, and whether every re-send of the delta on the
+        way was held (``push_delta``)."""
         nonlocal ps_generation
+        held = True
         with session.receive(cfg.results) as events:
             while True:
                 # Not bare next(): a severed bridge ends the SSE stream,
@@ -1397,18 +1437,7 @@ def run_training(
                         "ps restart detected (generation %s); re-sending "
                         "round %d delta", ps_generation, round_num,
                     )
-                    session.send_resource(
-                        cfg.updates,
-                        delta_path.name,
-                        resource=cfg.updates.ref.resource or "updates",
-                        meta=rtrace.stamp(
-                            {
-                                "num_samples": float(round_samples),
-                                "round": round_num,
-                            },
-                            round_num,
-                        ),
-                    )
+                    held &= push_delta(delta_path)
                 if meta.get(RESYNC_KEY) or meta.get(CATCHUP_KEY):
                     # Resync announcements carry no tensor payload; stray
                     # catch-ups target rejoiners and are folded into every
@@ -1425,11 +1454,11 @@ def run_training(
                     # merged it — absorbing again would double-apply.
                     (work_dir / event["path"]).unlink(missing_ok=True)
                     continue
-                return event, meta
+                return event, meta, held
 
     def do_update() -> bool:
         """Ship Δθ, wait for the PS broadcast, merge. True = next round."""
-        nonlocal state, anchor, host_anchor, round_num, round_samples
+        nonlocal state, anchor, host_anchor, round_num, round_samples, spare_delta
         rtrace.close_inner()
         rtrace.clock_mark(round_num)
         round_tp = rtrace.ctx(round_num)
@@ -1486,33 +1515,28 @@ def run_training(
                     # uncompressed wire can carry it exactly.
                     wire_flat = delta_ef.compensate(wire_flat)
                     delta_ef.reset()
-                compress.write_delta(
-                    delta_path, wire_flat, wire_codec, ef=delta_ef
-                )
+                # Over the file the last round's delta left, if no send of
+                # it is open; a tree that is not all f32 on an uncompressed
+                # wire is written as ever and the spare unlinked
+                # (``write_delta`` decides by what it is given). Held open,
+                # the spare says afterwards which it was: it has a name.
+                over = claim_spare(spare_delta) if spare_delta else None
+                spare_delta = None
+                with open(over, "rb") if over else contextlib.nullcontext() as was:
+                    compress.write_delta(
+                        delta_path, wire_flat, wire_codec, ef=delta_ef, over=over
+                    )
+                    named = was is not None and os.fstat(was.fileno()).st_nlink
+                pages = "recycled" if named else "fresh"
                 bytes_up = delta_path.stat().st_size
                 ph.set("bytes", bytes_up)
                 ph.set("leaves", len(wire_flat))
+                ph.set("pages", pages)
         with sync_phase(
             "upload", parent=round_tp, key="upload_s",
             round=round_num, codec=wire_codec, bytes=bytes_up,
         ):
-            session.send_resource(
-                cfg.updates,
-                delta_path.name,
-                # The Send reference's resource tag routes the stream to the
-                # right consumer on the PS node (job-unique, set by the
-                # scheduler's orchestrator).
-                resource=cfg.updates.ref.resource or "updates",
-                # round tags the delta so an elastic parameter server can
-                # reject a stale one (arriving after its round aggregated at
-                # quorum) instead of folding it into the wrong mean. Traced
-                # jobs additionally stamp the round context so the parameter
-                # server's spans join the round's trace.
-                meta=rtrace.stamp(
-                    {"num_samples": float(round_samples), "round": round_num},
-                    round_num,
-                ),
-            )
+            held = push_delta(delta_path)
         # What the worker waits for transport and parameter server together:
         # from the upload's end to the broadcast event that ends the loop.
         with sync_phase(
@@ -1532,7 +1556,7 @@ def run_training(
                     traceparent=round_tp,
                 )
             )
-            event, meta = await_round_update(delta_path)
+            event, meta, resends_held = await_round_update(delta_path)
         apply_codec_hint(meta)
         update_file = work_dir / event["path"]
         with sync_phase(
@@ -1578,12 +1602,16 @@ def run_training(
             "cleanup", parent=round_tp, key="cleanup_s",
             min_s=trace.SLOW_CLEANUP_S, round=round_num,
         ):
-            delta_path.unlink(missing_ok=True)
+            if held and resends_held:
+                # Stays under its name, for the next round to write over.
+                spare_delta = delta_path
+            else:
+                delta_path.unlink(missing_ok=True)
             # The broadcast update is merged — drop it, or a long job
             # accumulates one full-parameter-sized file per round under
             # work_dir/incoming.
             update_file.unlink(missing_ok=True)
-        log_sync(round_num, bytes_up, read)
+        log_sync(round_num, bytes_up, read, pages)
         resp = send_status_gated(
             Progress(
                 kind=ProgressKind.UPDATE_RECEIVED, job_id=spec.job_id,

@@ -519,7 +519,7 @@ class PushStream:
         with and alone still names (the PS's delta of the last round from
         its spool; on a worker's node the last broadcast of the stream,
         kept by a hard link, once its consumer has unlinked its own name:
-        ``worker/connectors.py`` ``_claim_spare``). The payload is written
+        ``worker/connectors.py`` ``claim_spare``). The payload is written
         over it from offset 0, so it lands in pages that exist and not in
         fresh ones (a parameter-sized pass into fresh memory runs at about
         1 GB/s on the chip's host, into pages that exist at several); at

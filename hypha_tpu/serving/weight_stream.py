@@ -192,7 +192,7 @@ class WeightStager:
 def _read_and_unlink(path: Path) -> dict:
     """Decode a wire and drop its name, in that order and in one thread. The
     node that saved the file writes the stream's next push over it once
-    nobody else names it (``worker/connectors.py`` ``_claim_spare``), so the
+    nobody else names it (``worker/connectors.py`` ``claim_spare``), so the
     name has to outlive the read, also where the caller is cancelled while
     this thread still reads."""
     try:

@@ -7,7 +7,12 @@ executor exactly four capabilities and nothing else:
   * ``POST /resources/fetch``   — materialize a Fetch reference under
     ``work_dir/artifacts`` (:216-248);
   * ``POST /resources/send``    — stream a work-dir file to peers in the
-    background (:256-327);
+    background (:256-327). The 202 carries ``held``: whether the node took
+    a second name for the file (a hard link under ``work_dir/held/``)
+    before it answered, which it keeps until the send has returned, raised
+    or been cancelled. A sender that holds one reads the file by that
+    name, so an executor that finds its file's link count at 1 knows no
+    send of it is open and may write over it (``claim_spare``);
   * ``POST /resources/receive`` — SSE stream of ``{path,size,from_peer}``
     pointers as files land in ``work_dir/incoming`` (:392-504);
   * ``POST /status/send``       — proxy a Progress message to the scheduler
@@ -20,8 +25,10 @@ Path safety: no absolute paths, no ``..`` traversal (:330-346).
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import logging
+import os
 import socket
 from pathlib import Path
 
@@ -63,6 +70,15 @@ def safe_rel(work_dir: Path, rel: str) -> Path:
     return work_dir / p
 
 
+def _release(held: Path) -> None:
+    """Drop a send's second name and the directory made for it."""
+    held.unlink(missing_ok=True)
+    try:
+        held.parent.rmdir()
+    except OSError:
+        pass
+
+
 class Bridge:
     def __init__(
         self,
@@ -91,6 +107,7 @@ class Bridge:
         self._server: asyncio.base_events.Server | None = None
         self._send_tasks: set[asyncio.Task] = set()
         self._conn_tasks: set[asyncio.Task] = set()
+        self._holds = itertools.count()  # a directory of its own a held send
 
     async def start(self) -> Path:
         self.work_dir.mkdir(parents=True, exist_ok=True, mode=0o700)
@@ -252,13 +269,36 @@ class Bridge:
             return
 
         # Background copy (bridge.rs:256-327): don't block the executor loop.
-        aio.spawn(
-            self.connector.send(send, path, resource, meta),
+        held = self._hold(path)
+        task = aio.spawn(
+            self.connector.send(send, held or path, resource, meta),
             tasks=self._send_tasks,
             what="background send",
             logger=log,
         )
-        await self._respond(writer, 202, {"ok": True})
+        if held is not None:
+            # A callback and no ``finally`` of the send's: a task cancelled
+            # before its first step never enters its body.
+            task.add_done_callback(lambda _: _release(held))
+        await self._respond(writer, 202, {"ok": True, "held": held is not None})
+
+    def _hold(self, path: Path) -> Path | None:
+        """A second name for ``path``, under the file's own name in a
+        directory of this send's (the push header carries the name), from
+        before the 202 until the send has ended any way it can: the bytes a
+        slow or retried send reads are this inode's whatever the executor
+        does with its own name, and the inode's link count tells the
+        executor that a send is open. None where a link cannot be had
+        (EPERM, EXDEV, EMLINK): the send reads ``path`` as it always did
+        and the 202 says so."""
+        held = self.work_dir / "held" / str(next(self._holds)) / path.name
+        try:
+            held.parent.mkdir(parents=True)
+            os.link(path, held)
+        except OSError:
+            _release(held)
+            return None
+        return held
 
     async def _receive(
         self,

@@ -46,7 +46,7 @@ from ..network.node import Node, PushStream, RequestError
 from ..telemetry import trace
 from ..telemetry.ft_metrics import DATA_METRICS
 
-__all__ = ["Connector", "ReceivedFile", "fetch_uri", "shard_route"]
+__all__ = ["Connector", "ReceivedFile", "claim_spare", "fetch_uri", "shard_route"]
 
 log = logging.getLogger("hypha.worker.connector")
 
@@ -56,22 +56,29 @@ def _safe_name(name: str) -> str:
     return hashlib.sha256(name.encode()).hexdigest()[:32]
 
 
-# A name of its own for every spare a save claims (``_claim_spare``).
+# A name of its own for every spare a write claims (``claim_spare``).
 _CLAIMS = itertools.count()
 
 
-def _claim_spare(spare: Path) -> Path | None:
-    """The file the stream's last push left, for this save to write over, if
-    the node is the only one that still names it; else None.
+def claim_spare(spare: Path) -> Path | None:
+    """The file the last write of its kind left, for this one to write over,
+    if the caller is the only one that still names it; else None. The one
+    definition of that claim: the node's (``Connector._save``, a stream's
+    last push) and the executor's (``run_training``'s ``encode.write``, the
+    last round's delta).
 
-    The spare is a second name (``_keep_spare``) of a file whose first name
-    went to a consumer. The rename takes it for this save alone: of two
-    saves that run at once one finds no spare and goes fresh. A link count
-    of 1 then says the consumer has unlinked its name, which every consumer
-    does after its last read, so the inode and its pages are this save's.
-    With 2 a reader may still hold it (a re-broadcast arriving during the
-    merge's read, a slice that is kept): the name is dropped, the file stays
-    the reader's, and the fresh file this save writes is the next spare."""
+    The node's spare is a second name (``_keep_spare``) of a file whose
+    first name went to a consumer; the executor's is its delta under the
+    name it was sent by, which a sender that has not ended holds by a
+    second name of the node's own (``Bridge._send``). The rename takes it
+    for this write alone: of two that run at once one finds no spare and
+    goes fresh. A link count of 1 then says the other has unlinked its
+    name, which every consumer does after its last read and the bridge when
+    its send has returned or raised, so the inode and its pages are this
+    write's. With 2 a reader may still hold it (a re-broadcast arriving
+    during the merge's read, a slice that is kept, a push that is being
+    retried): the name is dropped, the file stays the reader's, and the
+    fresh file this write makes is the next spare."""
     claimed = spare.with_name(f"{spare.stem}.{os.getpid()}-{next(_CLAIMS)}.over")
     try:
         os.rename(spare, claimed)
@@ -494,7 +501,7 @@ class Connector:
 
         The payload is written over the file the last push of this stream
         (sender and resource tag) left, once its consumer has unlinked it
-        (``_claim_spare``): ``pages=recycled``, and over plain TCP
+        (``claim_spare``): ``pages=recycled``, and over plain TCP
         ``path=thread``. The state is the file system's, under
         ``dest``'s ``spare/``, and goes with the directory."""
         spare = dest.parent / "spare" / f"{_safe_name(push.peer + '-' + resource)}.bin"
@@ -512,7 +519,7 @@ class Connector:
         try:
             # Not through a thread: from the rename on the spare is
             # ``save_to``'s to write, name and unlink, with no await between.
-            size = await push.save_to(dest, over=_claim_spare(spare))
+            size = await push.save_to(dest, over=claim_spare(spare))
             await asyncio.to_thread(_keep_spare, dest, spare)
         except BaseException:
             trace.finish(span, ok=False)

@@ -566,7 +566,7 @@ def _worker_job(tmp, spares: bool):
             mp.setattr(cluster, "ROUNDS", JOB_ROUNDS)
             mp.setattr(training, "merge_update", spy_merge)
             if not spares:
-                mp.setattr(connectors, "_claim_spare", lambda spare: None)
+                mp.setattr(connectors, "claim_spare", lambda spare: None)
                 mp.setattr(connectors, "_keep_spare", lambda dest, spare: None)
             result = cluster.run(cluster._job(tmp))
     finally:
