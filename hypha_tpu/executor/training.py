@@ -705,11 +705,11 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
         # A stack of more than one kind of layer says what it holds. The
         # fallbacks' configurations are a library's: no field is taken for granted.
         kinds = list(kinds)
-        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "scan_chunk")}
+        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "scan_chunk", "ssd_chunk", "expert_form")}
         log.info(
             "operators: %s%s",
             " ".join(f"{k}={kinds.count(k)}" for k in dict.fromkeys(kinds)),
-            "".join(f" {k}={v}" for k, v in sizes.items() if isinstance(v, int)),
+            "".join(f" {k}={v}" for k, v in sizes.items() if isinstance(v, (int, str))),
         )
     model_type = resolve_model_type(model_spec.get("model_type", ModelType.CAUSAL_LM))
     causal_lm = model_type not in _non_causal_types()
@@ -2014,7 +2014,7 @@ def run_training(
             # computed must equal pairs routed (nothing dropped); a held
             # expert's mean load is pairs over steps x expert layers x held.
             mcfg = model.config
-            layers = mcfg.num_layers - mcfg.num_dense_layers
+            layers = mcfg.num_expert_layers
             cells = routing["steps"] * layers
             load_mean = routing["pairs_computed"] / max(cells * mcfg.held, 1)
             log.info(

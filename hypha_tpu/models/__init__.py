@@ -13,6 +13,7 @@ from .lenet import LeNet, LeNetConfig
 from .gpt2 import GPT2, GPT2Config
 from .llama import Llama, LlamaConfig
 from .mixtral import Mixtral, MixtralConfig
+from .nemotron_h import NemotronH, NemotronHConfig
 from .phi4flash import Phi4Flash, Phi4FlashConfig
 from .registry import build_model, resolve_model_type
 
@@ -29,6 +30,8 @@ __all__ = [
     "LlamaConfig",
     "Mixtral",
     "MixtralConfig",
+    "NemotronH",
+    "NemotronHConfig",
     "Phi4Flash",
     "Phi4FlashConfig",
     "build_model",

@@ -107,6 +107,10 @@ class AfmoeConfig:
             raise ValueError("experts_held + expert_offset exceed num_experts")
 
     @property
+    def num_expert_layers(self) -> int:
+        return self.num_layers - self.num_dense_layers
+
+    @property
     def held(self) -> int:
         return self.num_experts if self.experts_held is None else self.experts_held
 

@@ -3,7 +3,7 @@
 The reference maps 38 ``ModelType`` variants to HF ``AutoModelFor*`` classes
 (executors/accelerate/.../model.py:48-123). Here every variant resolves:
 the flagship families (GPT-2, Llama + its Mistral/Qwen2/Gemma descendants,
-Mixtral, afmoe, lfm2_moe, phi4flash, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
+Mixtral, afmoe, lfm2_moe, phi4flash, nemotron_h, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
 head resolve through the hf fallback family (torch checkpoints convert via
 ``from_pt``); the remaining torch-only-head types resolve through the
 ``heads`` family — JAX task heads over Flax backbones (models/heads.py),
@@ -25,6 +25,7 @@ from .lenet import LeNet, LeNetConfig
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .llama import Llama, LlamaConfig
 from .mixtral import Mixtral, MixtralConfig
+from .nemotron_h import NemotronH, NemotronHConfig
 from .phi4flash import Phi4Flash, Phi4FlashConfig
 
 __all__ = ["build_model", "resolve_model_type", "FAMILIES"]
@@ -37,6 +38,7 @@ _PRESETS = {
     "afmoe": {"tiny": AfmoeConfig.tiny},
     "lfm2_moe": {"tiny": Lfm2MoeConfig.tiny},
     "phi4flash": {"tiny": Phi4FlashConfig.tiny},
+    "nemotron_h": {"tiny": NemotronHConfig.tiny},
 }
 
 FAMILIES = {
@@ -65,6 +67,11 @@ FAMILIES = {
     # values of two earlier layers; a layer's kind by the source's rule on its
     # index, ``layers_run`` the source layers a cut keeps.
     "phi4flash": (Phi4Flash, Phi4FlashConfig),
+    # NVIDIA's nemotron_h (Nemotron-H): a block is one norm and one part, a
+    # Mamba-2 mixer, attention or routed squared-ReLU experts beside a shared
+    # one, by the letter of ``pattern``; ``layers_run`` the source layers a cut
+    # keeps; one rank's share of the experts as afmoe's.
+    "nemotron_h": (NemotronH, NemotronHConfig),
     "lenet": (LeNet, LeNetConfig),
 }
 

@@ -6,15 +6,23 @@ and the selection bias that is no parameter.
 ``experts_per_token`` of ``s + b`` are chosen (``b``, the selection bias,
 enters the choice and not the weight); the chosen scores are normalised
 (``route_norm``: ``s / (sum s + route_eps)``) and scaled (``route_scale``);
-the output is the weighted sum of the chosen experts' SwiGLUs, plus a shared
+the output is the weighted sum of the chosen experts' outputs, plus a shared
 expert where the family has one. The configuration is whatever dataclass the
 family brings, read by these fields: ``dtype``, ``num_experts``,
 ``experts_per_token``, ``held``, ``expert_offset``, ``moe_intermediate_size``,
 ``moe_chunk``, ``num_shared_experts``, ``route_norm``, ``route_scale``,
-``route_eps``. What the two families differ in is three of them: afmoe has one
+``route_eps``, and two that a family may leave out: ``expert_form`` (``swiglu``
+where absent: ``down(silu(gate x) * up x)``, three matrices; ``relu2``:
+``down(relu(up x)^2)``, two matrices and no gate, for the routed experts and the
+shared one alike) and ``shared_expert_intermediate_size`` (where absent the
+shared expert is ``num_shared_experts`` routed experts wide), and
+``residual_scale`` (where absent 1: the variance of the experts' last matrix as
+a share of ``lecun_normal``'s). afmoe has one
 shared expert, ``route_eps`` 1e-20 and ``route_scale`` 2.826; lfm2_moe has no
 shared expert (no ``shared_experts`` parameters and no ``shared_expert``
-scope exist then), ``route_eps`` 1e-6 and ``route_scale`` 1.
+scope exist then), ``route_eps`` 1e-6 and ``route_scale`` 1; nemotron_h has
+``relu2`` experts, a shared one twice a routed one's width, ``route_eps`` 1e-20
+and ``route_scale`` 2.5.
 
 The bias lives in the ``moe_state`` collection, beside ``params``: the train
 step keeps it out of the gradient, of AdamW and of the pseudo-gradient, and
@@ -35,7 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import grouped_swiglu, sort_pairs
+from ..ops.grouped_matmul import grouped_experts, sort_pairs
 
 __all__ = ["STATE", "update_bias"]
 
@@ -51,6 +59,26 @@ class _SwiGLU(nn.Module):
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
         act = nn.silu(dense(self.width, "gate_proj")(x)) * dense(self.width, "up_proj")(x)
         return dense(x.shape[-1], "down_proj")(act)
+
+
+def _lecun(scale: float = 1.0, **kw):
+    """``lecun_normal`` at ``scale`` = 1; a family that rescales its residual
+    branches (``residual_scale``) draws their last matrix that much smaller."""
+    return nn.initializers.variance_scaling(scale, "fan_in", "truncated_normal", **kw)
+
+
+class _Relu2(nn.Module):
+    """``down(relu(up x)^2)``: a feed-forward part of two matrices, no gate."""
+
+    width: int
+    dtype: jnp.dtype
+    down_scale: float = 1.0  # the down projection's variance, as a share of lecun_normal's
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name, **kw: nn.Dense(n, use_bias=False, dtype=self.dtype, name=name, **kw)
+        act = jnp.square(nn.relu(dense(self.width, "up_proj")(x)))
+        return dense(x.shape[-1], "down_proj", kernel_init=_lecun(self.down_scale))(act)
 
 
 class _MoE(nn.Module):
@@ -79,20 +107,26 @@ class _MoE(nn.Module):
             if cfg.route_norm:
                 w = w / (w.sum(-1, keepdims=True) + cfg.route_eps)
             w = w * cfg.route_scale
+        form = getattr(cfg, "expert_form", "swiglu")
+        down_scale = getattr(cfg, "residual_scale", 1.0)
         shared = None
         if cfg.num_shared_experts:
+            wide = getattr(cfg, "shared_expert_intermediate_size", None) or F * cfg.num_shared_experts
             with jax.named_scope("shared_expert"):
-                shared = _SwiGLU(F * cfg.num_shared_experts, dtype, name="shared_experts")(m)
-        init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (G, D, F), jnp.float32)
-        w_up = self.param("experts_up", init, (G, D, F), jnp.float32)
-        w_down = self.param("experts_down", init, (G, F, D), jnp.float32)
+                if form == "relu2":
+                    shared = _Relu2(wide, dtype, down_scale, name="shared_experts")(m)
+                else:
+                    shared = _SwiGLU(wide, dtype, name="shared_experts")(m)
+        ws = tuple(
+            self.param(f"experts_{name}", _lecun(down_scale if name == "down" else 1.0, batch_axis=(0,)),
+                       (G, F, D) if name == "down" else (G, D, F), jnp.float32)
+            for name in (("up", "down") if form == "relu2" else ("gate", "up", "down")))
         with jax.named_scope("moe_dispatch"):
             order, sizes = sort_pairs(idx, cfg.expert_offset, G)
             tokens, weights = order // K, w.reshape(-1)[order]
-        routed = grouped_swiglu(
-            x, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype),
-            tokens, weights, sizes, chunk=cfg.moe_chunk,
+        routed = grouped_experts(
+            x, tuple(w.astype(dtype) for w in ws), tokens, weights, sizes,
+            form=form, chunk=cfg.moe_chunk,
         )
         with jax.named_scope("router"):
             experts = jnp.arange(E, dtype=idx.dtype)

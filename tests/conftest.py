@@ -71,6 +71,12 @@ def pytest_configure(config):
 # entries, appended as the contract asks, outdate.
 # ``tests/perfbench/test_phi4flash_counts.py`` holds every assertion of that test
 # again with the twelve found where they stand.
+#
+# Since PR 46 a fifth: ``test_rehearsal_phi4flash.py`` holds that Phi-4's nine
+# are the manifest's *last* entries (and its cell and configuration the last of
+# their lists), which the sixth cell's ten entries, appended, outdate.
+# ``tests/perfbench/test_rehearsal_nemotron_h.py`` holds every assertion of that
+# test again, by name and not by place.
 _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_rehearsal.py::test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give":
         ('len(manifest["per_layer"]) - 7',
@@ -84,6 +90,9 @@ _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_journey_metrics.py::test_the_twelve_are_the_last_entries_and_list_one_dense_and_one_sparse_cell":
         ("len(per_layer) == 62",
          "the twelve were the last of 62 entries until a fifth cell appended its own"),
+    "tests/perfbench/test_rehearsal_phi4flash.py::test_the_manifest_lists_the_nine_metrics_for_the_one_cell":
+        ('m["per_layer"][-len(NEW):]',
+         "Phi-4's nine were the last entries until a sixth cell appended its own"),
 }
 
 

@@ -16,7 +16,7 @@ from hypha_tpu.models import build_model
 from hypha_tpu.models.afmoe import FULL, SLIDING, STATE, AfmoeConfig, update_bias
 from hypha_tpu.ops.attention import dot_product_attention
 from hypha_tpu.ops.flash_attention import flash_attention
-from hypha_tpu.ops.grouped_matmul import grouped_swiglu, sort_pairs
+from hypha_tpu.ops.grouped_matmul import grouped_experts, sort_pairs
 
 # --------------------------------------------------------------------------
 # The flash kernel's window
@@ -133,8 +133,8 @@ def _loop(x, w_gate, w_up, w_down, idx, wt, offset):
 
 def _grouped(x, w_gate, w_up, w_down, idx, wt, offset, chunk):
     order, sizes = sort_pairs(idx, offset, w_gate.shape[0])
-    return grouped_swiglu(x, w_gate, w_up, w_down, order // idx.shape[1],
-                          wt.reshape(-1)[order], sizes, chunk=chunk)
+    return grouped_experts(x, (w_gate, w_up, w_down), order // idx.shape[1],
+                           wt.reshape(-1)[order], sizes, chunk=chunk)
 
 
 def _routing(kind: str, tokens=40, k=2, experts=8):

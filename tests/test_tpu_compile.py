@@ -54,12 +54,15 @@ def _compiled_text(fn, *shapes) -> str:
 # query heads to a key head, half the 128 lanes a tile; and
 # Phi-4-mini-flash-reasoning's differential attention as the program calls the
 # kernel (B1 S8192 H40/Hkv20 D64, two query heads to a key head): the window
-# of 512, which one forward tile of keys holds whole, and the full triangle.
+# of 512, which one forward tile of keys holds whole, and the full triangle;
+# and the Nemotron-H causal tower's attention (B1 S8192 H32/Hkv2 D128): sixteen
+# query heads to a key head.
 FLASH_SHAPES = {
     "gpt2": (16, 1024, 12, 12, 64, None), "mistral": (2, 4096, 32, 8, 128, None),
     "trinity_window": (1, 8192, 32, 4, 128, 2048), "trinity_full": (1, 8192, 32, 4, 128, None),
     "lfm2_full": (2, 8192, 32, 8, 64, None),
     "phi4_window": (1, 8192, 40, 20, 64, 512), "phi4_full": (1, 8192, 40, 20, 64, None),
+    "nemotron_full": (1, 8192, 32, 2, 128, None),
 }
 
 
@@ -86,7 +89,7 @@ def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
     128 experts held, 8 choices a token, the worst-case 65536 sorted pairs
     walked in chunks of 2048 by a loop the compiler keeps as a loop, around
     its own grouped-matmul call."""
-    from hypha_tpu.ops.grouped_matmul import grouped_swiglu
+    from hypha_tpu.ops.grouped_matmul import grouped_experts
 
     T, D, F, G, N = 8192, 2048, 1024, 8, 8192 * 8
 
@@ -100,7 +103,7 @@ def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
     )
 
     def fwd(x, wg, wu, wd, tok, wt, sizes):
-        return grouped_swiglu(x, wg, wu, wd, tok, wt, sizes)
+        return grouped_experts(x, (wg, wu, wd), tok, wt, sizes)
 
     def loss(x, wg, wu, wd, tok, wt, sizes):
         return fwd(x, wg, wu, wd, tok, wt, sizes).sum()
@@ -134,6 +137,56 @@ def test_the_selective_scan_compiles_for_v5e(chip, direction):
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9  # not the 2.7 GB of every state
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_ssd_scan_compiles_for_v5e(chip, direction):
+    """Mamba-2's scan at the Nemotron-H causal tower's widths: one sequence of
+    8192, 64 heads of 64 with a state of 128 in 8 groups, bf16 inputs and a
+    float32 step, chunk 128: batched products for the MXU, and nothing kept
+    for the backward pass but the inputs and the 64 boundary states (134 MB;
+    the states of every position would be 17 GB)."""
+    from hypha_tpu.ops.ssd_scan import ssd_scan
+
+    B, S, H, P, G, N = 1, 8192, 64, 64, 8, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (sds((B, S, H, P), jnp.bfloat16), sds((B, S, H), jnp.float32), sds((H,), jnp.float32),
+            sds((B, S, G, N), jnp.bfloat16), sds((B, S, G, N), jnp.bfloat16))
+
+    def fwd(x, dt, a, b, c):
+        return ssd_scan(x, dt, a, b, c)[0]
+
+    fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=tuple(range(5)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "convolution(" in text or "dot(" in text  # the products are the MXU's
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_grouped_product_of_two_matrix_experts_compiles_for_v5e(chip, direction):
+    """One rank's routed experts at the Nemotron-H causal tower's widths: 8192
+    tokens, 8 of 128 experts held, 6 choices a token, squared ReLU and no gate:
+    two grouped products a chunk where the gated expert has three."""
+    from hypha_tpu.ops.grouped_matmul import grouped_experts
+
+    T, D, F, G, N = 8192, 2688, 1856, 8, 8192 * 6
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (sds((T, D), jnp.bfloat16), (sds((G, D, F), jnp.bfloat16), sds((G, F, D), jnp.bfloat16)),
+            sds((N,), jnp.int32), sds((N,), jnp.float32), sds((G,), jnp.int32))
+
+    def fwd(x, ws, tok, wt, sizes):
+        return grouped_experts(x, ws, tok, wt, sizes, form="relu2")
+
+    fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=(0, 1, 3))
+    text = _compiled_text(fn, *args)
+    assert "ragged-dot" in text and " while(" in text
 
 
 @pytest.mark.parametrize("quant", ["bf16", "int8"])
