@@ -183,9 +183,12 @@ def write_delta(
     model: the file is :func:`frame_f32`'s head and then the leaves' own
     memory, written as it lies (:func:`write_exact`), with no ``tobytes``
     of anything. A leaf that is not C-contiguous is copied first, that leaf
-    alone, as every leaf always was normalised (on the TPU ``device_get``
-    hands back a few narrow matrices column-major: four routers of
-    2048 x 64 among LFM2's 49 leaves, 2 MB of 1.94 GB). The file loads with
+    alone, as every leaf always was normalised: on one thread, at about
+    0.55 GB/s (a TPU keeps some matrices column-major and ``device_get``
+    keeps the device's order: 0.81 GB of the 2.11 GB of a Nemotron-H cut).
+    The worker's ``extract_delta`` asks the device for row-major results, so
+    a leaf that arrives so is the exception, and ``encode.write``'s
+    ``copied_bytes`` names it. The file loads with
     ``safetensors`` to the keys, shapes and bytes ``save_file`` would have
     written, its leaves in the tree's order. Every other tree (bf16,
     int8/int4, "none" with a leaf of another dtype) is encoded and saved

@@ -18,11 +18,15 @@ the mean lowers to an ICI collective (parallel.collectives).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 __all__ = [
     "extract_delta",
+    "relaid",
     "merge_update",
     "apply_updates",
     "average_deltas",
@@ -31,10 +35,57 @@ __all__ = [
 ]
 
 
-@jax.jit
-def extract_delta(params, anchor):
-    """Pseudo-gradient Δθ = θ_t − θ_0 (both trees same structure)."""
+def _subtract(params, anchor):
     return jax.tree.map(lambda p, a: (p - a).astype(jnp.float32), params, anchor)
+
+
+@functools.lru_cache(maxsize=None)
+def _subtract_into(treedef, formats):
+    """:func:`_subtract` jitted to lay its results out as ``formats`` say: one
+    program per tree structure, so a round after the first finds its own
+    compiled."""
+    return jax.jit(_subtract, out_shardings=jax.tree.unflatten(treedef, formats))
+
+
+def _rows(leaf) -> tuple[int, ...]:
+    return tuple(range(leaf.ndim))
+
+
+def relaid(params) -> tuple[int, int]:
+    """How many leaves of ``params`` do not lie row-major on their device,
+    and the bytes of their part of the f32 delta: what :func:`extract_delta`
+    has the device transpose."""
+    sizes = [
+        int(p.size) * 4
+        for p in jax.tree.leaves(params)
+        if tuple(p.format.layout.major_to_minor) != _rows(p)
+    ]
+    return len(sizes), sum(sizes)
+
+
+def _subtraction_for(params):
+    """The jitted :func:`_subtract` for trees shaped and placed as ``params``."""
+    leaves, treedef = jax.tree.flatten(params)
+    formats = tuple(Format(Layout(major_to_minor=_rows(p)), p.sharding) for p in leaves)
+    return _subtract_into(treedef, formats)
+
+
+def extract_delta(params, anchor):
+    """Pseudo-gradient Δθ = θ_t − θ_0 (both trees same structure), every
+    leaf row-major: the order the wire file has.
+
+    A backend lays an array out as it likes (a v5e keeps an f32
+    ``[2688, 10304]`` column-major: a last dimension that is no multiple of
+    128 beside one that is), a jitted result takes the same layout by
+    default, and ``device_get`` keeps the device's order on the host, where
+    ``compress.write_delta`` would transpose such a leaf on one thread before
+    it can write the leaf's own memory. So the subtraction is compiled to
+    return every leaf row-major under its own sharding: where a leaf lies
+    otherwise the device transposes it inside the program that already runs
+    there, and where it lies so already, as on the CPU, the request is what
+    the compiler would have chosen. The values are the same bit for bit.
+    """
+    return _subtraction_for(params)(params, anchor)
 
 
 @jax.jit

@@ -75,6 +75,7 @@ from .diloco import (
     extract_delta,
     merge_update,
     merge_update_f32,
+    relaid,
 )
 from .serialization import flat_leaf_map, flatten_tree, replace_leaves, unflatten_like
 from .train import (
@@ -1376,6 +1377,13 @@ def run_training(
         for name in ("bytes", "leaves", "direct", "resident"):
             ph.set(name, getattr(read, name))
 
+    def note_relaid(ph, params) -> None:
+        """On ``encode.extract``: the leaves the device transposes for the
+        delta, and their bytes of it (``diloco.extract_delta``)."""
+        leaves, nbytes = relaid(params)
+        ph.set("relaid_leaves", leaves)
+        ph.set("relaid_bytes", nbytes)
+
     # The blocking sync's delta of the last round, still under the name it
     # was sent by: the file this round's ``encode.write`` writes over, so
     # that 4 B a parameter land in pages that exist and not in fresh ones.
@@ -1491,6 +1499,7 @@ def run_training(
                         lambda p, a: p - a, host_params, host_anchor
                     )
                 else:
+                    note_relaid(ph, state.params)
                     delta = extract_delta(state.params, anchor)
                     host_delta = jax.device_get(delta)
                 ph.set("bytes", _tree_nbytes(host_delta))
@@ -1522,6 +1531,11 @@ def run_training(
                 # the spare says afterwards which it was: it has a name.
                 over = claim_spare(spare_delta) if spare_delta else None
                 spare_delta = None
+                # What ``write_delta`` has to copy before it can write a
+                # leaf's own memory: ``extract_delta`` leaves it nothing.
+                copied_bytes = sum(
+                    int(v.nbytes) for v in wire_flat.values() if not v.flags.c_contiguous
+                )
                 with open(over, "rb") if over else contextlib.nullcontext() as was:
                     compress.write_delta(
                         delta_path, wire_flat, wire_codec, ef=delta_ef, over=over
@@ -1532,6 +1546,7 @@ def run_training(
                 ph.set("bytes", bytes_up)
                 ph.set("leaves", len(wire_flat))
                 ph.set("pages", pages)
+                ph.set("copied_bytes", copied_bytes)
         with sync_phase(
             "upload", parent=round_tp, key="upload_s",
             round=round_num, codec=wire_codec, bytes=bytes_up,
@@ -1690,6 +1705,7 @@ def run_training(
             round=round_num, codec=wire_codec,
         ) as enc:
             with sync_phase("encode.extract", parent=enc.span, usage=True) as ph:
+                note_relaid(ph, state.params)
                 delta = extract_delta(state.params, anchor)
                 host_delta = jax.device_get(delta)
                 ph.set("bytes", _tree_nbytes(host_delta))

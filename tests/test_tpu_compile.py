@@ -189,6 +189,47 @@ def test_the_grouped_product_of_two_matrix_experts_compiles_for_v5e(chip, direct
     assert "ragged-dot" in text and " while(" in text
 
 
+# The leaves a worker's delta is made of, f32: Nemotron-H's Mamba-2
+# in-projection and its experts' first matrices (a last dimension that is no
+# multiple of 128 beside one that is: the v5e keeps them column-major), its
+# experts' second matrices, LFM2's routers, Mistral's gate projection.
+DELTA_LEAVES = {
+    "nemotron_in_proj": ((2688, 10304), False), "nemotron_experts_up": ((8, 2688, 1856), False),
+    "nemotron_experts_down": ((8, 1856, 2688), True), "lfm2_router": ((2048, 64), False),
+    "mistral_gate": ((4096, 14336), True),
+}
+
+
+@pytest.mark.parametrize("leaf", list(DELTA_LEAVES))
+def test_the_delta_is_compiled_to_leave_the_v5e_row_major(chip, leaf):
+    """``extract_delta``'s program for a leaf that lies as the v5e's compiler
+    lays that shape out by default, which is how a train step hands it back:
+    the result is row-major, and where the default already is, the output's
+    format is the default's: the program is the one it was."""
+    from jax.experimental.layout import Format
+
+    from hypha_tpu.executor import diloco
+
+    shape, lies_row_major = DELTA_LEAVES[leaf]
+    rows = tuple(range(len(shape)))
+    plain = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    default = jax.jit(diloco._subtract).lower({"w": plain}, {"w": plain}).compile()
+    (lies, _), _ = default.input_formats
+    assert default.output_formats["w"] == lies["w"]
+    assert (tuple(lies["w"].layout.major_to_minor) == rows) == lies_row_major
+    tree = {"w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=Format(lies["w"].layout, chip))}
+    compiled = diloco._subtraction_for(tree).lower(tree, tree).compile()
+    out = compiled.output_formats["w"]
+    assert tuple(out.layout.major_to_minor) == rows and out.sharding == chip
+    assert out.layout.tiling == lies["w"].layout.tiling
+    if lies_row_major:
+        assert out == default.output_formats["w"]
+    else:
+        # The transposing copy beside the subtraction, and room for it.
+        assert compiled.cost_analysis()["bytes accessed"] > default.cost_analysis()["bytes accessed"]
+        assert compiled.memory_analysis().temp_size_in_bytes <= 170e6
+
+
 @pytest.mark.parametrize("quant", ["bf16", "int8"])
 def test_ragged_paged_attention_compiles_for_v5e(chip, quant):
     """The serving decode kernel at head_dim 128 (the Mosaic lane width),
