@@ -706,7 +706,7 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
         # A stack of more than one kind of layer says what it holds. The
         # fallbacks' configurations are a library's: no field is taken for granted.
         kinds = list(kinds)
-        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "scan_chunk", "ssd_chunk", "expert_form")}
+        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "value_dim", "scan_chunk", "ssd_chunk", "expert_form")}
         log.info(
             "operators: %s%s",
             " ".join(f"{k}={kinds.count(k)}" for k in dict.fromkeys(kinds)),

@@ -34,14 +34,18 @@ Mamba-kind layer is a GMU and an attention-kind layer cross-attention.
 ``layers_run`` names the source layers a cut runs (empty: all of them); a
 cross-decoder layer may be run only with the layer it reads from.
 
-**Departures**: the attention kernel takes one head size for keys and values,
-so the doubled value is two calls over the same queries and keys (``[v1, v1]``
-then ``[v2, v2]`` over the ``k1`` and ``k2`` heads), as the source's own
-flash path does it; the two softmax maps are therefore computed twice. The
-subtraction and the norm after it keep only the kernels' outputs for the
-backward pass (``jax.checkpoint`` on that elementwise epilogue). Weights
-are seeded (``A_log``, ``D`` and ``b_dt`` as Mamba-1 publishes them, the rest
-the program's initializers); no converter for published weights exists.
+**Departures**: the attention kernel takes a value wider than its keys
+(``ops/flash_attention.py``), so a layer is one call over the ``k1`` and ``k2``
+heads of 64 with the pair's value ``[v1, v2]`` of 128 laid beside itself, and
+each of the two softmax maps is computed once; the source's own flash path,
+whose kernel takes one head size, makes two calls (``[v1, v1]`` then
+``[v2, v2]``) and computes each map twice. The kernel gives a key head the
+value head of the same index, so the value lies twice in memory, once beside
+the ``k1`` heads and once beside the ``k2`` heads. The subtraction and the
+norm after it keep only the kernel's output for the backward pass
+(``jax.checkpoint`` on that elementwise epilogue). Weights are seeded
+(``A_log``, ``D`` and ``b_dt`` as Mamba-1 publishes them, the rest the
+program's initializers); no converter for published weights exists.
 Training only: a cached decode would keep a scan state and a conv state
 beside the key-value blocks, and does not exist.
 """
@@ -88,6 +92,11 @@ class Phi4FlashConfig:
     dtype: str = "bfloat16"
 
     scan_chunk: ClassVar[int] = scan_op.CHUNK  # set-up logs it; no key sets it
+
+    @property
+    def value_dim(self) -> int:
+        """A pair's value, which the attention kernel is handed whole; set-up logs it."""
+        return 2 * self.head_dim
 
     def __post_init__(self):
         run = tuple(self.layers_run) or tuple(range(self.num_layers))
@@ -207,32 +216,32 @@ class _DiffAttention(nn.Module):
             k, v = (t.reshape(batch, s, kv_heads, hd) for t in (k, v))
         # adjacent pairs: [.., pair, 2, hd] -> the first of each pair, then the second
         halves = lambda t, n: t.reshape(batch, s, n // 2, 2, hd).swapaxes(2, 3).reshape(batch, s, n, hd)
-        q, k12, v12 = halves(q, heads), halves(k, kv_heads), halves(v, kv_heads)
-        v1, v2 = jnp.split(v12, 2, axis=2)
+        q, k12 = halves(q, heads), halves(k, kv_heads)
+        # a pair's two values side by side are its one value of twice the head size
+        value = v.reshape(batch, s, kv_heads // 2, 2 * hd)
         impl = self.attn_impl or dot_product_attention
         window = cfg.sliding_window if self.kind == WINDOW else None
         with jax.named_scope("attention"):
-            # one head size a call: A1 and A2 over v1, then over v2
-            o1 = impl(q, k12, jnp.concatenate([v1, v1], axis=2), causal=True, window=window)
-            o2 = impl(q, k12, jnp.concatenate([v2, v2], axis=2), causal=True, window=window)
+            # one call: A1 over the k1 heads and A2 over the k2 heads, each times
+            # the whole [v1, v2], which is therefore laid beside itself
+            o = impl(q, k12, jnp.concatenate([value, value], axis=2), causal=True, window=window)
         lam = [self.param(f"lambda_{n}", nn.initializers.normal(0.1), (hd,), jnp.float32)
                for n in ("q1", "k1", "q2", "k2")]
         subln = self.param("subln", nn.initializers.ones, (2 * hd,), jnp.float32)
         init = 0.8 - 0.6 * math.exp(-0.3 * self.source)
 
-        def combine(o1, o2, lam, subln):
+        def combine(o, lam, subln):
             with jax.named_scope("diff_attention"):
                 full = jnp.exp(jnp.sum(lam[0] * lam[1])) - jnp.exp(jnp.sum(lam[2] * lam[3])) + init
-                o = jnp.concatenate([o1, o2], axis=-1).astype(jnp.float32)  # [B, S, heads, 2 hd]
-                a1, a2 = jnp.split(o, 2, axis=2)
+                a1, a2 = jnp.split(o.astype(jnp.float32), 2, axis=2)  # [B, S, heads / 2, 2 hd] each
                 o = rms_norm(a1 - full * a2, subln, cfg.layer_norm_eps) * (1.0 - init)
                 return o.astype(dtype).reshape(batch, s, heads * hd)
 
-        # The combine is elementwise over the kernels' two outputs, which the
-        # kernels' own backward pass keeps anyway: it keeps nothing else and makes
-        # its float32 intermediates (20 KB a token and layer) again, as a fused
+        # The combine is elementwise over the kernel's output, which the kernel's
+        # own backward pass keeps anyway: it keeps nothing else and makes its
+        # float32 intermediates (20 KB a token and layer) again, as a fused
         # epilogue would.
-        o = jax.checkpoint(combine)(o1, o2, lam, subln)
+        o = jax.checkpoint(combine)(o, lam, subln)
         return nn.Dense(e, dtype=dtype, name="out_proj")(o), (k, v)
 
 

@@ -1,7 +1,9 @@
 """Flash attention (forward + backward) as pallas TPU kernels.
 
 Online-softmax tiling: the grid is (batch·head, q-block, k-block); each cell
-loads one (block_q, d) Q tile and one (block_k, d) K/V tile into VMEM — K/V
+loads one (block_q, d) Q tile, one (block_k, d) K tile and one (block_k, dv)
+V tile into VMEM (the value's width is its own: the output, its cotangent and
+dV are dv wide, the scores and their scale are the keys') — K/V
 stream through VMEM one tile at a time (the k-block axis is the innermost,
 sequentially-executed grid dimension), so VMEM holds O(block² + block·d)
 bytes regardless of sequence length and the [Sq, Sk] score matrix never
@@ -43,8 +45,8 @@ bundled TPU kernel: scratch is [block_q, 128] and L is materialized
 Numerics (forward AND grad) are checked against the XLA reference
 (ops/attention.py) in the test suite via interpret mode.
 
-Falls back to the XLA path when shapes don't tile (block divisibility,
-head_dim > 128) — callers can always use :func:`flash_attention`.
+Falls back to the XLA path when shapes don't tile (block divisibility, keys
+or values wider than 128) — callers can always use :func:`flash_attention`.
 """
 
 from __future__ import annotations
@@ -166,14 +168,14 @@ def _fwd_kernel(
 
     @_run
     def _body():
-        d = q_ref.shape[-1]
+        dv = acc_scr.shape[-1]  # the value's width, which need not be the keys'
         # Inputs stay in their storage dtype (bf16): the MXU runs bf16×bf16
         # at full rate with f32 accumulation (preferred_element_type); an
         # f32 upcast before the dot would cut matmul throughput ~8× (the
         # r3 on-chip finding: f32-dot kernel was SLOWER than XLA dense).
         q = q_ref[0]  # [BQ, D]
         k = k_ref[0]  # [BK, D]
-        v = v_ref[0]
+        v = v_ref[0]  # [BK, Dv]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
@@ -187,7 +189,7 @@ def _fwd_kernel(
         alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)[:, None]
-        acc_scr[...] = acc_scr[...] * _to_lanes(alpha, d) + jnp.dot(
+        acc_scr[...] = acc_scr[...] * _to_lanes(alpha, dv) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
@@ -229,7 +231,7 @@ def _dq_kernel(
         q = q_ref[0]  # bf16-in, f32-accumulate (see fwd kernel note)
         k = k_ref[0]
         v = v_ref[0]
-        do = do_ref[0]  # [BQ, D]
+        do = do_ref[0]  # [BQ, Dv]
         o = o_ref[0]
         lse = _to_lanes(lse_ref[0], block_k)  # [BQ, BK]
         delta = jnp.sum(
@@ -333,7 +335,7 @@ def _fwd_impl(
     q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
     window=None,
 ):
-    """q: [B·H, S, D], k/v: [B·Hkv, S, D] → (o [B·H, Sq, D],
+    """q: [B·H, S, D], k: [B·Hkv, S, D], v: [B·Hkv, S, Dv] → (o [B·H, Sq, Dv],
     lse f32 [B·H, Sq, 128] lane-replicated — see layout note in module doc).
 
     ``window=None`` builds the call as it was before the window existed
@@ -342,7 +344,7 @@ def _fwd_impl(
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, dv = k.shape[1], v.shape[-1]
     num_q, num_k = seq_q // block_q, seq_k // block_k
     grid = (bh, num_q, num_k)
     kv = _kv_index(n_heads, n_kv)
@@ -363,20 +365,20 @@ def _fwd_impl(
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), k_map),
-            pl.BlockSpec((1, block_k, d), k_map),
+            pl.BlockSpec((1, block_k, dv), k_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
@@ -387,7 +389,8 @@ def _bwd_impl(
     q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
     window=None,
 ):
-    """Cotangents: dq [B·H, Sq, D]; dk/dv [B·Hkv, Sk, D] (GQA cotangents
+    """Cotangents: dq [B·H, Sq, D]; dk [B·Hkv, Sk, D]; dv [B·Hkv, Sk, Dv], as
+    wide as ``v``, ``o`` and ``do`` (GQA cotangents
     accumulate over the query heads sharing each kv head inside the dkv
     kernel — no repeat/sum round-trip through HBM)."""
     import jax.experimental.pallas as pl
@@ -395,6 +398,7 @@ def _bwd_impl(
 
     bh, seq_q, d = q.shape
     bh_kv, seq_k, _ = k.shape
+    dv = v.shape[-1]
     num_q, num_k = seq_q // block_q, seq_k // block_k
     reps = n_heads // n_kv
     kv = _kv_index(n_heads, n_kv)
@@ -403,6 +407,8 @@ def _bwd_impl(
     k_map = _k_index_map(kv, block_q, block_k, window)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     k_spec = pl.BlockSpec((1, block_k, d), k_map)
+    o_spec = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
+    v_spec = pl.BlockSpec((1, block_k, dv), k_map)
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kwargs = _tpu_kwargs(interpret)
 
@@ -417,7 +423,7 @@ def _bwd_impl(
             **band,
         ),
         grid=(bh, num_q, num_k),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -441,6 +447,8 @@ def _bwd_impl(
 
     q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, r: (qh(b, r), q_tile(j, r), 0))
     k_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
+    o_spec_t = pl.BlockSpec((1, block_q, dv), lambda b, j, r: (qh(b, r), q_tile(j, r), 0))
+    v_spec_t = pl.BlockSpec((1, block_k, dv), lambda b, j, r: (b, j, 0))
     row_spec_t = pl.BlockSpec(
         (1, block_q, _LANES), lambda b, j, r: (qh(b, r), q_tile(j, r), 0)
     )
@@ -456,15 +464,15 @@ def _bwd_impl(
             **band,
         ),
         grid=(bh_kv, num_k, reps * num_q),
-        in_specs=[q_spec_t, k_spec_t, k_spec_t, q_spec_t, q_spec_t, row_spec_t],
-        out_specs=[k_spec_t, k_spec_t],
+        in_specs=[q_spec_t, k_spec_t, v_spec_t, o_spec_t, o_spec_t, row_spec_t],
+        out_specs=[k_spec_t, v_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh_kv, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh_kv, seq_k, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
@@ -517,7 +525,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(
     q: jnp.ndarray,  # [B, Sq, H, D]
     k: jnp.ndarray,  # [B, Sk, Hkv, D]
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, Sk, Hkv, Dv]
     *,
     causal: bool = True,
     softmax_scale: float | None = None,
@@ -530,6 +538,13 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Flash attention with the framework's [B, S, H, D] convention and GQA.
 
+    The value has a width of its own: ``v`` is ``[B, Sk, Hkv, Dv]`` and the
+    result ``[B, Sq, H, Dv]``, with the scale ``D ** -0.5`` of the keys unless
+    one is given. A softmax map that weighs a value wider than its keys
+    (differential attention's ``[v1, v2]``) is one call, not one a slice of the
+    value; where ``Dv == D`` the calls are built as they were before ``Dv``
+    existed.
+
     ``window`` (causal only) is local attention: query i sees keys in
     (i - window, i]. Tiles wholly outside that band are neither computed nor
     loaded, in the forward and both backward kernels, so a window layer at
@@ -538,7 +553,7 @@ def flash_attention(
 
     Differentiable: a custom VJP runs the recomputation backward kernels, so
     this is safe inside the jitted ``value_and_grad`` train step. Tiling
-    requires Sq % block_q == 0, Sk % block_k == 0 and D <= 128; anything else
+    requires Sq % block_q == 0, Sk % block_k == 0 and max(D, Dv) <= 128; anything else
     transparently falls back to the XLA reference path (same numerics, denser
     memory traffic). ``interpret=None`` auto-selects interpret mode off-TPU
     so tests exercise the kernels on CPU; code on the chip path passes
@@ -557,6 +572,7 @@ def flash_attention(
     """
     B, Sq, H, D = q.shape
     _, Sk, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     if window is not None:
         if not causal or Sq != Sk or window < 1:
             raise ValueError("window needs causal self-attention and window >= 1")
@@ -572,7 +588,7 @@ def flash_attention(
         or block_k is None
         or not _legal_block(block_q, Sq)
         or not _legal_block(block_k, Sk)
-        or D > 128
+        or max(D, Dv) > 128
     ):
         return dot_product_attention(
             q, k, v, causal=causal, softmax_scale=softmax_scale, window=window
@@ -620,4 +636,4 @@ def flash_attention(
             causal, scale, block_q, block_k, block_q_bwd, block_k_bwd,
             interpret, H, Hkv, window,
         )
-        return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+        return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3)

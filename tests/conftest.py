@@ -77,6 +77,13 @@ def pytest_configure(config):
 # their lists), which the sixth cell's ten entries, appended, outdate.
 # ``tests/perfbench/test_rehearsal_nemotron_h.py`` holds every assertion of that
 # test again, by name and not by place.
+#
+# Since PR 48 a sixth, outdated by the program as the third was:
+# ``test_rehearsal_phi4flash.py`` holds the worker's ``operators:`` line to end
+# ``head_dim=8 scan_chunk=128``, and the line now states the width of the value
+# the attention kernel is handed between the two (``value_dim=16``).
+# ``tests/test_phi4flash_operators_line.py`` holds both assertions of that test
+# again, over one more run of the same rehearsal, with the line as it reads now.
 _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_rehearsal.py::test_a_traced_run_reports_the_per_layer_metrics_the_cpu_can_give":
         ('len(manifest["per_layer"]) - 7',
@@ -93,6 +100,9 @@ _COUNTS_A_NEW_CELL_OUTDATES = {
     "tests/perfbench/test_rehearsal_phi4flash.py::test_the_manifest_lists_the_nine_metrics_for_the_one_cell":
         ('m["per_layer"][-len(NEW):]',
          "Phi-4's nine were the last entries until a sixth cell appended its own"),
+    "tests/perfbench/test_rehearsal_phi4flash.py::test_the_worker_says_which_operators_it_holds_and_the_scans_chunk":
+        ("assert re.search(",
+         "the line ended `head_dim=8 scan_chunk=128` until it stated the value's width between the two (PR 48)"),
 }
 
 

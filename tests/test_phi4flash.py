@@ -1,8 +1,9 @@
 """The phi4flash family (models/phi4flash.py) and what it forced: a layer's
 kind by the source's rule on its index, two tensors handed from one layer to
-later ones outside the residual stream, differential attention over the
-attention kernel's one head size, LayerNorm, a dense model's tied head through
-a chunked step of its own, and the other families' programs left as they were."""
+later ones outside the residual stream, differential attention as one call of
+the attention kernel with a value twice as wide as its keys, LayerNorm, a dense
+model's tied head through a chunked step of its own, and the other families'
+programs left as they were."""
 
 from __future__ import annotations
 
@@ -273,7 +274,7 @@ def test_the_worker_logs_which_operators_it_holds_and_the_scans_chunk(ids, caplo
     with caplog.at_level(logging.INFO, logger="hypha.executor.training"):
         training._init_model(cfg, None, "/nonexistent", {"input_ids": np.asarray(ids)})
     assert ("operators: window_attention=1 mamba=1 full_attention=1 gmu=1 cross_attention=1 "
-            f"head_dim=8 scan_chunk={CHUNK}") in caplog.text
+            f"head_dim=8 value_dim=16 scan_chunk={CHUNK}\n") in caplog.text + "\n"
 
 
 def test_the_model_learns_a_counting_sequence_through_the_chunked_step():

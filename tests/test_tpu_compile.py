@@ -11,6 +11,7 @@ Skipped where the topology cannot be described (no libtpu).
 
 from __future__ import annotations
 
+import hashlib
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
@@ -56,22 +57,27 @@ def _compiled_text(fn, *shapes) -> str:
 # kernel (B1 S8192 H40/Hkv20 D64, two query heads to a key head): the window
 # of 512, which one forward tile of keys holds whole, and the full triangle;
 # and the Nemotron-H causal tower's attention (B1 S8192 H32/Hkv2 D128): sixteen
-# query heads to a key head.
+# query heads to a key head. A seventh number is the value's width where it is
+# not the keys': since PR 48 Phi-4's layers make one call each with the pair's
+# value of 128 beside keys of 64 (the two entries at 64 are what it called until
+# then, and LFM2's head size still).
 FLASH_SHAPES = {
     "gpt2": (16, 1024, 12, 12, 64, None), "mistral": (2, 4096, 32, 8, 128, None),
     "trinity_window": (1, 8192, 32, 4, 128, 2048), "trinity_full": (1, 8192, 32, 4, 128, None),
     "lfm2_full": (2, 8192, 32, 8, 64, None),
     "phi4_window": (1, 8192, 40, 20, 64, 512), "phi4_full": (1, 8192, 40, 20, 64, None),
     "nemotron_full": (1, 8192, 32, 2, 128, None),
+    "phi4_window_v128": (1, 8192, 40, 20, 64, 512, 128), "phi4_full_v128": (1, 8192, 40, 20, 64, None, 128),
 }
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
-def test_flash_attention_compiles_for_v5e(chip, shape, direction):
-    B, S, H, Hkv, D, window = FLASH_SHAPES[shape]
-    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=chip)
-    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16, sharding=chip)
+def _flash_case(shape, direction, sharding=None):
+    """(function, its three arguments' shapes) of one entry and direction."""
+    B, S, H, Hkv, D, window, *value = FLASH_SHAPES[shape]
+    Dv = value[0] if value else D
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=sharding)
+    k = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16, sharding=sharding)
+    v = jax.ShapeDtypeStruct((B, S, Hkv, Dv), jnp.bfloat16, sharding=sharding)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False, window=window)
@@ -79,8 +85,67 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
-    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))
-    assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)
+    return (fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2))), (q, k, v)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_attention_compiles_for_v5e(chip, shape, direction):
+    fn, args = _flash_case(shape, direction, chip)
+    text = _compiled_text(fn, *args)
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+
+
+# --------------------------------------------------------------------------
+# Where the value is as wide as the keys, the calls are the parent's
+# --------------------------------------------------------------------------
+
+# The kernel learnt a value width of its own (PR 48). Every cell but Phi-4's
+# hands it values as wide as its keys and must run the program it ran: the text
+# of the traced calls (grid, blocks, scratch, compiler parameters, the kernels'
+# bodies) and of every block's index map, hashed by this file run as a script
+# (``PYTHONPATH=<a checkout> python tests/test_tpu_compile.py``) on commit
+# bb23a3c and on this tree. No line number or file path stands in that text.
+CALLS_AT_THE_PARENT = {
+    ("gpt2", "fwd"): "2a8eb64fbf599a7bf9ca2eb2a47e713e295c1189c05efa66ea0bc5594e872f6a",
+    ("gpt2", "bwd"): "71d7ce239e29221bad84545778b21cd0da0c19a66c78aaf0b19d717cb52b8ad9",
+    ("mistral", "fwd"): "ca09052257d3e1082f09d9f1b1bd2957bbec044d49928dbd3bf1b7995a76d9b1",
+    ("mistral", "bwd"): "3dfbba122885388b10b902824238c907e4e6521c4d31d3e0b666eb605e956b86",
+    ("trinity_window", "fwd"): "411c9d4d820e4491a4b9a1fcbd76e8cbf17bf0ba4d4cfe332938757499296875",
+    ("trinity_window", "bwd"): "13ad52f842f37cf8a3baa02915de88c34eb02921fd5e42e414ef740b129272ff",
+    ("trinity_full", "fwd"): "6c6bc3febbc62b9932e1d1172349566a452a4610fe0c536e51c9e9af08283ad4",
+    ("trinity_full", "bwd"): "1f991357dab9d64505b229341e4691d81978abf409e3a7a580181100c0f87195",
+    ("lfm2_full", "fwd"): "f775895e03643c94c2ae5f440105b974245fbe86acd52f8b052384a8a61701c8",
+    ("lfm2_full", "bwd"): "b4cbafc26178f49ef32773f999a2e86d8b028fadef9fec137e68919834a7478c",
+    ("phi4_window", "fwd"): "d9f9edfb8482035b6f29fda15d8de06674a8ce4432a344bb085e259b46565bc1",
+    ("phi4_window", "bwd"): "d4b5ec02499bd7b1351ba174832371e00fbb204c26cf4ae0f49f583aa73d870b",
+    ("phi4_full", "fwd"): "e36c51502b6ac6f4fbd300cb3e76efa1c60f38fe3cdaf6b36a9a8c5a4246a5bd",
+    ("phi4_full", "bwd"): "6e6d063d0427595882667eba680b7d8e8388656f4aa05a193fce869c0d614a93",
+    ("nemotron_full", "fwd"): "371b93ad82e2ebdbde17996760d8f9eff5bd65f3b07c89ff719ebf3c166b671f",
+    ("nemotron_full", "bwd"): "d0ea65089428f74a2ef1776d92565efb54d8b89fa5f655be36a11e6c28006885",
+}
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(inner)
+
+
+def _calls_hash(shape, direction) -> str:
+    fn, args = _flash_case(shape, direction)
+    traced = jax.make_jaxpr(fn)(*args)
+    maps = [str(m.index_map_jaxpr) for call in _pallas_calls(traced.jaxpr)
+            for m in call.params["grid_mapping"].block_mappings]
+    return hashlib.sha256("\n".join([str(traced), *maps]).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", [s for s, dims in FLASH_SHAPES.items() if len(dims) == 6])
+def test_equal_widths_trace_to_the_calls_of_the_parent_commit(shape, direction):
+    assert _calls_hash(shape, direction) == CALLS_AT_THE_PARENT[shape, direction]
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -257,3 +322,10 @@ def test_ragged_paged_attention_compiles_for_v5e(chip, quant):
         decode, sds((B, 1, Hq, D), jnp.bfloat16), kv, sds((B,), jnp.int32)
     )
     assert "tpu_custom_call" in text
+
+
+if __name__ == "__main__":  # the hashes, for CALLS_AT_THE_PARENT
+    for name, dims in FLASH_SHAPES.items():
+        for way in ("fwd", "bwd"):
+            if len(dims) == 6:
+                print(f'    ("{name}", "{way}"): "{_calls_hash(name, way)}",')
