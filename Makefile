@@ -3,7 +3,7 @@
 
 PYTHON ?= python
 
-.PHONY: lint lint-graph lint-fixtures test compressbench streambench ftbench-ps ftbench-scheduler shardbench servbench servbench-smoke swapbench swapbench-smoke hetbench obsbench obsbench-smoke databench databench-smoke
+.PHONY: lint lint-graph lint-fixtures test
 
 # Whole-program by default: one parse per file feeds the file-local
 # families, the project graph, and the cross-file passes alike.
@@ -38,131 +38,3 @@ lint-fixtures:
 test:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider
-
-# Compressed delta transport: bytes-on-wire / wall-clock / fidelity per
-# delta_codec (docs/performance.md "Quantized delta transport").
-compressbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/compressbench.py \
-		--out COMPRESSBENCH_r06.json
-
-# Streaming outer sync: wall-clock/round, worker idle fraction and peak
-# bytes-in-flight for sync_mode blocking|overlap|stream, plus the
-# delayed-update-correction convergence check (docs/performance.md
-# "Streaming outer sync"). Asserts the PR's acceptance thresholds.
-streambench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/streambench.py \
-		--out STREAMBENCH_r07.json
-
-# Sharded parameter service: aggregate delta bytes/s and round wall-clock
-# at 1/2/4 PS shards at a fixed worker count (asserts >=2.5x aggregate
-# bandwidth at 4 shards), plus a real-executor kill-one-shard recovery
-# run (bit-exact, surviving shards keep closing rounds). Writes
-# SHARDBENCH_r08.json (docs/performance.md "Sharded parameter service").
-shardbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/shardbench.py \
-		--chaos kill-ps --out SHARDBENCH_r08.json
-
-# Paged KV serving r08: the r07 sections (block-granular admission >=1.5x
-# concurrency at equal KV memory, late-arrival p50 <=2x under a 4k prompt,
-# routed 2-worker >=1.8x under 100 clients, prefix-cache TTFT and tok/s
-# >=2x, n-gram speculation step-speedup >=1.3x, ragged paged attention,
-# int8 KV blocks, model-draft speculation) plus the fleet prefix cache
-# (cold-start TTFT via cross-worker block pull within 2x of a local hit
-# and >=2x better than re-prefill, fleet hit rate above the local-only
-# baseline) and KV migration vs recompute (prompt-length crossover,
-# LinkTable policy recomputing under a bw-cap link). Writes
-# SERVBENCH_<round>.json — the --round tag keeps re-runs from overwriting
-# older artifacts (docs/serving.md / docs/performance.md).
-servbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/servbench.py --round r08
-
-# Seconds-scale servbench for CI (tiny sections, same assertions with
-# smoke-adjusted floors).
-servbench-smoke:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/servbench.py --round smoke \
-		--smoke --out /tmp/SERVBENCH_smoke.json
-
-# Live weight streaming: closed-loop clients while >=5 outer rounds
-# hot-swap through the pool (0 failed/blocked requests, tok/s >=0.9x the
-# static-weights run, SLO watchdog green, completion stamps on-schedule),
-# per-round token provenance vs a host-side θ0+Σu reference fold, and
-# prefix-cache hit-rate recovery >=80% within 2 swap intervals. Writes
-# SWAPBENCH_<round>.json (docs/serving.md "Live weight streaming").
-swapbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/swapbench.py --round r14
-
-# Seconds-scale swapbench for CI (tiny sections, same assertions with
-# smoke-adjusted floors).
-swapbench-smoke:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/swapbench.py --round smoke \
-		--smoke --out /tmp/SWAPBENCH_smoke.json
-
-# WAN-adaptive outer rounds: a 4-worker pool with one bandwidth-capped +
-# one 4x slow-CPU peer, adaptive (straggler-adaptive inner steps +
-# per-link codec selection) vs static vs a uniform reference. Asserts
-# round wall <= 0.6x static, zero quorum drops adaptive vs >= 1/round
-# static, and final loss within 1e-3 of the uniform pool. Writes
-# HETBENCH_r09.json (docs/performance.md "Heterogeneous pools").
-hetbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/hetbench.py \
-		--out HETBENCH_r09.json
-
-# Durable PS: kill the parameter server mid-round, restart it, and prove
-# the job completes with bounded recovery wall-clock (ft.durable journal +
-# generation handshake). Writes FTBENCH_kill-ps-2.json.
-ftbench-ps:
-	$(PYTHON) bench.py --chaos kill-ps:2
-
-# Durable control plane: kill the SCHEDULER mid-round, restart it under the
-# same peer id, and prove the restarted generation re-adopts the live
-# executions in place (ft.durable DurableScheduler journal + the
-# SchedulerHello/AdoptAck handshake): zero lost rounds, zero full restarts,
-# final weights bit-equal to a no-kill baseline, added wall-clock at most
-# one round + a fixed restart budget. Writes FTBENCH_kill-scheduler-2.json.
-ftbench-scheduler:
-	$(PYTHON) bench.py --chaos kill-scheduler:2
-
-# Observability planes: end-to-end round tracing (traced round wall
-# within 3% of untraced; a bw-capped peer's upload span named as the
-# stall by the merged timeline) AND the live metrics plane (metrics-on
-# round wall within 3% of off; the fleet bandwidth rollup names the
-# bw-capped peer's gauge as the outlier; gap-free loss curves across a
-# kill-worker rejoin; reporting-off wire golden-pinned). Writes
-# OBSBENCH_r11.json + OBSBENCH_r11.telemetry.json (docs/observability.md).
-obsbench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/obsbench.py
-
-# CI-sized obsbench (the obs.yml workflow's smoke path).
-obsbench-smoke:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/obsbench.py --smoke --skip-trace \
-		--out /tmp/OBSBENCH_smoke.json
-
-# Async input pipeline (ISSUE 15): the same DiLoCo job with the
-# synchronous loader vs slice prefetch + zero-copy batching + deferred
-# device sync, under a bw-capped data link (ft.chaos bw-cap:data).
-# Asserts input-wait fraction and slice-boundary stall >=3x lower with
-# prefetch, tokens/s uplift on a slice-boundary workload, bit-exact loss
-# parity, and a kill-the-data-node-mid-prefetch recovery. Writes
-# DATABENCH_r13.json (docs/performance.md "Async input pipeline").
-databench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/databench.py \
-		--out DATABENCH_r13.json
-
-# CI-sized databench (the data.yml workflow's smoke path).
-databench-smoke:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/databench.py --smoke \
-		--out /tmp/DATABENCH_smoke.json
-
-# Control-plane scale harness (ISSUE 14): 128 in-process workers on the
-# memory fabric, star vs multi-level reduce/broadcast trees, plus a
-# kill-a-mid-tree-reducer chaos run. Asserts tree PS egress <= 0.25x
-# star at N=128, sublinear round wall + scheduler CPU, zero
-# double-counted deltas under the kill. Writes SCALEBENCH_r12.json.
-scalebench:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/scalebench.py \
-		--out SCALEBENCH_r12.json
-
-# CI-sized scalebench (the scale.yml workflow's smoke path: N in {4,16}).
-scalebench-smoke:
-	JAX_PLATFORMS=cpu $(PYTHON) benchmarks/scalebench.py --smoke \
-		--out /tmp/SCALEBENCH_smoke.json

@@ -96,7 +96,7 @@ WAIVERS: dict[str, str] = {
 
 # The global waiver table is judged for staleness only when the canonical
 # wire-surface module is part of the linted tree: a fixture package or a
-# benchmarks/ run declares none of the waived names, and that absence says
+# run over scripts declares none of the waived names, and that absence says
 # nothing about whether the waiver went stale.  Explicitly-passed waivers
 # (``check(project, waivers=...)``) are always enforced.
 WAIVER_ANCHOR = "hypha_tpu.messages"

@@ -75,7 +75,7 @@ are exactly the pre-cache pool's.
 
 The reference has no inference path at all (its Executor union is
 Train|Aggregate, crates/messages/src/lib.rs:627-631) — this is net-new
-capability, benchmarked in SERVBENCH (late-arrival p50 + aggregate tok/s).
+capability.
 """
 
 from __future__ import annotations

@@ -176,8 +176,8 @@ def chunked_causal_ce(
     logsumexp − picked, and drops it; ``jax.checkpoint`` makes the
     backward recompute the chunk's logits instead of storing them. Peak
     loss memory falls from O(B·S·V) to O(B·chunk·V) — the [B,S,50257]
-    f32 logits tensor is what OOMs the GPT-2 bench at B≥24
-    (MFUPROBE_r04.json). Labels == -100 are ignored, matching
+    f32 logits tensor is what ran GPT-2 out of device memory at B≥24.
+    Labels == -100 are ignored, matching
     :func:`compute_loss` CE semantics exactly.
     """
     B, S, D = hidden.shape

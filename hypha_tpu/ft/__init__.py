@@ -14,7 +14,7 @@ defined over whichever replicas actually reported:
   adaptive.py   — WAN-adaptive outer rounds: straggler-adaptive per-worker
                   inner steps (EWMA round-trip history) + per-link codec
                   selection from a measured-bandwidth table
-  chaos.py      — deterministic fault injection for tests and bench.py
+  chaos.py      — deterministic fault injection for tests and their harnesses
                   (kill / delay / partition events + steady degrade modes:
                   slow-CPU workers, per-link bandwidth caps, jitter)
 

@@ -4,7 +4,7 @@ Real failures are timing accidents; tests need them on a schedule. A
 :class:`ChaosController` watches the same per-round metrics stream the
 orchestrator's MetricsBridge sees and fires scripted actions at exact round
 boundaries, against the in-process :class:`~hypha_tpu.worker.runtime.
-WorkerNode` objects a test or ``bench.py --chaos`` holds:
+WorkerNode` objects a test's harness (``tests/harness/ft_chaos.py``) holds:
 
   * ``kill``       — stop the worker node outright (lease renewals start
     failing, its delta never arrives: the canonical DiLoCo dropout);
@@ -49,7 +49,7 @@ not an event, so these default to ``at_round=0`` and fire on attach):
     ``[0, s]`` to every push touching the peer (seeded per target, so a
     re-run sees the identical delay sequence).
 
-Specs compose: ``bench.py --chaos kill-worker:2,bw-cap:w1:10`` runs both
+Specs compose: ``kill-worker:2,bw-cap:w1:10`` runs both
 (:func:`parse_chaos_specs`).
 
 Trigger semantics: action ``at_round=r`` fires the first time a METRICS

@@ -32,7 +32,7 @@ def is_accelerator() -> bool:
 def interpret_default() -> bool:
     """Pallas interpret mode for kernels called with ``interpret=None`` —
     for CPU tests only. Entry points that run on the chip (the train
-    executor, ``bench.py``, ``chip_smoke.py``) pass ``interpret=False``."""
+    executor, ``chip_smoke.py``) pass ``interpret=False``."""
     return not is_accelerator()
 
 

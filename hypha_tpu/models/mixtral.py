@@ -46,7 +46,7 @@ class MixtralConfig:
     # Drop-free TRAINING (serving decode is always dropless): every token
     # reaches its top-k experts at E/K x the expert FLOPs — reachable from
     # job specs via {"config": {"dropless": true}}, so the capacity-vs-
-    # dropless fidelity tradeoff (MOE_r05.json) is an operator choice, not
+    # dropless fidelity tradeoff is an operator choice, not
     # a code edit.
     dropless: bool = False
 
@@ -149,8 +149,8 @@ class MoELayer(nn.Module):
         onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B, S, K, E]
         pos = jnp.cumsum(onehot.reshape(B, S * K, E), axis=1).reshape(B, S, K, E) - onehot
         keep = (pos < C) * onehot  # [B, S, K, E]
-        # Observability for the capacity-routing fidelity question
-        # (MOE_r05): fraction of (token, expert-slot) assignments dropped
+        # Observability for the capacity-routing fidelity question:
+        # fraction of (token, expert-slot) assignments dropped
         # this step. Recorded only when callers apply with
         # mutable=["intermediates"] — zero cost in the jitted train step.
         self.sow(

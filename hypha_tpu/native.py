@@ -5,14 +5,12 @@ the parameter server's outer step (SURVEY.md §2.9: candle-core averaging +
 Nesterov over mmapped SafeTensors). The C++ equivalents live in
 ``native/``:
 
-  * ``hypha_ps.cpp``          — flat f32 kernels (weighted sum, Nesterov,
-    and the PS's fold and outer step: a delta scaled into the round's sum,
-    mean + Nesterov, both in place and threaded);
+  * ``hypha_ps.cpp``          — flat f32 kernels: the PS's fold and outer
+    step (a delta scaled into the round's sum, mean + Nesterov, both in
+    place and threaded) and the plain Nesterov form the tests hold the
+    outer step to;
   * ``hypha_safetensors.cpp`` — mmap'd SafeTensors reader (own JSON header
-    parser), writer, and ``ps_outer_step``: the WHOLE outer step over the
-    delta files, zero-copy;
-  * ``hypha_io.cpp``          — sendfile(2) file→socket fast path for bulk
-    tensor serving (the data node's io::copy role, tensor_data.rs:8-16);
+    parser) behind :class:`SafeTensorsView`;
   * ``hypha_quant.cpp``       — chunkwise int8/int4 quantization for the
     compressed delta transport (hypha_tpu.compress), bit-exact against
     the numpy fallback there.
@@ -32,13 +30,10 @@ from pathlib import Path
 import numpy as np
 
 __all__ = [
-    "weighted_sum",
     "nesterov_update",
     "fused_mean_nesterov",
     "fold_scaled",
     "native_available",
-    "ps_outer_step",
-    "send_file_fd",
     "SafeTensorsView",
     "quant_chunks",
     "dequant_chunks",
@@ -50,7 +45,6 @@ _REPO = Path(__file__).resolve().parent.parent
 _SRCS = [
     _REPO / "native" / "hypha_ps.cpp",
     _REPO / "native" / "hypha_safetensors.cpp",
-    _REPO / "native" / "hypha_io.cpp",
     _REPO / "native" / "hypha_quant.cpp",
 ]
 _SO = _REPO / "native" / "build" / "libhypha_native.so"
@@ -80,9 +74,6 @@ def _load() -> ctypes.CDLL | None:
                 timeout=300,
             )
         lib = ctypes.CDLL(str(_SO))
-        lib.weighted_sum_f32.argtypes = [
-            ctypes.POINTER(_F32P), _F32P, ctypes.c_int64, _F32P, ctypes.c_int64,
-        ]
         lib.nesterov_update_f32.argtypes = [
             _F32P, _F32P, _F32P, ctypes.c_int64, ctypes.c_float, ctypes.c_float,
         ]
@@ -109,14 +100,6 @@ def _load() -> ctypes.CDLL | None:
             ctypes.c_int, ctypes.POINTER(ctypes.c_int),
         ]
         lib.st_tensor.restype = ctypes.c_void_p
-        lib.ps_outer_step.argtypes = [
-            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int64, _F32P,
-            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
-            ctypes.c_float, ctypes.c_float, ctypes.c_char_p, ctypes.c_int,
-        ]
-        lib.ps_outer_step.restype = ctypes.c_int64
-        lib.send_file_fd.argtypes = [ctypes.c_int, ctypes.c_char_p]
-        lib.send_file_fd.restype = ctypes.c_int64
         _U8P = ctypes.POINTER(ctypes.c_uint8)
         lib.quant_chunks_f32.argtypes = [
             _F32P, ctypes.c_int64, ctypes.c_int64, ctypes.c_int, _U8P, _F32P,
@@ -146,26 +129,16 @@ def _ptr(a: np.ndarray) -> "ctypes._Pointer":
     return a.ctypes.data_as(_F32P)
 
 
-def weighted_sum(srcs: list[np.ndarray], weights: np.ndarray) -> np.ndarray:
-    """sum_k w[k] * srcs[k]; pass normalized weights for a weighted mean."""
-    srcs = [_as_f32(s).ravel() for s in srcs]
-    w = _as_f32(np.asarray(weights)).ravel()
-    n = srcs[0].size
-    lib = _load()
-    if lib is None:
-        return sum(wk * s for wk, s in zip(w, srcs)).astype(np.float32)
-    dst = np.empty(n, np.float32)
-    arr_type = _F32P * len(srcs)
-    lib.weighted_sum_f32(
-        arr_type(*(_ptr(s) for s in srcs)), _ptr(w), len(srcs), _ptr(dst), n
-    )
-    return dst
-
-
 def nesterov_update(
     momentum: np.ndarray, grad: np.ndarray, lr: float, mu: float
 ) -> tuple[np.ndarray, np.ndarray]:
-    """m <- mu*m + g; update <- lr*(mu*m + g). Returns (momentum, update)."""
+    """m <- mu*m + g; update <- lr*(mu*m + g). Returns (momentum, update).
+
+    The tests' reference for :func:`fused_mean_nesterov`: the in-place pass
+    is held to ``RoundAccum.mean()`` followed by this, on the same backend,
+    every bit (g++ contracts ``mu*m + g`` to a fused multiply-add, so numpy
+    cannot stand in for the native form). No caller in the program.
+    """
     m = _as_f32(momentum).ravel().copy()
     g = _as_f32(grad).ravel()
     lib = _load()
@@ -278,7 +251,7 @@ def fold_scaled(
 
 
 # ---------------------------------------------------------------------------
-# Native SafeTensors + outer step + data-plane IO
+# Native SafeTensors
 # ---------------------------------------------------------------------------
 
 _DTYPES = {
@@ -377,47 +350,6 @@ class SafeTensorsView:
         self.close()
 
 
-def ps_outer_step(
-    delta_paths: list[str | Path],
-    weights: np.ndarray,
-    momentum_in: str | Path | None,
-    momentum_out: str | Path,
-    update_out: str | Path,
-    lr: float,
-    mu: float,
-) -> int | None:
-    """The whole DiLoCo outer step in C++ over mmapped delta files.
-
-    Returns total elements processed, or None when the native library is
-    unavailable (caller falls back to the Python path). Raises ValueError
-    on malformed/mismatched inputs.
-    """
-    lib = _load()
-    if lib is None:
-        return None
-    paths = [str(p).encode() for p in delta_paths]
-    arr = (ctypes.c_char_p * len(paths))(*paths)
-    w = _as_f32(np.asarray(weights)).ravel()
-    if w.size != len(paths):
-        raise ValueError("one weight per delta file required")
-    err = ctypes.create_string_buffer(256)
-    total = lib.ps_outer_step(
-        arr,
-        len(paths),
-        _ptr(w),
-        str(momentum_in).encode() if momentum_in else b"",
-        str(momentum_out).encode(),
-        str(update_out).encode(),
-        lr,
-        mu,
-        err,
-        len(err),
-    )
-    if total < 0:
-        raise ValueError(f"ps_outer_step failed: {err.value.decode()}")
-    return int(total)
-
-
 _QUANT_BITS = {"int8": 8, "int4": 4}
 
 
@@ -459,17 +391,3 @@ def dequant_chunks(
     if got < 0:
         raise ValueError(f"dequant_chunks_f32 rejected args (codec {codec})")
     return True
-
-
-def send_file_fd(fd: int, path: str | Path) -> int | None:
-    """sendfile(2) loop: file -> connected socket fd. Returns bytes sent,
-    None if the native library is unavailable. Raises OSError on errno."""
-    lib = _load()
-    if lib is None:
-        return None
-    n = lib.send_file_fd(fd, str(path).encode())
-    if n < 0:
-        import os
-
-        raise OSError(-n, os.strerror(-n), str(path))
-    return int(n)

@@ -47,8 +47,7 @@ MAX_FRAME = 32 * 1024 * 1024
 # StreamReader buffer limit. asyncio's 64 KiB default caps every read() at
 # 64 KiB, which on the bulk-push path costs one event-loop pass + one
 # worker-thread hop per 64 KiB — a first-order throughput limit on a
-# single-core host (measured in DISTBENCH: the 4 MiB limit nearly doubled
-# loopback stream throughput).
+# single-core host.
 STREAM_BUFFER_LIMIT = 4 * 1024 * 1024
 
 _LEN = struct.Struct("<Q")
@@ -281,7 +280,7 @@ class _TcpStream(Stream):
         The receiver mirror of ``sendfile_transport``: bulk pushes drain
         fastest with blocking ``recv_into`` straight into an mmap of the
         destination file (one kernel→page-cache copy, no event-loop
-        scheduling per chunk — DISTBENCH r4's remaining gap). Only valid
+        scheduling per chunk). Only valid
         on plain TCP (TLS bytes need the event-loop's decrypt) and only
         when the caller will consume the stream to EOF: reading is paused
         here and never resumed. Returns ``(socket, buffered)`` where

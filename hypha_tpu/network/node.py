@@ -499,15 +499,11 @@ class PushStream:
         and writes it (``_drain_socket_to_file``) — zero event-loop
         involvement, which is what bounds the default path on the chip's
         host (the loop's thread receives 1.92 GB in 1.5 s whatever the file
-        costs). Taken when ``over`` is given, and as an opt-in
-        (``HYPHA_RAW_DRAIN=1``) for every other push: into a *fresh* file it
-        was measured against the default only on CPU-era hosts, in an
-        earlier form that mapped the file (26% faster on a clean page
-        cache, slower under sustained writeback on a slow virtio disk:
-        DISTBENCH r4, r5; not measured on the chip's host but for one
-        reading, PERF.md PR 41), so there it stays off by default. TLS /
-        mux / relay streams always use the buffered path (their bytes must
-        pass through the event loop), with ``over`` too.
+        costs). Taken exactly when ``over`` is given on a stream that has a
+        raw socket and no ``hasher``; a push into a *fresh* file keeps the
+        default path. TLS / mux / relay streams always use the buffered
+        path (their bytes must pass through the event loop), with ``over``
+        too.
 
         ``hasher``: optional hashlib object updated with every chunk as it
         is written — a receiver that needs a digest of the payload (the
@@ -535,9 +531,7 @@ class PushStream:
         import os as _os
 
         handoff = None
-        if hasher is None and (
-            over is not None or _os.environ.get("HYPHA_RAW_DRAIN") == "1"
-        ):
+        if hasher is None and over is not None:
             handoff = getattr(self.stream, "raw_socket_handoff", None)
         handoff = handoff() if handoff is not None else None
         loop = asyncio.get_running_loop()

@@ -5,8 +5,8 @@ pallas kernel only lowers on real TPUs, but two paths need flash's MEMORY
 PROFILE — O(S·block) live scores instead of the dense O(S²) tensor — on
 backends where pallas can't run:
 
-  * AOT memory accounting (benchmarks/mem7b.py): per-device peak bytes for
-    the 7B train step are extracted from XLA's compiled-memory analysis on
+  * AOT memory accounting: per-device peak bytes of a train step can be
+    extracted from XLA's compiled-memory analysis on
     virtual CPU meshes; with dense attention the analysis would charge a
     [B,H,S,S] score buffer the TPU path never materializes.
   * CPU fallback/serving tests at long S, where dense attention OOMs.

@@ -562,10 +562,8 @@ def flash_attention(
     Default blocks come from on-chip sweeps (TPU v5e, r3+r4): forward
     (512, 512) — (128, 128) halved throughput, per-cell overhead dominates
     at small tiles — and backward (1024, 512), tiled independently via
-    ``block_q_bwd``/``block_k_bwd`` (the r4 sweep under the headline
-    timing protocol: fwd 512×512 + bwd 1024×512 measured 112.5k vs the r3
-    defaults' 108.1k tok/s on the GPT-2 step, MFUPROBE_r04.json). The
-    tuned defaults beat the XLA dense path at S=1024 and scale to the
+    ``block_q_bwd``/``block_k_bwd`` (the r4 sweep, on the GPT-2 step).
+    The tuned defaults beat the XLA dense path at S=1024 and scale to the
     long-context shapes dense cannot even compile. Explicitly passed
     forward tiles also govern the backward (a VMEM-bounding caller keeps
     their bound) unless the bwd params override them.

@@ -126,9 +126,9 @@ class BatchScheduler:
 
     # ------------------------------------------------------------------
     def on_progress(self, peer: str, progress: Progress) -> ProgressResponse:
-        # Control-loop timing reservoir (SCALE_METRICS): the number
-        # benchmarks/scalebench.py asserts flat per peer across fleet
-        # growth — every message pays one perf_counter pair, nothing else.
+        # Control-loop timing reservoir (SCALE_METRICS): the number that
+        # must stay flat per peer as the fleet grows — every message pays
+        # one perf_counter pair, nothing else.
         t0 = time.perf_counter()
         try:
             return self._on_progress_gated(peer, progress)

@@ -862,8 +862,7 @@ class Orchestrator:
             self.metrics_bridge.on_metrics(peer, round_num, metrics)
             if ctx.metrics is not None:
                 # Round-tagged training-quality points (loss, loss EWMA,
-                # delta norm, tokens/s) join the live store — the
-                # loss-curve feed benchmarks/convergence.py consumes.
+                # delta norm, tokens/s) join the live store.
                 ctx.metrics.ingest_quality(peer, round_num, metrics)
 
         batch_scheduler = BatchScheduler(

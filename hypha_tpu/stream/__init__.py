@@ -24,7 +24,7 @@ Pieces:
     without exchanging a manifest.
   * :mod:`sync`      — the fragment schedule and the delayed-update
     correction algebra (pure tree ops over flat dicts), shared by the
-    training executor, the tests and ``benchmarks/streambench.py``.
+    training executor and the tests.
 
 Selection is per job via ``sync_mode: blocking | overlap | stream`` on
 :class:`~hypha_tpu.scheduler.job_config.DiLoCoJob` (default ``blocking`` —

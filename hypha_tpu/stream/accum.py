@@ -268,7 +268,13 @@ class RoundAccum:
         return np.float32(max(self.total_samples, 1e-20))
 
     def mean(self) -> dict[str, np.ndarray]:
-        """The sample-weighted mean ḡ = Σ samples·Δθ / Σ samples (f32)."""
+        """The sample-weighted mean ḡ = Σ samples·Δθ / Σ samples (f32).
+
+        The tests' reference for ``native.fused_mean_nesterov``: the
+        in-place pass is held to this followed by
+        ``native.nesterov_update``, on the same backend, every bit. No
+        caller in the program.
+        """
         if not self._acc:
             raise ValueError("no deltas folded")
         denom = self._denom()

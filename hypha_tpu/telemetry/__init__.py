@@ -489,9 +489,7 @@ def global_telemetry() -> Telemetry:
 def metrics_snapshot() -> dict:
     """One JSON-safe snapshot of every process metrics surface: the FT /
     stream / shard / serve / heterogeneity bundles plus the global
-    registry's observable gauges (per-node bandwidth among them). This is
-    what ``bench.py`` dumps next to every ``*BENCH_*.json`` artifact so
-    future benches get metrics without bespoke plumbing."""
+    registry's observable gauges (per-node bandwidth among them)."""
     gauges: dict[str, float] = {}
     telemetry = global_telemetry()
     for (scope, name), (cb, _unit) in sorted(telemetry._gauges.items()):

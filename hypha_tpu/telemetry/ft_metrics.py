@@ -1,12 +1,11 @@
 """Fault-tolerance and streaming-sync instruments: shared bundles.
 
 The φ detector, elastic parameter server and rejoin path all record into a
-process-global :data:`FT_METRICS` bundle so in-process tests and ``bench.py
---chaos`` can read one snapshot regardless of which component did the work.
+process-global :data:`FT_METRICS` bundle so in-process tests and their chaos
+harness can read one snapshot regardless of which component did the work.
 :data:`STREAM_METRICS` does the same for the streaming outer sync
 (hypha_tpu.stream): the training executor's flight thread and the
-parameter server's per-fragment round loop both record here, and
-``benchmarks/streambench.py`` reads one snapshot per mode. ``register_on``
+parameter server's per-fragment round loop both record here. ``register_on``
 exposes both bundles as observable gauges on a real
 :class:`~hypha_tpu.telemetry.Meter` for OTLP export.
 """
@@ -259,8 +258,8 @@ class ServeMetrics:
       rejections (pool queue limit + router retry-after).
     * ``request latency`` — submit→resolve wall time per request, kept
       both as an OTLP histogram and as a bounded reservoir so
-      :meth:`snapshot` can report p50/p95 directly (what SERVBENCH and
-      the tests assert).
+      :meth:`snapshot` can report p50/p95 directly (what the tests
+      assert).
     * ``prefix cache`` — blocks hit/missed at admission, copy-on-write
       copies, LRU evictions, plus ``cached_blocks``/``shared_blocks``
       gauges (snapshotted per serve-loop iteration); the snapshot
@@ -275,7 +274,7 @@ class ServeMetrics:
     * ``weight streaming`` — the serving (round, generation) gauges
       stamped at each hot swap, applied/deferred/rolled-back swap
       counters, and a stage→flip swap-latency reservoir (same
-      quantile treatment as request latency; what SWAPBENCH asserts).
+      quantile treatment as request latency).
     * ``fleet cache`` — cross-worker prefix reuse: ``remote_prefix_hits``
       / ``remote_prefix_misses`` count KV blocks pulled from a peer vs
       pulls that fell back to recompute; ``blocks_shipped`` /
@@ -659,7 +658,7 @@ class ScaleMetrics:
       + response frames through ``Node``): membership updates
       (``/hypha-ft``), Status/ScheduleUpdate heartbeats
       (``/hypha-progress``), lease traffic (``/hypha-api``) — the numbers
-      ``benchmarks/scalebench.py`` asserts sublinear. Tensor payloads
+      that must grow sublinearly with the fleet. Tensor payloads
       (push/pull) deliberately do NOT record here; they are data plane.
     * ``tree folds/forwards`` — per-level reduce-tree activity: how many
       child contributions each level folded and how many cumulative

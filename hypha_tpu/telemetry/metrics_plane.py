@@ -12,7 +12,7 @@ deltas since the last report, gauges as last-value, reservoirs as
     (:class:`~hypha_tpu.telemetry.series.TimeSeriesStore`) with fleet
     rollups (sum / max / quantile-merge / outlier);
   * persists a round-stamped ``metrics-<job>.jsonl`` journal next to the
-    trace spans (``benchmarks/convergence.py``'s future loss-curve feed);
+    trace spans;
   * evaluates declarative SLO rules (:mod:`hypha_tpu.telemetry.slo`),
     firing flight-recorder events and :class:`~hypha_tpu.telemetry.slo.
     SLOAdvisory` notices the orchestrator logs;

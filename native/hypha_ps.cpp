@@ -5,14 +5,17 @@
 // SafeTensors plus the Nesterov outer update
 // (reference: crates/worker/src/executor/parameter_server.rs:331-446).
 // This is the C++ equivalent: flat float32 kernels invoked via ctypes, with
-// Python owning SafeTensors metadata. Single pass, no temporaries beyond
-// the destination — the job is memory-bandwidth bound.
+// Python owning SafeTensors metadata. Each is one pass in place — the job
+// is memory-bandwidth bound. Two run in the program: fold_scaled_f32 (a
+// delta into the round's sum) and fused_mean_nesterov_inplace_f32 (the sum
+// to the update); nesterov_update_f32 is the plain form the tests hold the
+// in-place pass to.
 //
 // Fixes folded in (reference TODO parameter_server.rs:192-194): the mean is
-// a single weighted sum over all N workers, not order-dependent pairwise
-// averaging.
+// one sample-weighted sum over all N workers divided once, not
+// order-dependent pairwise averaging.
 //
-// Build: g++ -O3 -march=native -pthread -shared -fPIC hypha_ps.cpp -o libhypha_ps.so
+// Build: g++ -O3 -march=native -shared -fPIC ... (see hypha_tpu/native.py)
 
 #include <algorithm>
 #include <cstddef>
@@ -46,19 +49,6 @@ static int64_t split_over_threads(int64_t n, int64_t threads, Body body) {
 
 extern "C" {
 
-// dst[i] = sum_k weights[k] * srcs[k][i]
-// Weights are expected pre-normalized (sum to 1) for a weighted mean.
-void weighted_sum_f32(const float *const *srcs, const float *weights,
-                      int64_t n_srcs, float *dst, int64_t n) {
-  for (int64_t i = 0; i < n; ++i) {
-    float acc = 0.0f;
-    for (int64_t k = 0; k < n_srcs; ++k) {
-      acc += weights[k] * srcs[k][i];
-    }
-    dst[i] = acc;
-  }
-}
-
 // Nesterov outer step, in place:
 //   m <- mu * m + g
 //   update <- lr * (mu * m + g)
@@ -70,22 +60,6 @@ void nesterov_update_f32(float *momentum, const float *grad, float *update_out,
     float m = mu * momentum[i] + grad[i];
     momentum[i] = m;
     update_out[i] = lr * (mu * m + grad[i]);
-  }
-}
-
-// Fused: weighted mean of N gradients -> nesterov -> update, one pass.
-// Avoids materializing the averaged gradient for the common case.
-void fused_mean_nesterov_f32(const float *const *srcs, const float *weights,
-                             int64_t n_srcs, float *momentum,
-                             float *update_out, int64_t n, float lr, float mu) {
-  for (int64_t i = 0; i < n; ++i) {
-    float g = 0.0f;
-    for (int64_t k = 0; k < n_srcs; ++k) {
-      g += weights[k] * srcs[k][i];
-    }
-    float m = mu * momentum[i] + g;
-    momentum[i] = m;
-    update_out[i] = lr * (mu * m + g);
   }
 }
 
@@ -146,36 +120,6 @@ int64_t fold_scaled_f32(float *acc, const float *x, float scale, int64_t n,
       add_scaled_range(acc, x, lo, hi, scale);
     }
   });
-}
-
-// BF16 variant for the wire-format deltas: a 7B round ships ~13.5 GB per
-// worker in bf16 vs 27 GB f32, and the PS is the fan-in point for N of
-// them. Deltas arrive bf16; the accumulator, momentum and update stay f32
-// (bf16's 8 mantissa bits are fine for the SHIPPED deltas — they are
-// differences the outer optimizer averages — but compounding state must
-// not round). bf16 is the f32 high half, so conversion is a shift.
-static inline float bf16_val(uint16_t b) {
-  union {
-    uint32_t u;
-    float f;
-  } cvt;
-  cvt.u = static_cast<uint32_t>(b) << 16;
-  return cvt.f;
-}
-
-void fused_mean_nesterov_bf16(const uint16_t *const *srcs,
-                              const float *weights, int64_t n_srcs,
-                              float *momentum, float *update_out, int64_t n,
-                              float lr, float mu) {
-  for (int64_t i = 0; i < n; ++i) {
-    float g = 0.0f;
-    for (int64_t k = 0; k < n_srcs; ++k) {
-      g += weights[k] * bf16_val(srcs[k][i]);
-    }
-    float m = mu * momentum[i] + g;
-    momentum[i] = m;
-    update_out[i] = lr * (mu * m + g);
-  }
 }
 
 }  // extern "C"

@@ -1,13 +1,7 @@
-// Native SafeTensors access + the parameter-server outer step, end to end.
+// Native SafeTensors access: a read-only view for the checkpoint converter.
 //
-// The reference's only native numerical component streams worker
-// pseudo-gradients from mmapped SafeTensors files and applies the Nesterov
-// outer update (reference: crates/worker/src/executor/parameter_server.rs:
-// 331-446, Rust + candle-core). This is the C++ equivalent, self-contained:
-// a minimal JSON header parser for the SafeTensors tensor table, mmap'd
-// zero-copy reads, the fused weighted-mean + Nesterov kernel, and a writer
-// for the update/momentum files. One pass over each tensor; the job is
-// memory-bandwidth bound.
+// A minimal JSON header parser for the SafeTensors tensor table and mmap'd
+// zero-copy reads (SafeTensorsView in hypha_tpu/native.py). Self-contained.
 //
 // SafeTensors layout: 8-byte LE u64 header length, JSON header
 // {"name": {"dtype": "F32", "shape": [...], "data_offsets": [s, e]}, ...},
@@ -25,16 +19,6 @@
 #include <sys/mman.h>
 #include <sys/stat.h>
 #include <unistd.h>
-
-// Shared flat kernels from hypha_ps.cpp (same shared library).
-extern "C" void fused_mean_nesterov_f32(const float *const *srcs,
-                                        const float *weights, int64_t n_srcs,
-                                        float *momentum, float *update_out,
-                                        int64_t n, float lr, float mu);
-extern "C" void fused_mean_nesterov_bf16(const uint16_t *const *srcs,
-                                         const float *weights, int64_t n_srcs,
-                                         float *momentum, float *update_out,
-                                         int64_t n, float lr, float mu);
 
 namespace {
 
@@ -269,71 +253,6 @@ struct StFile {
   }
 };
 
-std::string json_escape(const std::string &s) {
-  std::string out;
-  out.reserve(s.size());
-  for (char c : s) {
-    switch (c) {
-      case '"': out += "\\\""; break;
-      case '\\': out += "\\\\"; break;
-      case '\n': out += "\\n"; break;
-      case '\t': out += "\\t"; break;
-      case '\r': out += "\\r"; break;
-      default:
-        if (static_cast<unsigned char>(c) < 0x20) {
-          char buf[8];
-          std::snprintf(buf, sizeof buf, "\\u%04x", c);
-          out += buf;
-        } else {
-          out += c;
-        }
-    }
-  }
-  return out;
-}
-
-bool write_safetensors_f32(const char *path,
-                           const std::vector<TensorInfo> &infos,
-                           const std::vector<const float *> &ptrs,
-                           std::string *error) {
-  std::string header = "{";
-  int64_t offset = 0;
-  std::vector<int64_t> begins;
-  for (size_t i = 0; i < infos.size(); ++i) {
-    const TensorInfo &t = infos[i];
-    int64_t nbytes = t.end - t.begin;
-    if (i) header += ",";
-    // Escape the (peer-supplied) tensor name: a raw quote would terminate
-    // the JSON string early and let a crafted name inject entries whose
-    // data_offsets alias other tensors.
-    header += "\"" + json_escape(t.name) + "\":{\"dtype\":\"F32\",\"shape\":[";
-    for (size_t d = 0; d < t.shape.size(); ++d) {
-      if (d) header += ",";
-      header += std::to_string(t.shape[d]);
-    }
-    header += "],\"data_offsets\":[" + std::to_string(offset) + "," +
-              std::to_string(offset + nbytes) + "]}";
-    begins.push_back(offset);
-    offset += nbytes;
-  }
-  header += "}";
-  // Pad to 8 so the data section is aligned (spec allows trailing spaces).
-  while (header.size() % 8 != 0) header += ' ';
-
-  FILE *f = std::fopen(path, "wb");
-  if (f == nullptr) { *error = std::string("cannot write ") + path; return false; }
-  uint64_t hlen = header.size();
-  bool ok = std::fwrite(&hlen, 8, 1, f) == 1 &&
-            std::fwrite(header.data(), 1, header.size(), f) == header.size();
-  for (size_t i = 0; ok && i < infos.size(); ++i) {
-    size_t nbytes = static_cast<size_t>(infos[i].end - infos[i].begin);
-    ok = std::fwrite(ptrs[i], 1, nbytes, f) == nbytes;
-  }
-  if (std::fclose(f) != 0) ok = false;
-  if (!ok) *error = std::string("short write to ") + path;
-  return ok;
-}
-
 }  // namespace
 
 // ---------------------------------------------------------------------------
@@ -379,140 +298,6 @@ const void *st_tensor(void *handle, const char *name, int64_t *nbytes,
   *ndim = static_cast<int>(t->shape.size());
   for (int d = 0; d < *ndim && d < max_dims; ++d) shape[d] = t->shape[static_cast<size_t>(d)];
   return f->data + t->begin;
-}
-
-// The whole outer step, native (parameter_server.rs:331-446 equivalent) ----
-//
-//   ḡ = Σ_k w_k · Δθ_k   (single weighted pass — fixes the reference's
-//                         order-dependent pairwise averaging TODO :192-194)
-//   m ← μ·m + ḡ;  update = lr·(μ·m + ḡ)
-//
-// delta_paths: n_files SafeTensors files with identical tensor tables (F32).
-// momentum_in: prior momentum file ("" or missing tensors → zeros).
-// Writes update_out and momentum_out (both SafeTensors F32).
-// Returns total elements processed, or -1 with err set.
-int64_t ps_outer_step(const char *const *delta_paths, int64_t n_files,
-                      const float *weights, const char *momentum_in,
-                      const char *momentum_out, const char *update_out,
-                      float lr, float mu, char *err, int errlen) {
-  if (n_files <= 0) {
-    set_err(err, errlen, "no delta files");
-    return -1;
-  }
-  std::string error;
-  std::vector<StFile> files(static_cast<size_t>(n_files));
-  for (int64_t k = 0; k < n_files; ++k) {
-    if (!files[static_cast<size_t>(k)].open(delta_paths[k], &error)) {
-      set_err(err, errlen, error);
-      return -1;
-    }
-  }
-  const StFile &first = files[0];
-  // Validate identical tables.
-  for (int64_t k = 1; k < n_files; ++k) {
-    const StFile &f = files[static_cast<size_t>(k)];
-    if (f.tensors.size() != first.tensors.size()) {
-      set_err(err, errlen, "delta files have different tensor counts");
-      return -1;
-    }
-  }
-  StFile momentum;
-  bool have_momentum = false;
-  if (momentum_in != nullptr && momentum_in[0] != '\0') {
-    // A supplied-but-unreadable momentum file is an error, NOT "no
-    // momentum": silently zeroing resets the outer optimizer trajectory —
-    // the exact state checkpointing exists to preserve.
-    if (!momentum.open(momentum_in, &error)) {
-      set_err(err, errlen, "momentum file unreadable: " + error);
-      return -1;
-    }
-    have_momentum = true;
-  }
-
-  std::vector<std::vector<float>> new_momentum;
-  std::vector<std::vector<float>> updates;
-  std::vector<TensorInfo> out_infos;
-  new_momentum.reserve(first.tensors.size());
-  updates.reserve(first.tensors.size());
-  out_infos.reserve(first.tensors.size());
-  int64_t total = 0;
-
-  for (const TensorInfo &t : first.tensors) {
-    // Deltas may arrive F32 or BF16 (the bf16 wire format halves a 7B
-    // round's upload); momentum/update state stays F32 throughout.
-    const bool bf16 = t.dtype == "BF16";
-    if (!bf16 && t.dtype != "F32") {
-      set_err(err, errlen, "unsupported delta dtype for tensor: " + t.name);
-      return -1;
-    }
-    int64_t nbytes = t.end - t.begin;
-    int64_t n = nbytes / (bf16 ? 2 : 4);
-    std::vector<const float *> srcs;
-    srcs.reserve(static_cast<size_t>(n_files));
-    for (int64_t k = 0; k < n_files; ++k) {
-      const StFile &f = files[static_cast<size_t>(k)];
-      const TensorInfo *tk = f.find(t.name);
-      if (tk == nullptr || tk->end - tk->begin != nbytes ||
-          tk->dtype != t.dtype) {
-        set_err(err, errlen, "delta mismatch for tensor: " + t.name);
-        return -1;
-      }
-      srcs.push_back(reinterpret_cast<const float *>(f.data + tk->begin));
-    }
-    const float *m_in = nullptr;
-    if (have_momentum) {
-      const TensorInfo *tm = momentum.find(t.name);
-      if (tm != nullptr) {
-        // Present but mismatched momentum = wrong model/corruption: fail
-        // loudly (matches the Python fallback's size validation). A tensor
-        // absent from the momentum file starts at zero, like a fresh key.
-        // Momentum is F32 regardless of the delta wire dtype, so its
-        // expected byte count is n*4, not the delta's nbytes.
-        if (tm->end - tm->begin != n * 4 || tm->dtype != "F32") {
-          set_err(err, errlen, "momentum mismatch for tensor: " + t.name);
-          return -1;
-        }
-        m_in = reinterpret_cast<const float *>(momentum.data + tm->begin);
-      }
-    }
-    std::vector<float> m_new(static_cast<size_t>(n), 0.0f);
-    std::vector<float> upd(static_cast<size_t>(n));
-    if (m_in != nullptr) {
-      std::memcpy(m_new.data(), m_in, static_cast<size_t>(n) * 4);
-    }
-    // One source of truth for the outer-optimizer math: the shared kernels
-    // from hypha_ps.cpp (linked into the same library), in-out on m_new.
-    if (bf16) {
-      fused_mean_nesterov_bf16(
-          reinterpret_cast<const uint16_t *const *>(srcs.data()), weights,
-          n_files, m_new.data(), upd.data(), n, lr, mu);
-    } else {
-      fused_mean_nesterov_f32(srcs.data(), weights, n_files, m_new.data(),
-                              upd.data(), n, lr, mu);
-    }
-    new_momentum.push_back(std::move(m_new));
-    updates.push_back(std::move(upd));
-    // Outputs are F32: carry an info row with F32 byte extents so the
-    // writer's offsets stay right when the deltas arrived BF16.
-    TensorInfo out = t;
-    out.dtype = "F32";
-    out.begin = 0;
-    out.end = n * 4;
-    out_infos.push_back(std::move(out));
-    total += n;
-  }
-
-  std::vector<const float *> upd_ptrs, mom_ptrs;
-  for (size_t i = 0; i < updates.size(); ++i) {
-    upd_ptrs.push_back(updates[i].data());
-    mom_ptrs.push_back(new_momentum[i].data());
-  }
-  if (!write_safetensors_f32(update_out, out_infos, upd_ptrs, &error) ||
-      !write_safetensors_f32(momentum_out, out_infos, mom_ptrs, &error)) {
-    set_err(err, errlen, error);
-    return -1;
-  }
-  return total;
 }
 
 }  // extern "C"

@@ -46,6 +46,16 @@ def pytest_configure(config):
         "fault: chaos/fault-injection tests (hypha_tpu.ft) — filter with "
         "-m fault / -m 'not fault'",
     )
+    if not hasattr(config, "workerinput"):
+        # Every xdist worker imports tests/test_native.py, whose skipif asks
+        # for the library while it is collected, and ``native._load`` compiles
+        # straight into the library's path: in a checkout without
+        # ``native/build/`` the workers raced, one loaded a half-written file
+        # and skipped the file's 72 tests as "no native toolchain". Built here
+        # once, in the process that starts the workers, before it starts them.
+        from hypha_tpu import native
+
+        native.native_available()
 
 
 # Two tests under ``tests/perfbench/`` each hold one literal count of the

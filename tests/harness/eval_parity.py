@@ -5,21 +5,13 @@ path". This harness trains the SAME model (GPT-2 architecture, identical
 initial weights via the checkpoint converter) on the SAME token stream with
 the SAME optimizer (AdamW, no clipping — the reference's loop is plain
 zero_grad/backward/step, training.py:106-116) in BOTH stacks and compares
-the loss trajectories step by step.
-
-Run: python benchmarks/eval_parity.py [--steps 40] — prints one JSON line.
+the loss trajectories step by step
+(``tests/test_convert.py::test_training_loop_loss_parity_vs_torch``).
 """
 
 from __future__ import annotations
 
-import argparse
-import json
-import sys
-from pathlib import Path
-
 import numpy as np
-
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 LR = 1e-3
 WD = 0.01  # torch AdamW default; set explicitly in both stacks
@@ -75,45 +67,3 @@ def jax_losses(hf_model, state_dict, ids: np.ndarray, steps: int) -> list[float]
         state, metrics = step(state, batch)
         out.append(float(metrics["loss"]))
     return out
-
-
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--steps", type=int, default=40)
-    args = ap.parse_args()
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    import torch
-    import transformers
-
-    torch.manual_seed(0)
-    hf_cfg = transformers.GPT2Config(
-        vocab_size=128, n_positions=64, n_embd=64, n_layer=2, n_head=4,
-        resid_pdrop=0.0, embd_pdrop=0.0, attn_pdrop=0.0,  # determinism
-    )
-    hf_model = transformers.GPT2LMHeadModel(hf_cfg)
-    ids = np.random.default_rng(0).integers(0, 128, (4, 64)).astype(np.int64)
-
-    # Snapshot the INITIAL weights before the torch loop mutates them in
-    # place — both stacks must start from the identical parameters.
-    state_dict = {k: v.numpy().copy() for k, v in hf_model.state_dict().items()}
-    lt = torch_losses(hf_model, ids, args.steps)
-    lj = jax_losses(hf_model, state_dict, ids.astype(np.int32), args.steps)
-    diffs = [abs(a - b) for a, b in zip(lt, lj)]
-    rel_final = abs(lt[-1] - lj[-1]) / max(abs(lt[-1]), 1e-9)
-    print(json.dumps({
-        "metric": "eval_loss_parity_vs_torch",
-        "value": round(max(diffs), 5),
-        "unit": "max_abs_loss_diff",
-        "vs_baseline": round(rel_final, 5),
-        "steps": args.steps,
-        "loss_torch_first_last": [round(lt[0], 4), round(lt[-1], 4)],
-        "loss_jax_first_last": [round(lj[0], 4), round(lj[-1], 4)],
-        "mean_abs_diff": round(sum(diffs) / len(diffs), 6),
-    }))
-
-
-if __name__ == "__main__":
-    main()

@@ -1,4 +1,4 @@
-"""Fault-tolerance chaos benchmark: an orchestrated DiLoCo run under fire.
+"""Fault-tolerance chaos harness: an orchestrated DiLoCo run under fire.
 
 Stands up the full in-process topology (gateway + data node + N train
 workers + parameter server + scheduler on the memory fabric — the same
@@ -20,14 +20,13 @@ under the same peer id after a kill, and the result additionally reports
 ``ps_recoveries`` / ``retry_attempts`` / ``ps_journal_bytes`` /
 ``recovery_wall_s`` (chaos fire → the next round closing).
 
-Invoked by ``bench.py --chaos <spec>`` which persists the result as
-``FTBENCH_<scenario>.json``.
+Called by ``tests/test_durable.py``, ``tests/test_sched_recovery.py`` and
+``tests/test_metrics_plane.py``.
 """
 
 from __future__ import annotations
 
 import asyncio
-import os
 import sys
 import tempfile
 import time
@@ -85,11 +84,10 @@ def _run_worker_ps_scenario(
     metrics_interval_s: float = 0.25,
     samples_per_round: int = 24,
 ) -> dict:
-    """Run one chaos scenario; returns the FTBENCH result dict.
+    """Run one chaos scenario; returns the result dict.
 
     ``spec=None`` runs the same orchestrated topology with NO fault
-    injected — the baseline the observability bench (obsbench) compares
-    traced runs against. ``trace_dir`` turns on end-to-end round tracing
+    injected. ``trace_dir`` turns on end-to-end round tracing
     (telemetry.trace) and flight-recorder spill into that directory for
     the run's duration. ``model_scale`` multiplies the toy model's width
     so the delta grows (obsbench's bw-cap run needs uploads that dwarf
@@ -735,17 +733,3 @@ def run_scheduler_scenario(
         f"({max_round_wall:.1f}s) + {restart_budget_s:.0f}s budget"
     )
     return line
-
-
-def main() -> int:
-    spec = sys.argv[1] if len(sys.argv) > 1 else "kill-worker:1"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    line = run_chaos_scenario(spec)
-    import json
-
-    print(json.dumps(line))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

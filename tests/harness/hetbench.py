@@ -1,8 +1,8 @@
-"""Heterogeneous-pool benchmark: WAN-adaptive vs static outer rounds.
+"""Heterogeneous-pool harness: WAN-adaptive vs static outer rounds.
 
 Stands up the full in-process topology (gateway + data node + 4 train
 workers + parameter server + scheduler on the memory fabric — the same
-harness as benchmarks/ft_chaos.py) with elastic membership enabled and a
+harness as ``ft_chaos.py``) with elastic membership enabled and a
 reproducibly heterogeneous pool (hypha_tpu.ft.chaos degrade modes):
 
   * ``w1`` bandwidth-capped to a fraction of a megabit — its f32 delta
@@ -10,43 +10,30 @@ reproducibly heterogeneous pool (hypha_tpu.ft.chaos degrade modes):
   * ``w2`` slow-CPU by 4x — every inner batch takes 4x its natural
     wall-clock.
 
-Three runs:
+``tests/test_het.py::test_quorum_drop_vs_adapt_e2e`` (slow) runs it twice:
 
-  * **static**   — today's behavior (`adaptive_steps: off`, one job-wide
-    codec): the capped peer is quorum-dropped every round (its compute is
-    wasted) and every round stalls to the deadline waiting for it;
+  * **static**   — `adaptive_steps: off`, one job-wide codec: the capped
+    peer is quorum-dropped every round (its compute is wasted) and every
+    round stalls to the deadline waiting for it;
   * **adaptive** — straggler-adaptive inner steps + per-link codec
     selection (hypha_tpu.ft.adaptive): the slow-CPU peer is assigned
     ~k/4 steps, the capped link degrades to int4 (8x fewer bytes), and
-    every delta lands inside the deadline;
-  * **uniform**  — the no-chaos reference pool for the convergence check.
+    every delta lands inside the deadline.
 
-Asserted acceptance criteria (ISSUE 9 / HETBENCH_r09.json):
-
-  * adaptive round wall-clock <= 0.6x static;
-  * zero quorum drops adaptive vs >= 1 per round static;
-  * adaptive final loss within 1e-3 of the uniform-pool run (the data
-    slices are deliberately IDENTICAL so run-to-run loss differences
-    isolate the scheduling/codec changes, not data-order luck).
-
-Run: ``make hetbench`` (outside tier-1) or
-``python benchmarks/hetbench.py --out HETBENCH_r09.json``.
+The data slices are deliberately IDENTICAL so run-to-run loss differences
+isolate the scheduling/codec changes, not data-order luck; ``chaos=None``
+gives the uniform no-chaos pool.
 """
 
 from __future__ import annotations
 
-import argparse
 import asyncio
-import json
-import os
 import sys
 import tempfile
 import time
 from pathlib import Path
 
 import numpy as np
-
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def _log(msg: str) -> None:
@@ -258,95 +245,3 @@ def run_het_scenario(
         }
 
     return asyncio.run(asyncio.wait_for(main(), timeout=600))
-
-
-def main() -> int:
-    parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--out", default="HETBENCH_r09.json")
-    parser.add_argument("--rounds", type=int, default=4)
-    parser.add_argument("--deadline", type=float, default=5.0)
-    args = parser.parse_args()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-    _log("run 1/3: static heterogeneous pool (adaptive off)")
-    static = run_het_scenario(
-        adaptive=False, rounds=args.rounds, round_deadline_s=args.deadline
-    )
-    _log(f"static: {json.dumps(static)}")
-    _log("run 2/3: adaptive heterogeneous pool")
-    adaptive = run_het_scenario(
-        adaptive=True, rounds=args.rounds, round_deadline_s=args.deadline
-    )
-    _log(f"adaptive: {json.dumps(adaptive)}")
-    _log("run 3/3: uniform reference pool (no chaos, same adaptive knobs)")
-    # The convergence reference: SAME scheduling configuration, uniform
-    # peers. On a uniform pool the controller assigns every worker the
-    # base step count, so the loss comparison isolates what the
-    # heterogeneity response (fewer straggler steps, per-link
-    # quantization) did to the trajectory — not scheduler flavor.
-    uniform = run_het_scenario(
-        adaptive=True, chaos=None, rounds=args.rounds,
-        round_deadline_s=args.deadline,
-    )
-    _log(f"uniform: {json.dumps(uniform)}")
-
-    wall_ratio = adaptive["round_wall_s"] / max(static["round_wall_s"], 1e-9)
-    loss_delta = (
-        abs(adaptive["final_loss"] - uniform["final_loss"])
-        if adaptive["final_loss"] is not None and uniform["final_loss"] is not None
-        else None
-    )
-    planned = args.rounds
-    line = {
-        "metric": "het_adaptive_round_wall_ratio",
-        "value": round(wall_ratio, 3),
-        "unit": "x (adaptive/static, lower is better)",
-        "vs_baseline": None,  # the seed has no heterogeneity story at all
-        "planned_rounds": planned,
-        "num_workers": 4,
-        "chaos": DEFAULT_CHAOS,
-        "round_deadline_s": args.deadline,
-        "static": static,
-        "adaptive": adaptive,
-        "uniform": uniform,
-        "asserts": {
-            "adaptive_round_wall_le_0.6x_static": wall_ratio <= 0.6,
-            "zero_quorum_drops_adaptive": adaptive["quorum_drops"] == 0,
-            "static_drops_ge_1_per_round": (
-                static["quorum_drops"] >= static["rounds_completed"]
-            ),
-            "loss_within_1e-3_of_uniform": (
-                loss_delta is not None and loss_delta < 1e-3
-            ),
-        },
-        "loss_delta_vs_uniform": loss_delta,
-    }
-    # Hard acceptance gates (ISSUE 9): fail loudly, never a fake green.
-    assert wall_ratio <= 0.6, (
-        f"adaptive round wall {adaptive['round_wall_s']}s not <= 0.6x "
-        f"static {static['round_wall_s']}s"
-    )
-    assert adaptive["quorum_drops"] == 0, (
-        f"adaptive run still dropped {adaptive['quorum_drops']} deltas: "
-        f"{adaptive['quorum_drops_by_round']}"
-    )
-    assert static["quorum_drops"] >= static["rounds_completed"], (
-        f"static run dropped only {static['quorum_drops']} over "
-        f"{static['rounds_completed']} rounds (expected >= 1/round)"
-    )
-    assert loss_delta is not None and loss_delta < 1e-3, (
-        f"adaptive final loss {adaptive['final_loss']} vs uniform "
-        f"{uniform['final_loss']} (delta {loss_delta})"
-    )
-
-    out = Path(args.out)
-    with open(out, "w") as f:
-        json.dump(line, f, indent=2)
-        f.write("\n")
-    _log(f"wrote {out}")
-    print(json.dumps(line))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -217,11 +217,7 @@ def test_training_loop_loss_parity_vs_torch():
     """Short end-to-end parity: identical weights + data + AdamW, our jitted
     step vs the reference-style torch loop — loss trajectories must agree
     (BASELINE metric: 'eval-loss parity vs CUDA/accelerate path')."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmarks"))
-    from eval_parity import jax_losses, torch_losses
+    from harness.eval_parity import jax_losses, torch_losses
 
     torch.manual_seed(0)
     hf_cfg = transformers.GPT2Config(

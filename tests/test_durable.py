@@ -723,14 +723,11 @@ def test_recovered_ps_drops_stale_plain_resend(tmp_path):
 
 @pytest.mark.fault
 def test_kill_ps_e2e_job_completes(tmp_path):
-    """The acceptance scenario end to end (same harness as `make
-    ftbench-ps`): 4 workers + orchestrator + scheduler, PS node killed
+    """The acceptance scenario end to end (``tests/harness/ft_chaos.py``):
+    4 workers + orchestrator + scheduler, PS node killed
     mid-round 1 and restarted under the same peer id — the job completes
     every planned round via durable recovery, zero full restarts."""
-    import sys
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    from ft_chaos import run_chaos_scenario
+    from harness.ft_chaos import run_chaos_scenario
 
     line = run_chaos_scenario("kill-ps:1", rounds=3)
     assert line["rounds_completed"] == 3

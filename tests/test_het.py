@@ -17,7 +17,7 @@ Coverage map (ISSUE 9 satellites):
     Status round-trip;
   * quorum-drop-vs-adapt at the parameter-server collector (tier-1) and
     a full orchestrated 4-worker e2e under a 4x slow + bandwidth-capped
-    pool (slow-marked; benchmarks/hetbench.py runs the asserted version).
+    pool (slow-marked; the pool is ``tests/harness/hetbench.py``).
 """
 
 from __future__ import annotations
@@ -768,7 +768,7 @@ def test_het_metrics_snapshot_and_register_on():
 
 
 # --------------------------------------------------------------------------
-# orchestrated e2e (slow; benchmarks/hetbench.py runs the asserted version)
+# orchestrated e2e (slow)
 # --------------------------------------------------------------------------
 
 
@@ -776,11 +776,8 @@ def test_het_metrics_snapshot_and_register_on():
 def test_quorum_drop_vs_adapt_e2e():
     """4-worker pool, one 4x slow-CPU + one bandwidth-capped peer: the
     static run quorum-drops the capped peer; the adaptive run lands every
-    delta (HETBENCH asserts the wall-clock and loss bounds on top)."""
-    import sys
-
-    sys.path.insert(0, str(Path(__file__).parent.parent / "benchmarks"))
-    from hetbench import run_het_scenario
+    delta."""
+    from harness.hetbench import run_het_scenario
 
     static = run_het_scenario(adaptive=False, rounds=2)
     assert static["quorum_drops"] >= 1

@@ -708,11 +708,7 @@ def test_metrics_snapshot_is_json_safe_under_numpy_scalars():
 
 @pytest.mark.slow
 def test_metrics_plane_end_to_end_orchestrated(tmp_path):
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benchmarks"))
-    from ft_chaos import run_chaos_scenario
+    from harness.ft_chaos import run_chaos_scenario
 
     line = run_chaos_scenario(
         spec=None, num_workers=2, rounds=2,

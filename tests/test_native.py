@@ -1,18 +1,24 @@
-"""Native (C++) runtime layer tests: SafeTensors mmap reader/writer parity
-with the Python safetensors library, the full native outer step vs the
-Python path, sendfile data plane, and malformed-input rejection."""
+"""Native (C++) runtime layer tests: SafeTensors mmap reader parity with the
+Python safetensors library, the PS's in-place kernels against their plain
+references bit for bit, malformed-input rejection, and the seam the
+benchmark's harness leans on (its probe, and the symbols the loader binds)."""
 
 from __future__ import annotations
 
-import os
-import socket
-import threading
+import inspect
+import json
+import re
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
-from safetensors.numpy import load_file, save_file
+from safetensors.numpy import save_file
 
 from hypha_tpu import native
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 pytestmark = pytest.mark.skipif(
@@ -23,11 +29,6 @@ pytestmark = pytest.mark.skipif(
 def _write_st(path, tensors):
     save_file(tensors, str(path))
     return path
-
-
-def _mean_then_nesterov(srcs, w, momentum, lr, mu):
-    """The plain two-kernel reference: weighted mean, then Nesterov."""
-    return native.nesterov_update(momentum, native.weighted_sum(srcs, w), lr, mu)
 
 
 def test_safetensors_view_parity(tmp_path):
@@ -59,64 +60,6 @@ def test_safetensors_view_rejects_garbage(tmp_path):
     trunc.write_bytes(struct.pack("<Q", 1 << 40) + b"{}")
     with pytest.raises(ValueError):
         native.SafeTensorsView(trunc)
-
-
-def test_native_outer_step_matches_python_kernels(tmp_path):
-    rng = np.random.default_rng(5)
-    shapes = {"x/w": (8, 4), "y/b": (16,)}
-    n_workers = 3
-    paths = []
-    deltas = []
-    for k in range(n_workers):
-        t = {n: rng.standard_normal(s).astype(np.float32) for n, s in shapes.items()}
-        deltas.append(t)
-        paths.append(_write_st(tmp_path / f"d{k}.safetensors", t))
-    w = np.asarray([3.0, 1.0, 2.0], np.float32)
-    w = w / w.sum()
-    lr, mu = 0.7, 0.9
-
-    m_out = tmp_path / "m.safetensors"
-    u_out = tmp_path / "u.safetensors"
-    total = native.ps_outer_step(paths, w, None, m_out, u_out, lr, mu)
-    assert total == sum(int(np.prod(s)) for s in shapes.values())
-
-    update = load_file(str(u_out))
-    momentum = load_file(str(m_out))
-    for name in shapes:
-        srcs = [d[name] for d in deltas]
-        m_ref, u_ref = _mean_then_nesterov(
-            srcs, w, np.zeros(srcs[0].size, np.float32), lr, mu
-        )
-        np.testing.assert_allclose(update[name].ravel(), u_ref, rtol=1e-5)
-        np.testing.assert_allclose(momentum[name].ravel(), m_ref, rtol=1e-5)
-
-    # Second round consumes the momentum file
-    total2 = native.ps_outer_step(paths, w, m_out, m_out, u_out, lr, mu)
-    assert total2 == total
-    momentum2 = load_file(str(m_out))
-    for name in shapes:
-        srcs = [d[name] for d in deltas]
-        m1, _ = _mean_then_nesterov(
-            srcs, w, np.zeros(srcs[0].size, np.float32), lr, mu
-        )
-        m2_ref, _ = _mean_then_nesterov(srcs, w, m1, lr, mu)
-        np.testing.assert_allclose(momentum2[name].ravel(), m2_ref, rtol=1e-5)
-
-
-def test_native_outer_step_rejects_mismatch(tmp_path):
-    a = _write_st(tmp_path / "a.safetensors", {"x": np.zeros((4,), np.float32)})
-    b = _write_st(tmp_path / "b.safetensors", {"x": np.zeros((5,), np.float32)})
-    with pytest.raises(ValueError, match="mismatch"):
-        native.ps_outer_step(
-            [a, b], np.asarray([0.5, 0.5], np.float32),
-            None, tmp_path / "m", tmp_path / "u", 0.7, 0.9,
-        )
-    c = _write_st(tmp_path / "c.safetensors", {"x": np.zeros((4,), np.int64)})
-    with pytest.raises(ValueError, match="unsupported delta dtype"):
-        native.ps_outer_step(
-            [c], np.asarray([1.0], np.float32),
-            None, tmp_path / "m", tmp_path / "u", 0.7, 0.9,
-        )
 
 
 @pytest.mark.parametrize("threads", [1, 2, 7])
@@ -256,37 +199,45 @@ def test_fold_scaled_refuses_what_it_cannot_write_over(kernel_backend, fault):
     np.testing.assert_array_equal(acc, before)
 
 
-def test_send_file_fd_socketpair(tmp_path):
-    payload = os.urandom(1 << 20) + b"tail"
-    src = tmp_path / "blob.bin"
-    src.write_bytes(payload)
-    a, b = socket.socketpair()
-    received = bytearray()
+def test_the_benchmarks_probe_builds_and_calls_both_libraries():
+    """``perfbench/cluster.py`` runs this text in a child before any role
+    starts and refuses the run unless it ends in ``probed`` with both
+    libraries built: the program's side of that contract, held here."""
+    from perfbench.cluster import PROBE
 
-    def reader():
-        while True:
-            chunk = b.recv(1 << 16)
-            if not chunk:
-                return
-            received.extend(chunk)
-
-    t = threading.Thread(target=reader)
-    t.start()
-    try:
-        sent = native.send_file_fd(a.fileno(), src)
-        assert sent == len(payload)
-    finally:
-        a.close()
-        t.join(10)
-        b.close()
-    assert bytes(received) == payload
+    r = subprocess.run(
+        [sys.executable, "-c", PROBE], cwd=str(REPO), capture_output=True,
+        text=True, timeout=600,
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = r.stdout.strip().splitlines()
+    assert lines[-1] == "probed"
+    assert json.loads(lines[0]) == {"ps_kernels": True, "cbor_codec": True}
 
 
-def test_send_file_fd_missing_file(tmp_path):
-    a, b = socket.socketpair()
-    try:
-        with pytest.raises(OSError):
-            native.send_file_fd(a.fileno(), tmp_path / "nope")
-    finally:
-        a.close()
-        b.close()
+def _exported_by_the_sources() -> set[str]:
+    """Every non-static function defined between ``extern "C" {`` and its
+    ``}`` in the translation units of ``libhypha_native.so`` (a definition
+    starts in column 0 there, with its name on that line)."""
+    names: set[str] = set()
+    for src in native._SRCS:
+        for block in re.findall(r'extern "C" \{\n(.*?)\n\}  // extern "C"', src.read_text(), re.S):
+            names.update(re.findall(r"^(?!.*\bstatic\b)\w.*?(\w+)\(", block, re.M))
+    return names
+
+
+def _bound_by_the_loader() -> set[str]:
+    return set(re.findall(r"\blib\.(\w+)\.(?:argtypes|restype)\b", inspect.getsource(native._load)))
+
+
+def test_the_loader_binds_what_the_sources_export_and_nothing_else():
+    """``_load`` binds every symbol in one ``try`` that does not catch
+    ``AttributeError``: a function taken out of the C++ and left there turns
+    ``import``'s first call into a crash of every role, and one exported and
+    unbound is a kernel nobody can call. Both sets, and the built library."""
+    exported, bound = _exported_by_the_sources(), _bound_by_the_loader()
+    assert {"fused_mean_nesterov_inplace_f32", "fold_scaled_f32", "st_open"} <= exported
+    assert exported == bound
+    lib = native._load()
+    for name in sorted(bound):
+        assert hasattr(lib, name), name

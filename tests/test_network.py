@@ -348,66 +348,6 @@ def test_push_stream_from_file(tmp_path):
     run(main())
 
 
-def test_push_raw_drain_opt_in(tmp_path, monkeypatch):
-    """HYPHA_RAW_DRAIN=1 routes plain-TCP pushes through the dedicated
-    recv_into-mmap drain thread (DISTBENCH r5: wins on clean-page-cache /
-    fast-disk hosts); bytes must be identical and the byte counter
-    credited. Memory-transport streams have no raw socket and must fall
-    back transparently."""
-    monkeypatch.setenv("HYPHA_RAW_DRAIN", "1")
-    # Spy: the TCP pair MUST take the drain thread, the memory pair MUST
-    # not — otherwise a broken handoff silently re-tests the fallback.
-    import hypha_tpu.network.node as node_mod
-
-    drains = []
-    real_drain = node_mod._drain_socket_to_file
-    monkeypatch.setattr(
-        node_mod, "_drain_socket_to_file",
-        lambda *a, **kw: (drains.append(1), real_drain(*a, **kw))[1],
-    )
-
-    async def main():
-        from hypha_tpu.network import TcpTransport
-
-        a = Node(TcpTransport(), peer_id="a")
-        b = Node(TcpTransport(), peer_id="b")
-        await a.start(["127.0.0.1:0"])
-        await b.start(["127.0.0.1:0"])
-        a.add_peer_addr("b", b.listen_addrs[0])
-        src = tmp_path / "delta.bin"
-        src.write_bytes(bytes(range(256)) * 40000)  # ~10 MB
-
-        async def receive():
-            push = await b.next_push(timeout=5)
-            dst = tmp_path / "received.bin"
-            n = await push.save_to(dst)
-            return dst, n
-
-        recv = asyncio.create_task(receive())
-        await a.push("b", DataSlice(dataset="d", index=1), src)
-        dst, n = await recv
-        assert n == src.stat().st_size
-        assert dst.read_bytes() == src.read_bytes()
-        assert b.bytes_in >= n
-        assert drains == [1], "plain-TCP push did not take the raw drain"
-        # fallback: memory transport (no raw socket) keeps working
-        m1, m2 = await make_nodes(2)
-        await connect(m1, m2)
-
-        async def receive2():
-            push = await m2.next_push(timeout=5)
-            return await push.save_to(tmp_path / "mem.bin")
-
-        r2 = asyncio.create_task(receive2())
-        await m1.push(m2.peer_id, DataSlice(dataset="d", index=2), src)
-        assert await r2 == src.stat().st_size
-        assert drains == [1], "memory-transport push must use the fallback"
-        for node in (a, b, m1, m2):
-            await node.stop()
-
-    run(main())
-
-
 def test_push_dead_sender_releases_slot_default_path(tmp_path):
     """A sender dying mid-push on the DEFAULT (buffered) receive path must
     release the accept-semaphore slot — ACCEPT_LIMIT failed senders would

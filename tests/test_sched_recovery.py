@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import asyncio
 import struct
-import sys
 import time
 from pathlib import Path
 
@@ -716,14 +715,13 @@ def test_resume_without_plan_raises_adoption_failed(tmp_path):
 
 @pytest.mark.fault
 def test_kill_scheduler_e2e_bit_equal(tmp_path):
-    """The acceptance scenario end to end (same harness as `make
-    ftbench-scheduler`): 3 workers + durable PS + durable scheduler,
+    """The acceptance scenario end to end (``tests/harness/ft_chaos.py``):
+    3 workers + durable PS + durable scheduler,
     scheduler node killed mid-round and restarted under the same peer id.
     All rounds complete with zero full restarts, the restarted generation
     re-adopts every live execution, and the final weights are BIT-equal
     to a no-kill run of the identical blocking-f32 job."""
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    from ft_chaos import run_chaos_scenario
+    from harness.ft_chaos import run_chaos_scenario
 
     line = run_chaos_scenario("kill-scheduler:2", rounds=3)
     assert line["rounds_completed"] == 3

@@ -96,8 +96,7 @@ def test_flash_beats_dense_at_long_context_on_chip():
 
 
 def test_gpt2_flash_train_step_on_chip():
-    """One fused train step of GPT-2 with the flash kernel on hardware —
-    the exact path bench.py measures."""
+    """One fused train step of GPT-2 with the flash kernel on hardware."""
     import functools
 
     import jax

@@ -490,11 +490,17 @@ def _push_through_the_connectors(tmp_path, meta: dict, nbytes: int = 9_000_001, 
 
 
 @pytest.mark.parametrize("path", ["loop", "thread"])
-def test_a_push_leaves_a_span_at_each_end_of_one_trace(tmp_path, tracing_on, monkeypatch, path):
+def test_a_push_leaves_a_span_at_each_end_of_one_trace(tmp_path, tracing_on, path):
+    pages = "fresh"
     if path == "thread":
-        monkeypatch.setenv("HYPHA_RAW_DRAIN", "1")
-    else:
-        monkeypatch.delenv("HYPHA_RAW_DRAIN", raising=False)
+        # What the stream's last push left once its consumer unlinked it: a
+        # push that finds a spare is the one the drain thread takes.
+        from hypha_tpu.worker.connectors import _safe_name
+
+        spare = tmp_path / "incoming" / "spare" / f"{_safe_name('a-updates')}.bin"
+        spare.parent.mkdir(parents=True)
+        spare.write_bytes(b"\xee" * 10_000_000)
+        pages = "recycled"
     root = tracing_on.begin("round", attrs={"round": 3}, node="sched")
     meta = trace.inject({"num_samples": 8.0, "round": 3}, root)
     got, _ = _push_through_the_connectors(tmp_path, meta)
@@ -509,7 +515,7 @@ def test_a_push_leaves_a_span_at_each_end_of_one_trace(tmp_path, tracing_on, mon
         assert {"cpu_user_s", "cpu_sys_s", "minflt", "maxrss_kb"} <= set(s["attrs"])
     assert (send["attrs"]["peer"], send["attrs"]["attempt"]) == ("b", 1)
     a = receive["attrs"]
-    assert (a["peer"], a["resource"], a["pages"], a["path"]) == ("a", "updates", "fresh", path)
+    assert (a["peer"], a["resource"], a["pages"], a["path"]) == ("a", "updates", pages, path)
     took = (receive["mono_end_ns"] - receive["mono_start_ns"]) / 1e9
     assert a["read_s"] >= 0 and a["write_s"] > 0
     if path == "thread":  # there the two part socket from file, and nothing overlaps them

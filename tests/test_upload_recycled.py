@@ -193,8 +193,7 @@ def test_a_plain_tcp_push_with_a_spare_goes_to_the_drain_thread_and_no_other_doe
 ):
     """The thread that ``recv_into``s a kept buffer takes a push that comes
     with a spare over plain TCP; a digest, a transport without a raw socket
-    or no spare (and no ``HYPHA_RAW_DRAIN=1``) keep the loop's path."""
-    monkeypatch.delenv("HYPHA_RAW_DRAIN", raising=False)
+    or no spare keep the loop's path."""
     drains = []
     real = node_mod._drain_socket_to_file
     monkeypatch.setattr(
@@ -228,18 +227,14 @@ def test_a_plain_tcp_push_with_a_spare_goes_to_the_drain_thread_and_no_other_doe
         )):
             push = await one(a, b, i, **kw)
             taken.append((len(drains), push.recycled))
-        monkeypatch.setenv("HYPHA_RAW_DRAIN", "1")
-        push = await one(a, b, 3)                            # the thread, opted into
-        taken.append((len(drains), push.recycled))
-        monkeypatch.delenv("HYPHA_RAW_DRAIN")
         m1, m2 = await _pair("memory")
-        push = await one(m1, m2, 4, over=spare(4))           # the loop: no raw socket
+        push = await one(m1, m2, 3, over=spare(3))           # the loop: no raw socket
         taken.append((len(drains), push.recycled))
         for node in (a, b, m1, m2):
             await node.stop()
         return taken
 
-    assert run(main()) == [(1, True), (1, False), (1, True), (2, False), (2, True)]
+    assert run(main()) == [(1, True), (1, False), (1, True), (1, True)]
 
 
 class _Breaks:
