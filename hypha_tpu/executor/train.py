@@ -33,6 +33,7 @@ __all__ = [
     "make_train_step",
     "make_routed_train_step",
     "ROUTING_FIELDS",
+    "aux_fields",
 ]
 
 
@@ -310,6 +311,16 @@ ROUTING_FIELDS = (
 )
 
 
+def aux_fields(model) -> tuple:
+    """What follows ``ROUTING_FIELDS`` in the host vector of a model with a
+    second objective: ``aux_loss`` (the layers' sum), then whatever per-layer
+    ``stats`` the model names in ``aux_fields``, each a mean over the layers.
+    Empty for a model that names no ``aux_name``."""
+    if not getattr(model, "aux_name", None):
+        return ()
+    return ("aux_loss", *getattr(model, "aux_fields", ()))
+
+
 def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True):
     """The jitted step of a model with routed experts and a selection bias
     (``models/routed.py``): ``model.apply`` returns ``(out, stats)`` and
@@ -324,6 +335,13 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
     moments. The routing counters ride in ``metrics["host"]``
     (``ROUTING_FIELDS``) beside the loss: pairs summed and ``load_max``
     maximised over the expert layers.
+
+    **A second objective.** Where the model's ``stats`` carry ``aux_loss`` (a
+    value a layer: a learned key selection's own objective, whose gradient the
+    model keeps apart from the language model's by ``stop_gradient``), the step
+    differentiates the cross-entropy plus the layers' sum. The logged ``loss``
+    stays the cross-entropy; ``aux_loss`` and ``total_loss`` say the rest, and
+    the host vector gains :func:`aux_fields`.
     """
     from ..models.routed import STATE, update_bias
 
@@ -332,13 +350,16 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
 
     def loss_fn(params, extras, ids):
         hidden, stats = body.apply({**params, **extras}, ids)
-        return _head_loss(model, params, hidden, ids, loss_chunk), stats
+        loss = _head_loss(model, params, hidden, ids, loss_chunk)
+        aux = stats["aux_loss"].sum() if "aux_loss" in stats else None
+        return (loss if aux is None else loss + aux), (loss, aux, stats)
 
     def step(state: TrainState, batch) -> tuple:
         ids = batch["input_ids"] if "input_ids" in batch else batch["inputs"]
-        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (total, (loss, aux, stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, state.extras, ids
         )
+        more = [aux if k == "aux_loss" else stats[k].mean() for k in aux_fields(model)]
         with jax.named_scope("optimizer"):
             new_state = state.apply_gradients(grads)
             new_state = new_state.replace(
@@ -352,11 +373,11 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
         }
         metrics = {
             "loss": loss,
-            "total_loss": loss,
-            "aux_loss": jnp.float32(0),
+            "total_loss": total,
+            "aux_loss": jnp.float32(0) if aux is None else aux,
             "grad_norm": optax.global_norm(grads),
             "host": jnp.stack(
-                [loss] + [counters[k].astype(jnp.float32) for k in ROUTING_FIELDS[1:]]
+                [loss] + [counters[k].astype(jnp.float32) for k in ROUTING_FIELDS[1:]] + more
             ),
         }
         return new_state, metrics

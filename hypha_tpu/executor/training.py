@@ -81,6 +81,7 @@ from .serialization import flat_leaf_map, flatten_tree, replace_leaves, unflatte
 from .train import (
     ROUTING_FIELDS,
     TrainState,
+    aux_fields,
     build_optimizer,
     make_chunked_train_step,
     make_routed_train_step,
@@ -706,7 +707,9 @@ def _init_model(cfg: TrainExecutorConfig, session, work_dir: Path, first_batch):
         # A stack of more than one kind of layer says what it holds. The
         # fallbacks' configurations are a library's: no field is taken for granted.
         kinds = list(kinds)
-        sizes = {k: getattr(_mcfg, k, None) for k in ("head_dim", "value_dim", "scan_chunk", "ssd_chunk", "expert_form")}
+        sizes = {k: getattr(_mcfg, k, None) for k in (
+            "head_dim", "value_dim", "scan_chunk", "ssd_chunk", "expert_form",
+            "index_heads", "index_head_dim", "index_topk", "router")}
         log.info(
             "operators: %s%s",
             " ".join(f"{k}={kinds.count(k)}" for k in dict.fromkeys(kinds)),
@@ -1948,16 +1951,20 @@ def run_training(
     # A routed model's step packs its counters beside the loss
     # (``metrics["host"]``, ROUTING_FIELDS): the one transfer that fetches the
     # loss fetches them, and the round sums them for a line of its own.
-    routing = dict.fromkeys(("steps", *ROUTING_FIELDS[1:]), 0)
+    # Where the model has a second objective the vector goes on (aux_fields).
+    aux = aux_fields(model)
+    routing = dict.fromkeys(("steps", *ROUTING_FIELDS[1:], *aux), 0)
 
     def fetch_loss(metrics) -> float:
         host = metrics.get("host")
         if host is None:
             return float(metrics["loss"])
-        got = dict(zip(ROUTING_FIELDS, np.asarray(host).tolist()))
+        got = dict(zip(ROUTING_FIELDS + aux, np.asarray(host).tolist()))
         routing["steps"] += 1
         for key in ("pairs_routed", "pairs_computed", "tokens_elsewhere"):
             routing[key] += int(got[key])
+        for key in aux:  # summed here, a mean over the steps on the round's line
+            routing[key] += got[key]
         routing["load_max"] = max(routing["load_max"], int(got["load_max"]))
         return got["loss"]
 
@@ -2045,6 +2052,16 @@ def run_training(
                 routing["load_max"] / max(load_mean, 1e-9),
                 routing["tokens_elsewhere"],
             )
+            if aux:
+                # The second objective, apart from the loss, under the name the
+                # model gives it, and what else the model named: means over
+                # the round's steps.
+                log.info(
+                    "round %d objective: steps=%d %s=%.6f%s",
+                    result.rounds - 1, routing["steps"], model.aux_name,
+                    routing["aux_loss"] / routing["steps"],
+                    "".join(f" {k}={routing[k] / routing['steps']:.4f}" for k in aux[1:]),
+                )
             routing.update(dict.fromkeys(routing, 0))
         step_times.clear()
         round_mark.update(

@@ -11,6 +11,7 @@ from .afmoe import Afmoe, AfmoeConfig
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .lenet import LeNet, LeNetConfig
 from .gpt2 import GPT2, GPT2Config
+from .keye_vl2 import KeyeVL2, KeyeVL2Config
 from .llama import Llama, LlamaConfig
 from .mixtral import Mixtral, MixtralConfig
 from .nemotron_h import NemotronH, NemotronHConfig
@@ -26,6 +27,8 @@ __all__ = [
     "LeNetConfig",
     "GPT2",
     "GPT2Config",
+    "KeyeVL2",
+    "KeyeVL2Config",
     "Llama",
     "LlamaConfig",
     "Mixtral",

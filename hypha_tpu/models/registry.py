@@ -3,7 +3,7 @@
 The reference maps 38 ``ModelType`` variants to HF ``AutoModelFor*`` classes
 (executors/accelerate/.../model.py:48-123). Here every variant resolves:
 the flagship families (GPT-2, Llama + its Mistral/Qwen2/Gemma descendants,
-Mixtral, afmoe, lfm2_moe, phi4flash, nemotron_h, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
+Mixtral, afmoe, lfm2_moe, phi4flash, nemotron_h, keye_vl2, LeNet) are native JAX definitions; the 14 types with an HF **Flax**
 head resolve through the hf fallback family (torch checkpoints convert via
 ``from_pt``); the remaining torch-only-head types resolve through the
 ``heads`` family — JAX task heads over Flax backbones (models/heads.py),
@@ -21,6 +21,7 @@ from typing import Any
 from ..messages import ModelType
 from .afmoe import Afmoe, AfmoeConfig
 from .gpt2 import GPT2, GPT2Config
+from .keye_vl2 import KeyeVL2, KeyeVL2Config
 from .lenet import LeNet, LeNetConfig
 from .lfm2_moe import Lfm2Moe, Lfm2MoeConfig
 from .llama import Llama, LlamaConfig
@@ -39,6 +40,7 @@ _PRESETS = {
     "lfm2_moe": {"tiny": Lfm2MoeConfig.tiny},
     "phi4flash": {"tiny": Phi4FlashConfig.tiny},
     "nemotron_h": {"tiny": NemotronHConfig.tiny},
+    "keye_vl2": {"tiny": KeyeVL2Config.tiny},
 }
 
 FAMILIES = {
@@ -72,6 +74,11 @@ FAMILIES = {
     # one, by the letter of ``pattern``; ``layers_run`` the source layers a cut
     # keeps; one rank's share of the experts as afmoe's.
     "nemotron_h": (NemotronH, NemotronHConfig),
+    # Kwai Keye's KeyeVL2 language model (Keye-VL-2.0): QK-normed GQA over the
+    # keys a learned indexer picks for each query, the indexer trained by a KL
+    # objective of its own that the model returns beside its routing counts;
+    # softmax-routed experts with nothing dense beside them.
+    "keye_vl2": (KeyeVL2, KeyeVL2Config),
     "lenet": (LeNet, LeNetConfig),
 }
 

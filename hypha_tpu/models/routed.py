@@ -1,5 +1,5 @@
 """The routed expert layer that the sparse families share (``afmoe.py``,
-``lfm2_moe.py``), as one rank of an expert-parallel deployment computes it,
+``lfm2_moe.py``, ``nemotron_h.py``, ``keye_vl2.py``), as one rank of an expert-parallel deployment computes it,
 and the selection bias that is no parameter.
 
 ``s = sigmoid(m W_r)`` in float32 over all ``num_experts``; the top
@@ -17,12 +17,17 @@ where absent: ``down(silu(gate x) * up x)``, three matrices; ``relu2``:
 shared one alike) and ``shared_expert_intermediate_size`` (where absent the
 shared expert is ``num_shared_experts`` routed experts wide), and
 ``residual_scale`` (where absent 1: the variance of the experts' last matrix as
-a share of ``lecun_normal``'s). afmoe has one
+a share of ``lecun_normal``'s), and ``router`` (``sigmoid`` where absent;
+``softmax``: ``s = softmax(m W_r)`` over all ``num_experts``, so a chosen
+expert's score depends on every other's, and ``route_norm`` divides the chosen
+probabilities by their sum). afmoe has one
 shared expert, ``route_eps`` 1e-20 and ``route_scale`` 2.826; lfm2_moe has no
 shared expert (no ``shared_experts`` parameters and no ``shared_expert``
 scope exist then), ``route_eps`` 1e-6 and ``route_scale`` 1; nemotron_h has
 ``relu2`` experts, a shared one twice a routed one's width, ``route_eps`` 1e-20
-and ``route_scale`` 2.5.
+and ``route_scale`` 2.5; keye_vl2 has a ``softmax`` router, no shared expert,
+``route_eps`` 0, ``route_scale`` 1 and a bias that never moves
+(``load_balance_coeff`` 0).
 
 The bias lives in the ``moe_state`` collection, beside ``params``: the train
 step keeps it out of the gradient, of AdamW and of the pseudo-gradient, and
@@ -99,7 +104,8 @@ class _MoE(nn.Module):
         with jax.named_scope("router"):
             w_r = self.param("router", nn.initializers.lecun_normal(), (D, E), jnp.float32)
             bias = self.variable(STATE, "expert_bias", jnp.zeros, (E,), jnp.float32).value
-            scores = jax.nn.sigmoid(
+            squash = jax.nn.softmax if getattr(cfg, "router", "sigmoid") == "softmax" else jax.nn.sigmoid
+            scores = squash(
                 jnp.dot(x.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
             )
             _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), K)

@@ -380,7 +380,7 @@ class JobSection:
         default="mnist", metadata={"doc": "dataset name announced by a data node"}
     )
     model_family: str = field(
-        default="lenet", metadata={"doc": "gpt2 | llama | mistral | qwen2 | qwen3 | mixtral | afmoe | lfm2_moe | phi4flash | nemotron_h | lenet"}
+        default="lenet", metadata={"doc": "gpt2 | llama | mistral | qwen2 | qwen3 | mixtral | afmoe | lfm2_moe | phi4flash | nemotron_h | keye_vl2 | lenet"}
     )
     model_preset: str = field(default="", metadata={"doc": "named preset, e.g. small"})
     model_config: dict = field(

@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 
 from .attention import dot_product_attention
+from .index_select import GROUP as _GROUP  # keys behind one block of a selection's words
+from .index_select import masked_attention
 
 __all__ = ["flash_attention"]
 
@@ -119,6 +121,24 @@ def _to_lanes(x, n):
     return jnp.tile(x, (1, n // _LANES))
 
 
+def _selected(sel_ref, kj, block_q, block_k):
+    """[BQ, BK] bool from a selection's packed block (``ops/index_select.py``:
+    a word keeps the lane of its 32 keys, one bit a run of 128): the words
+    tiled over the key tile's lanes and shifted by each lane's run."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    run = (kj * block_k % _GROUP) // _LANES + jax.lax.shift_right_logical(lane, 7)
+    return jax.lax.shift_right_logical(_to_lanes(sel_ref[0], block_k), run) & 1 != 0
+
+
+def _sel_spec(block_q, block_k, heads_a_row):
+    """The block of a selection [B, S, W] for a (batch x head, q tile, k tile)
+    grid: the row's 128 words whose bits hold the k tile's keys, one row for
+    all ``heads_a_row`` heads of a query."""
+    import jax.experimental.pallas as pl
+
+    return pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b // heads_a_row, i, j * block_k // _GROUP))
+
+
 def _legal_block(block: int, dim: int) -> bool:
     """A block this kernel can run: divides the sequence, and its lane
     layout is expressible — whole blocks ≤ 128 lanes (equal-to-dim is
@@ -146,10 +166,13 @@ def _pick_block(dim: int, cap: int) -> int | None:
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, block_q, block_k, scale, causal, num_k, window=None,
+    q_ref, k_ref, v_ref, *rest, block_q, block_k, scale, causal, num_k, window=None,
+    selected=False,
 ):
     import jax.experimental.pallas as pl
+
+    sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
 
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -179,6 +202,8 @@ def _fwd_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
+        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+            s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         m = m_scr[...]  # [BQ, 128] lane-replicated
         m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
         # Fully-masked rows would give exp(-inf - -inf) = nan; clamp.
@@ -208,10 +233,13 @@ def _fwd_kernel(
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dq_scr,
-    *, block_q, block_k, scale, causal, num_k, window=None,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+    block_q, block_k, scale, causal, num_k, window=None, selected=False,
 ):
     import jax.experimental.pallas as pl
+
+    sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    dq_ref, dq_scr = rest
 
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -240,6 +268,8 @@ def _dq_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
+        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+            s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
@@ -253,11 +283,13 @@ def _dq_kernel(
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, block_q, block_k, scale, causal, num_q, reps, window=None,
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+    block_q, block_k, scale, causal, num_q, reps, window=None, selected=False,
 ):
     import jax.experimental.pallas as pl
+
+    sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    dk_ref, dv_ref, dk_scr, dv_scr = rest
 
     kj = pl.program_id(1)
     # Innermost axis enumerates (query-head-in-group, q-block) pairs, so a
@@ -291,6 +323,8 @@ def _dkv_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
+        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+            s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)  # [BQ, BK]
         pc = p.astype(do.dtype)
         dv_scr[...] += jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
@@ -333,13 +367,15 @@ def _kv_index(n_heads: int, n_kv: int):
 
 def _fwd_impl(
     q, k, v, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
-    window=None,
+    window=None, selection=None,
 ):
     """q: [B·H, S, D], k: [B·Hkv, S, D], v: [B·Hkv, S, Dv] → (o [B·H, Sq, Dv],
     lse f32 [B·H, Sq, 128] lane-replicated — see layout note in module doc).
 
     ``window=None`` builds the call as it was before the window existed
-    (``_k_index_map`` has what a window changes)."""
+    (``_k_index_map`` has what a window changes), and ``selection=None`` as it
+    was before a selection did: a selection [B, S, W] is one more input, its
+    block shared by a row's heads."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -351,6 +387,9 @@ def _fwd_impl(
     kwargs = _tpu_kwargs(interpret)
     band = {} if window is None else {"window": window}
     k_map = _k_index_map(kv, block_q, block_k, window)
+    picked, sel_specs = {}, []
+    if selection is not None:
+        picked, sel_specs = {"selected": True}, [_sel_spec(block_q, block_k, n_heads)]
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel,
@@ -360,12 +399,14 @@ def _fwd_impl(
             causal=causal,
             num_k=num_k,
             **band,
+            **picked,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), k_map),
             pl.BlockSpec((1, block_k, dv), k_map),
+            *sel_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -382,12 +423,12 @@ def _fwd_impl(
         ],
         interpret=interpret,
         **kwargs,
-    )(q, k, v)
+    )(q, k, v, *([] if selection is None else [selection]))
 
 
 def _bwd_impl(
     q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret, n_heads, n_kv,
-    window=None,
+    window=None, selection=None,
 ):
     """Cotangents: dq [B·H, Sq, D]; dk [B·Hkv, Sk, D]; dv [B·Hkv, Sk, Dv], as
     wide as ``v``, ``o`` and ``do`` (GQA cotangents
@@ -411,6 +452,9 @@ def _bwd_impl(
     v_spec = pl.BlockSpec((1, block_k, dv), k_map)
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kwargs = _tpu_kwargs(interpret)
+    picked, sel, sel_specs = {}, [], []
+    if selection is not None:
+        picked, sel, sel_specs = {"selected": True}, [selection], [_sel_spec(block_q, block_k, n_heads)]
 
     dq = pl.pallas_call(
         functools.partial(
@@ -421,15 +465,16 @@ def _bwd_impl(
             causal=causal,
             num_k=num_k,
             **band,
+            **picked,
         ),
         grid=(bh, num_q, num_k),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec, *sel_specs],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(q, k, v, o, do, lse)
+    )(q, k, v, o, do, lse, *sel)
 
     # dk/dv: grid over KV heads; k-block outer, (rep, q-block) inner. Index
     # maps see (b_kv, kj, r) with r = rep·num_q + qi; the q-side tensors map
@@ -452,6 +497,9 @@ def _bwd_impl(
     row_spec_t = pl.BlockSpec(
         (1, block_q, _LANES), lambda b, j, r: (qh(b, r), q_tile(j, r), 0)
     )
+    if selection is not None:
+        sel_specs = [pl.BlockSpec(
+            (1, block_q, _LANES), lambda b, j, r: (b // n_kv, q_tile(j, r), j * block_k // _GROUP))]
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel,
@@ -462,9 +510,10 @@ def _bwd_impl(
             num_q=num_q,
             reps=reps,
             **band,
+            **picked,
         ),
         grid=(bh_kv, num_k, reps * num_q),
-        in_specs=[q_spec_t, k_spec_t, v_spec_t, o_spec_t, o_spec_t, row_spec_t],
+        in_specs=[q_spec_t, k_spec_t, v_spec_t, o_spec_t, o_spec_t, row_spec_t, *sel_specs],
         out_specs=[k_spec_t, v_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, seq_k, d), k.dtype),
@@ -476,7 +525,7 @@ def _bwd_impl(
         ],
         interpret=interpret,
         **kwargs,
-    )(q, k, v, o, do, lse)
+    )(q, k, v, o, do, lse, *sel)
     return dq, dk, dv
 
 
@@ -507,6 +556,44 @@ def _scope(name: str, window: int | None) -> str:
     return name if window is None else f"{name}_w{window}"
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _flash_selected(
+    q, k, v, selection, scale, block_q, block_k, bwd_block_q, bwd_block_k,
+    interpret, n_heads, n_kv,
+):
+    """Causal attention over a selection of keys a query; returns the
+    log-sum-exp over the picked keys beside the output (it is no function to
+    differentiate: a cotangent on it is dropped)."""
+    o, lse = _fwd_impl(
+        q, k, v, True, scale, block_q, block_k, interpret, n_heads, n_kv, None, selection
+    )
+    return o, lse
+
+
+def _flash_selected_fwd(
+    q, k, v, selection, scale, block_q, block_k, bwd_block_q, bwd_block_k,
+    interpret, n_heads, n_kv,
+):
+    o, lse = _fwd_impl(
+        q, k, v, True, scale, block_q, block_k, interpret, n_heads, n_kv, None, selection
+    )
+    return (o, lse), (q, k, v, o, lse, selection)
+
+
+def _flash_selected_bwd(
+    scale, block_q, block_k, bwd_block_q, bwd_block_k, interpret, n_heads, n_kv, res, cts,
+):
+    q, k, v, o, lse, selection = res
+    with jax.named_scope("flash_attention_bwd_sel"):
+        return *_bwd_impl(
+            q, k, v, o, lse, cts[0], True, scale, bwd_block_q, bwd_block_k,
+            interpret, n_heads, n_kv, None, selection,
+        ), None
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
 def _flash_bwd(
     causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, interpret,
     n_heads, n_kv, window, res, do,
@@ -535,7 +622,8 @@ def flash_attention(
     block_k_bwd: int | None = None,
     interpret: bool | None = None,
     window: int | None = None,
-) -> jnp.ndarray:
+    selection: jnp.ndarray | None = None,  # int32 [B, Sq, W], packed
+):
     """Flash attention with the framework's [B, S, H, D] convention and GQA.
 
     The value has a width of its own: ``v`` is ``[B, Sk, Hkv, Dv]`` and the
@@ -550,6 +638,15 @@ def flash_attention(
     loaded, in the forward and both backward kernels, so a window layer at
     S > window costs about (window + block) / S of a full layer. ``None``, or
     a window that no query of this length reaches, is the program without it.
+
+    ``selection`` (causal self-attention, no window) is a mask that is data: a
+    packed bit a (query, key) pair (``ops/index_select.py``), one row of it for
+    all the heads of a query. The three kernels apply it beside the causal
+    mask; a tile below the diagonal cannot be skipped on it (picked keys are
+    scattered), a tile above still is. The call then returns ``(o, lse)`` with
+    ``lse`` float32 [B, H, Sq], the log-sum-exp over the picked keys, which the
+    indexer's objective reads; ``o`` alone is differentiated. With no selection
+    the calls are built as they were before one existed.
 
     Differentiable: a custom VJP runs the recomputation backward kernels, so
     this is safe inside the jitted ``value_and_grad`` train step. Tiling
@@ -576,6 +673,8 @@ def flash_attention(
             raise ValueError("window needs causal self-attention and window >= 1")
         if window >= Sq:
             window = None  # never cuts: the plain causal program
+    if selection is not None and (not causal or Sq != Sk or window is not None):
+        raise ValueError("a selection needs causal self-attention without a window")
     explicit_q, explicit_k = block_q is not None, block_k is not None
     if block_q is None:
         block_q = _pick_block(Sq, 512)
@@ -587,7 +686,12 @@ def flash_attention(
         or not _legal_block(block_q, Sq)
         or not _legal_block(block_k, Sk)
         or max(D, Dv) > 128
+        # a key tile's mask is whole runs of 128 lanes out of one block of words
+        or (selection is not None and (block_k % _LANES or _GROUP % block_k))
     ):
+        if selection is not None:
+            scale = softmax_scale if softmax_scale is not None else D**-0.5
+            return masked_attention(q, k, v, selection, scale)
         return dot_product_attention(
             q, k, v, causal=causal, softmax_scale=softmax_scale, window=window
         )
@@ -626,6 +730,16 @@ def flash_attention(
         b, s, h, d = x.shape
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
+    if selection is not None:
+        if block_k_bwd % _LANES or _GROUP % block_k_bwd:
+            raise ValueError(f"block_k_bwd={block_k_bwd} cuts a selection's words")
+        with jax.named_scope("flash_attention_sel"):
+            out, lse = _flash_selected(
+                to_bhsd(q), to_bhsd(k), to_bhsd(v), selection,
+                scale, block_q, block_k, block_q_bwd, block_k_bwd, interpret, H, Hkv,
+            )
+            return (out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3),
+                    jax.lax.stop_gradient(lse[..., 0].reshape(B, H, Sq)))
     # The scope names the forward kernel's device events; the backward
     # kernels are traced from _flash_bwd, under a scope of their own.
     with jax.named_scope(_scope("flash_attention", window)):

@@ -324,6 +324,51 @@ def test_ragged_paged_attention_compiles_for_v5e(chip, quant):
     assert "tpu_custom_call" in text
 
 
+# Keye-VL-2.0's language model at its cell's shapes (B1 S16384, H32/Hkv4 D128,
+# 16 index heads of 64, 2048 keys a query): the three flash kernels under a
+# packed selection, whose words a key tile reads by a lane-wise shift; the
+# choice of the keys (scores in tiles, the k-th value by bisection, the packing);
+# and the KL's walk over the causal triangle with its three gradients.
+KEYE = dict(S=16384, H=32, Hkv=4, D=128, J=16, Di=64, topk=2048)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_under_a_selection_compiles_for_v5e(chip, direction):
+    S, H, Hkv, D = (KEYE[k] for k in ("S", "H", "Hkv", "D"))
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    args = (sds((1, S, H, D), jnp.bfloat16), sds((1, S, Hkv, D), jnp.bfloat16), sds((1, S, Hkv, D), jnp.bfloat16),
+            sds((1, S, S // 32), jnp.int32))
+
+    def fwd(q, k, v, sel):
+        return flash_attention(q, k, v, selection=sel, interpret=False)
+
+    def loss(q, k, v, sel):
+        return fwd(q, k, v, sel)[0].astype(jnp.float32).sum()
+
+    text = _compiled_text(fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+
+
+@pytest.mark.parametrize("part", ["index_select", "index_kl"])
+def test_the_selection_of_keys_and_its_objective_compile_for_v5e(chip, part):
+    from hypha_tpu.ops.index_select import index_kl, index_select
+
+    S, H, Hkv, D, J, Di, topk = (KEYE[k] for k in ("S", "H", "Hkv", "D", "J", "Di", "topk"))
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    indexer = (sds((S, J, Di), jnp.bfloat16), sds((S, Di), jnp.bfloat16), sds((S, J), jnp.float32))
+    if part == "index_select":
+        compiled = jax.jit(lambda qi, ki, w: index_select(qi, ki, w, topk=topk)).lower(*indexer).compile()
+        assert "sort" not in compiled.as_text().lower()  # the choice is by bisection: no sort, no top-k call
+        assert "topk" not in compiled.as_text().lower()
+    else:
+        rest = (sds((S, H, D), jnp.bfloat16), sds((S, Hkv, D), jnp.bfloat16), sds((H, S), jnp.float32),
+                sds((S, S // 32), jnp.int32), sds((S,), jnp.float32))
+        walk = lambda qi, ki, w, q, k, lse, sel, lse_i: index_kl(qi, ki, w, q, k, lse, sel, lse_i, D**-0.5)
+        compiled = jax.jit(jax.value_and_grad(walk, argnums=(0, 1, 2))).lower(*indexer, *rest).compile()
+    # the [S, S] float32 score matrix (1.07 GB) never exists: the temporaries are tiles and rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 if __name__ == "__main__":  # the hashes, for CALLS_AT_THE_PARENT
     for name, dims in FLASH_SHAPES.items():
         for way in ("fwd", "bwd"):
