@@ -308,6 +308,7 @@ def make_chunked_train_step(model, *, loss_chunk: int = 512, donate: bool = True
 # and the step's routing counters, so one device-to-host transfer fetches all.
 ROUTING_FIELDS = (
     "loss", "pairs_routed", "pairs_computed", "load_max", "tokens_elsewhere",
+    "trips", "grad_experts",
 )
 
 
@@ -333,8 +334,8 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
     has two sources. The bias is updated from the step's counts after the
     optimizer (``models.routed.update_bias``); it gets no gradient and no
     moments. The routing counters ride in ``metrics["host"]``
-    (``ROUTING_FIELDS``) beside the loss: pairs summed and ``load_max``
-    maximised over the expert layers.
+    (``ROUTING_FIELDS``) beside the loss: pairs, trips and ``grad_experts``
+    summed and ``load_max`` maximised over the expert layers.
 
     **A second objective.** Where the model's ``stats`` carry ``aux_loss`` (a
     value a layer: a learned key selection's own objective, whose gradient the
@@ -365,11 +366,8 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
             new_state = new_state.replace(
                 extras={STATE: update_bias(state.extras[STATE], stats["chosen"], coeff)}
             )
-        counters = {
-            "pairs_routed": stats["pairs_routed"].sum(),
-            "pairs_computed": stats["pairs_computed"].sum(),
-            "load_max": stats["load_max"].max(),
-            "tokens_elsewhere": stats["tokens_elsewhere"].sum(),
+        counters = {  # over the expert layers: the fullest expert's load, the sum of every other
+            k: stats[k].max() if k == "load_max" else stats[k].sum() for k in ROUTING_FIELDS[1:]
         }
         metrics = {
             "loss": loss,

@@ -1961,8 +1961,9 @@ def run_training(
             return float(metrics["loss"])
         got = dict(zip(ROUTING_FIELDS + aux, np.asarray(host).tolist()))
         routing["steps"] += 1
-        for key in ("pairs_routed", "pairs_computed", "tokens_elsewhere"):
-            routing[key] += int(got[key])
+        for key in ROUTING_FIELDS[1:]:
+            if key != "load_max":
+                routing[key] += int(got[key])
         for key in aux:  # summed here, a mean over the steps on the round's line
             routing[key] += got[key]
         routing["load_max"] = max(routing["load_max"], int(got["load_max"]))
@@ -2035,7 +2036,9 @@ def run_training(
         if routing["steps"]:
             # The routed experts' counters, on a line of their own: pairs
             # computed must equal pairs routed (nothing dropped); a held
-            # expert's mean load is pairs over steps x expert layers x held.
+            # expert's mean load is pairs over steps x expert layers x held;
+            # a trip of the backward walk adds into grad_experts_per_trip
+            # experts' gradient rows (ops/grouped_matmul.py plan_trips).
             mcfg = model.config
             layers = mcfg.num_expert_layers
             cells = routing["steps"] * layers
@@ -2044,13 +2047,14 @@ def run_training(
                 "round %d routing: steps=%d expert_layers=%d experts_held=%d "
                 "pairs_routed=%d pairs_computed=%d pairs_per_token=%.4f "
                 "load_max=%d load_mean=%.2f load_max_over_mean=%.3f "
-                "tokens_elsewhere=%d",
+                "tokens_elsewhere=%d trips=%d grad_experts_per_trip=%.3f",
                 result.rounds - 1, routing["steps"], layers, mcfg.held,
                 routing["pairs_routed"], routing["pairs_computed"],
                 routing["pairs_computed"] / max(round_mark["tokens"] * layers, 1),
                 routing["load_max"], load_mean,
                 routing["load_max"] / max(load_mean, 1e-9),
-                routing["tokens_elsewhere"],
+                routing["tokens_elsewhere"], routing["trips"],
+                routing["grad_experts"] / max(routing["trips"], 1),
             )
             if aux:
                 # The second objective, apart from the loss, under the name the

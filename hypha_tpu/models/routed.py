@@ -48,7 +48,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import grouped_experts, sort_pairs
+from ..ops.grouped_matmul import grouped_experts, plan_trips, sort_pairs
 
 __all__ = ["STATE", "update_bias"]
 
@@ -90,7 +90,8 @@ class _MoE(nn.Module):
     """This rank's part of the routed sum, plus the shared expert where the
     family has one. Returns the output and the step's routing counts:
     ``chosen`` [experts] and the scalars ``pairs_routed``, ``pairs_computed``,
-    ``load_max``, ``tokens_elsewhere``."""
+    ``load_max``, ``tokens_elsewhere``, and of the grouped product's walk
+    ``trips`` and ``grad_experts`` (``ops.grouped_matmul.plan_trips``)."""
 
     config: Any
 
@@ -138,12 +139,15 @@ class _MoE(nn.Module):
             experts = jnp.arange(E, dtype=idx.dtype)
             chosen = jnp.sum(idx[..., None] == experts, axis=(0, 1), dtype=jnp.int32)
             held = (idx >= cfg.expert_offset) & (idx < cfg.expert_offset + G)
+            plan = plan_trips(sizes, cfg.moe_chunk, tokens.shape[0])
             stats = {
                 "chosen": chosen,  # [E]: tokens each expert was chosen for
                 "pairs_routed": jnp.sum(held, dtype=jnp.int32),  # by the choice
                 "pairs_computed": jnp.sum(sizes),  # by what the product walked
                 "load_max": jnp.max(sizes),
                 "tokens_elsewhere": jnp.sum(~held.any(-1), dtype=jnp.int32),
+                "trips": plan["trips"],  # of the walk, forward or backward
+                "grad_experts": plan["grad_experts"],  # whose gradient rows the backward trips added into
             }
         out = routed.reshape(B, S, D).astype(dtype)
         return (out if shared is None else shared + out), stats

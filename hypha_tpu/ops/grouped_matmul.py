@@ -18,8 +18,24 @@ chunk's whatever the imbalance. The worst case, every choice of every token
 held here, is ``tokens * k / chunk`` trips of the same body: slow, never
 wrong. A loop with a data-dependent trip count has no reverse-mode rule, so
 the backward pass is written here (``jax.custom_vjp``): the same walk, each
-chunk recomputing its first products and taking ``jax.vjp`` of the
-chunk function. The residuals are the layer's inputs and the sorted indices.
+chunk recomputing its first products and taking ``jax.vjp`` of the chunk
+function in its rows and weights. The residuals are the layer's inputs and
+the sorted indices.
+
+**What a backward trip costs.** The matrices' gradient is a float32 sum over
+the trips, [held, D, F] a matrix. The sorted rows of a chunk belong to
+contiguous experts (:func:`plan_trips` says which), so a trip whose rows lie
+within ``_window(held)`` of them takes each matrix's cotangent over that
+window alone (the transpose of the grouped product, over the window's group
+sizes) and adds it into that slice of the sum in place: its cost follows its
+rows, not ``held``. A trip whose chunk spans more experts than a window holds,
+nearly empty ones, takes the cotangent dense over all held experts and adds it
+to the whole sum, as every trip did before (0.39 ms a matrix and trip at
+LFM2-24B-A2B's widths, a quarter of the loop's time: ``PERF.md`` §6, PR 51).
+Nothing else of a trip differs between the two kinds, and each kind has a loop
+of its own: trips of one kind come in runs, the two loops take turns inside an
+outer one, and no conditional stands in a loop's body, where the compiler
+copies the sums in and out of it every trip.
 """
 
 from __future__ import annotations
@@ -29,7 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["sort_pairs", "grouped_experts", "FORMS"]
+__all__ = ["sort_pairs", "plan_trips", "grouped_experts", "FORMS"]
 
 
 def sort_pairs(top_idx: jnp.ndarray, expert_offset: int, experts_held: int):
@@ -47,23 +63,37 @@ def sort_pairs(top_idx: jnp.ndarray, expert_offset: int, experts_held: int):
 FORMS = ("swiglu", "relu2")  # an expert's form: how many matrices, and which activation
 
 
-def _chunk_out(xc, wts, ws, sizes, form):
+def _chunk_out(xc, wts, ws, sizes, form, taps=None):
     """One chunk of sorted pairs through its experts: [C, D] -> [C, D] f32,
     weighted. ``ws`` are the expert's matrices by ``form``: ``swiglu`` gate, up
     and down (``down(silu(gate x) * up x)``), ``relu2`` up and down
     (``down(relu(up x)^2)``, no gate). Rows past ``sum(sizes)`` belong to no
-    group; the caller masks them, before and after."""
+    group; the caller masks them, before and after. ``taps``, zeros, one for
+    each matrix, are added to that matrix's product: the backward pass reads a
+    product's cotangent off its tap, and then gets the down product's input
+    beside the output."""
     with jax.named_scope("moe_experts"):
         dot = functools.partial(
             jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=jnp.float32
         )
+        tap = (lambda i, p: p) if taps is None else (lambda i, p: p + taps[i])
         if form == "swiglu":
             w_gate, w_up, w_down = ws
-            act = (jax.nn.silu(dot(xc, w_gate)) * dot(xc, w_up)).astype(xc.dtype)
+            act = (jax.nn.silu(tap(0, dot(xc, w_gate))) * tap(1, dot(xc, w_up))).astype(xc.dtype)
         else:
             w_up, w_down = ws
-            act = jnp.square(jax.nn.relu(dot(xc, w_up))).astype(xc.dtype)
-        return dot(act, w_down) * wts[:, None]
+            act = jnp.square(jax.nn.relu(tap(0, dot(xc, w_up)))).astype(xc.dtype)
+        out = tap(-1, dot(act, w_down)) * wts[:, None]
+        return out if taps is None else (out, act)
+
+
+def _weight_grad(lhs, cot, sizes):
+    """The cotangent of ``w`` in ``ragged_dot(lhs, w, sizes)``, of as many
+    experts as ``sizes`` has: [C, A], [C, B] -> [len(sizes), A, B] in lhs's dtype."""
+    with jax.named_scope("moe_experts"):
+        product = lambda w: jax.lax.ragged_dot(lhs, w, sizes, preferred_element_type=jnp.float32)
+        like = jax.ShapeDtypeStruct((sizes.shape[0], lhs.shape[1], cot.shape[1]), lhs.dtype)
+        return jax.linear_transpose(product, like)(cot)[0]
 
 
 def _walk(pair_token, pair_weight, group_sizes, chunk):
@@ -83,6 +113,40 @@ def _walk(pair_token, pair_weight, group_sizes, chunk):
         return base, rows, jnp.where(valid, wts, 0.0), valid, sizes
 
     return (total + chunk - 1) // chunk, meta
+
+
+def _window(held: int) -> int:
+    """How many contiguous experts a trip's weight gradient is taken over."""
+    return min(held, 4)
+
+
+def plan_trips(group_sizes, chunk: int, pairs: int):
+    """The walk of ``pairs`` sorted rows in chunks of ``chunk``, trip by trip
+    (``pairs`` and ``chunk`` static, as :func:`grouped_experts` is handed
+    them): ``trips``, how many the walk makes; for each of the
+    ``ceil(pairs / chunk)`` it could make, ``windowed`` (its rows belong to at
+    most ``_window`` contiguous experts) and ``start`` (that window's first
+    expert, clipped so that the window lies inside the held ones); and
+    ``grad_experts``, the experts whose gradient rows the backward walk's
+    trips add into: the window's a windowed trip, every held one a trip whose
+    rows span more."""
+    held = group_sizes.shape[0]
+    width = _window(held)
+    chunk = min(chunk, pairs)
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    total = ends[-1]
+    base = jnp.arange(-(-pairs // chunk), dtype=jnp.int32) * chunk
+    last = jnp.minimum(base + chunk, total) - 1
+    # the expert a row belongs to: as many experts' rows end at or before it
+    lo = jnp.sum(ends[None, :] <= base[:, None], axis=1, dtype=jnp.int32)
+    hi = jnp.sum(ends[None, :] <= last[:, None], axis=1, dtype=jnp.int32)
+    windowed = hi - lo < width
+    return {
+        "trips": (total + chunk - 1) // chunk,
+        "windowed": windowed,
+        "start": jnp.minimum(lo, held - width),
+        "grad_experts": jnp.sum(jnp.where(base < total, jnp.where(windowed, width, held), 0)),
+    }
 
 
 def _gather(x, rows, valid):
@@ -115,23 +179,57 @@ def _grouped_fwd(x, ws, pair_token, pair_weight, group_sizes, chunk, form):
 def _grouped_bwd(chunk, form, res, dy):
     x, ws, pair_token, pair_weight, group_sizes = res
     trips, meta = _walk(pair_token, pair_weight, group_sizes, chunk)
+    held = group_sizes.shape[0]
+    width = _window(held)
+    plan = plan_trips(group_sizes, chunk, pair_token.shape[0])
+    taps = tuple(jnp.zeros((chunk, w.shape[2]), jnp.float32) for w in ws)
 
-    def body(i, carry):
+    def trip(i, carry, windowed):
         dx, dwt, dws = carry
         base, rows, wts, valid, sizes = meta(i)
         xc = _gather(x, rows, valid)
-        _, vjp = jax.vjp(lambda xc, wts, ws: _chunk_out(xc, wts, ws, sizes, form), xc, wts, ws)
-        dxc, dwts, dwsc = vjp(_gather(dy, rows, valid))
-        dwt = jax.lax.dynamic_update_slice(dwt, jnp.where(valid, dwts, 0.0), (base,))
-        return (
-            _scatter_add(dx, rows, valid, dxc), dwt,
-            tuple(d + dc.astype(jnp.float32) for d, dc in zip(dws, dwsc)),
+        _, vjp, act = jax.vjp(
+            lambda xc, wts, taps: _chunk_out(xc, wts, ws, sizes, form, taps), xc, wts, taps,
+            has_aux=True,
         )
+        dxc, dwts, cots = vjp(_gather(dy, rows, valid))
+        lhs = (xc,) * (len(ws) - 1) + (act,)  # what each matrix multiplies
+        if windowed:
+            # The chunk's experts lie in [start, start + width): those of the
+            # window before the first one count no row here, so the window's
+            # groups begin where the chunk does.
+            start = plan["start"][i]
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, width)
+            dws = tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    d, cut(d) + _weight_grad(a, c, cut(sizes)).astype(jnp.float32), start, 0)
+                for d, a, c in zip(dws, lhs, cots)
+            )
+        else:
+            dws = tuple(
+                d + _weight_grad(a, c, sizes).astype(jnp.float32) for d, a, c in zip(dws, lhs, cots)
+            )
+        dwt = jax.lax.dynamic_update_slice(dwt, jnp.where(valid, dwts, 0.0), (base,))
+        return _scatter_add(dx, rows, valid, dxc), dwt, dws
+
+    def run(windowed):
+        """The trips from ``i`` on for as long as they are of this kind."""
+        more = lambda state: (state[0] < trips) & (plan["windowed"][state[0]] == windowed)
+        return lambda state: jax.lax.while_loop(
+            more, lambda state: (state[0] + 1, trip(*state, windowed)), state)
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
-    dx, dwt, dws = jax.lax.fori_loop(
-        0, trips, body, (zeros(x), zeros(pair_weight), tuple(zeros(w) for w in ws)),
-    )
+    carry = (zeros(x), zeros(pair_weight), tuple(zeros(w) for w in ws))
+    if width == held:  # every trip adds into every held expert
+        dx, dwt, dws = jax.lax.fori_loop(0, trips, lambda i, carry: trip(i, carry, False), carry)
+    else:
+        # Trips of one kind come in runs (even loads: all windowed; most
+        # experts nearly empty: none), so each kind has a loop of its own and
+        # the two take turns: no conditional in a body (the module's docstring).
+        _, (dx, dwt, dws) = jax.lax.while_loop(
+            lambda state: state[0] < trips, lambda state: run(False)(run(True)(state)),
+            (jnp.int32(0), carry),
+        )
     return (
         dx.astype(x.dtype), tuple(d.astype(w.dtype) for d, w in zip(dws, ws)),
         None, dwt.astype(pair_weight.dtype), None,

@@ -20,7 +20,7 @@ import pytest
 from hypha_tpu.models import build_model
 from hypha_tpu.models.nemotron_h import EXPERTS, FULL, MAMBA2, NemotronHConfig, _Attention, _Mamba2
 from hypha_tpu.models.routed import STATE, _MoE
-from hypha_tpu.ops.grouped_matmul import grouped_experts, sort_pairs
+from hypha_tpu.ops.grouped_matmul import _window, grouped_experts, plan_trips, sort_pairs
 
 SOURCE = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -265,6 +265,86 @@ def test_the_grouped_product_takes_the_expert_form_and_its_gradients_are_the_den
         grouped_experts(x, ws + ws[:1], idx[:, 0], wts[:, 0], jnp.zeros((g,), jnp.int32), form=form)
 
 
+# The sorted pairs of each load, as pairs of every held expert; ``held`` 8 or 16.
+LOADS = {
+    "even": lambda held: [80 // held] * held,
+    "every_pair_on_one_expert": lambda held: [0] * 3 + [70] + [0] * (held - 4),
+    # one row an expert: the chunk spans more experts than a window holds
+    "nearly_empty_experts": lambda held: [1] * held,
+    # empty experts inside a window and at both ends: the last rows' window is clipped
+    "empty_experts_and_both_ends": lambda held: [0, 20, 0] + [0] * (held - 6) + [0, 20, 0],
+    "no_pair_held": lambda held: [0] * held,
+}
+
+
+def _dense_body(x, ws, tokens, wts, sizes, form):
+    """The plain reference: every pair through every held expert, all but its
+    own thrown away; no chunk, no loop, no window."""
+    ends = jnp.cumsum(sizes)
+    expert = jnp.sum(ends[None, :] <= jnp.arange(tokens.shape[0])[:, None], axis=1)
+    own = jax.nn.one_hot(expert, sizes.shape[0], dtype=x.dtype)  # a pair past the held ones: no expert
+    through = lambda rows, w: jnp.einsum("ngd,gdf->ngf", rows, w)
+    rows = jnp.broadcast_to(x[tokens][:, None], (tokens.shape[0], sizes.shape[0], x.shape[1]))
+    if form == "relu2":
+        act = jnp.square(jax.nn.relu(through(rows, ws[0])))
+    else:
+        act = jax.nn.silu(through(rows, ws[0])) * through(rows, ws[1])
+    out = (through(act, ws[-1]) * own[:, :, None]).sum(1) * wts[:, None]
+    return jnp.zeros_like(x).at[tokens].add(out)
+
+
+@pytest.mark.parametrize("chunk", [16, 32])
+@pytest.mark.parametrize("held", [8, 16])
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
+@pytest.mark.parametrize("load", list(LOADS))
+def test_the_windowed_backward_walk_gives_the_dense_bodys_output_and_gradients(load, form, held, chunk):
+    rng = np.random.default_rng(3)
+    t, d, f, n = 48, 16, 24, 96
+    sizes = jnp.asarray(LOADS[load](held), jnp.int32)
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    shapes = [(held, d, f)] * (2 if form == "swiglu" else 1) + [(held, f, d)]
+    ws = tuple(jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32) for s in shapes)
+    tokens = jnp.asarray(rng.integers(0, t, n), jnp.int32)
+    wts = jnp.asarray(rng.random(n), jnp.float32)
+    probe = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+
+    grouped = lambda x, ws, wts: grouped_experts(x, ws, tokens, wts, sizes, form=form, chunk=chunk)
+    dense = lambda x, ws, wts: _dense_body(x, ws, tokens, wts, sizes, form)
+    np.testing.assert_allclose(grouped(x, ws, wts), dense(x, ws, wts), atol=1e-4)
+    got = jax.grad(lambda *a: jnp.sum(grouped(*a) * probe), argnums=(0, 1, 2))(x, ws, wts)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * probe), argnums=(0, 1, 2))(x, ws, wts)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()) + 1e-6)
+
+    plan = plan_trips(sizes, chunk, n)
+    trips, windowed = int(plan["trips"]), np.asarray(plan["windowed"])[: int(plan["trips"])]
+    assert trips == -(-int(sizes.sum()) // chunk) and _window(held) == 4
+    assert int(plan["grad_experts"]) == int(np.where(windowed, 4, held).sum())
+    if load == "nearly_empty_experts":  # one trip over all of them: no window holds it
+        assert list(windowed) == [False]
+    elif load == "empty_experts_and_both_ends":  # only the chunk that holds rows of both spans more
+        assert sorted(windowed) == [False] + [True] * (trips - 1)
+        assert int(plan["start"][trips - 1]) == held - 4  # the last rows' expert is the last but one: clipped
+    elif (load, held, chunk) == ("even", 16, 32):  # five rows an expert: 32 rows span seven, seven, four
+        assert list(windowed) == [False, False, True]
+    else:
+        assert windowed.all()
+
+
+@pytest.mark.parametrize("sizes,chunk,trips,grad_experts", [
+    # rows 0-15 are experts 0, 1, 2; 16-31 experts 2, 4, 5 (3 is empty); 32-42 experts 5, 6, 7
+    ([5, 9, 3, 0, 11, 7, 2, 6], 16, 3, 4 + 4 + 4),
+    ([1] * 8, 16, 1, 8),  # one chunk of eight experts: all held
+    ([40] + [0] * 6 + [1], 16, 3, 4 + 4 + 8),  # the third chunk holds expert 0's last rows and expert 7's one
+    ([6] * 16, 32, 3, 3 * 16),  # 16 held: rows 0-31 experts 0-5, 32-63 experts 5-10, 64-95 experts 10-15
+    ([10] * 16, 32, 5, 5 * 4),  # rows 0-31 experts 0-3, 32-63 experts 3-6, ... 128-159 experts 12-15
+    ([0] * 8, 16, 0, 0),
+])
+def test_the_walks_counters_are_the_trips_and_windows_counted_by_hand(sizes, chunk, trips, grad_experts):
+    plan = plan_trips(jnp.asarray(sizes, jnp.int32), chunk, 256)
+    assert (int(plan["trips"]), int(plan["grad_experts"])) == (trips, grad_experts)
+
+
 # --------------------------------------------------------------------------
 # Training: the routed step through the untied head, and set-up's line
 # --------------------------------------------------------------------------
@@ -319,10 +399,12 @@ def test_no_familys_name_is_in_the_executor():
 # layers from the model; the cells that are there run these programs and they
 # must not move. (phi4flash's is the program since PR 48, which made its
 # differential attention one call a layer with a value twice as wide as the keys:
-# the same script on that tree.)
+# the same script on that tree; afmoe's and lfm2_moe's are the programs since PR 51,
+# which changed the grouped product's backward walk for every routed family on
+# purpose: the windowed weight gradient, ``ops/grouped_matmul.py``. mistral's must not move.)
 STEPS_AT_THE_PARENT = {
-    "afmoe": "6176e29b46871ef2c21c8ec2c301da095473be73783358834578ecb26c900a3c",
-    "lfm2_moe": "4395329e596f8a0b2071d05dcdf6d24c8689079aff8735d04c740aedf4504553",
+    "afmoe": "3a82be12e7153400a1340f109997b7ec64744ad95b1aeb80458de33426a4b099",
+    "lfm2_moe": "40d5a5e997ff3d26efa05055b08d9a6c7af629621f9025bfc771b93ea270d897",
     "mistral": "1ce07af37cee0bec6bbbc61e1a738285162c89baa436722008662b6367f6ad5d",
     "phi4flash": "4301376caff8d494dbae9584114c6039cd3e085e2b2d24fbb9d7802f61f22972",
 }

@@ -176,6 +176,9 @@ def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
     fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2, 3, 5))
     text = _compiled_text(fn, *args)
     assert "ragged-dot" in text and " while(" in text
+    # the backward walk's two kinds of trip are two loops: a conditional in a
+    # loop's body has the float32 sums copied in and out of it every trip
+    assert " conditional(" not in text
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -252,6 +255,9 @@ def test_the_grouped_product_of_two_matrix_experts_compiles_for_v5e(chip, direct
     fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=(0, 1, 3))
     text = _compiled_text(fn, *args)
     assert "ragged-dot" in text and " while(" in text
+    # the backward walk's two kinds of trip are two loops: a conditional in a
+    # loop's body has the float32 sums copied in and out of it every trip
+    assert " conditional(" not in text
 
 
 # The leaves a worker's delta is made of, f32: Nemotron-H's Mamba-2
