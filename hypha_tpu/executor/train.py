@@ -308,7 +308,7 @@ def make_chunked_train_step(model, *, loss_chunk: int = 512, donate: bool = True
 # and the step's routing counters, so one device-to-host transfer fetches all.
 ROUTING_FIELDS = (
     "loss", "pairs_routed", "pairs_computed", "load_max", "tokens_elsewhere",
-    "trips", "grad_experts",
+    "trips", "grad_experts", "combines",
 )
 
 
@@ -334,7 +334,7 @@ def make_routed_train_step(model, *, loss_chunk: int = 512, donate: bool = True)
     has two sources. The bias is updated from the step's counts after the
     optimizer (``models.routed.update_bias``); it gets no gradient and no
     moments. The routing counters ride in ``metrics["host"]``
-    (``ROUTING_FIELDS``) beside the loss: pairs, trips and ``grad_experts``
+    (``ROUTING_FIELDS``) beside the loss: pairs, trips, combines and ``grad_experts``
     summed and ``load_max`` maximised over the expert layers.
 
     **A second objective.** Where the model's ``stats`` carry ``aux_loss`` (a

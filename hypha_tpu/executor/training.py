@@ -2038,7 +2038,8 @@ def run_training(
             # computed must equal pairs routed (nothing dropped); a held
             # expert's mean load is pairs over steps x expert layers x held;
             # a trip of the backward walk adds into grad_experts_per_trip
-            # experts' gradient rows (ops/grouped_matmul.py plan_trips).
+            # experts' gradient rows, and the trips' rows went onto the tokens
+            # in combines batches (ops/grouped_matmul.py plan_trips).
             mcfg = model.config
             layers = mcfg.num_expert_layers
             cells = routing["steps"] * layers
@@ -2047,14 +2048,14 @@ def run_training(
                 "round %d routing: steps=%d expert_layers=%d experts_held=%d "
                 "pairs_routed=%d pairs_computed=%d pairs_per_token=%.4f "
                 "load_max=%d load_mean=%.2f load_max_over_mean=%.3f "
-                "tokens_elsewhere=%d trips=%d grad_experts_per_trip=%.3f",
+                "tokens_elsewhere=%d trips=%d grad_experts_per_trip=%.3f combines=%d",
                 result.rounds - 1, routing["steps"], layers, mcfg.held,
                 routing["pairs_routed"], routing["pairs_computed"],
                 routing["pairs_computed"] / max(round_mark["tokens"] * layers, 1),
                 routing["load_max"], load_mean,
                 routing["load_max"] / max(load_mean, 1e-9),
                 routing["tokens_elsewhere"], routing["trips"],
-                routing["grad_experts"] / max(routing["trips"], 1),
+                routing["grad_experts"] / max(routing["trips"], 1), routing["combines"],
             )
             if aux:
                 # The second objective, apart from the loss, under the name the

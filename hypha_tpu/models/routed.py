@@ -48,9 +48,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import grouped_experts, plan_trips, sort_pairs
+from ..ops.grouped_matmul import grouped_experts, plan_trips, sort_pairs_weighted
 
-__all__ = ["STATE", "update_bias"]
+__all__ = ["STATE", "chosen_scores", "update_bias"]
 
 STATE = "moe_state"  # the variable collection of the selection biases
 
@@ -86,12 +86,21 @@ class _Relu2(nn.Module):
         return dense(x.shape[-1], "down_proj", kernel_init=_lecun(self.down_scale))(act)
 
 
+def chosen_scores(scores: jnp.ndarray, hot: jnp.ndarray) -> jnp.ndarray:
+    """``take_along_axis(scores, idx, -1)`` by selection: ``scores`` [T, E] and
+    ``hot`` [T, K, E] (``idx[..., None] == arange(E)``) -> [T, K]. One term and
+    exact zeros a sum, so the same float32 value; fused elementwise passes
+    forward and backward (the transpose is a dense select) where the indexed
+    form gathers, and scatters back, T*K scalars at 10 ns each on a v5e."""
+    return jnp.sum(jnp.where(hot, scores[:, None, :], 0), axis=-1)
+
+
 class _MoE(nn.Module):
     """This rank's part of the routed sum, plus the shared expert where the
     family has one. Returns the output and the step's routing counts:
     ``chosen`` [experts] and the scalars ``pairs_routed``, ``pairs_computed``,
     ``load_max``, ``tokens_elsewhere``, and of the grouped product's walk
-    ``trips`` and ``grad_experts`` (``ops.grouped_matmul.plan_trips``)."""
+    ``trips``, ``combines`` and ``grad_experts`` (``ops.grouped_matmul.plan_trips``)."""
 
     config: Any
 
@@ -110,7 +119,8 @@ class _MoE(nn.Module):
                 jnp.dot(x.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
             )
             _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), K)
-            w = jnp.take_along_axis(scores, idx, axis=-1)
+            hot = idx[..., None] == jnp.arange(E, dtype=idx.dtype)  # [T, K, E]
+            w = chosen_scores(scores, hot)
             if cfg.route_norm:
                 w = w / (w.sum(-1, keepdims=True) + cfg.route_eps)
             w = w * cfg.route_scale
@@ -129,17 +139,15 @@ class _MoE(nn.Module):
                        (G, F, D) if name == "down" else (G, D, F), jnp.float32)
             for name in (("up", "down") if form == "relu2" else ("gate", "up", "down")))
         with jax.named_scope("moe_dispatch"):
-            order, sizes = sort_pairs(idx, cfg.expert_offset, G)
-            tokens, weights = order // K, w.reshape(-1)[order]
+            tokens, weights, sizes = sort_pairs_weighted(idx, w, cfg.expert_offset, G)
         routed = grouped_experts(
             x, tuple(w.astype(dtype) for w in ws), tokens, weights, sizes,
             form=form, chunk=cfg.moe_chunk,
         )
         with jax.named_scope("router"):
-            experts = jnp.arange(E, dtype=idx.dtype)
-            chosen = jnp.sum(idx[..., None] == experts, axis=(0, 1), dtype=jnp.int32)
+            chosen = jnp.sum(hot, axis=(0, 1), dtype=jnp.int32)
             held = (idx >= cfg.expert_offset) & (idx < cfg.expert_offset + G)
-            plan = plan_trips(sizes, cfg.moe_chunk, tokens.shape[0])
+            plan = plan_trips(sizes, cfg.moe_chunk, tokens.shape[0], x.shape[0])
             stats = {
                 "chosen": chosen,  # [E]: tokens each expert was chosen for
                 "pairs_routed": jnp.sum(held, dtype=jnp.int32),  # by the choice
@@ -147,6 +155,7 @@ class _MoE(nn.Module):
                 "load_max": jnp.max(sizes),
                 "tokens_elsewhere": jnp.sum(~held.any(-1), dtype=jnp.int32),
                 "trips": plan["trips"],  # of the walk, forward or backward
+                "combines": plan["combines"],  # the batches their rows went onto the tokens in
                 "grad_experts": plan["grad_experts"],  # whose gradient rows the backward trips added into
             }
         out = routed.reshape(B, S, D).astype(dtype)

@@ -16,7 +16,7 @@ from hypha_tpu.models import build_model
 from hypha_tpu.models.afmoe import FULL, SLIDING, STATE, AfmoeConfig, update_bias
 from hypha_tpu.ops.attention import dot_product_attention
 from hypha_tpu.ops.flash_attention import flash_attention
-from hypha_tpu.ops.grouped_matmul import grouped_experts, sort_pairs
+from hypha_tpu.ops.grouped_matmul import grouped_experts, sort_pairs, sort_pairs_weighted
 
 # --------------------------------------------------------------------------
 # The flash kernel's window
@@ -178,6 +178,69 @@ def test_sorted_pairs_count_every_held_choice_once():
     assert list(held) == sorted(held) and set(held) <= {2, 3, 4, 5}
 
 
+ROUTINGS = ["uniform", "an_empty_expert", "every_token_to_one_expert", "every_choice_held", "nothing_held"]
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_the_sort_that_carries_the_weights_is_the_gather_by_the_order_bit_for_bit(kind):
+    """``sort_pairs_weighted`` against ``order // k`` and ``w.reshape(-1)[order]``:
+    the tokens, the weights and the weights' gradient, to the bit."""
+    idx, wt = _routing(kind)
+    k = idx.shape[1]
+    order, sizes = sort_pairs(idx, 2, 4)
+    tokens, weights, sizes_too = sort_pairs_weighted(idx, wt, 2, 4)
+    assert tokens.dtype == jnp.int32 and weights.dtype == wt.dtype
+    np.testing.assert_array_equal(tokens, order // k)
+    np.testing.assert_array_equal(weights, wt.reshape(-1)[order])
+    np.testing.assert_array_equal(sizes_too, sizes)
+    ct = jax.random.normal(jax.random.key(9), (idx.size,))
+    carried = jax.grad(lambda wt: (sort_pairs_weighted(idx, wt, 2, 4)[1] * ct).sum())(wt)
+    gathered = jax.grad(lambda wt: (wt.reshape(-1)[order] * ct).sum())(wt)
+    np.testing.assert_array_equal(carried, gathered)
+    jitted = jax.jit(jax.grad(lambda wt, idx: (sort_pairs_weighted(idx, wt, 2, 4)[1] * ct).sum()))(wt, idx)
+    np.testing.assert_array_equal(jitted, gathered)
+
+
+@pytest.mark.parametrize("shape", [(96,), (3, 32)])
+@pytest.mark.parametrize("bound", [5, 2 ** 31], ids=["one_word", "key_and_place_apart"])
+def test_a_key_sorted_with_its_place_in_one_word_is_the_stable_sort(bound, shape):
+    """``_sorted_stably`` packs key and place into one word where both fit (every
+    word differs: no tie left to keep) and sorts stably where they do not: the
+    same keys, places and carried values either way, along the last axis."""
+    from hypha_tpu.ops.grouped_matmul import _sorted_stably
+
+    rng = np.random.default_rng(1)
+    key = rng.integers(0, 5, shape).astype(np.int32)  # many ties
+    carried = rng.random(shape).astype(np.float32)
+    got_key, got_at, got_carried = _sorted_stably(jnp.asarray(key), bound, jnp.asarray(carried))
+    at = np.argsort(key, axis=-1, kind="stable")
+    np.testing.assert_array_equal(got_at, at)
+    np.testing.assert_array_equal(got_key, np.take_along_axis(key, at, -1))
+    np.testing.assert_array_equal(got_carried, np.take_along_axis(carried, at, -1))
+
+
+@pytest.mark.parametrize("squash", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_the_chosen_scores_by_selection_are_take_along_axis_bit_for_bit(kind, squash):
+    from hypha_tpu.models.routed import chosen_scores
+
+    idx, _ = _routing(kind)
+    logits = jax.random.normal(jax.random.key(4), (idx.shape[0], 8)) * 3
+    ct = jax.random.normal(jax.random.key(6), idx.shape)
+    scores = lambda logits: getattr(jax.nn, squash)(logits)
+    hot = idx[..., None] == jnp.arange(8, dtype=idx.dtype)
+    selected = lambda logits: chosen_scores(scores(logits), hot)
+    indexed = lambda logits: jnp.take_along_axis(scores(logits), idx, axis=-1)
+    np.testing.assert_array_equal(selected(logits), indexed(logits))
+    # the gradient in the scores themselves: a select there, a scatter-add here
+    np.testing.assert_array_equal(
+        jax.grad(lambda s: (chosen_scores(s, hot) * ct).sum())(scores(logits)),
+        jax.grad(lambda s: (jnp.take_along_axis(s, idx, axis=-1) * ct).sum())(scores(logits)))
+    np.testing.assert_allclose(
+        jax.grad(lambda l: (selected(l) * ct).sum())(logits),
+        jax.grad(lambda l: (indexed(l) * ct).sum())(logits), rtol=0, atol=0)
+
+
 def test_the_trip_count_follows_the_pairs_held_not_the_pairs_there_are():
     """The loop's bound is ceil(pairs held / chunk): read from the jaxpr's
     while condition by running it on two routings of the same shapes."""
@@ -306,6 +369,7 @@ def test_the_routed_step_learns_updates_the_bias_and_keeps_it_out_of_adamw(ids, 
         host = dict(zip(ROUTING_FIELDS, np.asarray(metrics["host"]).tolist()))
         losses.append(host["loss"])
         assert host["pairs_routed"] == host["pairs_computed"] > 0
+        assert 0 < host["combines"] <= host["trips"]  # a trip a combine at the most
         assert host["loss"] == pytest.approx(float(metrics["loss"]))
     assert losses[-1] < losses[0] - 0.3
     moved = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()), state.extras, before)

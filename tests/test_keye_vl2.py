@@ -274,13 +274,15 @@ def test_no_familys_name_is_in_the_executor_and_no_switch_for_the_forms():
 # same script run on both trees. The flash kernels learnt a selection, ``_MoE`` a
 # router's kind and the routed step a second objective; the six cells that are
 # there run these programs and they must not move. (The three routed families'
-# are the programs since PR 51, which changed the grouped product's backward walk
-# on purpose; mistral's and phi4flash's, which have no routed layer, are 096803d's.)
+# are the programs since PR 52, which changed on purpose how the grouped product's
+# rows go onto the tokens and how the router makes the pairs' weights, as PR 51 had
+# changed the backward walk; mistral's and phi4flash's, which have no routed layer,
+# are 096803d's: the proof that the controls' programs did not change.)
 STEPS_AT_THE_PARENT = {
-    "afmoe": "3a82be12e7153400a1340f109997b7ec64744ad95b1aeb80458de33426a4b099",
-    "lfm2_moe": "40d5a5e997ff3d26efa05055b08d9a6c7af629621f9025bfc771b93ea270d897",
+    "afmoe": "61505bc922d89977289981fc321b233fd02ca26af46d7748639bf0b2e53508f9",
+    "lfm2_moe": "db2b81830a3dcfe5e3beb6d164699c9ecac4dbb901a3aa7f59d6795f2a3277e5",
     "mistral": "1ce07af37cee0bec6bbbc61e1a738285162c89baa436722008662b6367f6ad5d",
-    "nemotron_h": "4cc75bd7838ca9f67a438693039cacf23c79549ff6a90a4838ff797432bdcaa5",
+    "nemotron_h": "65aada133e7c05802d4be8278e614273db2dcbe979e98be3f3927f33da1afe53",
     "phi4flash": "4301376caff8d494dbae9584114c6039cd3e085e2b2d24fbb9d7802f61f22972",
 }
 MISTRAL = {"vocab_size": 256, "hidden_size": 64, "intermediate_size": 128, "num_layers": 2,

@@ -241,9 +241,11 @@ def test_a_configuration_with_kinds_and_no_head_size_is_logged_without_one(ids, 
 # lowers it: the same script run on both trees. ``_MoE`` moved to
 # ``models/routed.py``, took its epsilon from the configuration and learnt to
 # leave the shared expert out; Trinity's cell runs this program and it must
-# not move. (Since PR 51 the hash is that tree's: it changed the grouped
-# product's backward walk for every routed family on purpose.)
-AFMOE_STEP_AT_THE_PARENT = "3a82be12e7153400a1340f109997b7ec64744ad95b1aeb80458de33426a4b099"
+# not move. (Since PR 52 the hash is that tree's: it changed, for every routed
+# family on purpose, how the grouped product's rows go onto the tokens, in
+# batches, and how the router makes the pairs' weights, by selection and
+# through the sort; PR 51 had changed the backward walk the same way.)
+AFMOE_STEP_AT_THE_PARENT = "61505bc922d89977289981fc321b233fd02ca26af46d7748639bf0b2e53508f9"
 
 
 def test_afmoes_step_lowers_to_the_program_of_the_parent_commit():

@@ -7,6 +7,7 @@ untied head, and the other families' programs left as they were."""
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import logging
 import types
@@ -20,6 +21,7 @@ import pytest
 from hypha_tpu.models import build_model
 from hypha_tpu.models.nemotron_h import EXPERTS, FULL, MAMBA2, NemotronHConfig, _Attention, _Mamba2
 from hypha_tpu.models.routed import STATE, _MoE
+from hypha_tpu.ops import grouped_matmul
 from hypha_tpu.ops.grouped_matmul import _window, grouped_experts, plan_trips, sort_pairs
 
 SOURCE = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -316,7 +318,7 @@ def test_the_windowed_backward_walk_gives_the_dense_bodys_output_and_gradients(l
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()) + 1e-6)
 
-    plan = plan_trips(sizes, chunk, n)
+    plan = plan_trips(sizes, chunk, n, x.shape[0])
     trips, windowed = int(plan["trips"]), np.asarray(plan["windowed"])[: int(plan["trips"])]
     assert trips == -(-int(sizes.sum()) // chunk) and _window(held) == 4
     assert int(plan["grad_experts"]) == int(np.where(windowed, 4, held).sum())
@@ -341,8 +343,86 @@ def test_the_windowed_backward_walk_gives_the_dense_bodys_output_and_gradients(l
     ([0] * 8, 16, 0, 0),
 ])
 def test_the_walks_counters_are_the_trips_and_windows_counted_by_hand(sizes, chunk, trips, grad_experts):
-    plan = plan_trips(jnp.asarray(sizes, jnp.int32), chunk, 256)
+    plan = plan_trips(jnp.asarray(sizes, jnp.int32), chunk, 256, 64)
     assert (int(plan["trips"]), int(plan["grad_experts"])) == (trips, grad_experts)
+
+
+# How many of the walk's 96 sorted pairs are held, for a combine of two trips
+# (``rows`` 32 or 64), and the trips and combines that makes, counted by hand.
+COMBINES = {
+    "fewer_rows_than_one_batch": lambda rows: 20,  # one combine, most of it masked
+    "exactly_one_batch": lambda rows: rows,
+    "several_batches_and_a_masked_tail": lambda rows: 70,  # 32: 32 + 32 + 6; 64: 64 + 6
+    "every_pair_of_every_token": lambda rows: 96,  # the worst case: T * k / rows combines
+    "no_pair_held": lambda rows: 0,
+    "a_token_in_two_batches": lambda rows: 96,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _batched_and_dense(form, held, chunk):
+    """The grouped product with two trips a combine, and the dense body, jitted
+    once for all loads: the routing is an argument, as the router makes it."""
+    def grouped(x, ws, tokens, wts, sizes):
+        return grouped_experts(x, ws, tokens, wts, sizes, form=form, chunk=chunk)
+
+    def both(fn):
+        probe = jnp.asarray(np.random.default_rng(5).normal(size=(48, 16)), jnp.float32)
+        grads = jax.grad(lambda x, ws, tokens, wts, sizes: jnp.sum(fn(x, ws, tokens, wts, sizes) * probe),
+                         argnums=(0, 1, 3))
+        return jax.jit(lambda *a: (fn(*a), grads(*a)))
+
+    return both(grouped), both(lambda *a: _dense_body(*a, form))
+
+
+@pytest.mark.parametrize("chunk", [16, 32])
+@pytest.mark.parametrize("held", [8, 16])
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
+@pytest.mark.parametrize("case", list(COMBINES))
+def test_trips_combined_in_batches_give_the_dense_bodys_output_and_gradients(case, form, held, chunk, monkeypatch):
+    rows = 2 * chunk  # of a combine; the module's own rule would give these 48 tokens a trip a combine
+    monkeypatch.setattr(grouped_matmul, "_combine_rows", lambda tokens: rows)  # read while tracing
+    rng = np.random.default_rng(7)
+    t, d, f, n = 48, 16, 24, 96
+    total = COMBINES[case](rows)
+    sizes = np.full(held, total // held)
+    sizes[-1] += total - sizes.sum()
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    shapes = [(held, d, f)] * (2 if form == "swiglu" else 1) + [(held, f, d)]
+    ws = tuple(jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32) for s in shapes)
+    tokens = rng.integers(0, t, n)  # 96 rows onto 48 tokens: a token has several rows in a batch
+    if case == "a_token_in_two_batches":
+        tokens[rows - 2: rows + 2] = 7  # the first batch's last rows and the second's first
+    args = (x, ws, jnp.asarray(tokens, jnp.int32), jnp.asarray(rng.random(n), jnp.float32), jnp.asarray(sizes, jnp.int32))
+
+    grouped, dense = _batched_and_dense(form, held, chunk)
+    (got, got_grads), (want, want_grads) = grouped(*args), dense(*args)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()) + 1e-6)
+    plan = plan_trips(args[-1], chunk, n, t)
+    by_hand = {  # (chunk, case): trips, combines
+        (16, "fewer_rows_than_one_batch"): (2, 1), (32, "fewer_rows_than_one_batch"): (1, 1),
+        (16, "exactly_one_batch"): (2, 1), (32, "exactly_one_batch"): (2, 1),
+        (16, "several_batches_and_a_masked_tail"): (5, 3), (32, "several_batches_and_a_masked_tail"): (3, 2),
+        (16, "every_pair_of_every_token"): (6, 3), (32, "every_pair_of_every_token"): (3, 2),
+        (16, "no_pair_held"): (0, 0), (32, "no_pair_held"): (0, 0),
+        (16, "a_token_in_two_batches"): (6, 3), (32, "a_token_in_two_batches"): (3, 2),
+    }
+    assert (int(plan["trips"]), int(plan["combines"])) == by_hand[chunk, case]
+
+
+def test_a_trip_is_a_combine_where_a_trip_is_as_large_as_a_batch_may_be(monkeypatch):
+    """``combines == trips`` there, and the walk is the one without held rows."""
+    sizes = jnp.asarray([5, 9, 3, 0, 11, 7, 2, 6], jnp.int32)
+    for rows in (1, 16, 31):  # under two trips' rows: a batch is one trip's
+        monkeypatch.setattr(grouped_matmul, "_combine_rows", lambda tokens, rows=rows: rows)
+        plan = plan_trips(sizes, 16, 256, 64)
+        assert (int(plan["trips"]), int(plan["combines"])) == (3, 3)
+        assert grouped_matmul._batch_rows(64, 16, 256) == (16, 16)
+    monkeypatch.setattr(grouped_matmul, "_combine_rows", lambda tokens: 10 ** 6)
+    assert grouped_matmul._batch_rows(64, 16, 250) == (16, 256)  # no more than the padded pairs
+    assert int(plan_trips(sizes, 16, 250, 64)["combines"]) == 1
 
 
 # --------------------------------------------------------------------------
@@ -399,12 +479,15 @@ def test_no_familys_name_is_in_the_executor():
 # layers from the model; the cells that are there run these programs and they
 # must not move. (phi4flash's is the program since PR 48, which made its
 # differential attention one call a layer with a value twice as wide as the keys:
-# the same script on that tree; afmoe's and lfm2_moe's are the programs since PR 51,
-# which changed the grouped product's backward walk for every routed family on
-# purpose: the windowed weight gradient, ``ops/grouped_matmul.py``. mistral's must not move.)
+# the same script on that tree; afmoe's and lfm2_moe's are the programs since PR 52,
+# which changed for every routed family on purpose how the grouped product's rows go
+# onto the tokens (in batches, ``ops/grouped_matmul.py``) and how the router makes the
+# pairs' weights (``models/routed.py``), as PR 51 had changed the backward walk.
+# mistral's and phi4flash's must not move: they are the proof that a program with
+# no routed layer is the one it was.)
 STEPS_AT_THE_PARENT = {
-    "afmoe": "3a82be12e7153400a1340f109997b7ec64744ad95b1aeb80458de33426a4b099",
-    "lfm2_moe": "40d5a5e997ff3d26efa05055b08d9a6c7af629621f9025bfc771b93ea270d897",
+    "afmoe": "61505bc922d89977289981fc321b233fd02ca26af46d7748639bf0b2e53508f9",
+    "lfm2_moe": "db2b81830a3dcfe5e3beb6d164699c9ecac4dbb901a3aa7f59d6795f2a3277e5",
     "mistral": "1ce07af37cee0bec6bbbc61e1a738285162c89baa436722008662b6367f6ad5d",
     "phi4flash": "4301376caff8d494dbae9584114c6039cd3e085e2b2d24fbb9d7802f61f22972",
 }

@@ -300,11 +300,12 @@ def test_the_model_learns_a_counting_sequence_through_the_chunked_step():
 # of its own (``causal_taps``), the routed step its loss (``_head_loss``), and
 # set-up's ``operators:`` line learnt a second size; the cells that are there
 # run these programs and they must not move. (afmoe's and lfm2_moe's are the
-# programs since PR 51, which changed the grouped product's backward walk for
-# every routed family on purpose; mistral's is e8de834's.)
+# programs since PR 52, which changed for every routed family on purpose how the
+# grouped product's rows go onto the tokens and how the router makes the pairs'
+# weights, as PR 51 had changed the backward walk; mistral's is e8de834's.)
 STEPS_AT_THE_PARENT = {
-    "afmoe": "3a82be12e7153400a1340f109997b7ec64744ad95b1aeb80458de33426a4b099",
-    "lfm2_moe": "40d5a5e997ff3d26efa05055b08d9a6c7af629621f9025bfc771b93ea270d897",
+    "afmoe": "61505bc922d89977289981fc321b233fd02ca26af46d7748639bf0b2e53508f9",
+    "lfm2_moe": "db2b81830a3dcfe5e3beb6d164699c9ecac4dbb901a3aa7f59d6795f2a3277e5",
     "mistral": "1ce07af37cee0bec6bbbc61e1a738285162c89baa436722008662b6367f6ad5d",
 }
 MISTRAL = {"vocab_size": 256, "hidden_size": 64, "intermediate_size": 128, "num_layers": 2,

@@ -148,37 +148,66 @@ def test_equal_widths_trace_to_the_calls_of_the_parent_commit(shape, direction):
     assert _calls_hash(shape, direction) == CALLS_AT_THE_PARENT[shape, direction]
 
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_the_grouped_expert_product_compiles_for_v5e(chip, direction):
-    """One rank's routed experts at Trinity-Mini's widths: 8192 tokens, 8 of
-    128 experts held, 8 choices a token, the worst-case 65536 sorted pairs
-    walked in chunks of 2048 by a loop the compiler keeps as a loop, around
-    its own grouped-matmul call."""
+# One rank's routed experts as three cells run them: tokens, width, expert
+# width, experts held, choices a token, the expert's form; every choice of every
+# token as the sorted pairs (the worst case), walked in chunks of 2048.
+GROUPED_SHAPES = {
+    "trinity": (8192, 2048, 1024, 8, 8, "swiglu"),
+    "lfm2": (16384, 2048, 1536, 8, 4, "swiglu"),
+    "nemotron": (8192, 2688, 1856, 8, 6, "relu2"),  # squared ReLU and no gate: two grouped products a chunk
+}
+# ``memory_analysis().temp_size_in_bytes`` of Nemotron's shape at the parent
+# commit (c11993f: a trip's rows went onto the tokens a trip at a time), by the
+# same compile: its cell stands at 15.54 GB of the chip's 15.75.
+NEMOTRON_TEMP_AT_THE_PARENT = {"fwd": 83_091_968, "bwd": 928_245_248}
+
+
+def _compiled_grouped(chip, shape, direction):
     from hypha_tpu.ops.grouped_matmul import grouped_experts
 
-    T, D, F, G, N = 8192, 2048, 1024, 8, 8192 * 8
+    T, D, F, G, K, form = GROUPED_SHAPES[shape]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    args = (
-        sds((T, D), jnp.bfloat16), sds((G, D, F), jnp.bfloat16), sds((G, D, F), jnp.bfloat16),
-        sds((G, F, D), jnp.bfloat16), sds((N,), jnp.int32), sds((N,), jnp.float32),
-        sds((G,), jnp.int32),
-    )
+    ws = tuple([sds((G, D, F), jnp.bfloat16)] * (2 if form == "swiglu" else 1) + [sds((G, F, D), jnp.bfloat16)])
+    args = (sds((T, D), jnp.bfloat16), ws, sds((T * K,), jnp.int32), sds((T * K,), jnp.float32), sds((G,), jnp.int32))
 
-    def fwd(x, wg, wu, wd, tok, wt, sizes):
-        return grouped_experts(x, (wg, wu, wd), tok, wt, sizes)
+    def fwd(x, ws, tok, wt, sizes):
+        return grouped_experts(x, ws, tok, wt, sizes, form=form)
 
-    def loss(x, wg, wu, wd, tok, wt, sizes):
-        return fwd(x, wg, wu, wd, tok, wt, sizes).sum()
+    fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=(0, 1, 3))
+    return jax.jit(fn).lower(*args).compile()
 
-    fn = fwd if direction == "fwd" else jax.grad(loss, argnums=(0, 1, 2, 3, 5))
-    text = _compiled_text(fn, *args)
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", list(GROUPED_SHAPES))
+def test_the_grouped_expert_product_compiles_for_v5e(chip, shape, direction):
+    """A loop the compiler keeps as a loop around its own grouped-matmul call,
+    and the trips' rows onto the tokens in batches: 4096 rows of two trips at
+    8192 tokens, 10240 of five at LFM2's 16384 (``_combine_rows``)."""
+    from hypha_tpu.ops.grouped_matmul import _batch_rows
+
+    T, D, _, _, K, _ = GROUPED_SHAPES[shape]
+    compiled = _compiled_grouped(chip, shape, direction)
+    text = compiled.as_text()
     assert "ragged-dot" in text and " while(" in text
-    # the backward walk's two kinds of trip are two loops: a conditional in a
-    # loop's body has the float32 sums copied in and out of it every trip
+    # the backward walk's two kinds of trip are two loops, and a combine is a
+    # loop level: a conditional in a loop's body has the float32 sums copied in
+    # and out of it every trip
     assert " conditional(" not in text
+    # Which lowering the combine takes: the one scatter of the program (one
+    # batch's rows, whatever the kind of its trips) is handed its indices
+    # ascending, so the compiler sorts nothing itself: the one sort is
+    # ``_by_token``'s, once a layer, outside the loops.
+    assert _batch_rows(T, 2048, T * K) == (2048, {8192: 4096, 16384: 10240}[T])
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert len(scatters) == 1 and "indices_are_sorted=true" in scatters[0]
+    assert text.count(" sort(") == 1
+    if shape == "nemotron":  # the held rows, [4096, 2688] float32, and the order they go in; nothing else
+        held_rows = 4096 * D * 4
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= NEMOTRON_TEMP_AT_THE_PARENT[direction] + held_rows + 2 * 2 ** 20
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -232,32 +261,6 @@ def test_the_ssd_scan_compiles_for_v5e(chip, direction):
     text = compiled.as_text()
     assert "convolution(" in text or "dot(" in text  # the products are the MXU's
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
-
-
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_the_grouped_product_of_two_matrix_experts_compiles_for_v5e(chip, direction):
-    """One rank's routed experts at the Nemotron-H causal tower's widths: 8192
-    tokens, 8 of 128 experts held, 6 choices a token, squared ReLU and no gate:
-    two grouped products a chunk where the gated expert has three."""
-    from hypha_tpu.ops.grouped_matmul import grouped_experts
-
-    T, D, F, G, N = 8192, 2688, 1856, 8, 8192 * 6
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    args = (sds((T, D), jnp.bfloat16), (sds((G, D, F), jnp.bfloat16), sds((G, F, D), jnp.bfloat16)),
-            sds((N,), jnp.int32), sds((N,), jnp.float32), sds((G,), jnp.int32))
-
-    def fwd(x, ws, tok, wt, sizes):
-        return grouped_experts(x, ws, tok, wt, sizes, form="relu2")
-
-    fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=(0, 1, 3))
-    text = _compiled_text(fn, *args)
-    assert "ragged-dot" in text and " while(" in text
-    # the backward walk's two kinds of trip are two loops: a conditional in a
-    # loop's body has the float32 sums copied in and out of it every trip
-    assert " conditional(" not in text
 
 
 # The leaves a worker's delta is made of, f32: Nemotron-H's Mamba-2
