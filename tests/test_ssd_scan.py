@@ -148,3 +148,119 @@ def test_bfloat16_inputs_give_float32_outputs_near_the_float32_result():
 def test_the_scope_is_in_the_jaxpr():
     text = str(jax.make_jaxpr(lambda *t: ssd_scan(*t))(*inputs(128)).pretty_print(name_stack=True))
     assert "ssd_scan" in text
+
+
+# --------------------------------------------------------------------------
+# The kernels, interpreted (``interpret=True``): what the chip runs compiled
+# --------------------------------------------------------------------------
+
+# Tolerances. Float32 inputs: the kernels sum a chunk's products in another
+# order than the ``einsum``s (a slab of heads a product, the running sum as a
+# product with a triangle of ones), so values agree to float32 rounding over a
+# chunk's 128 terms (2e-5 of values of order one, as the einsum form's own tests
+# above) and gradients to 2e-4 of each gradient's largest entry. bfloat16
+# inputs: both forms round the masked ``C B^T`` and the state to bfloat16 before
+# the MXU, at points that differ (the kernels' backward pass leaves the step with
+# the inputs, the ``einsum``s' with the mask), so they agree as the file's
+# existing bfloat16 case does with float32: within a few roundings of 2^-8.
+KERNEL_CASES = {
+    # a sequence that is no multiple of the chunk: padded with steps of 0
+    "no_multiple_of_the_chunk": dict(s=300, heads=4, groups=2, chunk=128),
+    "shorter_than_a_chunk": dict(s=20, heads=4, groups=2, chunk=128),
+    "one_group": dict(s=128, heads=8, groups=1, chunk=64),
+    "eight_groups": dict(s=128, heads=8, groups=8, chunk=64),
+    "two_heads_a_slab_of_128_lanes": dict(s=256, heads=4, groups=2, chunk=128, p=64, n=128, batch=1),
+    "a_starting_state": dict(s=150, heads=4, groups=2, chunk=32, state=True),
+    "a_strong_decay": dict(s=512, heads=2, groups=1, chunk=128, steep=True),
+}
+
+
+def kernel_case(name):
+    case = dict(KERNEL_CASES[name])
+    chunk, state, steep = case.pop("chunk"), case.pop("state", False), case.pop("steep", False)
+    x, dt, a, b, c = inputs(**case)
+    if steep:  # dt A near -16 a position: an unmasked exponent of a chunk's span would be e^2048
+        dt = jnp.full(dt.shape, 16.0) + 0.1 * dt
+        a = jnp.asarray([-1.0, -0.001])
+    batch, _, heads, p = x.shape
+    h0 = jax.random.normal(jax.random.key(5), (batch, heads, p, b.shape[-1])) if state else None
+    return (x, dt, a, b, c), h0, chunk
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_the_kernels_give_the_other_forms_values(name):
+    args, h0, chunk = kernel_case(name)
+    y, h = jax.jit(lambda *t: ssd_scan(*t, state=h0, chunk=chunk, interpret=True))(*args)
+    assert y.dtype == h.dtype == jnp.float32
+    assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(h).all())
+    for want, want_h in (plain(*args, h0), ssd_scan(*args, state=h0, chunk=chunk)):
+        assert y.shape == want.shape
+        np.testing.assert_allclose(y, want, rtol=1e-5, atol=2e-5 * max(1.0, float(jnp.abs(want).max())))
+        np.testing.assert_allclose(h, want_h, rtol=1e-5, atol=2e-5 * max(1.0, float(jnp.abs(want_h).max())))
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_the_kernels_give_the_other_forms_six_gradients(name):
+    args, h0, chunk = kernel_case(name)
+    x = args[0]
+    if h0 is None:
+        h0 = jnp.zeros((x.shape[0], x.shape[2], x.shape[3], args[3].shape[-1]))
+    probe_y = jax.random.normal(jax.random.key(9), x.shape)
+    probe_h = jax.random.normal(jax.random.key(10), h0.shape)
+
+    def grads(fn):
+        def loss(*t):
+            y, h = fn(*t)
+            return jnp.sum(y * probe_y) + jnp.sum(h * probe_h)
+        return jax.jit(jax.grad(loss, argnums=range(6)))(*args, h0)
+
+    got = grads(lambda *t: ssd_scan(*t[:5], state=t[5], chunk=chunk, interpret=True))
+    for other in (plain, lambda *t: ssd_scan(*t[:5], state=t[5], chunk=chunk)):
+        for which, g, w in zip(("x", "dt", "a", "b", "c", "state"), got, grads(other)):
+            assert g.shape == w.shape and bool(jnp.isfinite(g).all()), which
+            np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.abs(w).max()) + 1e-30, err_msg=which)
+
+
+def test_the_kernels_chain_two_sequences_through_the_state_into_the_whole():
+    args = inputs(200)
+    x, dt, a, b, c = args
+    whole, last = ssd_scan(*args, chunk=32, interpret=True)
+    first, mid = ssd_scan(x[:, :90], dt[:, :90], a, b[:, :90], c[:, :90], chunk=32, interpret=True)
+    second, end = ssd_scan(x[:, 90:], dt[:, 90:], a, b[:, 90:], c[:, 90:], state=mid, chunk=32, interpret=True)
+    np.testing.assert_allclose(jnp.concatenate([first, second], axis=1), whole, rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(end, last, rtol=1e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("what", ["values", "gradients"])
+def test_the_kernels_take_bfloat16_inputs_and_sum_in_float32(what):
+    x, dt, a, b, c = inputs(256, heads=4, groups=2, p=64, n=128, batch=1)
+    xb, bb, cb = (t.astype(jnp.bfloat16) for t in (x, b, c))
+    if what == "values":
+        want, want_h = ssd_scan(xb, dt, a, bb, cb)
+        y, h = ssd_scan(xb, dt, a, bb, cb, interpret=True)
+        assert y.dtype == h.dtype == jnp.float32
+        assert float(jnp.abs(y - want).max()) < 0.02 * float(jnp.abs(want).max())
+        assert float(jnp.abs(h - want_h).max()) < 0.02 * float(jnp.abs(want_h).max())
+        return
+    probe = jax.random.normal(jax.random.key(9), x.shape)
+    loss = lambda interpret: lambda *t: jnp.sum(ssd_scan(*t, interpret=interpret)[0] * probe)
+    want = jax.grad(loss(None), argnums=range(5))(xb, dt, a, bb, cb)
+    got = jax.grad(loss(True), argnums=range(5))(xb, dt, a, bb, cb)
+    assert [g.dtype for g in got] == [w.dtype for w in want] == [jnp.bfloat16, jnp.float32, jnp.float32, jnp.bfloat16, jnp.bfloat16]
+    for which, g, w in zip(("x", "dt", "a", "b", "c"), got, want):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(g - w).max()) < 0.04 * float(jnp.abs(w).max()), which
+
+
+def test_off_the_chip_the_programs_call_lowers_to_no_pallas_call():
+    """``interpret=None`` is the program's call: on the CPU the ``einsum`` form, and
+    the kernels only where a test asks for them."""
+    args = inputs(128)
+    assert "pallas_call" not in str(jax.make_jaxpr(lambda *t: ssd_scan(*t))(*args))
+    assert "pallas_call" in str(jax.make_jaxpr(lambda *t: ssd_scan(*t, interpret=True))(*args))
+
+
+def test_the_compiled_kernels_refuse_shapes_that_fill_no_tile():
+    with pytest.raises(ValueError, match="tile"):
+        ssd_scan(*inputs(128), interpret=False)
+    assert op._tiles(8, 64, 128, 128) and not op._tiles(8, 64, 128, 64) and not op._tiles(2, 8, 16, 128)

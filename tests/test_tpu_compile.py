@@ -237,12 +237,20 @@ def test_the_selective_scan_compiles_for_v5e(chip, direction):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_the_ssd_scan_compiles_for_v5e(chip, direction):
+@pytest.mark.parametrize("form", ["kernels", "einsum"])
+def test_the_ssd_scan_compiles_for_v5e(chip, form, direction):
     """Mamba-2's scan at the Nemotron-H causal tower's widths: one sequence of
     8192, 64 heads of 64 with a state of 128 in 8 groups, bf16 inputs and a
-    float32 step, chunk 128: batched products for the MXU, and nothing kept
-    for the backward pass but the inputs and the 64 boundary states (134 MB;
-    the states of every position would be 17 GB)."""
+    float32 step, chunk 128. The kernels (``interpret=False``: what the chip
+    runs since PR 54): one Pallas call forward and one more backward, and no
+    temporary but the 64 boundary states (134 MB) and the layouts around the
+    calls (the steps transposed, the state's two layouts): 0.20 GB forward and
+    0.34 GB with the backward pass, under a ceiling of 0.5 where the ``einsum``
+    form's was 2.0 (a whole mixer's forward and backward, compiled the same way:
+    1.15 GB of temporaries with the kernels, 2.36 with the ``einsum``s and their
+    masks). The ``einsum`` form (``interpret=None`` off the chip) stays in
+    the tree as the kernels' oracle and still compiles for the chip: batched
+    products for the MXU under its old ceiling."""
     from hypha_tpu.ops.ssd_scan import ssd_scan
 
     B, S, H, P, G, N = 1, 8192, 64, 64, 8, 128
@@ -254,13 +262,19 @@ def test_the_ssd_scan_compiles_for_v5e(chip, direction):
             sds((B, S, G, N), jnp.bfloat16), sds((B, S, G, N), jnp.bfloat16))
 
     def fwd(x, dt, a, b, c):
-        return ssd_scan(x, dt, a, b, c)[0]
+        return ssd_scan(x, dt, a, b, c, interpret=False if form == "kernels" else None)[0]
 
     fn = fwd if direction == "fwd" else jax.grad(lambda *t: fwd(*t).sum(), argnums=tuple(range(5)))
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
-    assert "convolution(" in text or "dot(" in text  # the products are the MXU's
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    if form == "kernels":
+        assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
+        assert "reduce-window" not in text  # the running sums are the kernels'
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    else:
+        assert "tpu_custom_call" not in text
+        assert "convolution(" in text or "dot(" in text  # the products are the MXU's
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
 # The leaves a worker's delta is made of, f32: Nemotron-H's Mamba-2
