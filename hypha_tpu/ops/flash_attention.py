@@ -1,6 +1,7 @@
 """Flash attention (forward + backward) as pallas TPU kernels.
 
-Online-softmax tiling: the grid is (batch·head, q-block, k-block); each cell
+Online-softmax tiling: the grid is (batch·head, q-block, k-block), or for a
+causal call (batch·head, the listed tiles: below); each cell
 loads one (block_q, d) Q tile, one (block_k, d) K tile and one (block_k, dv)
 V tile into VMEM (the value's width is its own: the output, its cotangent and
 dV are dv wide, the scores and their scale are the keys') — K/V
@@ -15,8 +16,13 @@ persists across the k-block iterations — the standard flash recurrence:
     l' = l·α + rowsum(exp(S_j − m'))
     acc' = acc·α + exp(S_j − m') V_j
 
-Causal cells strictly above the diagonal skip their compute via ``pl.when``
-(~half the FLOPs on causal LM shapes).
+A causal call's grid holds no tile above the diagonal or, with a window,
+outside the band: its two block axes are one flat axis over the list of the
+(q tile, k tile) pairs that ``_block_needed`` admits (``_needed_tiles``, built
+when the call is traced and handed to the kernel as prefetched scalars that
+the index maps read), so a tile that is not needed costs no grid step and no
+fetch (~half the steps on causal LM shapes, more under a window). A
+non-causal call needs every tile and keeps the dense grid.
 
 The backward is the standard recomputation scheme under ``jax.custom_vjp``
 (the reference's torch path gets this from SDPA; here it must exist for the
@@ -55,6 +61,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .attention import dot_product_attention
 from .index_select import GROUP as _GROUP  # keys behind one block of a selection's words
@@ -76,36 +83,67 @@ def _causal_mask(qi, kj, block_q, block_k, window=None):
 
 
 def _block_needed(qi, kj, block_q, block_k, window=None):
-    """False when the k tile lies strictly above the causal diagonal or,
-    with a ``window``, wholly below the band (its last key is no later than
-    the tile's first query minus the window)."""
+    """Which tiles a causal call's grid lists (``_needed_tiles``). False when
+    the k tile lies strictly above the causal diagonal or, with a ``window``,
+    wholly below the band (its last key is no later than the tile's first
+    query minus the window)."""
     needed = kj * block_k <= qi * block_q + block_q - 1
     if window is None:
         return needed
     return needed & (kj * block_k + block_k - 1 > qi * block_q - window)
 
 
-def _k_band(qi, block_q, block_k, window):
-    """First and last k tile that a q tile's band touches."""
-    lo = jnp.maximum(qi * block_q - window + 1, 0) // block_k
-    return lo, (qi * block_q + block_q - 1) // block_k
+def _needed_tiles(num_q, num_k, block_q, block_k, window, reps=None):
+    """A causal call's grid: the (q tile, k tile) pairs ``_block_needed`` admits
+    as two int32 vectors ``(outer, inner)``, a grid step an entry. Forward and
+    dq (``reps=None``) walk q-major, k ascending within a q tile. dk/dv walk
+    k-major, the inner entry ``rep * num_q + q tile`` ascending within a k tile:
+    all ``reps`` query heads of a key head, then their q tiles. An outer tile's
+    steps are adjacent, which is how ``_step`` finds its first and last."""
+    need = _block_needed(*np.indices((num_q, num_k)), block_q, block_k, window)
+    # A k tile past the last query (Sk > Sq) has no pair. It is listed once, under
+    # the last q tile, so that its dk and dv are written: every score of that
+    # step is masked and adds nothing to any sum.
+    need[-1] |= ~need.any(axis=0)
+    if reps is None:
+        outer, inner = np.nonzero(need)
+    else:
+        outer, rep, qi = np.nonzero(np.broadcast_to(need.T[:, None], (num_k, reps, num_q)))
+        inner = rep * num_q + qi
+    return jnp.asarray(outer, jnp.int32), jnp.asarray(inner, jnp.int32)
 
 
-def _k_index_map(kv, block_q, block_k, window):
-    """The k/v tiles' index map over a (batch·head, q tile, k tile) grid. With
-    a ``window``, a tile outside the band is held at the band's nearest tile:
-    a block index that does not change is not fetched again, so what the
-    kernel skips is not loaded either."""
-    if window is None:
-        return lambda b, i, j: (kv(b), j, 0)
-    return lambda b, i, j: (kv(b), jnp.clip(j, *_k_band(i, block_q, block_k, window)), 0)
+def _step(refs, listed, last_inner):
+    """Where a grid step stands: ``(outer tile, inner tile, starts, ends,
+    refs)``; ``starts()`` and ``ends()`` say whether the step is its outer
+    tile's first or last (asked where ``_init`` and ``_finalize`` are traced, so
+    a dense grid's program is the one it always was). On a dense grid the two
+    tiles are the grid's own indices. On a listed grid (``_needed_tiles``) the
+    first two refs are the lists, read at the one flat index, and an outer tile
+    starts and ends where the list's neighbouring entry differs."""
+    import jax.experimental.pallas as pl
+
+    if not listed:
+        i, j = pl.program_id(1), pl.program_id(2)
+        return i, j, lambda: j == 0, lambda: j == last_inner, refs
+    outer, inner, *refs = refs
+    t, last = pl.program_id(1), pl.num_programs(1) - 1
+    i = outer[t]
+    return (
+        i, inner[t],
+        lambda: (t == 0) | (outer[jnp.maximum(t - 1, 0)] != i),
+        lambda: (t == last) | (outer[jnp.minimum(t + 1, last)] != i),
+        refs,
+    )
 
 
-def _q_band(kj, block_q, block_k, window, num_q):
-    """First and last q tile whose band touches a k tile."""
-    lo = kj * block_k // block_q
-    hi = (kj * block_k + block_k - 2 + window) // block_q
-    return lo, jnp.minimum(hi, num_q - 1)
+def _index_map(listed, fn):
+    """``fn(batch·head, outer tile, inner tile)`` as the index map of the grid
+    in use: itself on a dense grid; on a listed one the two tiles are read from
+    the prefetched lists at the flat index."""
+    if not listed:
+        return fn
+    return lambda b, t, outer, inner: fn(b, outer[t], inner[t])
 
 
 _LANES = 128  # TPU vector lane width: row stats are carried lane-replicated
@@ -130,13 +168,13 @@ def _selected(sel_ref, kj, block_q, block_k):
     return jax.lax.shift_right_logical(_to_lanes(sel_ref[0], block_k), run) & 1 != 0
 
 
-def _sel_spec(block_q, block_k, heads_a_row):
-    """The block of a selection [B, S, W] for a (batch x head, q tile, k tile)
-    grid: the row's 128 words whose bits hold the k tile's keys, one row for
-    all ``heads_a_row`` heads of a query."""
+def _sel_spec(at, block_q, block_k, heads_a_row):
+    """The block of a selection [B, S, W] at (batch x head, q tile, k tile),
+    ``at`` the grid's ``_index_map``: the row's 128 words whose bits hold the k
+    tile's keys, one row for all ``heads_a_row`` heads of a query."""
     import jax.experimental.pallas as pl
 
-    return pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b // heads_a_row, i, j * block_k // _GROUP))
+    return pl.BlockSpec((1, block_q, _LANES), at(lambda b, i, j: (b // heads_a_row, i, j * block_k // _GROUP)))
 
 
 def _legal_block(block: int, dim: int) -> bool:
@@ -166,30 +204,21 @@ def _pick_block(dim: int, cap: int) -> int | None:
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, *rest, block_q, block_k, scale, causal, num_k, window=None,
-    selected=False,
+    *refs, block_q, block_k, scale, causal, num_k, window=None, selected=False,
 ):
     import jax.experimental.pallas as pl
 
+    # Causal: the grid lists the needed tiles and no other, so every step works.
+    qi, kj, starts, ends, (q_ref, k_ref, v_ref, *rest) = _step(refs, causal, num_k - 1)
     sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
+    @pl.when(starts())
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _run(fn):
-        # Non-causal: every tile contributes; causal: skip above-diagonal
-        # tiles (the DMA still happens — grids are dense — but the FLOPs
-        # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
-
-    @_run
     def _body():
         dv = acc_scr.shape[-1]  # the value's width, which need not be the keys'
         # Inputs stay in their storage dtype (bf16): the MXU runs bf16×bf16
@@ -202,7 +231,7 @@ def _fwd_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
-        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+        if selected:  # a subset of the causal pairs: the grid is the causal one
             s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         m = m_scr[...]  # [BQ, 128] lane-replicated
         m_new = jnp.maximum(m, s.max(axis=-1)[:, None])
@@ -218,7 +247,9 @@ def _fwd_kernel(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
-    @pl.when(kj == num_k - 1)
+    _body()
+
+    @pl.when(ends())
     def _finalize():
         d = o_ref.shape[-1]
         m = m_scr[...]
@@ -233,28 +264,19 @@ def _fwd_kernel(
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
-    block_q, block_k, scale, causal, num_k, window=None, selected=False,
+    *refs, block_q, block_k, scale, causal, num_k, window=None, selected=False,
 ):
     import jax.experimental.pallas as pl
 
+    qi, kj, starts, ends, refs = _step(refs, causal, num_k - 1)
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest = refs
     sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     dq_ref, dq_scr = rest
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
+    @pl.when(starts())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _run(fn):
-        # Non-causal: every tile contributes; causal: skip above-diagonal
-        # tiles (the DMA still happens — grids are dense — but the FLOPs
-        # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
-
-    @_run
     def _body():
         q = q_ref[0]  # bf16-in, f32-accumulate (see fwd kernel note)
         k = k_ref[0]
@@ -268,7 +290,7 @@ def _dq_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
-        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+        if selected:  # a subset of the causal pairs: the grid is the causal one
             s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
@@ -277,39 +299,32 @@ def _dq_kernel(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
 
-    @pl.when(kj == num_k - 1)
+    _body()
+
+    @pl.when(ends())
     def _finalize():
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
-    block_q, block_k, scale, causal, num_q, reps, window=None, selected=False,
+    *refs, block_q, block_k, scale, causal, num_q, reps, window=None, selected=False,
 ):
     import jax.experimental.pallas as pl
 
-    sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
-    dk_ref, dv_ref, dk_scr, dv_scr = rest
-
-    kj = pl.program_id(1)
-    # Innermost axis enumerates (query-head-in-group, q-block) pairs, so a
+    # The inner entry enumerates (query-head-in-group, q-block) pairs, so a
     # kv head's cotangent accumulates over ALL query heads sharing it (GQA)
     # in one scratch lifetime.
-    r = pl.program_id(2)
+    kj, r, starts, ends, refs = _step(refs, causal, reps * num_q - 1)
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest = refs
+    sel_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    dk_ref, dv_ref, dk_scr, dv_scr = rest
     qi = r % num_q
 
-    @pl.when(r == 0)
+    @pl.when(starts())
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _run(fn):
-        # Non-causal: every tile contributes; causal: skip above-diagonal
-        # tiles (the DMA still happens — grids are dense — but the FLOPs
-        # don't).
-        return pl.when(_block_needed(qi, kj, block_q, block_k, window))(fn) if causal else fn()
-
-    @_run
     def _body():
         q = q_ref[0]  # bf16-in, f32-accumulate (see fwd kernel note)
         k = k_ref[0]
@@ -323,7 +338,7 @@ def _dkv_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, kj, block_q, block_k, window), s, _NEG_INF)
-        if selected:  # a subset of the causal pairs: the tiles above the diagonal stay skipped
+        if selected:  # a subset of the causal pairs: the grid is the causal one
             s = jnp.where(_selected(sel_ref, kj, block_q, block_k), s, _NEG_INF)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)  # [BQ, BK]
         pc = p.astype(do.dtype)
@@ -332,25 +347,40 @@ def _dkv_kernel(
         ds = (p * (dp - delta)).astype(q.dtype)
         dk_scr[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
-    @pl.when(r == reps * num_q - 1)
+    _body()
+
+    @pl.when(ends())
     def _finalize():
         # s was scaled after the QKᵀ dot, so dk = dsᵀ·q still needs ·scale.
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _tpu_kwargs(interpret: bool) -> dict:
+def _tpu_kwargs(interpret: bool, semantics=("parallel", "parallel", "arbitrary")) -> dict:
     """pallas_call kwargs carrying the TPU dimension_semantics (batch·head
-    and the outer block axis parallel, the reduction axis arbitrary); the
-    interpreter takes none."""
+    and the outer block axis parallel, the reduction axis arbitrary; a listed
+    grid's one flat axis is the reduction's too); the interpreter takes none."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=semantics)}
+
+
+def _grid(interpret, lists, grid, **specs) -> dict:
+    """pallas_call's kwargs for a grid: ``lists=None`` is the dense
+    ``(batch·head, outer tile, inner tile)``; with ``_needed_tiles``' lists the
+    two tile axes are the one flat axis over them, and the lists are the call's
+    first two operands."""
+    if lists is None:
+        return {"grid": grid, **specs, "interpret": interpret, **_tpu_kwargs(interpret)}
+    from jax.experimental.pallas import tpu as pltpu
+
     return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
+        "grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(grid[0], lists[0].shape[0]), **specs),
+        "interpret": interpret,
+        **_tpu_kwargs(interpret, ("parallel", "arbitrary")),
     }
 
 
@@ -372,24 +402,22 @@ def _fwd_impl(
     """q: [B·H, S, D], k: [B·Hkv, S, D], v: [B·Hkv, S, Dv] → (o [B·H, Sq, Dv],
     lse f32 [B·H, Sq, 128] lane-replicated — see layout note in module doc).
 
-    ``window=None`` builds the call as it was before the window existed
-    (``_k_index_map`` has what a window changes), and ``selection=None`` as it
-    was before a selection did: a selection [B, S, W] is one more input, its
-    block shared by a row's heads."""
+    A causal call walks the tiles ``_needed_tiles`` lists; a non-causal one is
+    built as it always was. A selection [B, S, W] is one more input, its block
+    shared by a row's heads."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
     seq_k, dv = k.shape[1], v.shape[-1]
     num_q, num_k = seq_q // block_q, seq_k // block_k
-    grid = (bh, num_q, num_k)
     kv = _kv_index(n_heads, n_kv)
-    kwargs = _tpu_kwargs(interpret)
+    lists = _needed_tiles(num_q, num_k, block_q, block_k, window) if causal else None
+    at = functools.partial(_index_map, causal)
     band = {} if window is None else {"window": window}
-    k_map = _k_index_map(kv, block_q, block_k, window)
-    picked, sel_specs = {}, []
+    picked, sel, sel_specs = {}, [], []
     if selection is not None:
-        picked, sel_specs = {"selected": True}, [_sel_spec(block_q, block_k, n_heads)]
+        picked, sel, sel_specs = {"selected": True}, [selection], [_sel_spec(at, block_q, block_k, n_heads)]
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel,
@@ -401,29 +429,29 @@ def _fwd_impl(
             **band,
             **picked,
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), k_map),
-            pl.BlockSpec((1, block_k, dv), k_map),
-            *sel_specs,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(q, k, v, *([] if selection is None else [selection]))
+        **_grid(
+            interpret, lists, (bh, num_q, num_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), at(lambda b, i, j: (b, i, 0))),
+                pl.BlockSpec((1, block_k, d), at(lambda b, i, j: (kv(b), j, 0))),
+                pl.BlockSpec((1, block_k, dv), at(lambda b, i, j: (kv(b), j, 0))),
+                *sel_specs,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, dv), at(lambda b, i, j: (b, i, 0))),
+                pl.BlockSpec((1, block_q, _LANES), at(lambda b, i, j: (b, i, 0))),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ],
+        ),
+    )(*(lists or ()), q, k, v, *sel)
 
 
 def _bwd_impl(
@@ -444,17 +472,17 @@ def _bwd_impl(
     reps = n_heads // n_kv
     kv = _kv_index(n_heads, n_kv)
 
+    lists = _needed_tiles(num_q, num_k, block_q, block_k, window) if causal else None
+    at = functools.partial(_index_map, causal)
     band = {} if window is None else {"window": window}
-    k_map = _k_index_map(kv, block_q, block_k, window)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), k_map)
-    o_spec = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
-    v_spec = pl.BlockSpec((1, block_k, dv), k_map)
-    row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
-    kwargs = _tpu_kwargs(interpret)
+    q_spec = pl.BlockSpec((1, block_q, d), at(lambda b, i, j: (b, i, 0)))
+    k_spec = pl.BlockSpec((1, block_k, d), at(lambda b, i, j: (kv(b), j, 0)))
+    o_spec = pl.BlockSpec((1, block_q, dv), at(lambda b, i, j: (b, i, 0)))
+    v_spec = pl.BlockSpec((1, block_k, dv), at(lambda b, i, j: (kv(b), j, 0)))
+    row_spec = pl.BlockSpec((1, block_q, _LANES), at(lambda b, i, j: (b, i, 0)))
     picked, sel, sel_specs = {}, [], []
     if selection is not None:
-        picked, sel, sel_specs = {"selected": True}, [selection], [_sel_spec(block_q, block_k, n_heads)]
+        picked, sel, sel_specs = {"selected": True}, [selection], [_sel_spec(at, block_q, block_k, n_heads)]
 
     dq = pl.pallas_call(
         functools.partial(
@@ -467,14 +495,14 @@ def _bwd_impl(
             **band,
             **picked,
         ),
-        grid=(bh, num_q, num_k),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec, *sel_specs],
-        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
-    )(q, k, v, o, do, lse, *sel)
+        **_grid(
+            interpret, lists, (bh, num_q, num_k),
+            in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec, *sel_specs],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+    )(*(lists or ()), q, k, v, o, do, lse, *sel)
 
     # dk/dv: grid over KV heads; k-block outer, (rep, q-block) inner. Index
     # maps see (b_kv, kj, r) with r = rep·num_q + qi; the q-side tensors map
@@ -484,22 +512,17 @@ def _bwd_impl(
             return b
         return (b // n_kv) * n_heads + (b % n_kv) * reps + r // num_q
 
-    def q_tile(j, r):
-        i = r % num_q
-        if window is not None:
-            i = jnp.clip(i, *_q_band(j, block_q, block_k, window, num_q))
-        return i
-
-    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, r: (qh(b, r), q_tile(j, r), 0))
-    k_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
-    o_spec_t = pl.BlockSpec((1, block_q, dv), lambda b, j, r: (qh(b, r), q_tile(j, r), 0))
-    v_spec_t = pl.BlockSpec((1, block_k, dv), lambda b, j, r: (b, j, 0))
+    lists = _needed_tiles(num_q, num_k, block_q, block_k, window, reps) if causal else None
+    q_spec_t = pl.BlockSpec((1, block_q, d), at(lambda b, j, r: (qh(b, r), r % num_q, 0)))
+    k_spec_t = pl.BlockSpec((1, block_k, d), at(lambda b, j, r: (b, j, 0)))
+    o_spec_t = pl.BlockSpec((1, block_q, dv), at(lambda b, j, r: (qh(b, r), r % num_q, 0)))
+    v_spec_t = pl.BlockSpec((1, block_k, dv), at(lambda b, j, r: (b, j, 0)))
     row_spec_t = pl.BlockSpec(
-        (1, block_q, _LANES), lambda b, j, r: (qh(b, r), q_tile(j, r), 0)
+        (1, block_q, _LANES), at(lambda b, j, r: (qh(b, r), r % num_q, 0))
     )
     if selection is not None:
         sel_specs = [pl.BlockSpec(
-            (1, block_q, _LANES), lambda b, j, r: (b // n_kv, q_tile(j, r), j * block_k // _GROUP))]
+            (1, block_q, _LANES), at(lambda b, j, r: (b // n_kv, r % num_q, j * block_k // _GROUP)))]
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel,
@@ -512,20 +535,20 @@ def _bwd_impl(
             **band,
             **picked,
         ),
-        grid=(bh_kv, num_k, reps * num_q),
-        in_specs=[q_spec_t, k_spec_t, v_spec_t, o_spec_t, o_spec_t, row_spec_t, *sel_specs],
-        out_specs=[k_spec_t, v_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, seq_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh_kv, seq_k, dv), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(q, k, v, o, do, lse, *sel)
+        **_grid(
+            interpret, lists, (bh_kv, num_k, reps * num_q),
+            in_specs=[q_spec_t, k_spec_t, v_spec_t, o_spec_t, o_spec_t, row_spec_t, *sel_specs],
+            out_specs=[k_spec_t, v_spec_t],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
+            ],
+        ),
+    )(*(lists or ()), q, k, v, o, do, lse, *sel)
     return dq, dk, dv
 
 
@@ -634,16 +657,20 @@ def flash_attention(
     existed.
 
     ``window`` (causal only) is local attention: query i sees keys in
-    (i - window, i]. Tiles wholly outside that band are neither computed nor
-    loaded, in the forward and both backward kernels, so a window layer at
-    S > window costs about (window + block) / S of a full layer. ``None``, or
-    a window that no query of this length reaches, is the program without it.
+    (i - window, i]. Tiles wholly outside that band are no step of the grid,
+    in the forward and both backward kernels, so a window layer costs its
+    band's tiles: about (window + block) / block a row of q tiles where the
+    full triangle has (S / block + 1) / 2 (at S 8192 and the default tiles 154
+    steps a head for a window of 2048 and 77 for one of 512, against 280).
+    ``None``, or a window that no query of this length reaches, is the program
+    without it.
 
     ``selection`` (causal self-attention, no window) is a mask that is data: a
     packed bit a (query, key) pair (``ops/index_select.py``), one row of it for
     all the heads of a query. The three kernels apply it beside the causal
-    mask; a tile below the diagonal cannot be skipped on it (picked keys are
-    scattered), a tile above still is. The call then returns ``(o, lse)`` with
+    mask; a tile below the diagonal cannot be left out on it (picked keys are
+    scattered), a tile above is no step of the grid, as in any causal call. The
+    call then returns ``(o, lse)`` with
     ``lse`` float32 [B, H, Sq], the log-sum-exp over the picked keys, which the
     indexer's objective reads; ``o`` alone is differentiated. With no selection
     the calls are built as they were before one existed.

@@ -83,24 +83,27 @@ def test_a_window_needs_causal_self_attention():
         flash_attention(q, k, v, causal=False, window=32, interpret=True)
 
 
-# The traced program of the kernels at window=None, forward and backward, as
-# the commit before the window existed traces them (2778437: the same script
-# run on both trees). A Mistral cell runs these; they must not move.
-BEFORE_THE_WINDOW = {
-    "fwd": "97a7444773defa02eb5c7c11e9f344430a8eb2dc754b101c63bda06bb7039bd0",
-    "bwd": "45b695bb2d000c79303f6344a56bc6afe124a40d94810657917abf130cc38551",
+# The traced program of the kernels at window=None, forward and backward. Until
+# PR 56 these were the hashes of the commit before the window existed (2778437):
+# a window was an addition beside a program that did not move. PR 56 changed every
+# causal call's grid on purpose (the needed tiles listed, with or without a
+# window), so the hashes are PR 56's tree's (the same script) and hold later PRs
+# to it: a Mistral cell runs these; they must not move unnoticed.
+WITHOUT_A_WINDOW_AT_PR_56 = {
+    "fwd": "15df38448bc2dd77e12e96a20292dcc60891b6c5f4c731f3e69ebf39f656269b",
+    "bwd": "e2e7b7d417e20554f593c6679d399c27a37d2be2c4526c0f814248f27ed27d51",
 }
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_without_a_window_the_kernels_trace_to_the_program_before_it_existed(direction):
+def test_without_a_window_the_kernels_trace_to_the_program_of_pr_56(direction):
     q, kv = jnp.zeros((1, 256, 4, 32), jnp.bfloat16), jnp.zeros((1, 256, 2, 32), jnp.bfloat16)
     f = lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64, interpret=False)
     if direction == "bwd":
         fwd = f
         f = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(f)(q, kv, kv)))
-    assert hashlib.sha256(text.encode()).hexdigest() == BEFORE_THE_WINDOW[direction]
+    assert hashlib.sha256(text.encode()).hexdigest() == WITHOUT_A_WINDOW_AT_PR_56[direction]
 
 
 def test_the_scope_of_a_windowed_call_names_its_window():

@@ -71,7 +71,7 @@ FLASH_SHAPES = {
 }
 
 
-def _flash_case(shape, direction, sharding=None):
+def _flash_case(shape, direction, sharding=None, causal=True):
     """(function, its three arguments' shapes) of one entry and direction."""
     B, S, H, Hkv, D, window, *value = FLASH_SHAPES[shape]
     Dv = value[0] if value else D
@@ -80,7 +80,7 @@ def _flash_case(shape, direction, sharding=None):
     v = jax.ShapeDtypeStruct((B, S, Hkv, Dv), jnp.bfloat16, sharding=sharding)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False, window=window)
+        return flash_attention(q, k, v, causal=causal, interpret=False, window=window)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
@@ -97,33 +97,41 @@ def test_flash_attention_compiles_for_v5e(chip, shape, direction):
 
 
 # --------------------------------------------------------------------------
-# Where the value is as wide as the keys, the calls are the parent's
+# Where the value is as wide as the keys, the calls are PR 56's
 # --------------------------------------------------------------------------
 
 # The kernel learnt a value width of its own (PR 48). Every cell but Phi-4's
 # hands it values as wide as its keys and must run the program it ran: the text
 # of the traced calls (grid, blocks, scratch, compiler parameters, the kernels'
 # bodies) and of every block's index map, hashed by this file run as a script
-# (``PYTHONPATH=<a checkout> python tests/test_tpu_compile.py``) on commit
-# bb23a3c and on this tree. No line number or file path stands in that text.
+# (``PYTHONPATH=<a checkout> python tests/test_tpu_compile.py``). No line number
+# or file path stands in that text. The table was taken on bb23a3c and held
+# every PR to that commit's programs until PR 56 changed every causal call's
+# grid on purpose (the needed tiles listed, ``_needed_tiles``): it is taken
+# again on PR 56's tree and holds later PRs to PR 56's programs. A call that
+# needs every tile is still the program it was: the non-causal hash below is
+# a27e5e2's, the commit PR 56 started from, and this tree's.
 CALLS_AT_THE_PARENT = {
-    ("gpt2", "fwd"): "2a8eb64fbf599a7bf9ca2eb2a47e713e295c1189c05efa66ea0bc5594e872f6a",
-    ("gpt2", "bwd"): "71d7ce239e29221bad84545778b21cd0da0c19a66c78aaf0b19d717cb52b8ad9",
-    ("mistral", "fwd"): "ca09052257d3e1082f09d9f1b1bd2957bbec044d49928dbd3bf1b7995a76d9b1",
-    ("mistral", "bwd"): "3dfbba122885388b10b902824238c907e4e6521c4d31d3e0b666eb605e956b86",
-    ("trinity_window", "fwd"): "411c9d4d820e4491a4b9a1fcbd76e8cbf17bf0ba4d4cfe332938757499296875",
-    ("trinity_window", "bwd"): "13ad52f842f37cf8a3baa02915de88c34eb02921fd5e42e414ef740b129272ff",
-    ("trinity_full", "fwd"): "6c6bc3febbc62b9932e1d1172349566a452a4610fe0c536e51c9e9af08283ad4",
-    ("trinity_full", "bwd"): "1f991357dab9d64505b229341e4691d81978abf409e3a7a580181100c0f87195",
-    ("lfm2_full", "fwd"): "f775895e03643c94c2ae5f440105b974245fbe86acd52f8b052384a8a61701c8",
-    ("lfm2_full", "bwd"): "b4cbafc26178f49ef32773f999a2e86d8b028fadef9fec137e68919834a7478c",
-    ("phi4_window", "fwd"): "d9f9edfb8482035b6f29fda15d8de06674a8ce4432a344bb085e259b46565bc1",
-    ("phi4_window", "bwd"): "d4b5ec02499bd7b1351ba174832371e00fbb204c26cf4ae0f49f583aa73d870b",
-    ("phi4_full", "fwd"): "e36c51502b6ac6f4fbd300cb3e76efa1c60f38fe3cdaf6b36a9a8c5a4246a5bd",
-    ("phi4_full", "bwd"): "6e6d063d0427595882667eba680b7d8e8388656f4aa05a193fce869c0d614a93",
-    ("nemotron_full", "fwd"): "371b93ad82e2ebdbde17996760d8f9eff5bd65f3b07c89ff719ebf3c166b671f",
-    ("nemotron_full", "bwd"): "d0ea65089428f74a2ef1776d92565efb54d8b89fa5f655be36a11e6c28006885",
+    ("gpt2", "fwd"): "893298effade8281dcc595d76701ba982b30b512f787e03a59f4cfd6863b1c45",
+    ("gpt2", "bwd"): "2e1ef2695f65e8172828f409855bb86dabfb30c09e1a061d4c90d2277e96a7db",
+    ("mistral", "fwd"): "d1165babc702d32dbde1bec91638e4184ee958d2da8ca0e5dc92acb4a7a9f564",
+    ("mistral", "bwd"): "8386f53e5021d46c2456b52b6958da104b2d3e563425370b4b73917e1e6c5232",
+    ("trinity_window", "fwd"): "53444298fea23eea868130d91154e4e54431a273474064f7cb5cf12943982aef",
+    ("trinity_window", "bwd"): "fb90a998e205eaa2b65cfaac3360fc8037c2fe1789564738a98b8bb2f1e69101",
+    ("trinity_full", "fwd"): "dc2c488d228fc1f6a7deeb9af59dc93af9349923905481870a983c36af4d0831",
+    ("trinity_full", "bwd"): "0436fd9fb2feb3ae8a5938bb0a214df732b527577fb408899a5db2f15334e2e1",
+    ("lfm2_full", "fwd"): "d493080376ab63113b28e12ddfd6ad04ac634b96b9a9c4c351837aff15ae40ba",
+    ("lfm2_full", "bwd"): "7af3165b6154ef5ef39788c01371ce6679406e17e47685460070dd945fcb7557",
+    ("phi4_window", "fwd"): "6001fc70e528f5e442b8b3d6f69b0c3dd2fb1c8b67a1a4ae5e816e930bebbf88",
+    ("phi4_window", "bwd"): "2fa7a43ba80a6ed94a898f1ec9958669f72bffd543307d56b70139f7cf2c9714",
+    ("phi4_full", "fwd"): "2376ac9eb12b8c0362ae71e78fba5fbb867966315c11cf5a5faee2c5a1416e43",
+    ("phi4_full", "bwd"): "5273aa08a0cc4d2b00565328ef7fff71c5b9d5202e8610efcd87e278d54587a3",
+    ("nemotron_full", "fwd"): "f926de99ecc665ff00691242213405feefa46ec6bdd494bf700c1d69d7df3f55",
+    ("nemotron_full", "bwd"): "a9004f433e754697f2cf0648851877c39a7a331fda6461f3ebd0a416cd183a9d",
 }
+
+
+EVERY_TILE_AT_THE_PARENT = "5b66ece72f6372bdc28a0a62ff0433e3d191cf3f7c237539df95d6bbb541e50b"
 
 
 def _pallas_calls(jaxpr):
@@ -134,8 +142,8 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(inner)
 
 
-def _calls_hash(shape, direction) -> str:
-    fn, args = _flash_case(shape, direction)
+def _calls_hash(shape, direction, causal=True) -> str:
+    fn, args = _flash_case(shape, direction, causal=causal)
     traced = jax.make_jaxpr(fn)(*args)
     maps = [str(m.index_map_jaxpr) for call in _pallas_calls(traced.jaxpr)
             for m in call.params["grid_mapping"].block_mappings]
@@ -146,6 +154,12 @@ def _calls_hash(shape, direction) -> str:
 @pytest.mark.parametrize("shape", [s for s, dims in FLASH_SHAPES.items() if len(dims) == 6])
 def test_equal_widths_trace_to_the_calls_of_the_parent_commit(shape, direction):
     assert _calls_hash(shape, direction) == CALLS_AT_THE_PARENT[shape, direction]
+
+
+def test_a_call_that_needs_every_tile_traces_to_the_calls_of_the_parent_commit():
+    """Mistral's shape without the causal mask, forward and the two backward
+    kernels: the dense grid, as before PR 56."""
+    assert _calls_hash("mistral", "bwd", causal=False) == EVERY_TILE_AT_THE_PARENT
 
 
 # One rank's routed experts as three cells run them: tokens, width, expert
@@ -397,3 +411,4 @@ if __name__ == "__main__":  # the hashes, for CALLS_AT_THE_PARENT
         for way in ("fwd", "bwd"):
             if len(dims) == 6:
                 print(f'    ("{name}", "{way}"): "{_calls_hash(name, way)}",')
+    print(f'EVERY_TILE_AT_THE_PARENT = "{_calls_hash("mistral", "bwd", causal=False)}"')
